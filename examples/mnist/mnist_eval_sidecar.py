@@ -22,9 +22,6 @@ import sys
 sys.path.insert(0, os.path.abspath(os.path.join(
     os.path.dirname(__file__), os.pardir, os.pardir)))
 
-from tensorflowonspark_tpu.utils.platform_env import drop_remote_plugin
-drop_remote_plugin()
-
 
 def main_fn(args, ctx):
   import os

@@ -931,6 +931,12 @@ def run(engine: Engine, main_fn, tf_args=None,
   if feed_target_bytes is not None and int(feed_target_bytes) < 0:
     raise ValueError("feed_target_bytes must be >= 0, got %r"
                      % (feed_target_bytes,))
+  if feed_transport not in ("auto", "shm", "queue"):
+    raise ValueError("feed_transport must be 'auto', 'shm' or 'queue', "
+                     "got %r" % (feed_transport,))
+  # an EXPLICIT 'shm' that a node cannot honour is an error at the driver;
+  # only 'auto' may settle for the queue (node bring-up checks this flag)
+  feed_transport_strict = feed_transport == "shm"
   if feed_transport == "auto":
     # shared-memory rings require the feeder task and the node to share a
     # host, which only engines with colocated executors guarantee; the
@@ -1020,6 +1026,7 @@ def run(engine: Engine, main_fn, tf_args=None,
       # shared-memory ring for the input stream; single host or per-host).
       # The default "auto" resolved above: shm on colocated engines.
       "feed_transport": feed_transport,
+      "feed_transport_strict": feed_transport_strict,
       # rows per feed chunk: one codec envelope / ring payload per chunk —
       # the transport batching unit AND the columnar assembly granularity
       "feed_chunk_size": feed_chunk_size,

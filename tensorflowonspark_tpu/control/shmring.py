@@ -35,16 +35,35 @@ _lib_tried = False
 
 
 def _compile(so: str) -> bool:
+  # build beside the target and rename into place: several executors of
+  # one host reach their first use together, and a half-written .so must
+  # never be what another process dlopens
+  tmp = "%s.%d.tmp" % (so, os.getpid())
   try:
     # -lrt: shm_open/shm_unlink live in librt on older glibc; linking it
     # explicitly is harmless where they moved into libc
     subprocess.run(["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                    "-o", so, os.path.abspath(_SRC_PATH), "-lrt"],
+                    "-o", tmp, os.path.abspath(_SRC_PATH), "-lrt"],
                    check=True, capture_output=True, timeout=120)
+    os.replace(tmp, so)
     return True
   except (OSError, subprocess.SubprocessError) as e:
     logger.warning("shmring native build failed: %s", e)
+    if os.path.exists(tmp):
+      os.unlink(tmp)
     return False
+
+
+def rebuild() -> bool:
+  """Build the native ring from ``native/shmring.cpp`` NOW, replacing any
+  library already on disk — so that what runs is what the source says (the
+  chip smoke starts here: the ``.so`` is git-ignored and may be stale).
+  Call before this process first uses the ring."""
+  global _lib, _lib_tried
+  if not _compile(os.path.abspath(_SO_PATH)):
+    return False
+  _lib, _lib_tried = None, False
+  return available()
 
 
 def _bind(so: str):

@@ -199,6 +199,18 @@ def _rotary(x, positions):
       [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _flash_tiles(seq_len: int) -> bool:
+  """Whether ``seq_len`` divides into blocks the flash kernels can take:
+  whole 128-blocks, or — below 128, where the one block IS the sequence —
+  a multiple of 8. The second clause is the chip's: Mosaic refuses a
+  block whose second-minor dim is not sublane-aligned ("cannot statically
+  prove that index in dimension 1 is a multiple of 8", first seen when a
+  63-token prompt reached the prefill kernel on a v5e); interpret mode
+  never minded."""
+  return seq_len >= 8 and seq_len % 8 == 0 \
+      and seq_len % min(128, seq_len) == 0
+
+
 def _flash_eligible(cfg: TransformerConfig, seq_len: int) -> bool:
   """Whether the Pallas flash kernel should handle this attention.
 
@@ -210,7 +222,7 @@ def _flash_eligible(cfg: TransformerConfig, seq_len: int) -> bool:
   """
   if cfg.attention_impl == "dense":
     return False
-  divisible = seq_len % min(128, max(1, seq_len)) == 0
+  divisible = _flash_tiles(seq_len)
   if cfg.attention_impl == "flash":
     if not divisible:
       # forcing must be honest: never silently degrade to dense
@@ -383,10 +395,16 @@ class Attention(nn.Module):
     else:
       if _flash_eligible(cfg, q.shape[1]):
         # the flash kernels consume grouped KV natively (grouped-aware
-        # BlockSpec; cross-head dK/dV accumulation in the backward grid)
-        from tensorflowonspark_tpu.ops import flash_attention
-        out = flash_attention(q, k, v, causal=True, interpret=interp,
-                              window=cfg.attention_window or None)
+        # BlockSpec; cross-head dK/dV accumulation in the backward grid).
+        # Under a >1-device mesh the kernel maps per shard: the TPU
+        # compiler refuses to partition a Mosaic kernel on its own
+        win = cfg.attention_window or None
+        if self.mesh is None or self.mesh.size == 1:
+          out = ops.flash_attention(q, k, v, causal=True, interpret=interp,
+                                    window=win)
+        else:
+          out = ops.flash_attention_sharded(q, k, v, self.mesh, causal=True,
+                                            interpret=interp, window=win)
       else:
         # the dense reference attends at full head count: broadcast each
         # KV head to its query group (XLA fuses the repeat)
@@ -574,7 +592,7 @@ class Attention(nn.Module):
     if not vec and heads_consistent and seg > 1 \
         and cfg.attention_impl != "dense":
       ecfg = cfg
-      if cfg.attention_impl == "flash" and seg % min(128, seg) != 0:
+      if cfg.attention_impl == "flash" and not _flash_tiles(seg):
         # serving accepts arbitrary prompt lengths the caller doesn't
         # block-align; degrade forced-flash to "auto" for this internal
         # shape rather than raise (the _generate_fn precedent)
@@ -589,19 +607,11 @@ class Attention(nn.Module):
         if single:
           return flash_attention(q, k, v, causal=True, interpret=interp,
                                  window=win).astype(q.dtype)
-        from tensorflowonspark_tpu.utils.compat import jax_shard_map as shard_map
-        from jax.sharding import PartitionSpec as P
-        batch_axes = mesh_lib.data_axes(self.mesh) or None
-        t_ax = mesh_lib.AXIS_TENSOR \
-            if _heads_logical(hk, self.mesh) == "heads" else None
-        spec = P(batch_axes, None, t_ax, None)
-        fn = shard_map(
-            lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=True,
-                                               interpret=interp,
-                                               window=win),
-            mesh=self.mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-        return fn(q, k, v).astype(q.dtype)
+        # heads_consistent (above) is what flash_attention_sharded's
+        # own both-divide rule needs to shard heads here
+        return ops.flash_attention_sharded(
+            q, k, v, self.mesh, causal=True, interpret=interp,
+            window=win).astype(q.dtype)
 
       out = lax.cond(idx == 0, _flash_prefill, _dense_attend, None)
     else:
@@ -1005,7 +1015,7 @@ def _generate_fn(cfg: TransformerConfig, plen: int, num_steps: int):
   generate calls reuse one compilation and params are never baked in as
   compile-time constants."""
   total = plen + num_steps
-  if cfg.attention_impl == "flash" and total % min(128, max(1, total)) != 0:
+  if cfg.attention_impl == "flash" and not _flash_tiles(total):
     # the generation buffer's length (plen + num_steps) is an internal
     # shape callers don't control block-alignment of — a forced-flash
     # model must still generate, so degrade to "auto" here (flash when

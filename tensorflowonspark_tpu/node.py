@@ -31,7 +31,8 @@ import traceback
 from typing import Dict, List, Optional
 
 from tensorflowonspark_tpu.control import feedhub, rendezvous
-from tensorflowonspark_tpu.utils import hostinfo, paths, tpu_info
+from tensorflowonspark_tpu.utils import (compile_cache, hostinfo, paths,
+                                         platform_env, tpu_info)
 
 logger = logging.getLogger(__name__)
 
@@ -42,14 +43,6 @@ HUB_ADDR_FILE = "hub_addr"
 
 #: pins the per-node coordinator/collectives port (env registry: TOS008)
 ENV_NODE_PORT = "TOS_TPU_NODE_PORT"
-
-#: directory for JAX's persistent compilation cache, applied at node
-#: bring-up in whichever process runs the user fn — relaunched/persistent
-#: executors then LOAD their jitted programs instead of recompiling them
-#: (cache hits are surfaced as ``xla.cache_hits``, never counted as
-#: fresh compiles — obs/device.py). Unset = no persistent cache.
-#: (env registry: TOS008)
-ENV_COMPILE_CACHE = "TOS_COMPILE_CACHE"
 
 #: feeder byte budget per wire envelope: when set (> 0), feeders size
 #: chunks adaptively from observed encoded bytes/row instead of the fixed
@@ -64,40 +57,6 @@ ENV_FEED_TARGET_BYTES = "TOS_FEED_TARGET_BYTES"
 #: dominate) nor more than the cap (consumer-side latency + memory)
 _ADAPT_MIN_ROWS = 16
 _ADAPT_MAX_ROWS = 8192
-
-
-def _setup_compile_cache() -> bool:
-  """Point JAX's persistent compilation cache at ``TOS_COMPILE_CACHE``.
-
-  Called at node bring-up in the process that runs the user main fn
-  (both the foreground FILES-mode path and the spawned background
-  runner) BEFORE any jit. Zero work — and no jax import — when the env
-  is unset, so feeder tasks and bare executors never pay it. The
-  min-compile-time / min-entry-size floors drop to 0 so even the small
-  CPU-harness programs cache: the knob's whole point is that a
-  supervised relaunch (or the next run of a persistent executor) skips
-  its recompiles.
-  """
-  cache_dir = os.environ.get(ENV_COMPILE_CACHE)
-  if not cache_dir:
-    return False
-  try:
-    os.makedirs(cache_dir, exist_ok=True)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-      try:
-        jax.config.update(knob, val)
-      except Exception:  # noqa: BLE001 - knob renamed on this jax
-        pass
-    logger.info("persistent compilation cache at %s", cache_dir)
-    return True
-  except Exception as e:  # noqa: BLE001 - a broken cache dir must not
-    # fail bring-up; the node just compiles as before
-    logger.warning("compilation cache setup failed (%s); continuing "
-                   "without it", e)
-    return False
 
 
 #: env values _apply_node_env exported in THIS (persistent) executor
@@ -415,10 +374,9 @@ def _background_runner(fn_bytes: bytes, tf_args, ctx_kwargs: dict,
   driver's supervisor declares the node dead.
   """
   import cloudpickle
-  # the background runner is the process that jits: point JAX's
-  # persistent compilation cache (TOS_COMPILE_CACHE) here, before the
-  # user fn's first compile
-  _setup_compile_cache()
+  # the background runner is the process that jits: place JAX's
+  # persistent compilation cache before the user fn's first compile
+  compile_cache.setup()
   hub = feedhub.connect(tuple(hub_addr), authkey)
   sender = None
   if server_addr and heartbeat_interval:
@@ -453,6 +411,28 @@ def _background_runner(fn_bytes: bytes, tf_args, ctx_kwargs: dict,
       hub.set("state", "stopped")
     except Exception:  # noqa: BLE001
       pass
+
+
+def _refuse_if_chip_held(executor_id: int) -> None:
+  """One process per chip: the background runner needs the chip, so the
+  executor process spawning it must not hold one.
+
+  Nothing in bring-up initialises a JAX backend here (the compile cache
+  helper only configures; the obs device tier lives in the runner), but an
+  EARLIER task on this persistent executor may have — a
+  ``TFModel.transform`` or any in-process jit. The child would then fail
+  or hang inside libtpu; say so instead."""
+  if "jax" not in sys.modules or not platform_env.backend_initialized():
+    return
+  import jax
+  if jax.default_backend() == "tpu":
+    raise RuntimeError(
+        "executor %d already initialised JAX on the TPU in this process "
+        "(an earlier task on this persistent executor jitted here, e.g. "
+        "TFModel.transform), so the ENGINE-mode node child it must spawn "
+        "can never take the chip its parent holds. Run the cluster on "
+        "fresh executors, or use InputMode.FILES (the user fn then runs "
+        "in the executor process itself)" % executor_id)
 
 
 def make_node_fn(main_fn, tf_args, cluster_meta: dict):
@@ -539,9 +519,18 @@ def make_node_fn(main_fn, tf_args, cluster_meta: dict):
                                                64 * 1024 * 1024))
         shmring.hold(executor_id, ring)
         hub.set("ring_name", ring_name)
+      elif meta.get("feed_transport_strict"):
+        # the caller ASKED for shm: a silent queue fallback would hand
+        # them a different transport than the one they are measuring
+        raise RuntimeError(
+            "feed_transport='shm' was requested but the native ring "
+            "(native/shmring.cpp, built with g++ on first use) is "
+            "unavailable on executor %d; use feed_transport='auto' to "
+            "let the node choose" % executor_id)
       else:
-        logger.warning("feed_transport='shm' requested but native ring "
-                       "unavailable; falling back to queue transport")
+        logger.warning("feed_transport 'auto' resolved to shm but the "
+                       "native ring is unavailable; using the queue "
+                       "transport")
     hostinfo.write_executor_id(executor_id, working_dir)
     with open(os.path.join(working_dir, HUB_ADDR_FILE), "w") as f:
       f.write("%s:%d" % hub.addr)
@@ -607,17 +596,15 @@ def make_node_fn(main_fn, tf_args, cluster_meta: dict):
       # with the reference's cluster-spec-derived local index, :386-388) —
       # executor ids are NOT contiguous per host, so id % workers_per_host
       # would double-claim chips.
+      # A request that cannot be honoured (no topology visible, more
+      # co-hosted nodes than chips) raises and reaches the driver.
       num_chips = meta.get("chips_per_node", 0)
-      if num_chips and not os.environ.get(tpu_info.ENV_TEST_MODE):
-        topo = tpu_info.get_topology()
-        if topo is not None:
-          cohosted = sorted(n["executor_id"] for n in cluster_info
-                            if n["host"] == host)
-          local_index = cohosted.index(executor_id)
-          workers_per_host = max(1, topo.chips_per_host // num_chips)
-          tpu_info.apply_chip_env(tpu_info.chip_env_for_worker(
-              num_chips, local_index, workers_per_host,
-              generation=topo.generation))
+      if num_chips:
+        cohosted = sorted(n["executor_id"] for n in cluster_info
+                          if n["host"] == host)
+        tpu_info.claim_chips(num_chips, cohosted.index(executor_id),
+                             workers_on_host=len(cohosted),
+                             what="executor %d" % executor_id)
 
       # 8. synthesize the cluster spec + JAX process coordinates (the TPU
       # analog of exporting TF_CONFIG, parity :373-384)
@@ -655,6 +642,7 @@ def make_node_fn(main_fn, tf_args, cluster_meta: dict):
       # tasks can be scheduled onto this executor) or parks on the control
       # queue (ps/evaluator) until the driver sends None (parity :431-458)
       tmp_sock.close()
+      _refuse_if_chip_held(executor_id)
       import multiprocessing as mp
       proc = mp.get_context("spawn").Process(
           target=_background_runner,
@@ -688,6 +676,11 @@ def make_node_fn(main_fn, tf_args, cluster_meta: dict):
       if release_now:
         tmp_sock.close()
         tmp_sock = None
+      # foreground workers jit in THIS process: place the persistent
+      # compilation cache before the user fn compiles — and before the
+      # beats start, so the jax import it costs falls under the startup
+      # grace instead of between two heartbeats
+      compile_cache.setup()
       sender = None
       if hb_interval:
         sender = rendezvous.HeartbeatSender(
@@ -696,9 +689,6 @@ def make_node_fn(main_fn, tf_args, cluster_meta: dict):
       shipper = _start_obs_shipper(meta["server_addr"], executor_id, sender)
       ctx = TPUNodeContext(hub=hub, tmp_socket=tmp_sock, heartbeat=sender,
                            **ctx_kwargs)
-      # foreground workers jit in THIS process: persistent compilation
-      # cache (TOS_COMPILE_CACHE) goes live before the user fn compiles
-      _setup_compile_cache()
       try:
         cloudpickle.loads(fn_bytes)(tf_args, ctx)
         hub.set("state", "stopped")
@@ -956,9 +946,12 @@ class DualInput(object):
     self._last = None
     self._stash = None    # ring tail (from the marker on) awaiting drain
     self._stash_chunk = None  # held-back end-of-feed chunk (get_chunk path)
+    #: deliveries per channel — which transport actually ran
+    self.deliveries = {"ring": 0, "queue": 0}
 
   def _from(self, ch, got):
     self._last = ch
+    self.deliveries["ring" if ch is self._ring else "queue"] += 1
     return got
 
   def _deliver_ring(self, got, max_items: int):

@@ -61,7 +61,7 @@ COMPILE_MS_BUCKETS = (1.0, 5.0, 10.0, 50.0, 100.0, 500.0, 1000.0,
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 #: the jax.monitoring instant event one PERSISTENT-cache hit emits
-#: (TOS_COMPILE_CACHE, node._setup_compile_cache). NOTE: jax's
+#: (utils.compile_cache.setup, called at node bring-up). NOTE: jax's
 #: ``_COMPILE_EVENT`` duration event WRAPS compile_or_get_cached, so it
 #: fires on hits too — this instant event fires INSIDE that region, and
 #: each one arms a ``_pending_hits`` discount that absorbs its paired
@@ -107,7 +107,7 @@ def _on_compile_duration(event: str, duration: float, **kwargs) -> None:
       # this "compile" was a persistent-cache load (the hit event fired
       # inside the wrapped lookup): already counted as xla.cache_hits,
       # must not count as a fresh compile or relaunched executors with a
-      # warm TOS_COMPILE_CACHE read as a recompile storm
+      # warm compile cache read as a recompile storm
       _pending_hits["n"] -= 1
       return
   reg = metrics_mod.active()

@@ -140,7 +140,9 @@ class StepTimer(object):
 
 # --- MFU accounting ----------------------------------------------------------
 
-# bf16 peak FLOP/s per chip by TPU generation (public spec sheets)
+# bf16 peak FLOP/s per chip by TPU generation (public spec sheets; v5e:
+# Google Cloud documentation "TPU v5e", 197 TFLOP/s). THE one peak table:
+# bench.py and the tools key into it through chip_peak_bf16_flops
 PEAK_BF16_FLOPS = {"v4": 275e12, "v5e": 197e12, "v5p": 459e12, "v6e": 918e12}
 
 
@@ -156,6 +158,20 @@ def resolve_chip_generation(hint: str = "") -> Optional[str]:
     if g in text:
       return g
   return None
+
+
+def chip_peak_bf16_flops(device_kind: str):
+  """``(generation, bf16 peak FLOP/s)`` for a device_kind as JAX reports it
+  (e.g. ``"TPU v5 lite"`` -> ``("v5e", 197e12)``). A device that is not in
+  :data:`PEAK_BF16_FLOPS` is an ERROR, never a default: a utilisation
+  against an assumed peak is not a measurement."""
+  gen = resolve_chip_generation(device_kind)
+  if gen is None:
+    raise ValueError(
+        "unknown device_kind %r: no entry in obs.profiler.PEAK_BF16_FLOPS "
+        "(%s) — add the chip with its published peak, do not assume one"
+        % (device_kind, ", ".join(sorted(PEAK_BF16_FLOPS))))
+  return gen, PEAK_BF16_FLOPS[gen]
 
 
 def transformer_flops_per_token(n_params: int, num_layers: int,

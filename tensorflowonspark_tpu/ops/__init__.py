@@ -52,7 +52,8 @@ def pallas_kernels_enabled() -> bool:
 
 
 from tensorflowonspark_tpu.ops.flash_attention import (  # noqa: F401,E402
-    flash_attention, flash_attention_block, merge_partials,
+    flash_attention, flash_attention_block, flash_attention_sharded,
+    merge_partials,
 )
 from tensorflowonspark_tpu.ops.layer_norm import (  # noqa: F401
     layer_norm, layer_norm_sharded,

@@ -771,6 +771,39 @@ def flash_attention(q, k, v, causal: bool = True, blk_q: int = 256,
                     _resolve_bwd(bwd), blk_bwd_q, blk_bwd_k, window)
 
 
+def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
+                            interpret: bool = False, window: int = None,
+                            batch_axes=None):
+  """:func:`flash_attention` applied per shard through shard_map.
+
+  For attention inside a GSPMD-partitioned model whose sequence is NOT
+  mesh-sharded (that case is ``parallel.ring_attention``): the TPU compiler
+  refuses to partition a Mosaic kernel on its own ("Mosaic kernels cannot
+  be automatically partitioned"), so the kernel is mapped over the shards
+  it is embarrassingly parallel in — batch over the data(+fsdp) axes, heads
+  over the tensor axis. Heads shard only when BOTH the query and the KV
+  head counts divide the tensor axis: the grouped kernel maps query head
+  ``i`` to KV head ``i // g`` locally, which needs the two laid out alike;
+  otherwise heads stay whole (replicated over tensor) on every shard.
+  """
+  from jax.sharding import PartitionSpec as P
+  from tensorflowonspark_tpu.parallel import mesh as mesh_lib
+  from tensorflowonspark_tpu.utils.compat import jax_shard_map as shard_map
+
+  if batch_axes is None:
+    batch_axes = mesh_lib.data_axes(mesh)
+  t = mesh.shape.get(mesh_lib.AXIS_TENSOR, 1)
+  heads_axis = mesh_lib.AXIS_TENSOR \
+      if t > 1 and q.shape[2] % t == 0 and k.shape[2] % t == 0 else None
+  spec = P(batch_axes or None, None, heads_axis, None)
+  fn = shard_map(
+      lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=causal,
+                                         interpret=interpret, window=window),
+      mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+      check_vma=False)
+  return fn(q, k, v)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_vjp(q, k, v, causal, blk_q, blk_k, interpret, bwd, blk_bwd_q,
                blk_bwd_k, window):

@@ -31,12 +31,8 @@ def run(engine: Engine, map_fn, tf_args=None,
   n = num_tasks if num_tasks is not None else engine.num_executors
 
   def _task_body(task_id: int, addresses: List[str]):
-    if chips_per_node and not os.environ.get("TOS_TPU_TEST_MODE"):
-      topo = tpu_info.get_topology()
-      if topo is not None:
-        workers_per_host = max(1, topo.chips_per_host // chips_per_node)
-        tpu_info.apply_chip_env(tpu_info.chip_env_for_worker(
-            chips_per_node, task_id, workers_per_host))
+    # a request that cannot be honoured raises (tpu_info.claim_chips)
+    tpu_info.claim_chips(chips_per_node, task_id, what="parallel.run")
     ctx = TPUNodeContext(
         executor_id=task_id, job_name="worker", task_index=task_id,
         cluster_spec={"worker": addresses},

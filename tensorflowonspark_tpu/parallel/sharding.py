@@ -272,6 +272,22 @@ class TrainLoop(object):
     self._record(len(losses), t0)
     return state, jnp.stack(losses) if losses else jnp.zeros((0,))
 
+  def lower(self, state, item):
+    """AOT-lower the program ``loop(state, item)`` would dispatch (the
+    fused scan for a full :class:`Slab`, else the per-step entry) — for
+    ``.compile()``'s ``as_text()``/``memory_analysis()`` and for the
+    deviceless compile gate, where ``state``/``item`` may be abstract."""
+    import jax
+    from tensorflowonspark_tpu.data.readers import Slab
+    if isinstance(item, Slab):
+      leaves = jax.tree.leaves(item.data)
+      if self._fused is not None and leaves \
+          and leaves[0].shape[0] == self.unroll:
+        return self._fused.lower(state, item.data)
+      raise ValueError("only a full [unroll=%d, ...] slab has a fused "
+                       "program to lower" % self.unroll)
+    return self._step.lower(state, item)
+
   def __call__(self, state, item):
     from tensorflowonspark_tpu.data.readers import Slab
     t0 = time.monotonic()

@@ -319,7 +319,8 @@ def _allocate_transform_chips(chips_per_node: int) -> None:
   """Claim this task's disjoint chip share before JAX initializes.
 
   No-op without ``chips_per_node``, in test mode, or when already
-  allocated / no TPU topology is visible.
+  allocated; a request with no TPU topology visible raises
+  (``tpu_info.claim_chips``).
   """
   if not chips_per_node or os.environ.get("TOS_TPU_TEST_MODE"):
     return
@@ -327,12 +328,10 @@ def _allocate_transform_chips(chips_per_node: int) -> None:
     return  # a prior task on this executor process already claimed chips
   from tensorflowonspark_tpu.utils import tpu_info
   topo = tpu_info.get_topology()
-  if topo is None:
-    return
-  workers_per_host = max(1, topo.chips_per_host // chips_per_node)
+  workers_per_host = max(1, topo.chips_per_host // chips_per_node) \
+      if topo is not None else 1
   slot = _transform_worker_slot(workers_per_host) % workers_per_host
-  tpu_info.apply_chip_env(tpu_info.chip_env_for_worker(
-      chips_per_node, slot, workers_per_host, generation=topo.generation))
+  tpu_info.claim_chips(chips_per_node, slot, what="TFModel.transform")
   os.environ["TOS_CHIP_ENV_APPLIED"] = "1"
 
 

@@ -454,6 +454,26 @@ class SlotDecoder(object):
     # deterministic fault site (TOS_CHAOS_SERVE): one count per fused
     # decode dispatch — "decode#N:raise" crashes the Nth horizon step
     chaos.serve_fault("decode")
+    fresh = horizon not in self._step_many_jits
+    fn = self.step_many_jit(horizon)
+    if fresh:
+      # the serving step's HLO cost (flops / bytes accessed), captured
+      # once per horizon at first use — rides the OBS wire as gauges.
+      # The horizon must live in the LABEL: it is a closed-over scan
+      # length, invisible to the arg-shape fingerprint, and two horizons
+      # have genuinely different costs
+      obs_device.capture_cost(
+          "serve.step_many.h%d" % horizon, fn, params, slabs,
+          jnp.asarray(last_tokens, jnp.int32),
+          jnp.asarray(active, jnp.bool_),
+          jnp.asarray(remaining, jnp.int32))
+    return fn(params, slabs, jnp.asarray(last_tokens, jnp.int32),
+              jnp.asarray(active, jnp.bool_),
+              jnp.asarray(remaining, jnp.int32))
+
+  def step_many_jit(self, horizon: int):
+    """The jitted ``horizon``-step scan behind :meth:`step_many` (built
+    once per horizon) — also what the deviceless compile gate lowers."""
     fn = self._step_many_jits.get(horizon)
     if fn is None:
       def impl(params, slabs, tok, active, remaining, _h=horizon):
@@ -475,19 +495,7 @@ class SlotDecoder(object):
         return slabs, toks, active, remaining
 
       fn = self._step_many_jits[horizon] = jax.jit(impl)
-      # the serving step's HLO cost (flops / bytes accessed), captured
-      # once per horizon at first use — rides the OBS wire as gauges.
-      # The horizon must live in the LABEL: it is a closed-over scan
-      # length, invisible to the arg-shape fingerprint, and two horizons
-      # have genuinely different costs
-      obs_device.capture_cost(
-          "serve.step_many.h%d" % horizon, fn, params, slabs,
-          jnp.asarray(last_tokens, jnp.int32),
-          jnp.asarray(active, jnp.bool_),
-          jnp.asarray(remaining, jnp.int32))
-    return fn(params, slabs, jnp.asarray(last_tokens, jnp.int32),
-              jnp.asarray(active, jnp.bool_),
-              jnp.asarray(remaining, jnp.int32))
+    return fn
 
   # -- self-speculative decode ----------------------------------------------
 

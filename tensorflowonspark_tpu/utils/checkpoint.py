@@ -281,10 +281,7 @@ class CheckpointManager(object):
     own birth forever.
     """
     if refresh:
-      try:
-        self._mgr.reload()
-      except AttributeError:   # older orbax: no reload(); best effort
-        pass
+      self._mgr.reload()
     return self._mgr.latest_step()
 
   def restore(self, state_template: Any, step: Optional[int] = None,

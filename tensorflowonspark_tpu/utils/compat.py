@@ -15,39 +15,18 @@ logger = logging.getLogger(__name__)
 
 
 def jax_shard_map(*args, **kwargs):
-  """``shard_map`` across jax versions.
-
-  Newer jax exports it at top level (``from jax import shard_map``) and
-  renamed ``check_rep`` to ``check_vma``; the version in this image still
-  has the pre-promotion ``jax.experimental.shard_map`` with ``check_rep``.
-  Every in-repo call site imports this shim (lazily, inside the function
-  using it — jax must not be imported at orchestration-layer import time)
-  and may pass either kwarg spelling.
-  """
-  try:
-    from jax import shard_map
-    legacy = False
-  except ImportError:
-    from jax.experimental.shard_map import shard_map
-    legacy = True
-  if legacy and "check_vma" in kwargs:
-    kwargs["check_rep"] = kwargs.pop("check_vma")
-  elif not legacy and "check_rep" in kwargs:
-    kwargs["check_vma"] = kwargs.pop("check_rep")
+  """``jax.shard_map`` (the installed jax, 0.9, exports it at top level
+  with ``check_vma``). An alias so the in-repo call sites keep ONE lazy
+  import — jax must not be imported at orchestration-layer import time."""
+  from jax import shard_map
   return shard_map(*args, **kwargs)
 
 
 def jax_axis_size(axis_name):
-  """``lax.axis_size`` across jax versions (use inside shard_map bodies).
-
-  Newer jax has ``lax.axis_size(name)``; on the version in this image the
-  classic ``psum(1, name)`` idiom serves — it constant-folds to a static
-  python int under shard_map, so it remains usable as a loop bound.
-  """
+  """``lax.axis_size`` — a static python int inside shard_map bodies,
+  usable as a loop bound."""
   from jax import lax
-  if hasattr(lax, "axis_size"):
-    return lax.axis_size(axis_name)
-  return lax.psum(1, axis_name)
+  return lax.axis_size(axis_name)
 
 
 def export_model(state, export_dir: str, is_chief: bool) -> str:

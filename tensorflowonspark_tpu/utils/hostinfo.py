@@ -67,12 +67,8 @@ def single_node_env(num_chips: int = 0, worker_index: int = 0,
   before any JAX/libtpu initialization.
   """
   from tensorflowonspark_tpu.utils import tpu_info
-  if num_chips and not os.environ.get("TOS_TPU_TEST_MODE"):
-    topo = tpu_info.get_topology()
-    if topo is not None:
-      tpu_info.apply_chip_env(tpu_info.chip_env_for_worker(
-          num_chips, worker_index, workers_per_host,
-          generation=topo.generation))
+  tpu_info.claim_chips(num_chips, worker_index, workers_per_host,
+                       what="single_node_env")
 
 
 def write_executor_id(num: int, working_dir: str = ".") -> None:

@@ -13,6 +13,7 @@ from tensorflowonspark_tpu.obs.profiler import (  # noqa: F401
     PEAK_BF16_FLOPS,
     StepTimer,
     annotate,
+    chip_peak_bf16_flops,
     device_memory_stats,
     mfu,
     resolve_chip_generation,

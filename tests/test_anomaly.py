@@ -915,7 +915,7 @@ class TestFusedLoopDeviceTier:
     assert snap["train.steps"]["value"] == 4 + 1 + 10 * 5
 
   def test_cache_hit_not_counted_as_fresh_compile(self, clean_active):
-    """TOS_COMPILE_CACHE hits fire jax's cache-hit event INSIDE the
+    """Persistent-compile-cache hits fire jax's cache-hit event INSIDE the
     compile-duration region — the paired duration event must count as a
     load (xla.cache_hits), never as a fresh compile, or a relaunched
     executor's warm bring-up reads as a recompile storm."""

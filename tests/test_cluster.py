@@ -683,3 +683,84 @@ def test_quarantine_drain_keeps_markers_for_inference_feeds():
   assert isinstance(pending["input"][2], EndPartition)  # position preserved
   pending = _drain("train")
   assert pending["input"] == [1, 2, 3]
+
+
+# --- no fallback that hides the device (PR 21) ------------------------------
+
+
+def _noop_main(args, ctx):
+  pass
+
+
+def test_requested_shm_transport_unavailable_raises_at_driver():
+  """feed_transport="shm" ASKED for and unavailable is an error that
+  reaches the driver — only "auto" may settle for the queue."""
+  def lose_the_ring(_it):
+    # this persistent executor process now behaves like a host whose
+    # native ring cannot be built (no g++, no prebuilt .so)
+    from tensorflowonspark_tpu.control import shmring
+    shmring._lib, shmring._lib_tried = None, True
+    return [shmring.available()]
+
+  e = LocalEngine(num_executors=1)
+  try:
+    assert e.run_on_executors(lose_the_ring).wait(timeout=60) == [[False]]
+    with pytest.raises(Exception, match="feed_transport='shm' was requested"):
+      tos_cluster.run(e, _noop_main, input_mode=InputMode.ENGINE,
+                      feed_transport="shm", reservation_timeout=30)
+    # "auto" may still choose: same executor, the queue serves
+    c = tos_cluster.run(e, _noop_main, input_mode=InputMode.ENGINE,
+                        reservation_timeout=30)
+    c.shutdown(timeout=60)
+  finally:
+    e.stop()
+
+
+def test_unknown_feed_transport_rejected(engine):
+  with pytest.raises(ValueError, match="feed_transport"):
+    tos_cluster.run(engine, _noop_main, feed_transport="carrier-pigeon")
+
+
+def test_chips_per_node_without_topology_raises_at_driver(monkeypatch):
+  """Outside TOS_TPU_TEST_MODE, chips_per_node > 0 with no TPU topology
+  visible is an error at the driver, not a silently skipped allocation."""
+  monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+  e = LocalEngine(num_executors=1, env={"TOS_TPU_TEST_MODE": ""})
+  try:
+    # allocation runs AFTER the reservation (it needs the co-hosted
+    # population), so the error surfaces from run() or from shutdown()
+    with pytest.raises(Exception, match="no TPU topology is visible"):
+      c = tos_cluster.run(e, _noop_main, chips_per_node=1,
+                          reservation_timeout=30)
+      c.shutdown(timeout=60)
+  finally:
+    e.stop()
+
+
+def test_parallel_run_chips_without_topology_raises(monkeypatch):
+  from tensorflowonspark_tpu.parallel import runner
+  monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+  e = LocalEngine(num_executors=1, env={"TOS_TPU_TEST_MODE": ""})
+  try:
+    with pytest.raises(Exception, match="no TPU topology is visible"):
+      runner.run(e, lambda args, ctx: 1, chips_per_node=1, timeout=60)
+    # nothing asked, nothing claimed: the same engine still runs tasks
+    assert runner.run(e, lambda args, ctx: 1, timeout=60) == [1]
+  finally:
+    e.stop()
+
+
+def test_engine_mode_refuses_when_the_executor_holds_the_chip(monkeypatch):
+  """One process per chip: an executor that already initialised JAX on the
+  TPU cannot hand the chip to the ENGINE-mode child it must spawn — fail
+  with a message that says so instead of a hang."""
+  import sys
+  import types
+  from tensorflowonspark_tpu import node as node_mod
+  node_mod._refuse_if_chip_held(0)                 # CPU backend: fine
+  monkeypatch.setattr(node_mod.platform_env, "backend_initialized",
+                      lambda: True)
+  fake = types.SimpleNamespace(default_backend=lambda: "tpu")
+  monkeypatch.setitem(sys.modules, "jax", fake)
+  with pytest.raises(RuntimeError, match="can never take the chip"):
+    node_mod._refuse_if_chip_held(3)

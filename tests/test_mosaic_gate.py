@@ -1,12 +1,19 @@
 """The deviceless Mosaic-lowering gate (tools/mosaic_gate.py).
 
-Round-2's on-chip session proved interpret-green Pallas kernels can be
-rejected by real Mosaic lowering ("XLA layout ... does not match Mosaic
-layout"); rounds 3-4 could not re-check because the device claim service
-was down. The gate AOT-compiles kernels against a TPU *topology*
-(jax.experimental.topologies) — libtpu's real compiler, no chip claimed —
-so Mosaic validity is a CI property of this image. These tests assert the
-gate is wired correctly AND has teeth (a Mosaic-invalid kernel turns red).
+Interpret-green Pallas kernels can be rejected by real Mosaic lowering
+("XLA layout ... does not match Mosaic layout", a block off the tiling, a
+kernel GSPMD cannot partition). The gate AOT-compiles kernels and whole
+programs against a DESCRIBED TPU topology (jax.experimental.topologies) —
+libtpu's real compiler, no chip attached — so what the compiler refuses
+costs no chip time. These tests assert the gate is wired correctly AND has
+teeth (a Mosaic-invalid kernel turns red), and keep chip_smoke.py's
+programs compiling at their real shapes.
+
+This is the ONE test file that loads the TPU compiler: only one process at
+a time may hold libtpu, so every test here takes the module-scoped ``topo``
+fixture (never autouse, nothing at import / in a skipif / in parametrize
+arguments / in conftest), compiles in the test's own process, and no second
+file may do the same (it could land on another xdist worker).
 """
 
 import os
@@ -17,36 +24,36 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _topology_or_skip():
+@pytest.fixture(scope="module")
+def topo():
+  """The described v5e:2x2 topology; skips from HERE where it cannot be
+  described (no local libtpu, or another process holds its lock)."""
   try:
     from tools.mosaic_gate import _topology
     return _topology("v5e:2x2")
-  except Exception as e:  # noqa: BLE001 - no local libtpu: gate unavailable
-    pytest.skip("deviceless TPU topology unavailable: %r" % (e,))
+  except Exception as e:  # noqa: BLE001 - gate unavailable on this host
+    pytest.skip("no v5e:2x2 topology can be described here: %r" % (e,))
 
 
-def test_gate_green_on_production_kernels():
+def test_gate_green_on_production_kernels(topo):
   """A fused-backward flash target (short-seq clamp path) and the fused
   LayerNorm compile through real Mosaic lowering, devicelessly."""
-  _topology_or_skip()
   from tools.mosaic_gate import run_gate
   results = run_gate(["layer_norm", "flash_short_seq_bwd"])
   assert all(r["ok"] for r in results), results
 
 
-def test_gate_red_on_mosaic_invalid_kernel():
+def test_gate_red_on_mosaic_invalid_kernel(topo):
   """A kernel that interpret mode happily runs (1-D iota) must FAIL the
   deviceless compile — proof the gate exercises real Mosaic lowering, not
   the interpret emulation."""
   import numpy as np
-  _topology_or_skip()
   import jax
   import jax.numpy as jnp
   from jax.experimental import pallas as pl
   from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-  from tools.mosaic_gate import _topology
 
-  mesh = Mesh(np.array(_topology("v5e:2x2").devices[:1]), ("one",))
+  mesh = Mesh(np.array(topo.devices[:1]), ("one",))
 
   def kern(x_ref, o_ref):
     o_ref[...] = x_ref[...] + jax.lax.iota(jnp.float32, 128)
@@ -67,14 +74,13 @@ def test_gate_red_on_mosaic_invalid_kernel():
     f.lower(x).compile()
 
 
-def test_int8_cache_never_materializes_f32(monkeypatch):
+def test_int8_cache_never_materializes_f32(topo, monkeypatch):
   """The int8 KV cache's HBM claim, checked on COMPILED TPU HLO: scales
   apply to k-indexed tensors (scores/probs), so the only cache-shaped
   producers are bare converts fused into the dots — no top-level
   (materialized) f32 buffer of the cache shape may exist, else decode
   would write+reread a dequantized copy and invert the feature."""
   import re
-  _topology_or_skip()
   monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
   from tools.mosaic_gate import TARGETS
   fn, args = TARGETS["serving_decode_int8"]()
@@ -87,13 +93,78 @@ def test_int8_cache_never_materializes_f32(monkeypatch):
   assert re.search(r"s8\[%s\]" % cache_shape, hlo)   # the cache IS int8
 
 
-def test_gate_full_train_step_compiles(monkeypatch):
+def test_gate_full_train_step_compiles(topo, monkeypatch):
   """The dryrun-config 8-chip fused training step (ring + GQA flash +
   ln_matmul_sharded + act fusion + remat) Mosaic-compiles on a v5e:2x4
   topology with abstract state — the multi-chip production path is
   compile-checked without any device."""
-  _topology_or_skip()
   monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
   from tools.mosaic_gate import run_gate
   results = run_gate(["train_step"])
   assert results[0]["ok"], results
+
+
+# chip_smoke.py's programs at its real shapes (12 layers / 768 / 12x64 /
+# 3072 / vocab 32000, 16 x 1024): names only here — the targets themselves
+# are built inside the test, after the fixture
+SMOKE_KERNELS = ("smoke_flash_fwd", "smoke_flash_fused_bwd",
+                 "smoke_layer_norm")
+SMOKE_SERVING = ("smoke_insert", "smoke_step_many", "smoke_paged_insert",
+                 "smoke_paged_step_many") + tuple(
+                     "smoke_prefill_%d" % b
+                     for b in (512, 128, 32, 16, 8, 4, 2, 1))
+
+
+def _gate_one(name, monkeypatch):
+  monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
+  from tools.mosaic_gate import run_gate
+  (res,) = run_gate([name])
+  assert res["ok"], res
+  return res
+
+
+@pytest.mark.parametrize("name", SMOKE_KERNELS)
+def test_smoke_kernels_compile_at_real_shapes(topo, monkeypatch, name):
+  """Flash fwd / fused bwd at B16 S1024 H12 D64 bf16 and the fused
+  LayerNorm fwd+bwd at 16384 x 768 — head_dim 64, the width the smoke's
+  model really has (the older targets use 128)."""
+  res = _gate_one(name, monkeypatch)
+  assert res["tpu_custom_calls"] >= 1, res
+
+
+@pytest.mark.parametrize("name", SMOKE_SERVING)
+def test_smoke_serving_programs_compile(topo, monkeypatch, name):
+  """Every program serving/slots.py jits at the smoke's widths: each
+  prefill bucket, insert and step_many for the engine's default
+  (contiguous) layout, insert_pages and step_many for the paged one."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one(name, monkeypatch)
+  assert res["device_bytes"] < V5E_HBM_BYTES, res
+  if "insert" not in name:
+    # the fused LayerNorm rides every forward; a data-movement-only
+    # program (insert) has no kernel to carry
+    assert res["tpu_custom_calls"] >= 1, res
+
+
+def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
+  """The whole make_train_loop K-step scan of chip_smoke's train phase
+  (abstract state) compiles for one v5e chip, carries the flash and
+  LayerNorm kernels, and fits 16 GB by memory_analysis()."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("smoke_train_loop", monkeypatch)
+  assert res["tpu_custom_calls"] >= 1, res
+  assert 0 < res["device_bytes"] < V5E_HBM_BYTES, res
+  assert not any(res["collectives"].values()), res["collectives"]
+
+
+def test_smoke_mesh_train_loop_compiles_sharded(topo, monkeypatch):
+  """chip_smoke --chips 4's mesh leg (data=2 x tensor=2, NamedSharding on
+  the four described chips): compiles — which needs the flash kernel
+  shard_mapped, GSPMD refuses to partition a Mosaic kernel on its own —
+  with collectives in, and per-device bytes (3.1 GB when written) well
+  under the one-chip program's 9.7 GB."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("smoke_mesh_train_loop", monkeypatch)
+  assert res["tpu_custom_calls"] >= 1, res
+  assert res["collectives"]["all-reduce"] > 0, res["collectives"]
+  assert res["device_bytes"] < V5E_HBM_BYTES / 3, res

@@ -4,6 +4,8 @@ import time
 
 import numpy as np
 
+import pytest
+
 from tensorflowonspark_tpu.utils import profiler
 
 
@@ -37,6 +39,17 @@ class TestMFU:
     assert profiler.resolve_chip_generation("tpu v5p slice") == "v5p"
     assert profiler.resolve_chip_generation("gpu a100") is None
     assert profiler.resolve_chip_generation("") is None
+
+  def test_chip_peak_resolves_v5_lite_to_v5e(self):
+    """The device_kind JAX reports on the v5e machine keys the one peak
+    table (Google Cloud "TPU v5e": 197 TFLOP/s bf16)."""
+    assert profiler.chip_peak_bf16_flops("TPU v5 lite") == ("v5e", 197e12)
+
+  @pytest.mark.parametrize("kind", ["cpu", "TPU v9 mega", ""])
+  def test_chip_peak_unknown_device_raises(self, kind):
+    """An unknown device is an error, never an assumed peak."""
+    with pytest.raises(ValueError, match="unknown device_kind"):
+      profiler.chip_peak_bf16_flops(kind)
 
   def test_peak_table_covers_known_generations(self):
     for g in ("v4", "v5e", "v5p", "v6e"):

@@ -188,3 +188,67 @@ class TestTPUInfo:
     with pytest.raises(ValueError, match="cannot tile"):
       tpu_info.chip_env_for_worker(3, worker_index=0, workers_per_host=1,
                                    generation="v5e")
+
+  # -- discovery bounded by the chips the host really shows ------------------
+
+  def test_from_env_bounded_by_device_nodes(self, monkeypatch):
+    """The one-chip v5e machine says v5litepod-4 / 2,2,1 and shows ONE
+    /dev/vfio/<n>: allocating over four would wait for three peers."""
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 1)
+    topo = tpu_info.from_env()
+    assert (topo.chips_per_host, topo.num_chips, topo.host_grid) == \
+        (1, 1, None)
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 4)
+    topo = tpu_info.from_env()
+    assert (topo.chips_per_host, topo.host_grid) == (4, (2, 2))
+
+  def test_from_device_nodes_when_env_is_silent(self, monkeypatch):
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 4)
+    monkeypatch.setattr(tpu_info, "_pci_generation", lambda: "v5e")
+    topo = tpu_info.from_env()
+    assert (topo.generation, topo.chips_per_host, topo.num_hosts) == \
+        ("v5e", 4, 1)
+    # an explicit environ stays pure (no host probing) for the mocks above
+    assert tpu_info.from_env({}) is None
+
+  def test_claim_chips_without_topology_raises_outside_test_mode(
+      self, monkeypatch):
+    """chips_per_node > 0 with no topology is an ERROR, not a skip: every
+    co-hosted process would otherwise take all the host's chips."""
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 0)
+    assert tpu_info.claim_chips(0, 0) is None          # nothing asked
+    assert tpu_info.claim_chips(1, 0) is None          # test mode (conftest)
+    monkeypatch.delenv("TOS_TPU_TEST_MODE")
+    with pytest.raises(RuntimeError, match="no TPU topology is visible"):
+      tpu_info.claim_chips(1, 0, what="executor 0")
+
+  def test_claim_chips_more_workers_than_chips_raises(self, monkeypatch):
+    monkeypatch.delenv("TOS_TPU_TEST_MODE")
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 1)
+    applied = []
+    monkeypatch.setattr(tpu_info, "apply_chip_env", applied.append)
+    with pytest.raises(RuntimeError, match="must not exceed the chips"):
+      tpu_info.claim_chips(1, 0, workers_on_host=4)
+    env = tpu_info.claim_chips(1, 0, workers_on_host=1)
+    assert applied == [env]
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"].count(",") == 0
+
+  def test_claim_chips_sizes_the_grid_by_the_cohosted_population(
+      self, monkeypatch):
+    """Two executors on a four-chip host form a 2-process slice; sizing it
+    by capacity (four) would wait for two peers that never come."""
+    monkeypatch.delenv("TOS_TPU_TEST_MODE")
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    monkeypatch.setattr(tpu_info, "local_chip_count", lambda: 4)
+    monkeypatch.setattr(tpu_info, "apply_chip_env", lambda env: None)
+    env = tpu_info.claim_chips(1, 1, workers_on_host=2)
+    assert env["TPU_VISIBLE_CHIPS"] == "1"
+    assert env["TPU_PROCESS_BOUNDS"] == "2,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"].count(",") == 1

@@ -1,9 +1,8 @@
 """Analytic roofline for the bench transformer: per-config MFU ceilings.
 
-Round-2/3 verdicts asked for ">=55% MFU or a profile-backed ceiling
-analysis". When the chip is unreachable (three rounds of BENCH_r0N = 0.0
-were exactly that) the profile half cannot run — this tool provides the
-analytic half: a first-principles FLOPs + HBM-traffic model of one
+A utilisation target wants either the number or a profile-backed ceiling
+analysis. Without a chip the profile half cannot run — this tool provides
+the analytic half: a first-principles FLOPs + HBM-traffic model of one
 training step of the bench transformer under each sweep config, bounding
 the achievable step time by max(compute_time, memory_time) and hence MFU
 by compute_time / bound. The same accounting slots straight into the

@@ -807,6 +807,17 @@ def run_fleet_xhost(args):
   from tensorflowonspark_tpu.serving import remote as remote_mod
   from tensorflowonspark_tpu.utils import chaos
 
+  if jax.default_backend() == "tpu":
+    # one process per chip: this process holds the chip (reference
+    # decodes, the in-process leg) and then spawns host processes that
+    # need the same one — they would fail or hang inside libtpu
+    sys.exit("serve_bench --fleet --cross-host cannot run on a TPU as it "
+             "stands: this process takes the chip and its spawned hosts "
+             "need it too (one process per chip). Run it with "
+             "JAX_PLATFORMS=cpu; the chip version is ROADMAP S1/S7 "
+             "(thread-mode hosts, or legs in children of a JAX-free "
+             "parent)")
+
   shape = _FLEET_SMOKE if args.smoke else _FLEET_FULL
   if args.requests:
     shape = dict(shape, requests=args.requests)
@@ -1425,8 +1436,7 @@ def main():
   ap.add_argument("--steps", type=int, default=128)
   ap.add_argument("--configs", default=None,
                   help="comma list of config names to measure (default: "
-                       "all) — one config per subprocess fits a short "
-                       "claim window (tools/micro_capture.py)")
+                       "all)")
   ap.add_argument("--compare", action="store_true",
                   help="continuous (serving.ServingEngine) vs static "
                        "batching on a seeded mixed-length workload")
@@ -1474,6 +1484,8 @@ def main():
   ap.add_argument("--json-out", default=None,
                   help="also write the --compare JSON line here")
   args = ap.parse_args()
+  from tensorflowonspark_tpu.utils import compile_cache
+  compile_cache.setup()                  # this process jits: place the cache
   if args.compare:
     sys.exit(run_compare(args))
   if args.chaos:

@@ -551,9 +551,8 @@ def sweep_blocks(results):
 
 class _TeeResults(list):
   """Write-through results list: each appended row also lands on disk
-  immediately (one JSON line), so a claim window that closes mid-matrix
-  keeps every row that finished instead of losing the whole run. Used by
-  the micro-capture queue (tools/micro_capture.py)."""
+  immediately (one JSON line), so a run that dies mid-matrix keeps every
+  row that finished instead of losing the whole run."""
 
   def __init__(self, path):
     super().__init__()
@@ -578,8 +577,7 @@ def main(argv=None):
                        "matrix — e.g. when a capture just ran it)")
   ap.add_argument("--select", default=None,
                   help="comma list of family[:shape_idx] items to run "
-                       "instead of the full matrix — one small subprocess "
-                       "per claim window (micro-capture mode). Families: "
+                       "instead of the full matrix. Families: "
                        "flash_bf16, flash_f32, gqa, block, ln, lnmm, gelu")
   ap.add_argument("--append-jsonl", default=None,
                   help="append each result row to this file the moment it "
@@ -590,8 +588,12 @@ def main(argv=None):
   dev = jax.devices()[0]
   print("device: %s (%s)" % (dev, dev.platform), file=sys.stderr)
   if dev.platform != "tpu":
-    print("WARNING: not a TPU — results are for the %s backend"
-          % dev.platform, file=sys.stderr)
+    # on-chip numerics + timing: another backend's numbers are not this
+    # tool's result, and must not be mistaken for it
+    print("not a TPU: JAX found the %s backend (%s); tpu_validate runs on "
+          "the chip or not at all" % (dev.platform, dev.device_kind),
+          file=sys.stderr)
+    return 3
 
   results = _TeeResults(args.append_jsonl)
   if args.quick:

@@ -294,6 +294,8 @@ def main():
   ap.add_argument("--json-out", default=None,
                   help="additionally write the JSON result to this path")
   args = ap.parse_args()
+  from tensorflowonspark_tpu.utils import compile_cache
+  compile_cache.setup()                  # this process jits: place the cache
   if args.smoke or os.environ.get("TOS_BENCH_SMOKE"):
     args.steps, args.batch, args.hidden, args.reps = 32, 16, 64, 1
   if args.steps % args.unroll:
