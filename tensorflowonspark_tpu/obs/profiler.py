@@ -58,12 +58,6 @@ def trace(log_dir: str, host_tracer_level: int = 2):
   logger.info("profile trace written to %s", log_dir)
 
 
-def annotate(name: str):
-  """Named region annotation for traces (shows up on the timeline)."""
-  import jax
-  return jax.profiler.TraceAnnotation(name)
-
-
 # --- step timing / throughput ------------------------------------------------
 
 

@@ -19,6 +19,7 @@ never wedge the runtime it observes.
 
 import contextlib
 import os
+import sys
 import threading
 import time
 import uuid
@@ -208,9 +209,11 @@ _active_lock = threading.Lock()
 def active() -> Optional[SpanRecorder]:
   """The process recorder, or None when the obs plane is off (mirrors
   ``metrics.active``)."""
-  from tensorflowonspark_tpu.obs import metrics
   global _active
-  if _active is None and metrics.enabled():
+  if _active is not None:
+    return _active
+  from tensorflowonspark_tpu.obs import metrics
+  if metrics.enabled():
     with _active_lock:
       if _active is None:
         _active = SpanRecorder()
@@ -228,3 +231,73 @@ def deactivate() -> None:
   global _active
   with _active_lock:
     _active = None
+
+
+# -- the span seam: one region, three sinks -------------------------------------
+
+_tls = threading.local()
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+  """A ``jax.profiler.TraceAnnotation`` for ``name``; a no-op context while
+  this process has not loaded JAX. NEVER imports it: ``control/rendezvous.py``
+  and ``cluster.py`` import this module in processes that must stay off
+  the chip. With no profiler session live the annotation is an
+  inactive-flag check."""
+  profiler = getattr(sys.modules.get("jax"), "profiler", None)
+  return _NO_ANNOTATION if profiler is None \
+      else profiler.TraceAnnotation(name)
+
+
+class Region(object):
+  """What ``with region(...) as r`` binds: ``t0`` and ``dur`` (the region's
+  one clock reading, ``dur`` set on exit) and the writable ``attrs`` of its
+  recorder span, for values known only once the work is done."""
+
+  __slots__ = ("t0", "dur", "attrs", "_counted")
+
+  def __init__(self, attrs: dict):
+    self.attrs = attrs
+    self.t0 = self.dur = self._counted = 0.0
+
+
+@contextlib.contextmanager
+def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
+           trace: Optional[str] = None, record: bool = True, **attrs):
+  """One timed region of the calling thread, written to three sinks::
+
+      with region("serve.insert", acc=stats, key="t_insert_s"):
+          ...
+
+  * counter, always on: on exit ``acc[key]`` grows by the region's SELF
+    seconds — its duration less what regions nested inside it put into
+    their own counters — so the keys of one thread partition its wall
+    time and no second is counted twice (a nested region without a
+    counter stays in its parent's);
+  * trace clock, on while a ``jax.profiler`` session is live: the region
+    is a ``TraceAnnotation`` on the host plane of the same ``.xplane.pb``
+    as the device's ``XLA Ops`` line;
+  * recorder, on with ``TOS_OBS=1``: the span lands in the active
+    :class:`SpanRecorder` with ``trace``/attrs, like
+    ``SpanRecorder.span``; ``record=False`` keeps a region out of it.
+  """
+  r = Region(attrs)
+  parent = getattr(_tls, "top", None)
+  with _annotation(name):
+    _tls.top = r
+    r.t0 = time.monotonic()
+    try:
+      yield r
+    finally:
+      r.dur = dur = time.monotonic() - r.t0
+      _tls.top = parent
+      if acc is not None:
+        acc[key] += dur - r._counted
+        r._counted = dur
+      if parent is not None:
+        parent._counted += r._counted
+      if record:
+        rec = active()
+        if rec is not None:
+          rec.record_span(name, r.t0, dur, trace=trace, **r.attrs)

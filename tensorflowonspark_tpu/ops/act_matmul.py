@@ -62,7 +62,10 @@ def _act_matmul_kernel(x_ref, w_ref, o_ref):
   o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _act_matmul_fwd(x, W, blk_rows, blk_cols, interpret):
+# jitted under the name a device trace should show (ops/layer_norm.py)
+@functools.partial(jax.jit, static_argnames=("blk_rows", "blk_cols",
+                                             "interpret"))
+def act_matmul_fwd(x, W, blk_rows, blk_cols, interpret):
   shape = x.shape
   f = shape[-1]
   n = W.shape[-1]
@@ -83,17 +86,18 @@ def _act_matmul_fwd(x, W, blk_rows, blk_cols, interpret):
       out_specs=pl.BlockSpec((blk_r, blk_n), lambda i, j: (i, j)),
       out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
       interpret=interpret,
+      name="act_matmul_fwd",
   )(xf, W)
   return out.reshape(shape[:-1] + (n,))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _act_matmul_vjp(x, W, blk_rows, blk_cols, interpret):
-  return _act_matmul_fwd(x, W, blk_rows, blk_cols, interpret)
+  return act_matmul_fwd(x, W, blk_rows, blk_cols, interpret)
 
 
 def _fwd_rule(x, W, blk_rows, blk_cols, interpret):
-  return _act_matmul_fwd(x, W, blk_rows, blk_cols, interpret), (x, W)
+  return act_matmul_fwd(x, W, blk_rows, blk_cols, interpret), (x, W)
 
 
 def _bwd_rule(blk_rows, blk_cols, interpret, res, g):

@@ -102,11 +102,11 @@ def layer_norm_sharded(x, weight, mesh, eps: float = 1e-6,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _ln_vjp(x, weight, eps, blk_rows, interpret):
-  return _ln_fwd(x, weight, eps, blk_rows, interpret)
+  return layer_norm_fwd(x, weight, eps, blk_rows, interpret)
 
 
 def _ln_fwd_rule(x, weight, eps, blk_rows, interpret):
-  y = _ln_fwd(x, weight, eps, blk_rows, interpret)
+  y = layer_norm_fwd(x, weight, eps, blk_rows, interpret)
   return y, (x, weight)
 
 
@@ -143,7 +143,14 @@ def _pick_block(rows: int, blk_rows: int, h: int, itemsize: int = 0) -> int:
   return rows
 
 
-def _ln_fwd(x, weight, eps, blk_rows, interpret):
+# A device trace names a Mosaic kernel after the innermost ``jax.jit`` it
+# sits in (``%layer_norm_fwd``), which is why the two launchers below are
+# jitted under the names a reader of the trace should see: named scopes and
+# ``pallas_call(name=...)`` alone do not reach the instruction's name once
+# ``utils.compile_cache.setup`` has turned the traceback locations off, and
+# the kernel reads ``%tpu_custom_call.N`` (PERF.md section 6, PR 24).
+@functools.partial(jax.jit, static_argnames=("eps", "blk_rows", "interpret"))
+def layer_norm_fwd(x, weight, eps, blk_rows, interpret):
   shape = x.shape
   h = shape[-1]
   rows = 1
@@ -163,12 +170,18 @@ def _ln_fwd(x, weight, eps, blk_rows, interpret):
       out_specs=pl.BlockSpec((blk, h), lambda i: (i, 0)),
       out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
       interpret=interpret,
+      name="layer_norm_fwd",
   )(xf, w2)
   return y.reshape(shape)
 
 
 def _ln_bwd_rule(eps, blk_rows, interpret, residuals, g):
   x, weight = residuals
+  return layer_norm_bwd(x, weight, g, eps, blk_rows, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "blk_rows", "interpret"))
+def layer_norm_bwd(x, weight, g, eps, blk_rows, interpret):
   shape = x.shape
   h = shape[-1]
   rows = 1
@@ -196,6 +209,7 @@ def _ln_bwd_rule(eps, blk_rows, interpret, residuals, g):
           jax.ShapeDtypeStruct((1, h), jnp.float32),
       ],
       interpret=interpret,
+      name="layer_norm_bwd",
   )(xf, w2, gf)
 
   dw = dw_partial[0].astype(weight.dtype)

@@ -289,12 +289,25 @@ class ServingEngine(object):
                   "engine_restarts": 0, "poisoned": 0,
                   "replay_mismatches": 0, "prefix_hits": 0,
                   "prefix_evictions": 0, "spec_accepted": 0,
-                  "spec_rejected": 0}
-    # obs seam (docs/OBSERVABILITY.md): cached handles; disabled = one
-    # None check per decode dispatch
+                  "spec_rejected": 0,
+                  # device dispatches: one per _decode_once, one per
+                  # prefill chunk (SlotDecoder.prefill counts them)
+                  "decode_dispatches": 0, "prefill_chunks": 0,
+                  # the loop thread's SELF seconds by phase, written by
+                  # the regions below (obs.spans.region): the keys
+                  # partition the loop thread's wall time
+                  "t_reap_s": 0.0, "t_idle_s": 0.0, "t_admit_s": 0.0,
+                  "t_prefill_s": 0.0, "t_prefill_sync_s": 0.0,
+                  "t_insert_s": 0.0, "t_decode_prep_s": 0.0,
+                  "t_decode_dispatch_s": 0.0, "t_decode_fetch_s": 0.0,
+                  "t_decode_harvest_s": 0.0}
+    # obs seam (docs/OBSERVABILITY.md): every loop-thread phase is one
+    # obs.spans.region — counter (stats above, always on), trace
+    # annotation (while a jax.profiler session is live) and recorder
+    # span (TOS_OBS=1; the per-dispatch phases only with TRACE_DETAIL)
     self._rec = obs_spans.active()
-    self._trace_detail = os.environ.get(ENV_OBS_TRACE_DETAIL,
-                                        "1") not in ("0",)
+    self._detail = self._rec is not None and os.environ.get(
+        ENV_OBS_TRACE_DETAIL, "1") not in ("0",)
     reg = obs_metrics.active()
     self._obs_m = None if reg is None else {
         "tokens": reg.counter("serve.tokens"),
@@ -818,11 +831,19 @@ class ServingEngine(object):
     while not self._stop_evt.is_set():
       try:
         self._ensure_slabs()               # rebuilt after a crash
-        self._reap()
-        self._admit()
+        # reap/admit/idle run every pass of an IDLE engine too: counter
+        # and annotation only, never the bounded recorder
+        with obs_spans.region("serve.reap", self.stats, "t_reap_s",
+                              record=False):
+          self._reap()
+        with obs_spans.region("serve.admit", self.stats, "t_admit_s",
+                              record=False):
+          self._admit()
         if not any(r is not None for r in self._slots):
           # idle: bounded block until work arrives (TOS001)
-          self._queue.wait_nonempty(timeout=self._poll)
+          with obs_spans.region("serve.idle", self.stats, "t_idle_s",
+                                record=False):
+            self._queue.wait_nonempty(timeout=self._poll)
           continue
         self._decode_once()
         self._crash_streak = 0             # a full decode pass = healthy
@@ -1112,12 +1133,11 @@ class ServingEngine(object):
           self._rec.record_span("serve.queue", req.submitted_at,
                                 req.started_at - req.submitted_at,
                                 trace=req.trace_id, rid=req.rid)
-      cm = self._rec.span("serve.prefill", trace=req.trace_id,
-                          rid=req.rid,
-                          prompt_len=len(req.prompt), slot=slot,
-                          shared_tokens=shared_tokens) \
-          if self._rec is not None else contextlib.nullcontext()
-      with cm:
+      with obs_spans.region("serve.prefill", self.stats, "t_prefill_s",
+                            trace=req.trace_id,
+                            record=self._rec is not None, rid=req.rid,
+                            prompt_len=len(req.prompt), slot=slot,
+                            shared_tokens=shared_tokens):
         resume = None
         if shared_tokens:
           # prefix hit: rebuild the warm row cache from the shared pages
@@ -1128,8 +1148,8 @@ class ServingEngine(object):
           resume = (row, shared_tokens)
         row_cache, first = self.decoder.prefill(
             self.params, req.prompt, self.buckets, resume=resume,
-            trace=req.trace_id if self._rec is not None
-            and self._trace_detail else None)
+            trace=req.trace_id if self._detail else None,
+            acc=self.stats)
       if req.prefill_done_at is None:   # replays keep the original stamp
         req.prefill_done_at = time.monotonic()
       self.stats["prefills"] += 1
@@ -1145,10 +1165,13 @@ class ServingEngine(object):
             self._pool.unref(p)
         self._admitting = None
         continue                 # slot stays free for the next request
+      with self._phase("serve.insert", "t_insert_s"):
+        if self.decoder.paged:
+          self._slabs = self.decoder.insert_pages(
+              self._slabs, row_cache, slot, table, start=shared_tokens)
+        else:
+          self._slabs = self.decoder.insert(self._slabs, row_cache, slot)
       if self.decoder.paged:
-        self._slabs = self.decoder.insert_pages(self._slabs, row_cache,
-                                                slot, table,
-                                                start=shared_tokens)
         if self._prefix is not None:
           # the prompt's full pages become shareable: the cache takes
           # its own ref on each newly cached page, outliving this
@@ -1159,12 +1182,15 @@ class ServingEngine(object):
           if over:
             self._evict_prefix(over)
         self._req_pages[req.rid] = pages
-      else:
-        self._slabs = self.decoder.insert(self._slabs, row_cache, slot)
       with self._lock:
         self._slots[slot] = req
       self._admitting = None
       self._last[slot] = first
+
+  def _phase(self, name: str, key: str):
+    """A per-dispatch phase of the loop thread: counter and annotation
+    always, recorder span only with ``TOS_OBS_TRACE_DETAIL``."""
+    return obs_spans.region(name, self.stats, key, record=self._detail)
 
   def _mark_admitting(self, req: sched.Request) -> None:
     self._admitting = req
@@ -1202,51 +1228,48 @@ class ServingEngine(object):
     num_slots]`` token matrix, so the two views cannot diverge. A lane
     that stops mid-horizon idles (frozen) for the remaining scan steps —
     the bounded price of amortizing dispatch over the horizon."""
-    t0 = time.monotonic()
     tokens_before = self.stats["emitted_tokens"]
-    active = np.asarray([r is not None for r in self._slots], bool)
-    remaining = np.asarray(
-        [0 if r is None else r.max_new_tokens - r.generated
-         for r in self._slots], np.int32)
-    if self.spec_depth > 0:
-      steps, lanes = self._decode_spec(active, remaining)
-    else:
-      steps, lanes = self._decode_plain(active, remaining)
-    dt = time.monotonic() - t0
+    with obs_spans.region("serve.decode", record=self._rec is not None,
+                          horizon=self.horizon) as dec:
+      with self._phase("serve.decode.prep", "t_decode_prep_s"):
+        active = np.asarray([r is not None for r in self._slots], bool)
+        remaining = np.asarray(
+            [0 if r is None else r.max_new_tokens - r.generated
+             for r in self._slots], np.int32)
+        dec.attrs["active"] = int(active.sum())
+      if self.spec_depth > 0:
+        steps, lanes = self._decode_spec(active, remaining)
+      else:
+        steps, lanes = self._decode_plain(active, remaining)
+    self.stats["decode_dispatches"] += 1
+    t0, dt = dec.t0, dec.dur         # the one clock reading of the pass
     emitted = self.stats["emitted_tokens"] - tokens_before
     if dt > 0 and emitted:
       # live tokens/s EMA — the denominator of the retry-after hint
       rate = emitted / dt
       self._tok_rate = rate if self._tok_rate <= 0 \
           else 0.5 * self._tok_rate + 0.5 * rate
-    if self._rec is not None or self._obs_m is not None:
-      live = sum(1 for r in self._slots if r is not None)
-      if self._rec is not None:
-        self._rec.record_span("serve.decode", t0, dt,
-                              horizon=self.horizon,
-                              active=int(active.sum()))
-        # slot-attributed decode horizons: one child span per lane that
-        # decoded in this dispatch, carrying the request's trace and its
-        # per-lane emitted count (from the harvest of step_many's
-        # [horizon, slots] token matrix) — the decode phase of the
-        # per-request waterfall (obs_report --request). TRACE_DETAIL
-        # gated: the one span family that scales with slots × dispatches
-        if self._trace_detail:
-          for slot, trace, emitted_lane in lanes:
-            self._rec.record_span("serve.decode.slot", t0, dt,
-                                  trace=trace, slot=slot,
-                                  tokens=emitted_lane)
-      m = self._obs_m
-      if m is not None:
-        m["steps"].inc(steps)
-        m["tokens"].inc(emitted)
-        m["decode_ms"].observe(dt * 1e3)
-        m["occupancy"].set(self.occupancy)
-        m["queue_depth"].set(len(self._queue))
-        m["slots_active"].set(live)
-        if self._pool is not None:
-          m["kv_pages_in_use"].set(self._pool.in_use)
-          m["kv_pages_free"].set(self._pool.free_pages)
+    # slot-attributed decode horizons: one span per lane that decoded in
+    # this dispatch, on the serve.decode region's clock, carrying the
+    # request's trace and its per-lane emitted count (from the harvest of
+    # step_many's [horizon, slots] token matrix) — the decode phase of
+    # the per-request waterfall (obs_report --request). TRACE_DETAIL
+    # gated (lanes is empty without it): the one span family that scales
+    # with slots × dispatches
+    for slot, trace, emitted_lane in lanes:
+      self._rec.record_span("serve.decode.slot", t0, dt, trace=trace,
+                            slot=slot, tokens=emitted_lane)
+    m = self._obs_m
+    if m is not None:
+      m["steps"].inc(steps)
+      m["tokens"].inc(emitted)
+      m["decode_ms"].observe(dt * 1e3)
+      m["occupancy"].set(self.occupancy)
+      m["queue_depth"].set(len(self._queue))
+      m["slots_active"].set(sum(1 for r in self._slots if r is not None))
+      if self._pool is not None:
+        m["kv_pages_in_use"].set(self._pool.in_use)
+        m["kv_pages_free"].set(self._pool.free_pages)
 
   def _harvest(self, req, tok: int, slot: int, freed: List[int]) -> bool:
     """Record one emitted token; on the request's stop, free its slot
@@ -1270,29 +1293,34 @@ class ServingEngine(object):
     """The non-speculative fused horizon (SlotDecoder.step_many).
     Returns ``(steps, lanes)`` — ``lanes`` is the slot-attributed
     ``(slot, trace_id, emitted)`` list for the per-request decode spans,
-    built only while the recorder is live (zero work otherwise)."""
-    self._slabs, toks, _, _ = self.decoder.step_many(
-        self.params, self._slabs, self._last, active, remaining,
-        self.horizon)
-    toks = np.asarray(toks)                       # [horizon, num_slots]
-    self.stats["steps"] += self.horizon
-    want_lanes = self._rec is not None and self._trace_detail
+    built only while the recorder is live (zero work otherwise). The
+    three phases of a dispatch are regions: the call returning, the wait
+    for the token matrix, and the host's harvest of it."""
+    with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
+      self._slabs, toks, _, _ = self.decoder.step_many(
+          self.params, self._slabs, self._last, active, remaining,
+          self.horizon)
+    with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
+      toks = np.asarray(toks)                     # [horizon, num_slots]
     lanes: List[tuple] = []
     freed: List[int] = []
-    for slot in range(self.num_slots):
-      req = self._slots[slot]
-      if req is None:
-        continue
-      emitted = 0
-      for j in range(self.horizon):
-        emitted += 1
-        if self._harvest(req, int(toks[j, slot]), slot, freed):
-          break
-      else:
-        self._last[slot] = int(toks[self.horizon - 1, slot])
-      if want_lanes:
-        lanes.append((slot, req.trace_id, emitted))
-    self._reset_freed(freed)
+    # ONE region round the whole harvest: _harvest runs per token
+    with self._phase("serve.decode.harvest", "t_decode_harvest_s"):
+      self.stats["steps"] += self.horizon
+      for slot in range(self.num_slots):
+        req = self._slots[slot]
+        if req is None:
+          continue
+        emitted = 0
+        for j in range(self.horizon):
+          emitted += 1
+          if self._harvest(req, int(toks[j, slot]), slot, freed):
+            break
+        else:
+          self._last[slot] = int(toks[self.horizon - 1, slot])
+        if self._detail:
+          lanes.append((slot, req.trace_id, emitted))
+      self._reset_freed(freed)
     return self.horizon, lanes
 
   def _decode_spec(self, active, remaining):
@@ -1306,37 +1334,40 @@ class ServingEngine(object):
     lanes)`` like :meth:`_decode_plain`.
     """
     k, rounds = self.spec_depth, self._spec_rounds
-    self._slabs, toks, counts, acc, rej, _, _ = self.decoder.step_spec(
-        self.params, self._slabs, self._last, active, remaining, rounds)
-    toks = np.asarray(toks)            # [rounds, spec_depth, num_slots]
-    counts = np.asarray(counts)        # [rounds, num_slots]
-    # a round's slot-step opportunity is its verify window (k wide) —
-    # occupancy then reads as useful-token fraction incl. rejections
-    self.stats["steps"] += rounds * k
-    self._count("spec_accepted", int(np.asarray(acc).sum()))
-    self._count("spec_rejected", int(np.asarray(rej).sum()))
-    want_lanes = self._rec is not None and self._trace_detail
+    with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
+      self._slabs, toks, counts, acc, rej, _, _ = self.decoder.step_spec(
+          self.params, self._slabs, self._last, active, remaining, rounds)
+    with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
+      toks = np.asarray(toks)          # [rounds, spec_depth, num_slots]
+      counts = np.asarray(counts)      # [rounds, num_slots]
+      n_acc, n_rej = int(np.asarray(acc).sum()), int(np.asarray(rej).sum())
     lanes: List[tuple] = []
     freed: List[int] = []
-    for slot in range(self.num_slots):
-      req = self._slots[slot]
-      if req is None:
-        continue
-      done = False
-      emitted = 0
-      last_tok = None
-      for r in range(rounds):
-        for j in range(int(counts[r, slot])):
-          last_tok = int(toks[r, j, slot])
-          emitted += 1
-          if self._harvest(req, last_tok, slot, freed):
-            done = True
+    with self._phase("serve.decode.harvest", "t_decode_harvest_s"):
+      # a round's slot-step opportunity is its verify window (k wide) —
+      # occupancy then reads as useful-token fraction incl. rejections
+      self.stats["steps"] += rounds * k
+      self._count("spec_accepted", n_acc)
+      self._count("spec_rejected", n_rej)
+      for slot in range(self.num_slots):
+        req = self._slots[slot]
+        if req is None:
+          continue
+        done = False
+        emitted = 0
+        last_tok = None
+        for r in range(rounds):
+          for j in range(int(counts[r, slot])):
+            last_tok = int(toks[r, j, slot])
+            emitted += 1
+            if self._harvest(req, last_tok, slot, freed):
+              done = True
+              break
+          if done:
             break
-        if done:
-          break
-      if not done and last_tok is not None:
-        self._last[slot] = last_tok
-      if want_lanes:
-        lanes.append((slot, req.trace_id, emitted))
-    self._reset_freed(freed)
+        if not done and last_tok is not None:
+          self._last[slot] = last_tok
+        if self._detail:
+          lanes.append((slot, req.trace_id, emitted))
+      self._reset_freed(freed)
     return rounds * k, lanes

@@ -60,7 +60,6 @@ are live, per-request budgets/EOS, the page allocator / prefix trie).
 """
 
 import dataclasses
-import time
 from typing import Sequence, Tuple
 
 import jax
@@ -217,7 +216,7 @@ class SlotDecoder(object):
     return mutated["cache"], nxt
 
   def prefill(self, params, prompt, buckets: Sequence[int] = DEFAULT_BUCKETS,
-              resume=None, trace=None) -> Tuple[object, int]:
+              resume=None, trace=None, acc=None) -> Tuple[object, int]:
     """Prefill one prompt into a fresh [1, ...] row cache.
 
     Returns ``(row_cache, first_token)``: the warm cache (cursor at
@@ -231,12 +230,16 @@ class SlotDecoder(object):
     ``start`` must leave at least one tail token (the last prompt token
     must run through the model to yield g1).
 
-    ``trace`` (a request trace id) turns on per-chunk
-    ``serve.prefill.chunk`` spans when the obs recorder is live — the
-    bucketed-decomposition phase of the request waterfall. Chunk
-    dispatches are async (only the final ``int(nxt[0])`` syncs), so a
-    chunk span measures dispatch-to-dispatch time; the enclosing
-    ``serve.prefill`` span carries the true synced total.
+    Each chunk dispatch (its ``dynamic_slice`` included) is a
+    ``serve.prefill.chunk`` region and the wait for the last chunk a
+    ``serve.prefill.sync`` region (``obs.spans.region``): trace
+    annotations always; recorder spans when ``trace`` (a request trace
+    id) is given — the bucketed-decomposition phase of the request
+    waterfall. Chunk dispatches are async, so a chunk region measures
+    dispatch-to-dispatch time; the enclosing ``serve.prefill`` span
+    carries the true synced total. ``acc`` (the engine's ``stats``)
+    counts the dispatches in ``prefill_chunks`` and the wait in
+    ``t_prefill_sync_s``.
     """
     plen = len(prompt)
     if plen + 1 > self.cfg.max_seq_len:
@@ -263,18 +266,21 @@ class SlotDecoder(object):
         self._zero_row = tfm._zero_cache(self.model, 1)
       cache, off = self._zero_row, 0
     prompt = jnp.asarray(prompt, jnp.int32).reshape(1, plen)
-    rec = obs_spans.active() if trace is not None else None
+    plan = chunk_plan(plen - off, buckets)
+    if acc is not None:
+      acc["prefill_chunks"] += len(plan)
     nxt = None
-    for seg in chunk_plan(plen - off, buckets):
-      t0 = time.monotonic()
-      cache, nxt = self._prefill_fn(
-          params, cache, lax.dynamic_slice(prompt, (0, off), (1, seg)))
-      if rec is not None:
-        rec.record_span("serve.prefill.chunk", t0,
-                        time.monotonic() - t0, trace=trace,
-                        chunk=seg, offset=off)
+    for seg in plan:
+      with obs_spans.region("serve.prefill.chunk", trace=trace,
+                            record=trace is not None, chunk=seg,
+                            offset=off):
+        cache, nxt = self._prefill_fn(
+            params, cache, lax.dynamic_slice(prompt, (0, off), (1, seg)))
       off += seg
-    return cache, int(nxt[0])
+    with obs_spans.region("serve.prefill.sync", acc, "t_prefill_sync_s",
+                          trace=trace, record=trace is not None):
+      first = int(nxt[0])              # waits for the last chunk
+    return cache, first
 
   # -- slot insert ----------------------------------------------------------
 

@@ -12,7 +12,6 @@ import warnings
 from tensorflowonspark_tpu.obs.profiler import (  # noqa: F401
     PEAK_BF16_FLOPS,
     StepTimer,
-    annotate,
     chip_peak_bf16_flops,
     device_memory_stats,
     mfu,

@@ -441,7 +441,7 @@ class TestStepTimerRegistrySeam:
     from tensorflowonspark_tpu.obs import profiler as new
     assert shim.StepTimer is new.StepTimer
     assert shim.mfu is new.mfu
-    assert shim.annotate is new.annotate
+    assert shim.trace is new.trace
 
 
 class TestShipperSamplersAndTopSummary:

@@ -1,0 +1,273 @@
+"""The serving loop's phases through the one span seam
+(``obs.spans.region``): the always-on counters in ``ServingEngine.stats``,
+the recorder spans under ``TOS_OBS=1`` and the ``jax.profiler`` trace
+annotations — CPU, toy engine.
+"""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from tensorflowonspark_tpu.models import transformer as tfm
+from tensorflowonspark_tpu.obs import spans as spans_mod
+from tensorflowonspark_tpu.serving import ServingEngine, chunk_plan
+
+PHASE_KEYS = ("t_reap_s", "t_idle_s", "t_admit_s", "t_prefill_s",
+              "t_prefill_sync_s", "t_insert_s", "t_decode_prep_s",
+              "t_decode_dispatch_s", "t_decode_fetch_s",
+              "t_decode_harvest_s")
+COUNT_KEYS = ("decode_dispatches", "prefill_chunks")
+
+
+@pytest.fixture(scope="module")
+def toy():
+  # wide enough that a dispatch outweighs the Python between two regions
+  cfg = tfm.TransformerConfig(vocab_size=64, num_layers=2, num_heads=2,
+                              d_model=64, d_ff=256, max_seq_len=96,
+                              remat=False, dtype=jnp.float32)
+  return cfg, tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16)
+
+
+def _prompts(n, seed=3, longest=60):
+  rng = np.random.RandomState(seed)
+  return [rng.randint(1, 64, (k,)).astype(np.int32)
+          for k in rng.randint(2, longest, n)]
+
+
+def _serve(eng, prompts, budget):
+  rids = [eng.submit(p, max_new_tokens=budget) for p in prompts]
+  for rid in rids:
+    eng.result(rid, timeout=120)
+
+
+# -- the seam itself ----------------------------------------------------------
+
+
+def test_region_self_time_partitions_nested_regions(monkeypatch):
+  """A counter gets SELF time: duration less what nested regions put into
+  their own counters; a nested region without a counter stays in its
+  parent's.  A scripted clock makes the arithmetic exact."""
+  ticks = iter([0.0,            # outer in
+                1.0, 3.0,       # a in/out (2 s, counted)
+                4.0,            # b in (no counter)
+                5.0, 5.5,       # c in/out (0.5 s, counted, inside b)
+                7.0,            # b out
+                10.0])          # outer out
+  monkeypatch.setattr(spans_mod.time, "monotonic", lambda: next(ticks))
+  acc = dict(outer=0.0, a=0.0, c=0.0)
+  with spans_mod.region("outer", acc, "outer", record=False) as outer:
+    with spans_mod.region("a", acc, "a", record=False):
+      pass
+    with spans_mod.region("b", record=False) as b:
+      with spans_mod.region("c", acc, "c", record=False):
+        pass
+  assert (outer.dur, b.dur) == (10.0, 3.0)
+  assert acc == dict(outer=7.5, a=2.0, c=0.5)
+  assert sum(acc.values()) == outer.dur
+
+
+def test_region_records_like_span_when_the_plane_is_on():
+  rec = spans_mod.activate()
+  try:
+    with spans_mod.region("x.kept", trace="t1", n=np.int32(3)) as r:
+      r.attrs["late"] = 7
+    with spans_mod.region("x.dropped", record=False):
+      pass
+  finally:
+    spans_mod.deactivate()
+  (got,) = rec.drain()
+  assert got["name"] == "x.kept" and got["trace"] == "t1"
+  assert got["attrs"] == {"n": 3, "late": 7} and got["ph"] == "X"
+  assert got["t0"] == r.t0 and got["dur"] == r.dur
+  assert got["tid"] == threading.current_thread().name
+
+
+def test_spans_and_control_plane_import_without_jax():
+  """``obs/spans`` rides into processes that must stay off the chip
+  (rendezvous, the benchmark's parent): importing it, and running a
+  region there, never loads JAX."""
+  code = (
+      "import sys\n"
+      "import tensorflowonspark_tpu.obs.spans as s\n"
+      "import tensorflowonspark_tpu.control.rendezvous\n"
+      "acc = {'k': 0.0}\n"
+      "with s.region('a.b', acc, 'k'):\n"
+      "  pass\n"
+      "assert acc['k'] >= 0 and s._annotation('a.b') is s._NO_ANNOTATION\n"
+      "assert 'jax' not in sys.modules, 'jax was loaded'\n")
+  out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+  assert out.returncode == 0, out.stderr[-2000:]
+
+
+# -- the counter sink ---------------------------------------------------------
+
+
+def test_phase_keys_exist_at_construction(toy):
+  cfg, state = toy
+  eng = ServingEngine(state.params, cfg, num_slots=2)
+  for k in PHASE_KEYS:
+    assert eng.stats[k] == 0.0 and isinstance(eng.stats[k], float)
+  for k in COUNT_KEYS:
+    assert eng.stats[k] == 0 and isinstance(eng.stats[k], int)
+  # the benchmark's delta surface carries every one of them
+  assert set(PHASE_KEYS + COUNT_KEYS) <= set(eng.stats_snapshot().delta())
+
+
+def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
+  """Over a warm run of a few dozen requests the phase counters sum to the
+  loop thread's lifetime within 2%: nothing of an iteration is outside a
+  region, and no second is counted twice."""
+  cfg, state = toy
+  eng = ServingEngine(state.params, cfg, num_slots=4, eos_id=None)
+  lives, inner = [], eng._loop
+
+  def timed_loop():
+    t0 = time.monotonic()
+    inner()
+    lives.append(time.monotonic() - t0)
+
+  eng._loop = timed_loop
+  prompts = _prompts(48)
+  with eng:                             # cold: every shape compiles
+    _serve(eng, prompts[:12], 12)
+  base = dict(eng.stats)
+  seen, stop = [], threading.Event()
+
+  def watch():
+    while not stop.is_set():
+      seen.append([eng.stats[k] for k in PHASE_KEYS + COUNT_KEYS])
+      time.sleep(0.002)
+
+  watcher = threading.Thread(target=watch, daemon=True)
+  with eng:                             # warm: a fresh loop thread
+    watcher.start()
+    _serve(eng, prompts, 12)
+  stop.set()
+  watcher.join(10)
+  assert not watcher.is_alive() and len(seen) > 5 and len(lives) == 2
+  for a, b in zip(seen, seen[1:]):
+    assert all(y >= x for x, y in zip(a, b))
+  delta = {k: eng.stats[k] - base[k] for k in PHASE_KEYS}
+  assert all(v >= 0 for v in delta.values())
+  assert delta["t_decode_dispatch_s"] > 0 and delta["t_prefill_s"] > 0
+  assert sum(delta.values()) == pytest.approx(lives[1], rel=0.02)
+  assert sum(delta.values()) <= lives[1]
+
+
+@pytest.mark.parametrize("spec_depth", [0, 2])
+def test_dispatch_counters_are_exact(toy, spec_depth):
+  cfg, state = toy
+  prompts = _prompts(30, seed=5)
+  with ServingEngine(state.params, cfg, num_slots=3, eos_id=None,
+                     spec_depth=spec_depth, spec_layers=1) as eng:
+    _serve(eng, prompts, 9)
+  st = eng.stats               # read with the loop stopped
+  per_dispatch = eng.horizon if spec_depth == 0 \
+      else eng._spec_rounds * spec_depth
+  assert st["prefills"] == len(prompts)
+  assert st["prefill_chunks"] == sum(
+      len(chunk_plan(len(p), eng.buckets)) for p in prompts)
+  assert st["decode_dispatches"] > 0
+  assert st["decode_dispatches"] * per_dispatch == st["steps"]
+
+
+# -- the recorder sink --------------------------------------------------------
+
+
+@pytest.mark.parametrize("detail", ["1", "0"])
+def test_recorder_names_and_gating(toy, monkeypatch, detail):
+  """``TOS_OBS=1``: today's names keep coming, the per-dispatch phases
+  join them under ``TOS_OBS_TRACE_DETAIL``, and the phases an IDLE engine
+  runs every poll never reach the bounded recorder."""
+  cfg, state = toy
+  monkeypatch.setenv("TOS_OBS_TRACE_DETAIL", detail)
+  rec = spans_mod.activate()
+  try:
+    with ServingEngine(state.params, cfg, num_slots=2, eos_id=None) as eng:
+      rid = eng.submit(_prompts(1, longest=20)[0], max_new_tokens=6)
+      list(eng.stream(rid, timeout=120))
+  finally:
+    spans_mod.deactivate()
+  stats = eng.stats            # read with the loop stopped: every region shut
+  recs = rec.drain()
+  names = {r["name"] for r in recs}
+  assert {"serve.queue", "serve.prefill", "serve.decode",
+          "serve.stream"} <= names
+  fine = {"serve.prefill.chunk", "serve.prefill.sync", "serve.insert",
+          "serve.decode.slot", "serve.decode.prep", "serve.decode.dispatch",
+          "serve.decode.fetch", "serve.decode.harvest"}
+  assert fine <= names if detail == "1" else not fine & names
+  assert not {"serve.reap", "serve.idle", "serve.admit"} & names
+  decode = [r for r in recs if r["name"] == "serve.decode"]
+  assert len(decode) == stats["decode_dispatches"]
+  assert all(set(r["attrs"]) == {"horizon", "active"} for r in decode)
+  if detail == "1":
+    # a decode's phases lie inside it, on the region's own clock
+    d = decode[0]
+    inside = [r for r in recs if r["name"].startswith("serve.decode.")
+              and d["t0"] <= r["t0"] and r["t0"] + r["dur"]
+              <= d["t0"] + d["dur"] + 1e-9]
+    assert {"serve.decode.prep", "serve.decode.dispatch",
+            "serve.decode.fetch", "serve.decode.harvest",
+            "serve.decode.slot"} <= {r["name"] for r in inside}
+    chunks = [r for r in recs if r["name"] == "serve.prefill.chunk"]
+    assert len(chunks) == stats["prefill_chunks"]
+    assert all(r.get("trace") for r in chunks)
+
+
+# -- the trace-clock sink -----------------------------------------------------
+
+
+def test_profiler_session_alone_shows_the_nested_phases(toy, tmp_path):
+  """No ``TOS_OBS``: a ``jax.profiler`` session by itself puts the loop's
+  phases on the host plane of the ``.xplane.pb``, nested as written."""
+  from jax.profiler import ProfileData
+  cfg, state = toy
+  assert spans_mod.active() is None
+  prompts = _prompts(4, seed=11, longest=30)
+  with ServingEngine(state.params, cfg, num_slots=2, eos_id=None) as eng:
+    _serve(eng, prompts[:1], 4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+      _serve(eng, prompts, 6)
+      # the last result is delivered from INSIDE a harvest: let the loop
+      # close its open regions (one whole idle wait) before the session ends
+      idle, deadline = eng.stats["t_idle_s"], time.monotonic() + 30
+      while eng.stats["t_idle_s"] == idle and time.monotonic() < deadline:
+        time.sleep(0.01)
+    finally:
+      jax.profiler.stop_trace()
+  (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+  lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for e in line.events if e.name.startswith("serve.")]
+           for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:") for line in plane.lines]
+  (loop,) = [evs for evs in lines if evs]        # one thread: the loop's
+
+  def inside(child, parent):
+    outer = [(s, e) for n, s, e in loop if n == parent]
+    inner = [(s, e) for n, s, e in loop if n == child]
+    assert inner and outer, (child, parent)
+    return all(any(s0 <= s and e <= e0 for s0, e0 in outer)
+               for s, e in inner)
+
+  for child, parent in (("serve.decode.harvest", "serve.decode"),
+                        ("serve.decode.dispatch", "serve.decode"),
+                        ("serve.prefill.chunk", "serve.prefill"),
+                        ("serve.prefill.sync", "serve.prefill"),
+                        ("serve.prefill", "serve.admit"),
+                        ("serve.insert", "serve.admit")):
+    assert inside(child, parent), (child, parent)
+  assert {n for n, _, _ in loop} >= {"serve.reap", "serve.decode.prep",
+                                     "serve.decode.fetch"}
