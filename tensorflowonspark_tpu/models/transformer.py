@@ -332,6 +332,150 @@ def _heads_logical(n_heads: int, mesh) -> Optional[str]:
   return "heads" if n_heads % max(1, t) == 0 else None
 
 
+#: columns of one MXU pass (v5e: 128 x 128). The folded decode attention
+#: expands q block-diagonally over the KV heads; the zeros of that expansion
+#: cost nothing while the expanded operand still fits ONE pass, and past it
+#: they cost kv_heads times the arithmetic, so wider query blocks (prefill
+#: chunks against their one-row cache) take the 4-D contraction instead
+_MXU_COLS = 128
+
+
+def _bf16_terms(x):
+  """``x`` as bf16 arrays that SUM to it: itself when it is bf16, else
+  three terms (8 + 8 + 8 significant bits hold an f32's 24).
+  ``reduce_precision`` and not a convert pair: XLA may elide
+  f32->bf16->f32 (``xla_allow_excess_precision``), and the remainder
+  would silently read 0."""
+  if x.dtype == jnp.bfloat16:
+    return [x]
+  terms, rest = [], x.astype(jnp.float32)
+  for _ in range(3):
+    t = lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+    terms.append(t.astype(jnp.bfloat16))
+    rest = rest - t
+  return terms
+
+
+def _cache_contract(eq, small, cache):
+  """``einsum(eq, small, cache)`` in f32 with NEITHER operand rounded.
+
+  ``cache`` is a whole KV buffer ``[b, max, c]`` and is read as stored:
+  bf16 (or int8 values, which bf16 holds exactly) meets ``small``
+  ``[b, n, x]`` as bf16 terms stacked along ``n``; bf16 x bf16 products
+  are exact in f32, so one MXU pass accumulating in f32 IS the f32
+  contraction (no f32 copy of the cache, no six-pass ``HIGHEST``). Any
+  other cache dtype takes the f32 contraction itself."""
+  if cache.dtype not in (jnp.bfloat16, jnp.int8):
+    return jnp.einsum(eq, small.astype(jnp.float32),
+                      cache.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+  terms = _bf16_terms(small)
+  out = jnp.einsum(eq, jnp.concatenate(terms, axis=1),
+                   cache.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+  n = small.shape[1]
+  return sum(out[:, i * n:(i + 1) * n] for i in range(len(terms)))
+
+
+def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
+                      k_scale=None, v_scale=None):
+  """Masked softmax attention of a query block over a KV cache AND the
+  block's own keys/values, which the cache does not hold yet.
+
+  ``q [b, seg, h, d]`` (rotated), ``k`` / ``v`` ``[b, seg, kv_heads, d]``
+  the block's own keys and values AS THE CACHE WILL HOLD THEM (rotated;
+  dequantized int8 for an int8 cache); ``cached_k`` / ``cached_v``
+  ``[b, max, kv_heads * d]`` — the cache's stored, lane-dense layout, as it
+  was BEFORE this block is written; ``q_pos [b | 1, seg]`` each query's
+  absolute position. A query attends the cache entries before the block's
+  first position (later ones are unwritten or stale) and the block's
+  entries up to its own; ``window`` > 0 masks entries older than the
+  window in both. Query head i reads KV head i // g. int8 caches pass
+  their ``[b, max, kv_heads]`` scales, applied to K-INDEXED tensors —
+  scores (sum_d q·k8·s[k] = (sum_d q·k8)·s[k]) and probs (folding v's
+  scale) — so no dequantized cache-sized tensor exists in the program.
+
+  Reading the cache as it was keeps the read and the block's write
+  INDEPENDENT: the compiler stages each cache leaf in fast memory for the
+  big contraction, and a leaf that was written there first has to be
+  copied back whole (3 GB a decode step at gpt2-large's widths: half of
+  the step, PERF.md section 6, PR 25); a leaf that is only read is not.
+
+  A narrow query block (decode steps, a speculative verify window)
+  contracts against the cache AS STORED: q is expanded block-diagonally to
+  ``[b, seg*h, kv_heads*d]`` (head i's d values in KV head i//g's block,
+  zeros elsewhere), so scores are one batched matmul over the folded axis
+  and the output is each head's own block of ``probs @ V``. No 4-D view of
+  the cache exists, so the compiler has nothing to relayout: on the TPU a
+  ``[.., kv_heads, 64]`` view pads 64 to 128 lanes and copies the slab in
+  and out of that layout at every program's edge. A wide block reshapes
+  its (one-row) cache to 4-D instead. Probabilities stay f32 all the way
+  into V either way.
+  """
+  b, seg, h, d = q.shape
+  mx = cached_k.shape[1]
+  hk = cached_k.shape[2] // d
+  g = h // hk
+  folded = seg * h <= _MXU_COLS
+  scale = 1.0 / (d ** 0.5)
+  qg = q.reshape(b, seg, hk, g, d).astype(jnp.float32)
+  if folded:
+    # own[i, j] = 1 where query head i reads KV head j
+    own = jnp.repeat(jnp.eye(hk, dtype=jnp.float32), g,
+                     axis=0)[None, None, :, :, None]
+    q_bd = (q[:, :, :, None, :] * own.astype(q.dtype)).reshape(
+        b, seg * h, hk * d)
+    s_cache = _cache_contract("bnc,bkc->bnk", q_bd, cached_k)
+  else:
+    s_cache = jnp.einsum(
+        "bqhgd,bkhd->bqhgk", qg,
+        cached_k.reshape(b, mx, hk, d).astype(jnp.float32))
+  s_cache = s_cache.reshape(b, seg, h, mx) * scale
+
+  def per_head(s):               # [b, max, hk] -> [b, 1, h, max]
+    return jnp.repeat(s.transpose(0, 2, 1), g, axis=1)[:, None]
+
+  if k_scale is not None:
+    s_cache = s_cache * per_head(k_scale)
+  # the block against itself: small, so plainly in f32
+  s_own = jnp.einsum("bqhgd,bkhd->bqhgk", qg, k.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+  s_own = s_own.reshape(b, seg, h, seg) * scale
+
+  k_pos, own_pos = jnp.arange(mx), jnp.arange(seg)
+  keep_cache = k_pos < q_pos[:, :1, None]       # written before the block
+  causal = own_pos[None, :] <= own_pos[:, None]
+  if window:
+    # sliding window: entries older than the window are masked (they stay
+    # in the cache buffer; the mask is what bounds decode)
+    keep_cache = jnp.logical_and(keep_cache,
+                                 k_pos > q_pos[..., None] - window)
+    causal = jnp.logical_and(causal,
+                             own_pos[None, :] > own_pos[:, None] - window)
+  s_cache = jnp.where(keep_cache[:, :, None, :], s_cache, -1e30)
+  s_own = jnp.where(causal[None, :, None, :], s_own, -1e30)
+  # ONE softmax over both parts (a query always keeps its own entry)
+  top = jnp.maximum(s_cache.max(axis=-1), s_own.max(axis=-1))[..., None]
+  e_cache, e_own = jnp.exp(s_cache - top), jnp.exp(s_own - top)
+  total = e_cache.sum(axis=-1) + e_own.sum(axis=-1)      # [b, seg, h]
+  if v_scale is not None:
+    e_cache = e_cache * per_head(v_scale)
+  if folded:
+    o = _cache_contract("bnk,bkc->bnc", e_cache.reshape(b, seg * h, mx),
+                        cached_v)
+    o = (o.reshape(b, seg, h, hk, d) * own).sum(axis=3)
+  else:
+    o = jnp.einsum(
+        "bqhgk,bkhd->bqhgd", e_cache.reshape(b, seg, hk, g, mx),
+        cached_v.reshape(b, mx, hk, d).astype(jnp.float32)).reshape(
+            b, seg, h, d)
+  o = o + jnp.einsum(
+      "bqhgk,bkhd->bqhgd", e_own.reshape(b, seg, hk, g, seg),
+      v.astype(jnp.float32),
+      precision=lax.Precision.HIGHEST).reshape(b, seg, h, d)
+  return (o / total[..., None]).astype(q.dtype)
+
+
 class Attention(nn.Module):
   cfg: TransformerConfig
   mesh: Optional[Any] = None
@@ -426,13 +570,16 @@ class Attention(nn.Module):
     """Incremental attention against a KV cache (serving path).
 
     Writes the new keys/values at the cache cursor, attends the query
-    block against everything cached so far, and advances the cursor.
-    Cache shape is [batch, max_seq_len, kv_heads, head_dim] per layer —
-    under GQA the cache holds only the grouped KV heads (the serving
-    memory win), and the attention einsums carry an explicit group axis
-    instead of materializing an expanded cache. A fresh-cache prefill of
-    a block-divisible segment runs through the GQA flash kernel instead
-    of the seg × max_seq dense einsum (see the cond below).
+    block against everything cached before it plus the block itself
+    (``_cached_attention``: the read takes the cache as it was, so it does
+    not wait on the write), and advances the cursor. Cache shape is
+    [batch, max_seq_len, kv_heads * head_dim] per layer: heads folded into
+    the minor axis, so the stored layout is lane-dense at every head_dim
+    and is the layout decode attention computes on — under GQA the cache
+    holds only the grouped KV heads (the serving memory win), never an
+    expanded copy. A fresh-cache prefill of a block-divisible segment runs
+    through the GQA flash kernel instead of the seg × max_seq dense einsum
+    (see the cond below).
 
     The cursor (``cache/index``) is either a SCALAR — all rows in
     lockstep, the classic batched-decode path — or a VECTOR of per-row
@@ -449,10 +596,10 @@ class Attention(nn.Module):
     quant = cfg.kv_cache_dtype == "int8"
     cache_dt = jnp.int8 if quant else cfg.dtype
     cached_k = self.variable(
-        "cache", "cached_k", jnp.zeros, (b, cfg.max_seq_len, hk, d),
+        "cache", "cached_k", jnp.zeros, (b, cfg.max_seq_len, hk * d),
         cache_dt)
     cached_v = self.variable(
-        "cache", "cached_v", jnp.zeros, (b, cfg.max_seq_len, hk, d),
+        "cache", "cached_v", jnp.zeros, (b, cfg.max_seq_len, hk * d),
         cache_dt)
     if quant:
       k_scale = self.variable("cache", "k_scale", jnp.zeros,
@@ -471,10 +618,10 @@ class Attention(nn.Module):
     q = _rotary(q, positions)
     k = _rotary(k, positions)
 
-    def _cache_write(buf, val, trail):
-      """Write ``val`` at the cursor: one dynamic_update_slice for the
-      shared scalar cursor, a vmapped per-row update (one scatter) for
-      per-slot cursors. ``trail``: trailing dims after the seq axis.
+    def _cache_write(buf, val):
+      """Write ``val [b, seg, c]`` at the cursor: one
+      dynamic_update_slice for the shared scalar cursor, a vmapped per-row
+      update (one scatter) for per-slot cursors.
 
       Multi-token per-row writes go through an explicit OOB-dropping
       scatter instead: a speculative verify window may transiently
@@ -484,22 +631,21 @@ class Attention(nn.Module):
       cursor (breaking bit-parity) instead of dropping the overflow
       (which is never attended: accepted tokens stay within budget)."""
       if not vec:
-        return jax.lax.dynamic_update_slice(
-            buf, val, (0, idx) + (0,) * trail)
+        return jax.lax.dynamic_update_slice(buf, val, (0, idx, 0))
       if seg == 1:
         # single-token decode can never overshoot (cursor < max_seq_len
         # by the submit-time budget check): keep the cheap update-slice
         return jax.vmap(
             lambda row, v, i: jax.lax.dynamic_update_slice(
-                row, v, (i,) + (0,) * trail))(buf, val, idx)
+                row, v, (i, 0)))(buf, val, idx)
       rows = jnp.broadcast_to(jnp.arange(b)[:, None], (b, seg)).reshape(-1)
       pos = positions.reshape(-1)          # OOB entries drop, not clamp
-      return buf.at[rows, pos].set(val.reshape((b * seg,) + val.shape[2:]))
-    # tensor-parallel serving: keep the cache sharded on its (grouped)
-    # heads dim so each chip holds 1/t of the KV bytes and attends its own
-    # head slice — without the constraint GSPMD may gather the cache.
-    # Same divisibility rule as the projection kernels (_heads_logical).
-    kv_spec = ("batch", None, _heads_logical(hk, self.mesh), "kv")
+      return buf.at[rows, pos].set(val.reshape(b * seg, val.shape[2]))
+    # tensor-parallel serving: keep the cache sharded on its folded
+    # (grouped) heads axis so each chip holds 1/t of the KV bytes — whole
+    # heads, by the divisibility rule the projection kernels share
+    # (_heads_logical) — without the constraint GSPMD may gather the cache.
+    kv_spec = ("batch", None, _heads_logical(hk, self.mesh))
 
     def _quantize(x):
       # per-token/head symmetric int8 over the head dim
@@ -509,68 +655,37 @@ class Attention(nn.Module):
       v8 = jnp.clip(jnp.round(xf / s[..., None]), -127, 127)
       return v8.astype(jnp.int8), s
 
+    # what the dense attention reads: the cache before this block's write
+    # (_cached_attention says why) and the block as the cache stores it
+    was = dict(cached_k=cached_k.value, cached_v=cached_v.value)
     if quant:
-      k8, ks = _quantize(k)
-      v8, vs = _quantize(v)
-      k_store, v_store = k8, v8
-      k_scale.value = _constrain(_cache_write(k_scale.value, ks, 1),
-                                 kv_spec[:3], self.mesh)
-      v_scale.value = _constrain(_cache_write(v_scale.value, vs, 1),
-                                 kv_spec[:3], self.mesh)
+      k_store, ks = _quantize(k)
+      v_store, vs = _quantize(v)
+      k_own = k_store.astype(jnp.float32) * ks[..., None]
+      v_own = v_store.astype(jnp.float32) * vs[..., None]
+      was.update(k_scale=k_scale.value, v_scale=v_scale.value)
+      k_scale.value = _constrain(_cache_write(k_scale.value, ks),
+                                 kv_spec, self.mesh)
+      v_scale.value = _constrain(_cache_write(v_scale.value, vs),
+                                 kv_spec, self.mesh)
     else:
       k_store, v_store = k.astype(cfg.dtype), v.astype(cfg.dtype)
-    cached_k.value = _constrain(_cache_write(cached_k.value, k_store, 2),
-                                kv_spec, self.mesh)
-    cached_v.value = _constrain(_cache_write(cached_v.value, v_store, 2),
-                                kv_spec, self.mesh)
+      k_own, v_own = k_store, v_store
+    cached_k.value = _constrain(
+        _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d)),
+        kv_spec, self.mesh)
+    cached_v.value = _constrain(
+        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d)),
+        kv_spec, self.mesh)
     cursor.value = idx + seg
 
-    scale = 1.0 / (d ** 0.5)
-
     def _dense_attend(_):
-      # q regrouped [b, seg, kv_head, group, d]: query head i = KV head
-      # i//g; attends the whole cache with the causal+unwritten mask.
-      # int8 cache: the scales apply to K-INDEXED tensors — scores
-      # (sum_d q·k8·s[k] = (sum_d q·k8)·s[k]) and probs (folding v's
-      # scale) — so no dequantized cache-sized f32 tensor exists in the
-      # program AT ALL; the dots consume the int8 values via a bare
-      # convert (the per-step HBM traffic is the int8 bytes by
-      # construction, not by hoping a broadcast-multiply fuses)
-      kf = cached_k.value.astype(jnp.float32)
-      vf = cached_v.value.astype(jnp.float32)
-      qg = q.reshape(b, seg, hk, h // hk, d).astype(jnp.float32)
-      scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kf) * scale
-      if quant:
-        # [b, max, hk] -> [b, hk, 1, 1, max] over the scores' k dim
-        ks5 = k_scale.value.transpose(0, 2, 1)[:, :, None, None, :]
-        scores = scores * ks5
-      if vec:
-        # per-row cursors: each slot masks against ITS length
-        q_pos = idx[:, None, None] + jnp.arange(seg)[None, :, None]
-        k_pos = jnp.arange(cfg.max_seq_len)[None, None, :]
-        keep = k_pos <= q_pos                         # [b, seg, max]
-        if cfg.attention_window:
-          keep = jnp.logical_and(keep,
-                                 k_pos > q_pos - cfg.attention_window)
-        mask = keep[:, None, None]                    # [b,1,1,seg,max]
-      else:
-        q_pos = idx + jnp.arange(seg)[:, None]        # [seg, 1]
-        k_pos = jnp.arange(cfg.max_seq_len)[None, :]  # [1, max]
-        keep = k_pos <= q_pos                         # causal + unwritten
-        if cfg.attention_window:
-          # sliding window: cache entries older than the window are
-          # masked (they stay in the cache buffer; the mask is what
-          # bounds decode)
-          keep = jnp.logical_and(keep,
-                                 k_pos > q_pos - cfg.attention_window)
-        mask = keep[None, None, None]
-      scores = jnp.where(mask, scores, -1e30)
-      probs = jax.nn.softmax(scores, axis=-1)
-      if quant:
-        vs5 = v_scale.value.transpose(0, 2, 1)[:, :, None, None, :]
-        probs = probs * vs5
-      o = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vf)
-      return o.reshape(b, seg, h, d).astype(q.dtype)
+      # the cache as it was plus the block itself (what the write above
+      # stored of it); per-row cursors (vec) mask each slot against ITS
+      # length
+      return _cached_attention(
+          q, k_own, v_own, q_pos=positions if vec else positions[:1],
+          window=cfg.attention_window, **was)
 
     # PREFILL fast path: a fresh-cache multi-token segment attends only
     # within itself (causal), so the flash kernel runs it O(seg²)-tiled
@@ -1207,12 +1322,16 @@ def greedy_generate_kv(params, cfg: TransformerConfig, prompt,
 
 
 def _zero_cache(model, batch: int):
-  """A fresh all-zeros decode cache for ``model`` (init runs the decode
-  path on a dummy token; zeroing resets its cursor advance)."""
-  return jax.tree.map(
-      jnp.zeros_like,
-      model.init(jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
-                 decode=True)["cache"])
+  """A fresh all-zeros decode cache for ``model``: the cache's shapes from
+  an ABSTRACT init of the decode path on a dummy token, then zeros. Run
+  for real, ``init`` draws every parameter and executes every layer op by
+  op, each op its own small program, only for the result to be thrown
+  away: seconds of every serving start at gpt2-large's size."""
+  shapes = jax.eval_shape(
+      lambda: model.init(jax.random.PRNGKey(0),
+                         jnp.zeros((batch, 1), jnp.int32),
+                         decode=True)["cache"])
+  return jax.tree.map(lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes)
 
 
 def _set_cache_cursor(cache, value):

@@ -293,6 +293,10 @@ class ServingEngine(object):
                   # device dispatches: one per _decode_once, one per
                   # prefill chunk (SlotDecoder.prefill counts them)
                   "decode_dispatches": 0, "prefill_chunks": 0,
+                  # calls of a slab-returning program, and those after
+                  # which the slab that went in is deleted: its donation
+                  # was USED, the program ran in place (_on_slab)
+                  "slab_dispatches": 0, "slab_in_place": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -391,6 +395,23 @@ class ServingEngine(object):
       self._prefix = sched.PrefixCache(self.page_size, self.prefix_pages) \
           if self.prefix_pages > 0 else None
       self._req_pages = {}
+
+  def _on_slab(self, op):
+    """Run ``op(slab)`` — one slab-returning ``SlotDecoder`` program — on
+    the engine's slab and rebind ``self._slabs`` to the slab it returns
+    (the result, or a tuple's first member). Every such program takes its
+    slab donated; JAX only WARNS when a donation turns out unusable (an
+    output whose shape or layout no longer matches) and then copies the
+    slab in silence, so the counters say what happened: the slab that went
+    in is deleted exactly when the program took it over. Loop thread
+    only: the slab is lifecycle-fenced (start() and stop() touch it before
+    the thread exists and after its join), so no lock is held here."""
+    old = self._slabs
+    out = op(old)
+    self._slabs = out[0] if isinstance(out, tuple) else out
+    self.stats["slab_dispatches"] += 1
+    self.stats["slab_in_place"] += slots_lib.consumed(old)
+    return out
 
   def start(self) -> "ServingEngine":
     if self._thread is not None and self._thread.is_alive():
@@ -1013,7 +1034,7 @@ class ServingEngine(object):
       return
     mask = np.zeros((self.num_slots,), bool)
     mask[freed] = True
-    self._slabs = self.decoder.reset_slots(self._slabs, mask)
+    self._on_slab(lambda slabs: self.decoder.reset_slots(slabs, mask))
 
   def _reap_queue(self, now: float) -> None:
     for req in self._queue.reap(
@@ -1167,10 +1188,11 @@ class ServingEngine(object):
         continue                 # slot stays free for the next request
       with self._phase("serve.insert", "t_insert_s"):
         if self.decoder.paged:
-          self._slabs = self.decoder.insert_pages(
-              self._slabs, row_cache, slot, table, start=shared_tokens)
+          self._on_slab(lambda slabs: self.decoder.insert_pages(
+              slabs, row_cache, slot, table, start=shared_tokens))
         else:
-          self._slabs = self.decoder.insert(self._slabs, row_cache, slot)
+          self._on_slab(lambda slabs: self.decoder.insert(
+              slabs, row_cache, slot))
       if self.decoder.paged:
         if self._prefix is not None:
           # the prompt's full pages become shareable: the cache takes
@@ -1297,9 +1319,8 @@ class ServingEngine(object):
     three phases of a dispatch are regions: the call returning, the wait
     for the token matrix, and the host's harvest of it."""
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
-      self._slabs, toks, _, _ = self.decoder.step_many(
-          self.params, self._slabs, self._last, active, remaining,
-          self.horizon)
+      _, toks, _, _ = self._on_slab(lambda slabs: self.decoder.step_many(
+          self.params, slabs, self._last, active, remaining, self.horizon))
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(toks)                     # [horizon, num_slots]
     lanes: List[tuple] = []
@@ -1335,8 +1356,9 @@ class ServingEngine(object):
     """
     k, rounds = self.spec_depth, self._spec_rounds
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
-      self._slabs, toks, counts, acc, rej, _, _ = self.decoder.step_spec(
-          self.params, self._slabs, self._last, active, remaining, rounds)
+      _, toks, counts, acc, rej, _, _ = self._on_slab(
+          lambda slabs: self.decoder.step_spec(
+              self.params, slabs, self._last, active, remaining, rounds))
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(toks)          # [rounds, spec_depth, num_slots]
       counts = np.asarray(counts)      # [rounds, num_slots]

@@ -3,9 +3,10 @@
 The slab is ONE persistent KV-cache pytree with a fixed slot capacity.
 Two layouts exist:
 
-* CONTIGUOUS (default): per layer ``[num_slots, max_seq_len, kv_heads,
-  head_dim]`` key/value buffers plus a VECTOR cursor ``index:
-  [num_slots]`` (the per-slot-cursor branch of
+* CONTIGUOUS (default): per layer ``[num_slots, max_seq_len, kv_heads *
+  head_dim]`` key/value buffers (heads folded into the minor axis: the
+  layout decode attention computes on, lane-dense at every head_dim) plus
+  a VECTOR cursor ``index: [num_slots]`` (the per-slot-cursor branch of
   ``models.transformer.Attention._decode_attend``). Every slot reserves
   ``max_seq_len`` of HBM whether it needs it or not.
 * PAGED (``page_size > 0``): per layer a page POOL ``[num_pages,
@@ -57,6 +58,12 @@ Jitted functions owning the slab:
 Everything here is functional — the ``serving.engine.ServingEngine``
 thread owns the slab value and the host-side bookkeeping (which slots
 are live, per-request budgets/EOS, the page allocator / prefix trie).
+
+The slab is ONE buffer for the life of its owner: every program that
+returns a slab takes its slab argument DONATED and updates it in place, so
+the value a caller passed in is deleted by the call — rebind the result
+(``slabs = dec.insert(slabs, ...)``) and never read the old one again.
+Row caches (``prefill``'s result, ``gather_pages``') are not donated.
 """
 
 import dataclasses
@@ -122,6 +129,14 @@ def _with_cursor(slabs, vec):
       slabs)
 
 
+def consumed(slabs) -> bool:
+  """Whether a program took ``slabs`` over: every buffer of a donated
+  argument is deleted once the program that aliases it has been issued,
+  and none is when the donation could not be used (JAX then only warns,
+  and the program copies)."""
+  return all(leaf.is_deleted() for leaf in jax.tree.leaves(slabs))
+
+
 class SlotDecoder(object):
   """Jitted slab operations for one (config, num_slots) serving shape.
 
@@ -178,11 +193,13 @@ class SlotDecoder(object):
     # jit caches retrace per chunk shape (bounded by the bucket set) /
     # once for insert+step (fixed slab shapes)
     self._prefill_fn = jax.jit(self._prefill_impl)
-    self._insert_fn = jax.jit(self._insert_impl)
-    self._insert_pages_fn = jax.jit(self._insert_pages_impl)
+    self._insert_fn = jax.jit(self._insert_impl, donate_argnums=0)
+    self._insert_pages_fn = jax.jit(self._insert_pages_impl,
+                                    donate_argnums=0)
     self._gather_pages_fn = jax.jit(self._gather_pages_impl)
-    self._reset_slots_fn = jax.jit(self._reset_slots_impl)
-    self._step_fn = jax.jit(self._step_impl)
+    self._reset_slots_fn = jax.jit(self._reset_slots_impl,
+                                   donate_argnums=0)
+    self._step_fn = jax.jit(self._step_impl, donate_argnums=1)
     self._step_many_jits = {}    # horizon -> jitted fused-scan step
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
@@ -343,10 +360,12 @@ class SlotDecoder(object):
       pg = jnp.where(valid, pages[jnp.clip(pos // ps, 0, pp - 1)], 0)
       off = pos % ps
       new = dict(att_s)
-      new["pages_k"] = att_s["pages_k"].at[pg, off].set(
-          att_r["cached_k"][0].astype(att_s["pages_k"].dtype))
-      new["pages_v"] = att_s["pages_v"].at[pg, off].set(
-          att_r["cached_v"][0].astype(att_s["pages_v"].dtype))
+      for name in ("k", "v"):
+        pool = att_s["pages_" + name]
+        # the row cache folds heads into its minor axis; the pool does not
+        new["pages_" + name] = pool.at[pg, off].set(
+            att_r["cached_" + name][0].astype(pool.dtype).reshape(
+                (max_len,) + pool.shape[-2:]))
       new["page_table"] = att_s["page_table"].at[slot].set(pages)
       new["index"] = att_s["index"].at[slot].set(plen)
       return new
@@ -380,8 +399,9 @@ class SlotDecoder(object):
       row = {}
       for name in ("cached_k", "cached_v"):
         src = att_s["pages_" + name[-1]]
-        flat = src[pages].reshape(pp * ps, hk, d)
-        buf = jnp.zeros((max_len, hk, d), src.dtype)
+        # heads fold into the row cache's minor axis
+        flat = src[pages].reshape(pp * ps, hk * d)
+        buf = jnp.zeros((max_len, hk * d), src.dtype)
         row[name] = buf.at[:take].set(flat[:take])[None]
       row["index"] = n_tokens.astype(jnp.int32)
       return row
@@ -500,7 +520,7 @@ class SlotDecoder(object):
             body, (slabs, tok, active, remaining), None, length=_h)
         return slabs, toks, active, remaining
 
-      fn = self._step_many_jits[horizon] = jax.jit(impl)
+      fn = self._step_many_jits[horizon] = jax.jit(impl, donate_argnums=1)
     return fn
 
   # -- self-speculative decode ----------------------------------------------
@@ -604,7 +624,7 @@ class SlotDecoder(object):
         toks, counts, acc, rej = ys
         return slabs, toks, counts, acc, rej, active, remaining
 
-      fn = self._step_spec_jits[rounds] = jax.jit(impl)
+      fn = self._step_spec_jits[rounds] = jax.jit(impl, donate_argnums=1)
       obs_device.capture_cost(
           "serve.step_spec.r%d" % rounds, fn, params, slabs,
           jnp.asarray(last_tokens, jnp.int32),

@@ -604,11 +604,13 @@ class TestTransformer:
     model = tfm.Transformer(cfg)
     variables = model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 1), jnp.int32), decode=True)
+    # [batch, max_seq_len, kv_heads * head_dim]: heads folded into the
+    # minor axis (the layout decode attention computes on)
     kv_arrays = [leaf for leaf in jax.tree.leaves(variables["cache"])
-                 if getattr(leaf, "ndim", 0) == 4]
+                 if getattr(leaf, "ndim", 0) == 3]
     assert kv_arrays, "no KV cache arrays found"
     for leaf in kv_arrays:
-      assert leaf.shape[2] == 2, leaf.shape
+      assert leaf.shape == (1, 32, 2 * cfg.head_dim), leaf.shape
 
   def test_gqa_kv_cache_matches_recompute(self):
     """GQA decode through the grouped-einsum cache path must agree with
@@ -937,3 +939,104 @@ class TestSlidingWindowModel:
     full = tfm.greedy_generate(state.params, cfg, prompt, num_steps=10)
     kv = tfm.greedy_generate_kv(state.params, cfg, prompt, num_steps=10)
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(full))
+
+
+class TestCachedAttention:
+  """``transformer._cached_attention``: dense decode attention computed on
+  the cache AS STORED ([b, max, kv_heads * head_dim]) and AS IT WAS before
+  the query block's own keys/values are written, against a plain f32
+  einsum over the 4-D view of the cache WITH the block written in, on a
+  RANDOM cache (entries from the cursor on hold garbage the mask must
+  hide)."""
+
+  B, H, MAX = 3, 4, 64
+
+  @staticmethod
+  def _reference(q, k4, v4, q_pos, window):
+    """[b, seg, h, d] attention in f32 over K/V [b, max, hk, d]."""
+    b, seg, h, d = q.shape
+    g = h // k4.shape[2]
+    kf = jnp.repeat(k4.astype(jnp.float32), g, axis=2)
+    vf = jnp.repeat(v4.astype(jnp.float32), g, axis=2)
+    with jax.default_matmul_precision("highest"):
+      s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kf) / d ** 0.5
+      k_pos = jnp.arange(k4.shape[1])
+      keep = k_pos <= q_pos[..., None]
+      if window:
+        keep = jnp.logical_and(keep, k_pos > q_pos[..., None] - window)
+      s = jnp.where(keep[:, None], s, -jnp.inf)
+      return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), vf)
+
+  @pytest.mark.parametrize("cache", ["bf16", "int8"])
+  @pytest.mark.parametrize("window", [0, 5])
+  @pytest.mark.parametrize("seg", [1, 3, 40])       # 40 x 4 heads: 4-D form
+  @pytest.mark.parametrize("cursor", ["scalar", "per_slot"])
+  @pytest.mark.parametrize("kv_heads", [4, 2])      # MHA, GQA
+  @pytest.mark.parametrize("head_dim", [64, 128])
+  def test_matches_f32_reference(self, head_dim, kv_heads, cursor, seg,
+                                 window, cache):
+    from tensorflowonspark_tpu.models import transformer as tfm
+    b, h, mx, d = self.B, self.H, self.MAX, head_dim
+    assert (seg * h <= tfm._MXU_COLS) == (seg < 40)  # both forms are run
+    rng = np.random.RandomState(head_dim + 7 * kv_heads + seg + window)
+    # the int8 case runs an f32 q: its output is f32, so the check is
+    # tight enough to see a probability rounded to bf16 on its way to V
+    q_dt = jnp.float32 if cache == "int8" else jnp.bfloat16
+    q = jnp.asarray(rng.randn(b, seg, h, d), q_dt)
+    if cache == "int8":
+      k8 = rng.randint(-127, 128, (b, mx, kv_heads, d)).astype(np.int8)
+      v8 = rng.randint(-127, 128, (b, mx, kv_heads, d)).astype(np.int8)
+      ks = rng.uniform(0.002, 0.02, (b, mx, kv_heads)).astype(np.float32)
+      vs = rng.uniform(0.002, 0.02, (b, mx, kv_heads)).astype(np.float32)
+      k4, v4 = k8 * ks[..., None], v8 * vs[..., None]
+      stored, scales = (k8, v8), dict(k_scale=jnp.asarray(ks),
+                                      v_scale=jnp.asarray(vs))
+    else:
+      k4 = jnp.asarray(rng.randn(b, mx, kv_heads, d), jnp.bfloat16)
+      v4 = jnp.asarray(rng.randn(b, mx, kv_heads, d), jnp.bfloat16)
+      stored, scales = (k4, v4), {}
+    start = np.asarray([3, 17, 11]) if cursor == "per_slot" \
+        else np.asarray([9])
+    q_pos = jnp.asarray(start[:, None] + np.arange(seg)[None])
+    # the block's own keys/values, as the cache will hold them (an int8
+    # cache: the dequantized numbers); the reference reads them FROM the
+    # cache, written at the block's positions
+    own_k = jnp.asarray(rng.randn(b, seg, kv_heads, d), jnp.bfloat16)
+    own_v = jnp.asarray(rng.randn(b, seg, kv_heads, d), jnp.bfloat16)
+    rows = np.arange(b)[:, None]
+    at = np.broadcast_to(np.asarray(q_pos), (b, seg))
+    k4 = jnp.asarray(k4, jnp.float32).at[rows, at].set(
+        own_k.astype(jnp.float32))
+    v4 = jnp.asarray(v4, jnp.float32).at[rows, at].set(
+        own_v.astype(jnp.float32))
+    out = tfm._cached_attention(
+        q, own_k, own_v,
+        *(jnp.asarray(x).reshape(b, mx, kv_heads * d) for x in stored),
+        q_pos, window=window, **scales)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref = self._reference(q, k4, v4, q_pos, window)
+    tol = 2e-5 if q_dt == jnp.float32 else 2 ** -7   # one bf16 rounding
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+  def test_cache_contract_rounds_neither_operand(self):
+    """An f32 operand against a bf16 cache: the stacked bf16 terms ARE the
+    f32 number, so the product matches float64 to f32's accuracy (a
+    single bf16 rounding of the operand would miss by about 2^-9)."""
+    from tensorflowonspark_tpu.models import transformer as tfm
+    rng = np.random.RandomState(0)
+    p = jax.nn.softmax(jnp.asarray(rng.randn(2, 5, 96), jnp.float32), -1)
+    v = jnp.asarray(rng.randn(2, 96, 256), jnp.bfloat16)
+    terms = tfm._bf16_terms(p)
+    assert len(terms) == 3 and all(t.dtype == jnp.bfloat16 for t in terms)
+    np.testing.assert_array_equal(
+        np.asarray(sum(t.astype(jnp.float32) for t in terms)), np.asarray(p))
+    got = np.asarray(tfm._cache_contract("bnk,bkc->bnc", p, v), np.float64)
+    want = np.einsum("bnk,bkc->bnc", np.asarray(p, np.float64),
+                     np.asarray(v.astype(jnp.float32), np.float64))
+    assert np.abs(got - want).max() < 1e-6
+    rounded = np.einsum(
+        "bnk,bkc->bnc",
+        np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32), np.float64),
+        np.asarray(v.astype(jnp.float32), np.float64))
+    assert np.abs(rounded - want).max() > 1e-4        # the test has teeth

@@ -86,9 +86,11 @@ def test_int8_cache_never_materializes_f32(topo, monkeypatch):
   fn, args = TARGETS["serving_decode_int8"]()
   hlo = fn.lower(*args).compile().as_text()
   # per-shard cache shape for the target's config: batch 4 over data=2,
-  # max_seq 64, kv_heads 2 over tensor=2, head_dim 128/4 = 32
-  cache_shape = "2,64,1,32"
-  bad = [l for l in hlo.splitlines() if "f32[%s]" % cache_shape in l]
+  # max_seq 64, the folded kv_heads * head_dim axis (2 x 128/4 = 64) over
+  # tensor=2; neither that shape nor a 4-D view of it may exist in f32
+  cache_shape = "2,64,32"
+  bad = [l for l in hlo.splitlines()
+         if "f32[%s]" % cache_shape in l or "f32[2,64,1,32]" in l]
   assert not bad, "dequantized f32 cache tensors:\n" + "\n".join(bad[:4])
   assert re.search(r"s8\[%s\]" % cache_shape, hlo)   # the cache IS int8
 
@@ -144,6 +146,58 @@ def test_smoke_serving_programs_compile(topo, monkeypatch, name):
     # the fused LayerNorm rides every forward; a data-movement-only
     # program (insert) has no kernel to carry
     assert res["tpu_custom_calls"] >= 1, res
+
+
+@pytest.mark.parametrize("name", ("smoke_insert", "smoke_step_many"))
+def test_smoke_slab_programs_run_in_place(topo, monkeypatch, name):
+  """The slab is ONE buffer in ONE layout: the program aliases the whole
+  slab it was given (``alias`` = the slab's bytes), keeps under a tenth of
+  it in temporaries (beside the bf16 copies of the smoke's f32 parameters,
+  at most half their bytes, which the compiler hoists out of the scan),
+  and its entry computation copies nothing of a slab leaf's shape: no
+  relayout into a padded 4-D layout and back (PERF.md section 6, PR 25:
+  40% of the serving cells' device time before it; this program's temp
+  was 434 MB then, 2.9 slabs)."""
+  import jax
+  from tools.mosaic_gate import TARGETS, compiled_facts
+  monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
+  fn, args = TARGETS[name]()
+  def nbytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+  slabs, params = (args[0], ()) if name == "smoke_insert" else args[1::-1]
+  leaves = jax.tree.leaves(slabs)
+  slab_bytes = nbytes(slabs)
+  big = {"%s[%s]" % ({"bfloat16": "bf16"}[x.dtype.name],
+                      ",".join(map(str, x.shape)))
+         for x in leaves if x.ndim == 3}
+  assert len(big) == 1, big
+  facts = compiled_facts(fn.lower(*args).compile())
+  mb = facts["memory_bytes"]
+  # the runtime pads each tiny cursor leaf to a tile: a few KB over
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, (mb, slab_bytes)
+  assert mb["temp"] < 0.1 * slab_bytes + nbytes(params) / 2, (mb, slab_bytes)
+  assert not big & set(facts["entry_copies"]), facts["entry_copies"]
+
+
+def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
+                                                            monkeypatch):
+  """The benchmark's serving step at its real size (gpt2-large, 16 slots
+  x 1024, horizon 4): the 3.02 GB slab is aliased whole, temporaries stay
+  under 0.5 GB (7.31 GB before PR 25), the entry computation copies no
+  slab leaf, and the compiler copies at most a few of the 72 leaves BACK
+  from fast memory a step: attention reads the cache as it was before the
+  step's write, so the staged copy of a leaf is read-only (71 of 72 came
+  back whole, 3 GB a step, while the write came first)."""
+  res = _gate_one("gpt2l_step_many", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 72 * 16 * 1024 * 1280 * 2
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.5e9, mb
+  leaf = "bf16[16,1024,1280]"
+  assert leaf not in res["entry_copies"], res["entry_copies"]
+  assert res["copies_back_to_hbm"].get(leaf, 0) <= 4, \
+      res["copies_back_to_hbm"]
 
 
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):

@@ -253,7 +253,10 @@ def test_profiler_session_alone_shows_the_nested_phases(toy, tmp_path):
             for e in line.events if e.name.startswith("serve.")]
            for plane in ProfileData.from_file(path).planes
            if plane.name.startswith("/host:") for line in plane.lines]
-  (loop,) = [evs for evs in lines if evs]        # one thread: the loop's
+  # one thread: THIS engine's loop. An engine that an earlier test file of
+  # the same worker process left polling shows too, but only ever idle
+  (loop,) = [evs for evs in lines
+             if any(n == "serve.decode" for n, _, _ in evs)]
 
   def inside(child, parent):
     outer = [(s, e) for n, s, e in loop if n == parent]

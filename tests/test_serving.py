@@ -210,6 +210,47 @@ class TestSlotDecoder:
         "live slot must advance, idle slot must stay frozen"
     assert int(np.asarray(nxt)[1]) == PAD
 
+  @pytest.mark.parametrize("op", ["insert", "step", "step_many", "step_spec",
+                                  "insert_pages", "reset_slots"])
+  def test_slab_programs_run_in_place(self, tiny_state, op):
+    """Every program that returns a slab takes its slab DONATED and uses
+    the donation: the slab that went in is deleted by the call, and JAX
+    raised no "donated buffers were not usable" warning (how a layout or
+    shape mismatch between the slab going in and coming out would show:
+    the program would then copy the whole slab in silence). The row cache
+    is not donated."""
+    import warnings
+    from tensorflowonspark_tpu.serving import slots as slots_lib
+    cfg, state = tiny_state
+    paged = op in ("insert_pages", "reset_slots")
+    dec = SlotDecoder(cfg, 2, page_size=4 if paged else 0,
+                      spec_depth=2 if op == "step_spec" else 0)
+    slabs = dec.init_slabs()
+    row, first = dec.prefill(state.params, np.asarray([3, 4, 5], np.int32))
+    pages = np.eye(1, dec.pages_per_slot, dtype=np.int32)[0]   # [1, 0..]
+    toks, live, left = [first, PAD], [True, False], [4, 0]
+    call = {
+        "insert": lambda: dec.insert(slabs, row, 0),
+        "step": lambda: dec.step(state.params, slabs, toks, live)[0],
+        "step_many": lambda: dec.step_many(
+            state.params, slabs, toks, live, left, 2)[0],
+        "step_spec": lambda: dec.step_spec(
+            state.params, slabs, toks, live, left, 1)[0],
+        "insert_pages": lambda: dec.insert_pages(slabs, row, 0, pages),
+        "reset_slots": lambda: dec.reset_slots(slabs, [True, False]),
+    }[op]
+    assert not slots_lib.consumed(slabs)
+    with warnings.catch_warnings(record=True) as caught:
+      warnings.simplefilter("always")
+      out = call()
+      jax.block_until_ready(out)
+    assert not [w for w in caught if "donated" in str(w.message).lower()], \
+        [str(w.message) for w in caught]
+    assert slots_lib.consumed(slabs)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(out))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(row))
+    assert jax.tree.structure(out) == jax.tree.structure(slabs)
+
 
 class TestServingEngine:
   def test_mixed_length_parity(self, tiny_state):
@@ -237,6 +278,30 @@ class TestServingEngine:
     for p, b, out in zip(prompts, budgets, outs):
       np.testing.assert_array_equal(out,
                                     _reference(state.params, cfg, p, b))
+
+  @pytest.mark.parametrize("stack", ["contiguous", "paged_prefix", "spec"])
+  def test_every_slab_dispatch_runs_in_place(self, tiny_state, stack):
+    """``slab_dispatches`` counts the engine's calls of slab-returning
+    programs (insert / insert_pages / reset_slots / step_many /
+    step_spec) and ``slab_in_place`` those that took the slab over: on
+    every stack they are equal, and the traffic is still served
+    bit-identically from the one buffer."""
+    cfg, state = tiny_state
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(1, 64, (int(p),)).astype(np.int32)
+               for p in (4, 7, 11, 16, 7, 4)]
+    kw = {"contiguous": {}, "paged_prefix": dict(page_size=4, prefix_pages=6),
+          "spec": dict(spec_depth=3)}[stack]
+    with ServingEngine(state.params, cfg, num_slots=3, eos_id=EOS,
+                       **kw) as eng:
+      outs = eng.generate(prompts, max_new_tokens=8, timeout=120)
+      stats = dict(eng.stats)
+    assert stats["slab_dispatches"] >= stats["prefills"] \
+        + stats["decode_dispatches"] > 0
+    assert stats["slab_in_place"] == stats["slab_dispatches"]
+    for p, out in zip(prompts, outs):
+      np.testing.assert_array_equal(out,
+                                    _reference(state.params, cfg, p, 8))
 
   def test_horizon_invariant(self, tiny_state):
     """The decode horizon is a dispatch-amortization knob, never a
@@ -897,6 +962,9 @@ class TestServingChaos:
     assert stats["replays"] >= 1
     assert stats["replay_mismatches"] == 0
     assert stats["poisoned"] == 0
+    # the slab is donated to every program: the crash's victims replay on
+    # a FRESH slab, and every dispatch on either side of it ran in place
+    assert stats["slab_in_place"] == stats["slab_dispatches"] > 0
     assert len(log) == 1 and log[0]["duration_s"] >= 0.01
     replayed = [o for o in outs if o["timing"]["replays"]]
     assert replayed                  # the crash hit someone in flight
@@ -935,6 +1003,7 @@ class TestServingChaos:
     assert stats["engine_restarts"] == 1
     assert stats["replays"] >= 1
     assert stats["replay_mismatches"] == 0
+    assert stats["slab_in_place"] == stats["slab_dispatches"] > 0
     for p, out in zip(prompts, outs):
       np.testing.assert_array_equal(
           out, _reference(state.params, cfg, p, 8))

@@ -777,13 +777,40 @@ def t_smoke_insert():
   return dec._insert_fn, (slabs, row, _i32())
 
 
-def _smoke_step_many(paged: bool):
+def _step_many_target(dec, params, slabs):
+  """``SlotDecoder.step_many``'s program at the engine's default horizon."""
   import jax.numpy as jnp
   from tensorflowonspark_tpu.serving import engine as engine_mod
-  dec, params, _, slabs = _smoke_decoder(paged)
   n = dec.num_slots
   return dec.step_many_jit(engine_mod._DEFAULT_HORIZON), (
       params, slabs, _i32(n), _on_chip0(_sh(n, dtype=jnp.bool_)), _i32(n))
+
+
+def _smoke_step_many(paged: bool):
+  dec, params, _, slabs = _smoke_decoder(paged)
+  return _step_many_target(dec, params, slabs)
+
+
+def t_gpt2l_step_many():
+  """The benchmark's serving step at its real size: gpt2-large (36 x 1280
+  x 20 heads of 64, vocab 50257, bf16 matrices), 16 slots x 1024, horizon
+  4 — the one size at which the compiler's fast-memory staging of a 3 GB
+  slab shows (PERF.md section 6, PR 25)."""
+  import jax
+  import jax.numpy as jnp
+  from flax.core import meta
+  from tensorflowonspark_tpu.models import transformer as tfm
+  from tensorflowonspark_tpu.serving import slots as slots_lib
+  cfg = tfm.TransformerConfig(
+      vocab_size=50257, num_layers=36, num_heads=20, d_model=1280,
+      d_ff=5120, max_seq_len=1024, remat=False)
+  dec = slots_lib.SlotDecoder(cfg, 16)
+  params = _on_chip0(jax.eval_shape(lambda: jax.tree.map(
+      lambda x: x.astype(jnp.bfloat16) if x.ndim > 1 else x,
+      meta.unbox(dec.model.init(
+          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+  return _step_many_target(dec, params,
+                           _on_chip0(jax.eval_shape(dec.init_slabs)))
 
 
 def t_smoke_step_many():
@@ -838,6 +865,7 @@ TARGETS = {
     "smoke_step_many": t_smoke_step_many,
     "smoke_paged_insert": t_smoke_paged_insert,
     "smoke_paged_step_many": t_smoke_paged_step_many,
+    "gpt2l_step_many": t_gpt2l_step_many,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
@@ -846,10 +874,44 @@ TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
 
+def entry_copies(hlo_text: str) -> dict:
+  """``{result shape: count}`` of the plain ``copy`` instructions in the
+  ENTRY computation: what a program moves at its own edges. A copy whose
+  result has the shape of a whole argument (a KV slab leaf) is a relayout
+  or an undonated update of it: the whole buffer read and written once a
+  dispatch (PERF.md section 6, PR 25)."""
+  import re
+  start = hlo_text.find("\nENTRY ")
+  if start < 0:
+    return {}
+  entry = hlo_text[start:hlo_text.index("\n}", start)]
+  out = {}
+  for shape in re.findall(r" = (\w+\[[\d,]*\])\S* copy\(", entry):
+    out[shape] = out.get(shape, 0) + 1
+  return out
+
+
+def copies_back_to_hbm(hlo_text: str) -> dict:
+  """``{shape: count}`` of the asynchronous copies whose DESTINATION is
+  plain HBM (no ``S(n)`` memory space): buffers the compiler staged in fast
+  memory, had written there, and so has to copy back whole. A KV slab leaf
+  among them is read AND rewritten in full every decode step (PERF.md
+  section 6, PR 25); a leaf that is only read in fast memory is not."""
+  import re
+  out = {}
+  for shape, layout in re.findall(
+      r"copy-start\.?\d* = \((\w+\[[\d,]*\])(\{[^}]*\})", hlo_text):
+    if "S(" not in layout:
+      out[shape] = out.get(shape, 0) + 1
+  return out
+
+
 def compiled_facts(compiled) -> dict:
   """What a deviceless compile can say about a program: per-device bytes
   (``memory_analysis``), whether the Pallas kernels are in
-  (``tpu_custom_call``) and which collectives the compiler put in."""
+  (``tpu_custom_call``), which collectives the compiler put in, what the
+  entry computation copies (:func:`entry_copies`) and what comes back
+  from fast memory (:func:`copies_back_to_hbm`)."""
   facts = {}
   m = compiled.memory_analysis()
   if m is not None:
@@ -865,6 +927,8 @@ def compiled_facts(compiled) -> dict:
       op: text.count(op + "(") + text.count(op + "-start(")
       for op in ("all-reduce", "all-gather", "reduce-scatter",
                  "collective-permute", "all-to-all")}
+  facts["entry_copies"] = entry_copies(text)
+  facts["copies_back_to_hbm"] = copies_back_to_hbm(text)
   return facts
 
 
