@@ -125,6 +125,57 @@ class TransformerConfig:
   # a one-hot over the vocab-sharded table — no table all-gather at all, at
   # 2·B·S·V·D extra FLOPs, the right trade for huge vocabs on large meshes
   embed_lookup: str = "gather"
+  # Per-layer block spec (ROADMAP R1). ``layer_types[i]`` names layer i's
+  # token mixer: "attn" (the attention above), "kda" (gated delta-rule
+  # linear attention, models/kda.py) or "mla" (latent attention without
+  # rotary positions, models/mla.py); ``ffn_types[i]`` its feed-forward:
+  # "mlp" or "experts" (held sparse experts, models/experts.py). () keeps
+  # every layer "attn" and the moe_experts/moe_every rule: GPT-2 is that
+  # one spec. The new layers' modules are imported only when asked for.
+  layer_types: tuple = ()
+  ffn_types: tuple = ()
+  norm: str = "layer"          # "layer" (LayerNorm) | "rms" (RMSNorm)
+  norm_eps: float = 1e-6
+  mlp_act: str = "gelu"        # "gelu" (ungated) | "swiglu" (gate/up/down)
+  tie_embeddings: bool = True  # False: an untied ``head`` projection
+  attn_head_dim: int = 0       # 0 = d_model // num_heads
+  # KDA: heads of kda_head_dim keys AND values, a depthwise causal
+  # convolution of kda_conv taps, low-rank decay/gate maps of kda_rank
+  kda_heads: int = 0
+  kda_head_dim: int = 128
+  kda_conv: int = 4
+  kda_rank: int = 128
+  # MLA: num_heads query heads of (nope + rope) dims, one shared latent of
+  # mla_kv_rank (+ mla_rope_dim unrotated key dims) a token
+  mla_kv_rank: int = 512
+  mla_nope_dim: int = 128
+  mla_rope_dim: int = 64
+  mla_v_dim: int = 128
+  # Held experts: the router is experts_total wide (the published count);
+  # THIS program holds experts [experts_first, experts_first + experts_held)
+  # and computes their part of the layer for the tokens routed to them
+  experts_total: int = 0
+  experts_held: int = 0
+  experts_first: int = 0
+  experts_top_k: int = 8
+  experts_d_ff: int = 0
+  experts_shared: int = 1      # shared experts (dense, every token)
+  experts_scale: float = 1.0   # routed_scaling_factor
+  # The typed layers' ACTIVATION precision at a matrix product with bf16
+  # weights (``Proj``, ``_weight_matmul``). False: the activation is rounded
+  # to the compute dtype (one MXU pass). True: the float32 activation goes
+  # in as its three bf16 terms stacked along the rows, so it meets the bf16
+  # weights EXACTLY (three passes over the same weight bytes: free where a
+  # step is bound by bytes, 3x the MXU work in a prefill chunk), and the
+  # residual stream stays float32. A top-k router makes a sparse model with
+  # random weights chaotic: an activation rounded to bf16 moves a near-tie
+  # at the k-th place, the token passes another expert, and the stream is
+  # 12% off the float32 reference by layer 27 (PERF.md section 6, PR 26);
+  # with float32 activations the program follows the reference. It is what
+  # the benchmark's check asks of this model, not what a deployment runs:
+  # trained weights route with margins, and the check decides on the widest
+  # gap of a served token (PERF.md section 7 asks for a p99 or mean limit).
+  act_f32: bool = False
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -163,7 +214,38 @@ class TransformerConfig:
         or self.kv_pages_per_slot < 0:
       raise ValueError("kv_page_size/kv_num_pages/kv_pages_per_slot must "
                        "be >= 0")
+    for name, types, known in (
+        ("layer_types", self.layer_types, ("attn", "kda", "mla")),
+        ("ffn_types", self.ffn_types, ("mlp", "experts"))):
+      if types and (len(types) != self.num_layers
+                    or any(t not in known for t in types)):
+        raise ValueError("%s must name one of %r for each of the %d layers, "
+                         "got %r" % (name, known, self.num_layers, types))
+    if self.norm not in ("layer", "rms"):
+      raise ValueError("norm must be 'layer' or 'rms', got %r" % (self.norm,))
+    if self.mlp_act not in ("gelu", "swiglu"):
+      raise ValueError("mlp_act must be 'gelu' or 'swiglu', got %r"
+                       % (self.mlp_act,))
+    if "experts" in self.ffn_types and not (
+        0 <= self.experts_first
+        and 0 < self.experts_held
+        and self.experts_first + self.experts_held <= self.experts_total
+        and 0 < self.experts_top_k <= self.experts_total):
+      raise ValueError(
+          "held experts [%d, %d) must lie inside the router's %d, with "
+          "0 < experts_top_k=%d <= that" % (
+              self.experts_first, self.experts_first + self.experts_held,
+              self.experts_total, self.experts_top_k))
     if self.kv_page_size > 0:
+      if self.non_kv_layers:
+        raise ValueError(
+            "the paged KV pool (kv_page_size=%d) holds keys and values per "
+            "head in every layer; this model's %s layers cache %s instead "
+            "(a paged latent cache or recurrent state does not exist yet)"
+            % (self.kv_page_size, "/".join(self.non_kv_layers),
+               " and ".join({"kda": "a recurrent state without positions",
+                             "mla": "one shared latent a token"}[t]
+                            for t in self.non_kv_layers)))
       if self.kv_num_pages < 2:
         raise ValueError(
             "paged KV needs kv_num_pages >= 2 (page 0 is the reserved "
@@ -177,8 +259,23 @@ class TransformerConfig:
 
   @property
   def head_dim(self) -> int:
+    if self.attn_head_dim:
+      return self.attn_head_dim
     assert self.d_model % self.num_heads == 0
     return self.d_model // self.num_heads
+
+  @property
+  def non_kv_layers(self) -> tuple:
+    """The layer types here whose decode cache is NOT keys and values per
+    head: "kda" keeps a recurrent state, "mla" one shared latent a token.
+    What pages or shares K/V has to refuse them (serving/slots.py)."""
+    return tuple(sorted(set(self.layer_types) - {"attn"}))
+
+  @property
+  def recurrent_state(self) -> bool:
+    """Whether some layer's decode cache has NO position axis ("kda"): a
+    cursor rollback or a prefix of pages cannot restore such a state."""
+    return "kda" in self.layer_types
 
   @property
   def kv_heads(self) -> int:
@@ -277,6 +374,8 @@ class FusedLayerNorm(nn.Module):
 
 
 def _make_layer_norm(cfg: TransformerConfig, mesh, name: str):
+  if cfg.norm == "rms":
+    return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
   if _fused_ln_eligible(cfg):
     return FusedLayerNorm(mesh=mesh, name=name,
                           interpret=ops.pallas_interpret())
@@ -340,6 +439,32 @@ def _heads_logical(n_heads: int, mesh) -> Optional[str]:
 _MXU_COLS = 128
 
 
+def _cache_write(buf, val, idx, positions):
+  """Write ``val [b, seg, c]`` into the cache leaf ``buf [b, max, c]`` at
+  the cursor ``idx``: one dynamic_update_slice for the shared scalar
+  cursor, a vmapped per-row update (one scatter) for per-slot cursors.
+
+  Multi-token per-row writes go through an explicit OOB-dropping
+  scatter instead: a speculative verify window may transiently
+  overshoot ``max_seq_len`` on a lane whose remaining budget is
+  smaller than the draft depth, and dynamic_update_slice would
+  CLAMP the start — silently overwriting live attended KV below the
+  cursor (breaking bit-parity) instead of dropping the overflow
+  (which is never attended: accepted tokens stay within budget)."""
+  b, seg = val.shape[:2]
+  if idx.ndim == 0:
+    return jax.lax.dynamic_update_slice(buf, val, (0, idx, 0))
+  if seg == 1:
+    # single-token decode can never overshoot (cursor < max_seq_len
+    # by the submit-time budget check): keep the cheap update-slice
+    return jax.vmap(
+        lambda row, v, i: jax.lax.dynamic_update_slice(
+            row, v, (i, 0)))(buf, val, idx)
+  rows = jnp.broadcast_to(jnp.arange(b)[:, None], (b, seg)).reshape(-1)
+  pos = positions.reshape(-1)          # OOB entries drop, not clamp
+  return buf.at[rows, pos].set(val.reshape(b * seg, val.shape[2]))
+
+
 def _bf16_terms(x):
   """``x`` as bf16 arrays that SUM to it: itself when it is bf16, else
   three terms (8 + 8 + 8 significant bits hold an f32's 24).
@@ -375,6 +500,61 @@ def _cache_contract(eq, small, cache):
                    preferred_element_type=jnp.float32)
   n = small.shape[1]
   return sum(out[:, i * n:(i + 1) * n] for i in range(len(terms)))
+
+
+def _weight_matmul(eq, x, w, cfg, f32_out: bool = False):
+  """``einsum(eq, x, w)`` of an activation with a WEIGHT of the typed layers
+  (``x``'s subscripts must not use ``z``). Without ``cfg.act_f32`` both
+  meet in the compute dtype (what ``nn.Dense(dtype=cfg.dtype)`` does; the
+  result stays in it unless ``f32_out``). With it the result is float32
+  and the activation is not rounded: against a bf16 weight ``x`` goes in
+  as its three bf16 terms stacked along a new leading axis
+  (``_bf16_terms``): bf16 x bf16 products are exact in f32, so one matmul
+  of three times the rows IS the float32 activation times the bf16 weight;
+  against a float32 weight (an init, a test) it is the f32 product."""
+  lhs, rest = eq.split(",")
+  rhs, out = rest.split("->")
+  if cfg.act_f32 and w.dtype == jnp.bfloat16:
+    parts = jnp.stack(_bf16_terms(x))
+    return jnp.einsum("z%s,%s->z%s" % (lhs, rhs, out), parts, w,
+                      preferred_element_type=jnp.float32).sum(axis=0)
+  if cfg.act_f32:            # float32 weights (an init, a test): no rounding
+    return jnp.einsum(eq, x.astype(jnp.float32), w.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+  return jnp.einsum(eq, x.astype(cfg.dtype), w.astype(cfg.dtype),
+                    preferred_element_type=jnp.float32 if f32_out else None)
+
+
+def _act_einsum(eq, a, b, cfg):
+  """``einsum`` of two ACTIVATIONS in the typed layers, accumulated in f32:
+  at full float32 precision under ``cfg.act_f32`` (neither side is a
+  stored bf16 number), as the operands come otherwise."""
+  if cfg.act_f32:
+    return jnp.einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+  return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+class Proj(nn.Module):
+  """``x @ kernel`` over ``x``'s last ``in_dims`` axes onto ``features``,
+  for the typed layers: the param sits where ``nn.Dense`` /
+  ``nn.DenseGeneral`` would put it (``<name>/kernel``), the product goes
+  through :func:`_weight_matmul` (``cfg.act_f32``)."""
+  cfg: TransformerConfig
+  features: tuple
+  in_dims: int = 1
+
+  @nn.compact
+  def __call__(self, x):
+    n_in, n_out = self.in_dims, len(self.features)
+    kernel = self.param(
+        "kernel", nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=tuple(range(n_in)),
+            out_axis=tuple(range(n_in, n_in + n_out))),
+        tuple(x.shape[-n_in:]) + tuple(self.features), jnp.float32)
+    ins, outs = "abc"[:n_in], "uvw"[:n_out]
+    return _weight_matmul("...%s,%s%s->...%s" % (ins, ins, outs, outs), x,
+                          kernel, self.cfg)
 
 
 def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
@@ -618,29 +798,6 @@ class Attention(nn.Module):
     q = _rotary(q, positions)
     k = _rotary(k, positions)
 
-    def _cache_write(buf, val):
-      """Write ``val [b, seg, c]`` at the cursor: one
-      dynamic_update_slice for the shared scalar cursor, a vmapped per-row
-      update (one scatter) for per-slot cursors.
-
-      Multi-token per-row writes go through an explicit OOB-dropping
-      scatter instead: a speculative verify window may transiently
-      overshoot ``max_seq_len`` on a lane whose remaining budget is
-      smaller than the draft depth, and dynamic_update_slice would
-      CLAMP the start — silently overwriting live attended KV below the
-      cursor (breaking bit-parity) instead of dropping the overflow
-      (which is never attended: accepted tokens stay within budget)."""
-      if not vec:
-        return jax.lax.dynamic_update_slice(buf, val, (0, idx, 0))
-      if seg == 1:
-        # single-token decode can never overshoot (cursor < max_seq_len
-        # by the submit-time budget check): keep the cheap update-slice
-        return jax.vmap(
-            lambda row, v, i: jax.lax.dynamic_update_slice(
-                row, v, (i, 0)))(buf, val, idx)
-      rows = jnp.broadcast_to(jnp.arange(b)[:, None], (b, seg)).reshape(-1)
-      pos = positions.reshape(-1)          # OOB entries drop, not clamp
-      return buf.at[rows, pos].set(val.reshape(b * seg, val.shape[2]))
     # tensor-parallel serving: keep the cache sharded on its folded
     # (grouped) heads axis so each chip holds 1/t of the KV bytes — whole
     # heads, by the divisibility rule the projection kernels share
@@ -664,19 +821,21 @@ class Attention(nn.Module):
       k_own = k_store.astype(jnp.float32) * ks[..., None]
       v_own = v_store.astype(jnp.float32) * vs[..., None]
       was.update(k_scale=k_scale.value, v_scale=v_scale.value)
-      k_scale.value = _constrain(_cache_write(k_scale.value, ks),
-                                 kv_spec, self.mesh)
-      v_scale.value = _constrain(_cache_write(v_scale.value, vs),
-                                 kv_spec, self.mesh)
+      k_scale.value = _constrain(
+          _cache_write(k_scale.value, ks, idx, positions), kv_spec,
+          self.mesh)
+      v_scale.value = _constrain(
+          _cache_write(v_scale.value, vs, idx, positions), kv_spec,
+          self.mesh)
     else:
       k_store, v_store = k.astype(cfg.dtype), v.astype(cfg.dtype)
       k_own, v_own = k_store, v_store
     cached_k.value = _constrain(
-        _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d)),
-        kv_spec, self.mesh)
+        _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d), idx,
+                     positions), kv_spec, self.mesh)
     cached_v.value = _constrain(
-        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d)),
-        kv_spec, self.mesh)
+        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), idx,
+                     positions), kv_spec, self.mesh)
     cursor.value = idx + seg
 
     def _dense_attend(_):
@@ -864,6 +1023,15 @@ def _gelu_matmul_call(x, w, mesh=None):
   return gelu_matmul(x, w, interpret=interp)
 
 
+def _swiglu(x, d_ff: int, cfg):
+  """``down(silu(gate x) * up x)`` inside the calling module's scope
+  (params ``gate``/``up``/``down``): the gated SiLU MLP, dense or as a
+  shared expert."""
+  h = nn.silu(Proj(cfg, (d_ff,), name="gate")(x)) \
+      * Proj(cfg, (d_ff,), name="up")(x)
+  return Proj(cfg, (cfg.d_model,), name="down")(h)
+
+
 class MLPBlock(nn.Module):
   cfg: TransformerConfig
   mesh: Optional[Any] = None
@@ -878,6 +1046,8 @@ class MLPBlock(nn.Module):
     over the pre-activation (ops.gelu_matmul) — combined with the LN
     fusion the whole MLP is two kernels with nothing unfused between."""
     cfg = self.cfg
+    if cfg.mlp_act == "swiglu":
+      return _swiglu(x, cfg.d_ff, cfg)
     if ln_scale is not None:
       kernel = _UpKernel(cfg.d_model, cfg.d_ff, name="up")()
       h = _ln_matmul_call(x, ln_scale, kernel.astype(cfg.dtype),
@@ -979,13 +1149,20 @@ class _LNScale(nn.Module):
 
 
 class Block(nn.Module):
+  """One pre-norm residual layer: ``x += Mix(norm(x)); x += FFN(norm(x))``.
+  ``mixer``/``ffn`` pick the two (``TransformerConfig.layer_types`` /
+  ``ffn_types``); the defaults are the attention + MLP block."""
   cfg: TransformerConfig
   mesh: Optional[Any] = None
   use_moe: bool = False
+  mixer: str = "attn"
+  ffn: str = "mlp"
 
   @nn.compact
   def __call__(self, x, positions, decode: bool = False):
     cfg = self.cfg
+    if self.mixer != "attn" or self.ffn != "mlp":
+      return self._typed(x, positions, decode)
     fuse_ln = cfg.ln_matmul_impl == "fused" and not decode
     if fuse_ln and cfg.fuse_qkv:
       # ln1 + the fused QKV projection as ONE kernel over the raw
@@ -1013,6 +1190,32 @@ class Block(nn.Module):
     if decode:
       return x
     return _constrain(x, ("batch", "sequence", "embed"), self.mesh)
+
+  def _typed(self, x, positions, decode):
+    """A layer whose mixer or feed-forward is not the default pair. Their
+    modules are imported HERE, so a model of plain blocks never loads
+    them; each runs under its own ``jax.named_scope``."""
+    cfg = self.cfg
+    if cfg.act_f32:
+      x = x.astype(jnp.float32)    # the residual stream is not rounded
+    y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
+    if self.mixer == "kda":
+      from tensorflowonspark_tpu.models import kda
+      with jax.named_scope("kda"):
+        x = x + kda.KDA(cfg, name="kda")(y, decode=decode)
+    elif self.mixer == "mla":
+      from tensorflowonspark_tpu.models import mla
+      with jax.named_scope("mla"):
+        x = x + mla.MLA(cfg, name="mla")(y, decode=decode)
+    else:
+      x = x + Attention(cfg, self.mesh, name="attn")(y, positions,
+                                                     decode=decode)
+    y = _make_layer_norm(cfg, self.mesh, "ln2")(x)
+    if self.ffn == "experts":
+      from tensorflowonspark_tpu.models import experts
+      with jax.named_scope("moe"):
+        return x + experts.HeldExperts(cfg, name="moe")(y)
+    return x + MLPBlock(cfg, self.mesh, name="mlp")(y)
 
 
 def _remat_block(cfg: TransformerConfig):
@@ -1110,7 +1313,10 @@ class Transformer(nn.Module):
     for i in range(cfg.num_layers if exit_layer is None else exit_layer):
       use_moe = (cfg.moe_experts > 0
                  and i % cfg.moe_every == cfg.moe_every - 1)
-      layer = block(cfg, self.mesh, use_moe, name="layer_%d" % i)
+      layer = block(cfg, self.mesh, use_moe,
+                    cfg.layer_types[i] if cfg.layer_types else "attn",
+                    cfg.ffn_types[i] if cfg.ffn_types else "mlp",
+                    name="layer_%d" % i)
       x = layer(x, positions, True) if decode else layer(x, positions)
 
     x = _make_layer_norm(cfg, self.mesh, "ln_f")(x)
@@ -1119,6 +1325,9 @@ class Transformer(nn.Module):
       # (:func:`causal_lm_loss_blocked`) — callers project against the
       # tied table chunk-by-chunk instead of materializing [B, S, V]
       return x.astype(cfg.dtype)
+    if not cfg.tie_embeddings:
+      return Proj(cfg, (cfg.vocab_size,), name="head")(x).astype(
+          jnp.float32)
     # tied output projection (attend to the embedding table)
     logits = emb.attend(x.astype(cfg.dtype))
     return logits.astype(jnp.float32)

@@ -17,6 +17,11 @@ Two dispatch strategies:
 - :func:`moe_ffn_a2a` — GShard-style all-to-all token exchange with
   capacity bounds: each device runs only its experts on only their
   assigned tokens (the communication-optimal variant).
+
+And the ONE CHIP'S SHARE of an expert-parallel layer (ROADMAP R2), which
+needs no mesh: :func:`route_sigmoid_topk` routes over the router's whole
+width, :func:`held_experts_ffn` computes what the experts held HERE add for
+the tokens routed to them, dropping none.
 """
 
 import functools
@@ -210,6 +215,78 @@ def moe_ffn_a2a(params, x, mesh, capacity_factor: float = 2.0,
                 P(mesh_lib.AXIS_EXPERT)),
       out_specs=P(token_axes), check_vma=False)(
           x, params["w_gate"], params["w_up"], params["w_down"])
+
+
+# ---------------------------------------------------------------------------
+# one chip's share: routed over all experts, computed for the experts here
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0):
+  """Sigmoid top-k routing over the router's WHOLE width, in float32 (a
+  rounded score moves a near-tie at the k-th place to another expert).
+
+  ``x [T, D]``, ``router [D, E]``, ``bias [E]`` (added for the SELECTION
+  only). Returns ``(experts [T, k] int32, weights [T, k] f32)``: the k
+  largest of ``s + bias`` with ``s = sigmoid(x W)``, weighted ``s_e / sum
+  of the selected s`` times ``scale``."""
+  s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST))
+  _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
+  picked = jnp.take_along_axis(s, experts, axis=-1)
+  return experts.astype(jnp.int32), \
+      picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+
+
+def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
+                     split=None):
+  """What the experts held here add to the layer: ``sum over a token's
+  assignments to experts in [first, first + held) of w * down_e(silu(gate_e
+  x) * up_e x)``; assignments to experts held elsewhere add nothing.
+
+  ``x [T, D]``; ``experts``/``weights [T, k]`` from
+  :func:`route_sigmoid_topk`; ``gate``/``up [held, D, F]``, ``down
+  [held, F, D]`` in the compute dtype. The ``T * k`` assignments are sorted
+  by expert (those held elsewhere last, outside every group) and multiplied
+  as ONE grouped product a matrix (``lax.ragged_dot``: rows of a group meet
+  that group's expert only, f32 accumulation), whatever the imbalance:
+  there is no capacity, so no token is dropped, and an expert nobody chose
+  is a group of no rows. ``split`` turns an activation into the list of
+  arrays of the weights' dtype that SUM to it (default: one cast); with n
+  terms each assignment's row goes in n times, term after term, into a
+  group n times as long, and the n results are added: float32 activations
+  times bf16 weights at the price of n x the rows, not n x the weight
+  bytes. Returns ``(y [T, D] f32, held [T, k] bool)``."""
+  t, k = experts.shape
+  n_held = gate.shape[0]
+  local = experts - first
+  held = jnp.logical_and(local >= 0, local < n_held)
+  key = jnp.where(held, local, n_held).reshape(-1)          # [T * k]
+  order = jnp.argsort(key, stable=True)
+  sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+  rows = jnp.take(x, order // k, axis=0)                    # [T * k, D]
+
+  def grouped(lhs, rhs):
+    parts = split(lhs) if split is not None else [lhs.astype(rhs.dtype)]
+    n = len(parts)
+    lhs = jnp.stack(parts, axis=1).reshape(-1, lhs.shape[-1])
+    out = lax.ragged_dot(
+        lhs, rhs, sizes * n, preferred_element_type=jnp.float32,
+        # float32 operands (a test, an init): not one rounded bf16 pass
+        precision=lax.Precision.HIGHEST if lhs.dtype == jnp.float32
+        else None)
+    return out.reshape(-1, n, out.shape[-1]).sum(axis=1)
+
+  hidden = jax.nn.silu(grouped(rows, gate)) * grouped(rows, up)
+  out = grouped(hidden, down)                               # [T * k, D]
+  w = jnp.where(held, weights, 0.0).reshape(-1)[order]
+  # rows past the last group belong to no expert here: whatever the grouped
+  # product left there is not a number to scale
+  out = jnp.where((w > 0)[:, None], out * w[:, None], 0.0)
+  # back to assignment order: a gather by the inverse permutation
+  y = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1).sum(axis=1)
+  return y, held
 
 
 def shard_moe_params(params, mesh):

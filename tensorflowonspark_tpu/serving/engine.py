@@ -242,6 +242,13 @@ class ServingEngine(object):
                           else _env_int(ENV_SERVE_SPEC_DEPTH, 0))
     spec_layers = int(spec_layers if spec_layers is not None
                       else _env_int(ENV_SERVE_SPEC_LAYERS, 0))
+    if self.prefix_pages > 0 and cfg.non_kv_layers:
+      raise ValueError(
+          "the shared-prefix cache (prefix_pages=%d) reuses a prompt "
+          "prefix's K/V PAGES by position; this model's %s layers cache no "
+          "K/V pages, and a KDA state at the prefix's end is in no page "
+          "(prefix reuse by state snapshot does not exist yet)"
+          % (self.prefix_pages, "/".join(cfg.non_kv_layers)))
     if self.prefix_pages > 0 and self.page_size <= 0:
       raise ValueError(
           "the shared-prefix cache shares POOL PAGES — "
@@ -293,6 +300,13 @@ class ServingEngine(object):
                   # device dispatches: one per _decode_once, one per
                   # prefill chunk (SlotDecoder.prefill counts them)
                   "decode_dispatches": 0, "prefill_chunks": 0,
+                  # a model with held experts (SlotDecoder.counted), summed
+                  # over LIVE lanes by step_many on the device: (token,
+                  # expert) assignments to experts held here, held experts
+                  # that got a live token (a layer-step), and the tokens
+                  # the live lanes' caches held when each step began
+                  "moe_assignments_held": 0, "moe_experts_touched": 0,
+                  "live_context_tokens": 0,
                   # calls of a slab-returning program, and those after
                   # which the slab that went in is deleted: its donation
                   # was USED, the program ran in place (_on_slab)
@@ -1319,10 +1333,15 @@ class ServingEngine(object):
     three phases of a dispatch are regions: the call returning, the wait
     for the token matrix, and the host's harvest of it."""
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
-      _, toks, _, _ = self._on_slab(lambda slabs: self.decoder.step_many(
+      out = self._on_slab(lambda slabs: self.decoder.step_many(
           self.params, slabs, self._last, active, remaining, self.horizon))
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
-      toks = np.asarray(toks)                     # [horizon, num_slots]
+      toks = np.asarray(out[1])                   # [horizon, num_slots]
+      if self.decoder.counted:       # the step's own sums, beside the tokens
+        for key, name in (("moe_assignments_held", "held"),
+                          ("moe_experts_touched", "touched"),
+                          ("live_context_tokens", "context")):
+          self.stats[key] += int(np.asarray(out[4][name]))
     lanes: List[tuple] = []
     freed: List[int] = []
     # ONE region round the whole harvest: _harvest runs per token

@@ -120,6 +120,13 @@ def _cursor_leaf(slabs):
   raise ValueError("slab pytree has no 'index' leaf")
 
 
+def _sown(counters, name: str) -> list:
+  """The arrays the expert layers ``sow``ed under ``name``, in layer order."""
+  from jax.tree_util import tree_flatten_with_path
+  return [leaf for path, leaf in tree_flatten_with_path(counters)[0]
+          if any(getattr(k, "key", None) == name for k in path)]
+
+
 def _with_cursor(slabs, vec):
   """Every layer's cursor set to ``vec`` (vectorized rollback: rejected
   speculative entries sit past the cursor, masked and overwritten — the
@@ -150,6 +157,26 @@ class SlotDecoder(object):
   alone never shrinks capacity; set ``num_pages`` lower to spend less
   HBM than ``num_slots × max_seq_len``). ``spec_depth > 0`` enables
   :meth:`step_spec` with a ``spec_layers``-deep shallow-exit draft.
+
+  Not every layer's cache is keys and values by position
+  (``TransformerConfig.layer_types``): a KDA layer keeps a recurrent state
+  and a convolution tail with NO position axis, an MLA layer one shared
+  latent a token. The contiguous slab takes them as they are: ``insert``
+  writes every ``[1, ...]`` row leaf into ``[slots, ...]``, whatever its
+  rank. What cannot take them is refused at construction, by name: the
+  paged pool (``TransformerConfig`` itself raises) and the prefix cache
+  hold K/V pages per head, and speculative decoding's cursor rollback
+  cannot unwind a recurrent state.
+
+  A FROZEN lane's recurrent state needs no restore. A lane is frozen (its
+  cursor bump undone, its token forced to pad) only once its request has
+  stopped, mid-horizon, or while its slot is free: the engine never
+  resumes a frozen lane, it hands the slot to the next request, and
+  ``insert`` overwrites the whole row of every leaf, state and tail
+  included. The garbage a frozen lane integrates meanwhile stays in its
+  own row (no operation mixes lanes) and stays bounded (the delta rule is
+  a contraction: unit keys, decay and write strength in (0, 1)).
+  ``tests/test_kimi_linear.py`` stops a lane mid-horizon and reuses a slot.
   """
 
   def __init__(self, cfg, num_slots: int, pad_id: int = 0, eos_id=None,
@@ -165,6 +192,9 @@ class SlotDecoder(object):
     self.mesh = mesh
     self.page_size = int(page_size)
     self.paged = self.page_size > 0
+    #: whether the model counts (its expert layers sow ``counters``):
+    #: step_many then returns a fifth member
+    self.counted = "experts" in cfg.ffn_types
     if self.paged:
       pps = int(pages_per_slot) or -(-cfg.max_seq_len // self.page_size)
       pool = int(num_pages) or num_slots * pps + 1
@@ -182,6 +212,12 @@ class SlotDecoder(object):
     self.spec_depth = int(spec_depth)
     if self.spec_depth < 0:
       raise ValueError("spec_depth must be >= 0, got %d" % self.spec_depth)
+    if self.spec_depth and cfg.recurrent_state:
+      raise ValueError(
+          "speculative decoding (spec_depth=%d) rejects drafts by rolling "
+          "the cache cursor back; this model's KDA layers keep a recurrent "
+          "state with no position axis, which a cursor cannot unwind"
+          % self.spec_depth)
     self.spec_layers = int(spec_layers) or max(1, cfg.num_layers // 2)
     if self.spec_depth and not 1 <= self.spec_layers <= cfg.num_layers:
       raise ValueError(
@@ -433,11 +469,29 @@ class SlotDecoder(object):
 
   # -- decode step ----------------------------------------------------------
 
-  def _one_step(self, params, slabs, tok, active):
+  def _one_step(self, params, slabs, tok, active, count: bool = False):
+    """One token a lane: ``(new_slabs, next_tokens, counts)``. With
+    ``count`` (a model whose expert layers sow ``counters``) ``counts``
+    holds int32 sums over LIVE lanes, else it is ``None``: ``held``
+    assignments to experts held here, ``touched`` held experts that got at
+    least one live token (summed over expert layers), ``context`` tokens
+    the live lanes' caches held before the step."""
     logits, mutated = self.slab_model.apply(
         {"params": params, "cache": slabs}, tok[:, None], decode=True,
-        mutable=["cache"])
+        mutable=["cache", "counters"] if count else ["cache"])
     new_cache = mutated["cache"]
+    counts = None
+    if count:
+      sown = mutated["counters"]
+      held = _sown(sown, "held")                       # [slots] a layer
+      hit = _sown(sown, "hit")                         # [slots, held]
+      counts = dict(
+          held=sum(jnp.sum(jnp.where(active, x, 0)) for x in held),
+          touched=sum(jnp.sum(jnp.any(
+              jnp.logical_and(x, active[:, None]), axis=0), dtype=jnp.int32)
+                      for x in hit),
+          context=jnp.sum(jnp.where(
+              active, _cursor_leaf(slabs).astype(jnp.int32), 0)))
 
     def freeze(path, new, old):
       # inactive slots must not advance: undo their cursor bump so the
@@ -450,11 +504,11 @@ class SlotDecoder(object):
     new_cache = tree_map_with_path(freeze, new_cache, slabs)
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     nxt = jnp.where(active, nxt, jnp.int32(self.pad_id))
-    return new_cache, nxt
+    return new_cache, nxt, counts
 
   def _step_impl(self, params, slabs, tok, active):
     obs_device.note_trace("serve.step")
-    return self._one_step(params, slabs, tok, active)
+    return self._one_step(params, slabs, tok, active)[:2]
 
   def step(self, params, slabs, last_tokens, active):
     """One token for every live slot: ``(new_slabs, next_tokens)``.
@@ -474,6 +528,8 @@ class SlotDecoder(object):
     stop (EOS inclusive / budget exhausted), pad after; the host replays
     the same stop rule to harvest. ``remaining: [num_slots] int32`` is
     each lane's unspent token budget. One compile per distinct horizon.
+    A model that counts (``self.counted``) returns a fifth member: the
+    ``_one_step`` counters summed over the horizon, int32 scalars.
     """
     if horizon < 1:
       raise ValueError("horizon must be >= 1, got %d" % horizon)
@@ -507,17 +563,21 @@ class SlotDecoder(object):
 
         def body(carry, _):
           slabs, tok, active, remaining = carry
-          slabs, nxt = self._one_step(params, slabs, tok, active)
+          slabs, nxt, counts = self._one_step(params, slabs, tok, active,
+                                              count=self.counted)
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:
             done_now = jnp.logical_or(done_now, nxt == self.eos_id)
           new_active = jnp.logical_and(active, jnp.logical_not(done_now))
           tok = jnp.where(new_active, nxt, jnp.int32(self.pad_id))
-          return (slabs, tok, new_active, remaining), nxt
+          return (slabs, tok, new_active, remaining), (nxt, counts)
 
-        (slabs, _, active, remaining), toks = lax.scan(
+        (slabs, _, active, remaining), (toks, counts) = lax.scan(
             body, (slabs, tok, active, remaining), None, length=_h)
+        if self.counted:
+          return slabs, toks, active, remaining, \
+              jax.tree.map(lambda x: jnp.sum(x, axis=0), counts)
         return slabs, toks, active, remaining
 
       fn = self._step_many_jits[horizon] = jax.jit(impl, donate_argnums=1)

@@ -200,6 +200,36 @@ def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
       res["copies_back_to_hbm"]
 
 
+def test_kimi_linear_step_many_keeps_every_kind_of_leaf_in_place(
+    topo, monkeypatch):
+  """The cell kimi-linear-serve-backlog's decode step at its real size (27
+  layers at published widths, float32 activations on bf16 matrices, 48
+  slots x 4096, horizon 4): ONE slab of 20 float32 KDA states (100 MB
+  each), 20 convolution tails and 7 bf16 latent caches (lane-dense: 576
+  padded to 640) is aliased whole; with 8.6 GB of
+  weights beside it the program fits the chip; temporaries stay under 0.5
+  GB; the entry computation copies no slab leaf (a 576-wide latent leaf was
+  kept transposed and copied in and out, 14 x 226 MB a dispatch); the
+  latent cache never comes back from fast memory (absorbed decode reads it
+  as it was before the step's write), and at most a few of the states do;
+  the grouped expert products are kernels."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("serving_decode_kimi_linear", monkeypatch)
+  mb = res["memory_bytes"]
+  state, tail, latent = (48 * 32 * 128 * 128 * 4, 48 * 3 * 12288 * 4,
+                         48 * 4096 * 640 * 2)
+  slab_bytes = 20 * (state + tail) + 7 * latent
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.5e9, mb
+  assert res["device_bytes"] < 0.85 * V5E_HBM_BYTES, res["device_bytes"]
+  leaves = ("f32[48,32,128,128]", "f32[48,3,12288]", "bf16[48,4096,640]")
+  assert not set(leaves) & set(res["entry_copies"]), res["entry_copies"]
+  back = res["copies_back_to_hbm"]
+  assert leaves[2] not in back and back.get(leaves[0], 0) <= 8, back
+  assert back.get(leaves[1], 0) <= 2, back
+  assert res["tpu_custom_calls"] >= 26 * 3, res["tpu_custom_calls"]
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

@@ -813,6 +813,58 @@ def t_gpt2l_step_many():
                            _on_chip0(jax.eval_shape(dec.init_slabs)))
 
 
+#: the benchmark cell kimi-linear-serve-backlog: slots x max_seq
+KIMI_LINEAR_SLOTS, KIMI_LINEAR_MAX_SEQ = 48, 4096
+
+
+def kimi_linear_cfg(max_seq: int = KIMI_LINEAR_MAX_SEQ):
+  """Kimi-Linear-48B-A3B-Instruct as ``benchmarks/configs/
+  kimi-linear-48b-a3b.json`` cuts it to one chip's share (published widths,
+  all 27 layers, 16 of 256 experts held, 1/8 of the vocabulary), spelled
+  out so that the gate needs nothing of ``benchmarks/``;
+  ``benchmarks/tests/test_kimi_linear.py`` keeps the two equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  return tfm.TransformerConfig(
+      vocab_size=20480, num_layers=27, num_heads=32, d_model=2304, d_ff=9216,
+      max_seq_len=max_seq, remat=False, dtype=jnp.bfloat16,
+      layer_types=tuple("mla" if i in (3, 7, 11, 15, 19, 23, 26) else "kda"
+                        for i in range(27)),
+      ffn_types=("mlp",) + ("experts",) * 26, norm="rms", norm_eps=1e-5,
+      mlp_act="swiglu", tie_embeddings=False, kda_heads=32, kda_head_dim=128,
+      kda_conv=4, kda_rank=128, mla_kv_rank=512, mla_nope_dim=128,
+      mla_rope_dim=64, mla_v_dim=128, experts_total=256, experts_held=16,
+      experts_first=0, experts_top_k=8, experts_d_ff=1024, experts_shared=1,
+      experts_scale=2.446, act_f32=True)
+
+
+def kimi_linear_decoder(slots: int = KIMI_LINEAR_SLOTS,
+                        max_seq: int = KIMI_LINEAR_MAX_SEQ):
+  """(SlotDecoder, abstract bf16 params, row cache, slab) at the cell's
+  sizes."""
+  import jax
+  import jax.numpy as jnp
+  from flax.core import meta
+  from tensorflowonspark_tpu.models import transformer as tfm
+  from tensorflowonspark_tpu.serving import slots as slots_lib
+  dec = slots_lib.SlotDecoder(kimi_linear_cfg(max_seq), slots)
+  f32 = ("scale", "A_log", "dt_bias", "o_norm", "router", "router_bias")
+  params = _on_chip0(jax.eval_shape(lambda: jax.tree_util.tree_map_with_path(
+      lambda p, x: x if p[-1].key in f32 else x.astype(jnp.bfloat16),
+      meta.unbox(dec.model.init(
+          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+  row = _on_chip0(jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1)))
+  return dec, params, row, _on_chip0(jax.eval_shape(dec.init_slabs))
+
+
+def t_serving_decode_kimi_linear():
+  """The cell kimi-linear-serve-backlog's decode step at its real size: 27
+  layers at published widths (20 KDA states of 100 MB and 7 latent caches
+  of 226 MB in one slab of 48 x 4096), horizon 4."""
+  dec, params, _, slabs = kimi_linear_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
 def t_smoke_step_many():
   return _smoke_step_many(paged=False)
 
@@ -866,6 +918,7 @@ TARGETS = {
     "smoke_paged_insert": t_smoke_paged_insert,
     "smoke_paged_step_many": t_smoke_paged_step_many,
     "gpt2l_step_many": t_gpt2l_step_many,
+    "serving_decode_kimi_linear": t_serving_decode_kimi_linear,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
