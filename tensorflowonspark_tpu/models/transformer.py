@@ -1289,7 +1289,7 @@ class Transformer(nn.Module):
   @nn.compact
   def __call__(self, tokens, decode: bool = False,
                return_hidden: bool = False,
-               exit_layer: Optional[int] = None):
+               exit_layer: Optional[int] = None, logits_at=None):
     """``exit_layer`` (static) runs only the first N blocks before the
     final norm + tied projection — the SHALLOW-EXIT draft of
     self-speculative decoding (serving/slots.py): the draft is a prefix
@@ -1319,6 +1319,11 @@ class Transformer(nn.Module):
                     name="layer_%d" % i)
       x = layer(x, positions, True) if decode else layer(x, positions)
 
+    if logits_at is not None:
+      # one position (a traced scalar): only that row goes through the
+      # final norm and the head, the result is [batch, 1, vocab] — a
+      # padded prefill chunk wants the logits of its last REAL token
+      x = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
     x = _make_layer_norm(cfg, self.mesh, "ln_f")(x)
     if return_hidden:
       # pre-projection hidden states for the fused blocked loss

@@ -10,8 +10,11 @@ Public surface:
   (:class:`RequestCancelled`), crash-replay recovery with poison
   detection (:class:`PoisonedRequest`), and graceful ``drain(timeout)``.
 * :class:`~tensorflowonspark_tpu.serving.slots.SlotDecoder` /
+  :func:`~tensorflowonspark_tpu.serving.slots.padded_plan` /
   :func:`~tensorflowonspark_tpu.serving.slots.chunk_plan` — the jitted
-  device ops and the bucketed-prefill policy.
+  device ops and the bucketed-prefill policy (a prompt's tail padded to
+  a bucket and masked by the cursor; the exact decomposition for models
+  with recurrent layers).
 * :class:`~tensorflowonspark_tpu.serving.scheduler.Request` /
   :class:`~tensorflowonspark_tpu.serving.scheduler.RequestQueue` — the
   host-side bookkeeping (bounded, closable admission queue).
@@ -77,4 +80,4 @@ from tensorflowonspark_tpu.serving.scheduler import (         # noqa: F401
     PrefixCache, Request, RequestCancelled, RequestQueue,
     ServingOverloaded)
 from tensorflowonspark_tpu.serving.slots import (             # noqa: F401
-    DEFAULT_BUCKETS, SlotDecoder, chunk_plan)
+    DEFAULT_BUCKETS, EXACT_BUCKETS, SlotDecoder, chunk_plan, padded_plan)

@@ -208,9 +208,6 @@ class ServingEngine(object):
     self.pad_id = int(pad_id)
     self.horizon = horizon
     self.default_max_new_tokens = int(max_new_tokens)
-    # explicit argument beats the env knob (the num_slots/horizon rule)
-    self.buckets = tuple(buckets) if buckets is not None \
-        else sched.buckets_from_env(slots_lib.DEFAULT_BUCKETS)
     self.max_queue = int(max_queue if max_queue is not None
                          else _env_int(ENV_SERVE_MAX_QUEUE,
                                        _DEFAULT_MAX_QUEUE))
@@ -257,6 +254,10 @@ class ServingEngine(object):
         cfg, num_slots, pad_id=pad_id, eos_id=self.eos_id, mesh=mesh,
         page_size=self.page_size, num_pages=self.num_pages,
         spec_depth=self.spec_depth, spec_layers=spec_layers)
+    # explicit argument beats the env knob (the num_slots/horizon rule);
+    # neither: the shapes of the decoder's own prefill plan
+    self.buckets = tuple(buckets) if buckets is not None \
+        else sched.buckets_from_env(self.decoder.buckets)
     # spec rounds per dispatch: each round emits 1..spec_depth tokens,
     # so this keeps the best-case tokens-per-dispatch near the horizon
     self._spec_rounds = max(1, -(-horizon // max(1, self.spec_depth)))
@@ -298,8 +299,11 @@ class ServingEngine(object):
                   "prefix_evictions": 0, "spec_accepted": 0,
                   "spec_rejected": 0,
                   # device dispatches: one per _decode_once, one per
-                  # prefill chunk (SlotDecoder.prefill counts them)
+                  # prefill chunk (SlotDecoder.prefill counts them, and
+                  # beside them the tokens the chunks computed and how
+                  # many of those were a padded tail's padding)
                   "decode_dispatches": 0, "prefill_chunks": 0,
+                  "prefill_tokens": 0, "prefill_padded_tokens": 0,
                   # a model with held experts (SlotDecoder.counted), summed
                   # over LIVE lanes by step_many on the device: (token,
                   # expert) assignments to experts held here, held experts

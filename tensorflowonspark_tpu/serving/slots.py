@@ -23,10 +23,22 @@ Two layouts exist:
 Jitted functions owning the slab:
 
 * :meth:`SlotDecoder.prefill` — run one request's prompt through the
-  model on a fresh single-row cache, in bucket-sized chunks so the jit
-  cache holds at most ``len(buckets)`` prefill shapes. The first chunk
-  is a fresh-cache prefill (flash-eligible on TPU); later chunks ride
-  the warm-cache ``idx > 0`` dense branch of the same cond.
+  model on a fresh single-row cache, in bucket-shaped chunks so the jit
+  cache holds at most ``len(buckets)`` prefill shapes. A prompt is ONE
+  program where it can be: whole chunks of the largest bucket, then the
+  tail PADDED with ``pad_id`` up to the smallest bucket that holds it
+  (:func:`padded_plan`); the program takes the tail's true length,
+  leaves the cursor there and reads the first token off the last REAL
+  row. Padding needs no other mask: a padded query sits after every
+  real one, so under the causal mask no real position reads it, and its
+  K/V land past the cursor, where decode masks them and overwrites them
+  one by one before it attends them (``_set_cache_cursor``'s free
+  rollback). A model with RECURRENT layers (``cfg.recurrent_state``: a
+  KDA state and convolution tail have no position axis, so a padded
+  token would be integrated into them) keeps the exact decomposition
+  (:func:`chunk_plan` over ``EXACT_BUCKETS``). The first chunk is a
+  fresh-cache prefill (flash-eligible on TPU); later chunks ride the
+  warm-cache ``idx > 0`` dense branch of the same cond.
 * :meth:`SlotDecoder.insert` — scatter that row cache into the slab at a
   freed slot (``lax.dynamic_update_slice`` on every leaf) and set the
   slot's cursor to the prompt length.
@@ -71,6 +83,7 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.tree_util import tree_map_with_path
 
@@ -79,14 +92,25 @@ from tensorflowonspark_tpu.obs import device as obs_device
 from tensorflowonspark_tpu.obs import spans as obs_spans
 from tensorflowonspark_tpu.utils import chaos
 
-#: prompt-chunk sizes for bucketed prefill, largest-first. The compiled
-#: prefill cache holds at most one entry per size, so arbitrary prompt
-#: lengths never grow the jit cache unboundedly; 1 must be reachable so
-#: every length decomposes.
-DEFAULT_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
+#: chunk SHAPES of the bucketed prefill: the compiled prefill cache holds
+#: at most one program a shape, so arbitrary prompt lengths never grow the
+#: jit cache. These are the shapes a padded tail may take
+#: (:func:`padded_plan`). Why these six (chip runs at gpt2-large's widths,
+#: PERF.md section 6, PR 27): a dispatch costs the host 6.5 ms and the
+#: device 3.3 ms at 16 tokens, 8.4 at 512, whatever is real in it, so a
+#: prompt wants ONE chunk and nothing under 16; powers of two keep the
+#: padding under half (30% of the benchmark's mix, 12.8 ms a prompt; the
+#: four shapes 512/128/32/16: 49%, 13.9 ms); each shape is one more
+#: 36-layer program to compile (11-20 s) or load at every start.
+DEFAULT_BUCKETS = (512, 256, 128, 64, 32, 16)
+
+#: chunk sizes of the EXACT decomposition (:func:`chunk_plan`), which
+#: models with recurrent layers keep; 1 must be reachable so every length
+#: decomposes.
+EXACT_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
 
 
-def chunk_plan(plen: int, buckets: Sequence[int] = DEFAULT_BUCKETS):
+def chunk_plan(plen: int, buckets: Sequence[int] = EXACT_BUCKETS):
   """Decompose a prompt length into descending bucket-sized chunks.
 
   Greedy largest-first: ``chunk_plan(37, (128, 32, 8, 4, 2, 1))`` →
@@ -103,6 +127,38 @@ def chunk_plan(plen: int, buckets: Sequence[int] = DEFAULT_BUCKETS):
     while rem >= b:
       plan.append(b)
       rem -= b
+  return plan
+
+
+def padded_plan(n: int, room: int, buckets: Sequence[int] = DEFAULT_BUCKETS):
+  """The chunks ``n`` prompt tokens run as when the tail may be padded:
+  ``[(shape, valid), ...]``, ``valid`` real tokens in a chunk of
+  ``shape``.
+
+  Whole chunks of the largest bucket while more than one is left, then
+  ONE chunk: the remainder in the smallest bucket that holds it.
+  ``padded_plan(521, 1024)`` → ``[(512, 512), (16, 9)]``; at most 512
+  tokens are one program. ``room`` is what the row has left from the
+  first token's position (``max_seq_len - offset``): a padded chunk must
+  END inside the row, because ``lax.dynamic_update_slice`` clamps its
+  start and would silently overwrite live entries below the cursor.
+  Where no bucket both holds the remainder and fits, the largest bucket
+  under the remainder goes exactly (1 if there is none) and the rest is
+  tried again. A function of ``n`` and ``room`` alone, so warming one
+  prompt of each length compiles every shape.
+  """
+  if n < 1:
+    raise ValueError("prompt length must be >= 1, got %d" % n)
+  sizes = sorted({int(b) for b in buckets if int(b) > 0})
+  plan = []
+  while n:
+    fit = next((b for b in sizes if n <= b <= room), None)
+    if fit is not None:
+      plan.append((fit, n))
+      break
+    b = max((b for b in sizes if b <= n), default=1)
+    plan.append((b, b))
+    n, room = n - b, room - b
   return plan
 
 
@@ -226,6 +282,14 @@ class SlotDecoder(object):
     self.model = tfm.Transformer(cfg, mesh=mesh)
     self.slab_model = tfm.Transformer(self.slab_cfg, mesh=mesh) \
         if self.paged else self.model
+    # THE place the prefill plan is chosen, from what the config's layer
+    # types say of the cache: every leaf indexed by position (K/V, int8
+    # K/V with scales, the MLA latent) -> the tail is padded and masked by
+    # the cursor; a recurrent state or convolution tail would integrate a
+    # padded token -> the exact decomposition, the programs it always had
+    self.padded_prefill = not cfg.recurrent_state
+    #: the chunk shapes :meth:`prefill` compiles when its caller names none
+    self.buckets = DEFAULT_BUCKETS if self.padded_prefill else EXACT_BUCKETS
     # jit caches retrace per chunk shape (bounded by the bucket set) /
     # once for insert+step (fixed slab shapes)
     self._prefill_fn = jax.jit(self._prefill_impl)
@@ -258,23 +322,49 @@ class SlotDecoder(object):
 
   # -- prefill (single row, bucketed chunks) --------------------------------
 
-  def _prefill_impl(self, params, cache, tokens):
+  def _prefill_impl(self, params, cache, tokens, n_valid=None):
+    """One chunk. ``n_valid`` (a traced int32 scalar; ``None`` in the
+    exact plan, whose program is then what it always was) is how many of
+    ``tokens [1, seg]`` are real: the rest is padding behind them."""
     # recompile sentinel seam: fires once per (re)trace — the prefill jit
     # cache must stay bounded by the bucket set (obs/device.py)
     obs_device.note_trace("serve.prefill")
+    # padded: only the last REAL row goes through the final norm and the
+    # head (a [seg, vocab] logits block is never built to pick one row)
     logits, mutated = self.model.apply(
         {"params": params, "cache": cache}, tokens, decode=True,
-        mutable=["cache"])
+        mutable=["cache"],
+        logits_at=None if n_valid is None else n_valid - 1)
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    return mutated["cache"], nxt
+    if n_valid is None:
+      return mutated["cache"], nxt
+    # every layer advanced its cursor by the chunk's SHAPE: set it to the
+    # true length, so the padding's entries lie past it
+    return _with_cursor(
+        mutated["cache"],
+        _cursor_leaf(cache).astype(jnp.int32) + n_valid), nxt
 
-  def prefill(self, params, prompt, buckets: Sequence[int] = DEFAULT_BUCKETS,
-              resume=None, trace=None, acc=None) -> Tuple[object, int]:
+  def plan(self, n: int, offset: int = 0, buckets=None):
+    """The chunks :meth:`prefill` runs ``n`` prompt tokens as, the first
+    of them at position ``offset`` of the row: ``[(shape, valid), ...]``
+    (:func:`padded_plan`; the exact :func:`chunk_plan`, ``valid ==
+    shape``, for a model with recurrent layers)."""
+    buckets = self.buckets if buckets is None else buckets
+    if self.padded_prefill:
+      return padded_plan(n, self.cfg.max_seq_len - offset, buckets)
+    return [(b, b) for b in chunk_plan(n, buckets)]
+
+  def prefill(self, params, prompt, buckets=None, resume=None, trace=None,
+              acc=None) -> Tuple[object, int]:
     """Prefill one prompt into a fresh [1, ...] row cache.
 
     Returns ``(row_cache, first_token)``: the warm cache (cursor at
     ``len(prompt)``) and the first generated token g1. Chunks follow
-    :func:`chunk_plan`, so only the LAST chunk's logits matter.
+    :meth:`plan` over ``buckets`` (default: ``self.buckets``): the tail
+    padded up to a bucket and masked by the cursor, or, for a model with
+    recurrent layers, the exact decomposition. Only the LAST chunk's
+    token matters. Entries a padded chunk wrote past the cursor are
+    harmless: nothing attends them before decode overwrites them.
 
     ``resume=(row_cache, start)`` skips the first ``start`` prompt
     tokens: the given warm cache already holds their KV (the
@@ -283,15 +373,16 @@ class SlotDecoder(object):
     ``start`` must leave at least one tail token (the last prompt token
     must run through the model to yield g1).
 
-    Each chunk dispatch (its ``dynamic_slice`` included) is a
-    ``serve.prefill.chunk`` region and the wait for the last chunk a
+    Each chunk dispatch (the host's slice-and-pad of its tokens included)
+    is a ``serve.prefill.chunk`` region and the wait for the last chunk a
     ``serve.prefill.sync`` region (``obs.spans.region``): trace
     annotations always; recorder spans when ``trace`` (a request trace
-    id) is given — the bucketed-decomposition phase of the request
-    waterfall. Chunk dispatches are async, so a chunk region measures
-    dispatch-to-dispatch time; the enclosing ``serve.prefill`` span
-    carries the true synced total. ``acc`` (the engine's ``stats``)
-    counts the dispatches in ``prefill_chunks`` and the wait in
+    id) is given — the chunk-plan phase of the request waterfall. Chunk
+    dispatches are async, so a chunk region measures dispatch-to-dispatch
+    time; the enclosing ``serve.prefill`` span carries the true synced
+    total. ``acc`` (the engine's ``stats``) counts the dispatches in
+    ``prefill_chunks``, the tokens they computed in ``prefill_tokens``,
+    the padding among them in ``prefill_padded_tokens`` and the wait in
     ``t_prefill_sync_s``.
     """
     plen = len(prompt)
@@ -318,21 +409,30 @@ class SlotDecoder(object):
         # serves every prefill
         self._zero_row = tfm._zero_cache(self.model, 1)
       cache, off = self._zero_row, 0
-    prompt = jnp.asarray(prompt, jnp.int32).reshape(1, plen)
-    plan = chunk_plan(plen - off, buckets)
+    prompt = np.asarray(prompt, np.int32).reshape(plen)
+    plan = self.plan(plen - off, off, buckets)
     if acc is not None:
       acc["prefill_chunks"] += len(plan)
+      acc["prefill_tokens"] += sum(seg for seg, _ in plan)
+      acc["prefill_padded_tokens"] += sum(seg - n for seg, n in plan)
     nxt = None
-    for seg in plan:
+    for seg, n in plan:
       with obs_spans.region("serve.prefill.chunk", trace=trace,
                             record=trace is not None, chunk=seg,
                             offset=off):
+        # sliced and padded on the host: the jit takes the numpy block as
+        # it is (an eager slice would be a dispatch of its own a chunk)
+        tokens = np.full((1, seg), self.pad_id, np.int32)
+        tokens[0, :n] = prompt[off:off + n]
         cache, nxt = self._prefill_fn(
-            params, cache, lax.dynamic_slice(prompt, (0, off), (1, seg)))
-      off += seg
+            params, cache, tokens,
+            np.int32(n) if self.padded_prefill else None)
+      off += n
     with obs_spans.region("serve.prefill.sync", acc, "t_prefill_sync_s",
                           trace=trace, record=trace is not None):
-      first = int(nxt[0])              # waits for the last chunk
+      # waits for the last chunk; fetched whole, because indexing the
+      # device array would be two more eager programs (slice, squeeze)
+      first = int(np.asarray(nxt)[0])
     return cache, first
 
   # -- slot insert ----------------------------------------------------------

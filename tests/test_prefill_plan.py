@@ -1,0 +1,299 @@
+"""The padded prefill plan (serving/slots.py): a prompt's tail padded up to
+a bucket and masked by the cursor, so a prompt is one program where it can
+be, and the exact decomposition that models with recurrent layers keep.
+CPU, toy widths, real models.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import kimi_linear_family as fam
+from tensorflowonspark_tpu.models import transformer as tfm
+from tensorflowonspark_tpu.serving import (
+    DEFAULT_BUCKETS, EXACT_BUCKETS, ServingEngine, SlotDecoder, chunk_plan,
+    padded_plan)
+
+#: the benchmark pool's twelve prompt lengths and their weights
+#: (benchmarks/traffic/serve-backlog.json), and the plan each gets in a
+#: 1024-long row
+POOL = {16: [(16, 16)], 23: [(32, 23)], 33: [(64, 33)], 47: [(64, 47)],
+        67: [(128, 67)], 95: [(128, 95)], 131: [(256, 131)],
+        191: [(256, 191)], 263: [(512, 263)], 383: [(512, 383)],
+        521: [(512, 512), (16, 9)], 768: [(512, 512), (256, 256)]}
+POOL_WEIGHTS = (3, 4, 6, 8, 10, 12, 13, 11, 8, 6, 4, 3)
+B = max(DEFAULT_BUCKETS)
+
+
+# -- the plan -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,plan", sorted(POOL.items()) + [
+    (1, [(16, 1)]),                        # below every bucket
+    (B, [(B, B)]),                         # a whole bucket pads nothing
+    (B + 1, [(B, B), (16, 1)]),
+    (1023, [(B, B), (B, B - 1)]),          # the longest prompt a row takes
+])
+def test_padded_plan_of_a_fresh_row(n, plan):
+  assert padded_plan(n, 1024) == plan
+
+
+def test_pool_is_one_program_a_prompt_and_a_third_padding():
+  chunks = [len(POOL[n]) for n in sorted(POOL)]
+  per_prompt = np.average(chunks, weights=POOL_WEIGHTS)
+  assert per_prompt == pytest.approx(1.08, abs=0.005)
+  exact = np.average([len(chunk_plan(n)) for n in sorted(POOL)],
+                     weights=POOL_WEIGHTS)
+  assert exact == pytest.approx(4.91, abs=0.005)
+  computed = np.average([sum(s for s, _ in POOL[n]) for n in sorted(POOL)],
+                        weights=POOL_WEIGHTS)
+  real = np.average(sorted(POOL), weights=POOL_WEIGHTS)
+  assert 0.25 < 1 - real / computed < 0.35
+  # the exact plan's four largest sizes as padded shapes would compute more
+  four = np.average(
+      [sum(s for s, _ in padded_plan(n, 1024, (512, 128, 32, 16)))
+       for n in sorted(POOL)], weights=POOL_WEIGHTS)
+  assert 0.45 < 1 - real / four < 0.55
+
+
+@pytest.mark.parametrize("n,room,buckets,plan", [
+    # the tail's bucket would pass the row's end: exact pieces until one fits
+    (440, 464, DEFAULT_BUCKETS, [(256, 256), (128, 128), (64, 56)]),
+    (100, 101, DEFAULT_BUCKETS, [(64, 64), (32, 32)] + [(1, 1)] * 4),
+    (3, 8, DEFAULT_BUCKETS, [(1, 1)] * 3),
+    (14, 48, (64,), [(1, 1)] * 14),        # no bucket fits the row at all
+    (14, 48, (8, 4, 2, 1), [(8, 8), (8, 6)]),
+    (5, 48, (4,), [(4, 4), (4, 1)]),
+])
+def test_padded_plan_falls_back_to_exact_pieces(n, room, buckets, plan):
+  assert padded_plan(n, room, buckets) == plan
+
+
+@pytest.mark.parametrize("offset", [0, 5, 48, 500, 1000])
+def test_padded_plan_invariants_at_resume_offsets(offset):
+  """Whatever the offset a resumed prefill starts at: every real token
+  runs once, no chunk ends past the row (``dynamic_update_slice`` would
+  clamp it over live entries), only the LAST chunk is padded, and the
+  shapes are buckets (1 where none is small enough)."""
+  max_seq = 1024
+  for n in range(1, max_seq - offset):      # plen + 1 <= max_seq_len
+    plan = padded_plan(n, max_seq - offset)
+    assert sum(v for _, v in plan) == n
+    assert all(s == v for s, v in plan[:-1]) and plan[-1][1] <= plan[-1][0]
+    assert offset + sum(v for _, v in plan[:-1]) + plan[-1][0] <= max_seq
+    assert {s for s, _ in plan} <= set(DEFAULT_BUCKETS) | {1}
+    if offset == 0:
+      assert len(plan) == -(-n // B)
+
+
+def test_padded_plan_rejects_an_empty_prompt():
+  with pytest.raises(ValueError, match="prompt length"):
+    padded_plan(0, 1024)
+
+
+# -- the program: padded against exact ----------------------------------------
+
+
+def _tiny(**kw):
+  kw.setdefault("dtype", jnp.float32)
+  kw.setdefault("num_heads", 2)
+  return tfm.TransformerConfig(vocab_size=64, num_layers=2, d_model=32,
+                               d_ff=64, max_seq_len=48, remat=False, **kw)
+
+
+VARIANTS = {
+    "f32": dict(),
+    "bf16": dict(dtype=jnp.bfloat16),
+    "int8_kv": dict(kv_cache_dtype="int8"),
+    "gqa": dict(num_heads=4, num_kv_heads=2),
+    "window": dict(attention_window=8),
+    "flash": dict(attention_impl="flash"),   # the chip's fresh-row branch
+}
+
+
+def _written(leaf, n):
+  """What the cursor covers of a row-cache leaf: entries past it are a
+  padded tail's garbage by design."""
+  leaf = np.asarray(leaf, np.float32)
+  return leaf[:, :n] if leaf.ndim > 1 else leaf
+
+
+def _decode_from(dec, params, cache, first, steps=6):
+  slabs = dec.insert(dec.init_slabs(), cache, 0)
+  toks, tok = [first], first
+  for _ in range(steps):
+    slabs, nxt = dec.step(params, slabs, [tok], [True])
+    tok = int(np.asarray(nxt)[0])
+    toks.append(tok)
+  return toks
+
+
+def _cursors(cache):
+  from jax.tree_util import tree_flatten_with_path
+  return [int(leaf) for path, leaf in tree_flatten_with_path(cache)[0]
+          if getattr(path[-1], "key", None) == "index"]
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_padded_prefill_equals_the_exact_plan(variant):
+  """One padded program (and a whole chunk plus a padded one) against the
+  exact decomposition: the same row cache on positions [0, n), the cursor
+  at n, the same first token and the same decoded continuation."""
+  cfg = _tiny(**VARIANTS[variant])
+  params = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16).params
+  n = 21
+  prompt = np.random.RandomState(4).randint(1, 64, (n,)).astype(np.int32)
+  exact = SlotDecoder(cfg, 1)
+  exact.padded_prefill = False              # the test's own steering
+  assert exact.plan(n, 0, (16, 4, 1)) == [(16, 16), (4, 4), (1, 1)]
+  want_cache, want_first = exact.prefill(params, prompt, (16, 4, 1))
+  want_stream = _decode_from(exact, params, want_cache, want_first)
+  assert set(_cursors(want_cache)) == {n}
+
+  dec = SlotDecoder(cfg, 1)
+  assert dec.padded_prefill and dec.buckets == DEFAULT_BUCKETS
+  tol = dict(atol=1e-5, rtol=1e-5)
+  if variant == "bf16":
+    tol = dict(atol=0.05, rtol=0.05)
+  if variant == "int8_kv":
+    tol = dict(atol=1.0, rtol=1e-4)          # one step of the int8 grid
+  for buckets, plan in (((32, 16), [(32, n)]),
+                        ((16, 8), [(16, 16), (8, n - 16)])):
+    assert dec.plan(n, 0, buckets) == plan
+    cache, first = dec.prefill(params, prompt, buckets)
+    assert set(_cursors(cache)) == {n}
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+      np.testing.assert_allclose(_written(a, n), _written(b, n), **tol)
+    assert first == want_first
+    assert _decode_from(dec, params, cache, first) == want_stream, buckets
+
+
+def test_padded_tail_after_a_prefix_cache_resume():
+  """Paged slab + shared prefix: the warm row ``gather_pages`` rebuilds
+  holds the prefix's pages, the padded plan runs the TAIL from there, and
+  ``insert_pages`` masks by the row's cursor (the true length), so the
+  padding never reaches a pool page. Same tokens as a contiguous decode of
+  the whole prompt."""
+  cfg = _tiny()
+  params = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16).params
+  rng = np.random.RandomState(9)
+  prefix = rng.randint(1, 64, (8,)).astype(np.int32)
+  first_prompt = np.concatenate([prefix, rng.randint(1, 64, (3,))]).astype(
+      np.int32)
+  prompt = np.concatenate([prefix, rng.randint(1, 64, (5,))]).astype(np.int32)
+  n, shared, ps = len(prompt), len(prefix), 4
+  dec = SlotDecoder(cfg, 2, page_size=ps)
+  slabs = dec.init_slabs()
+
+  def table(pages):
+    return pages + [0] * (dec.pages_per_slot - len(pages))
+
+  row, _ = dec.prefill(params, first_prompt, (16, 8))
+  slabs = dec.insert_pages(slabs, row, 0, table([1, 2, 3]))
+  # the second request shares the prefix's two full pages and owns 3..
+  pages = table([1, 2, 4, 5, 6])
+  warm = dec.gather_pages(slabs, pages, shared)
+  assert dec.plan(n - shared, shared, (16, 8)) == [(8, n - shared)]
+  cache, first = dec.prefill(params, prompt, (16, 8), resume=(warm, shared))
+  assert set(_cursors(cache)) == {n}
+
+  whole = SlotDecoder(cfg, 1)
+  whole.padded_prefill = False
+  want_cache, want_first = whole.prefill(params, prompt, (8, 4, 1))
+  for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+    np.testing.assert_allclose(_written(a, n), _written(b, n), atol=1e-5,
+                               rtol=1e-5)
+  assert first == want_first
+  want = _decode_from(whole, params, want_cache, want_first)
+
+  slabs = dec.insert_pages(slabs, cache, 1, pages, start=shared)
+  toks, tok = [first], first
+  for _ in range(6):
+    slabs, nxt = dec.step(params, slabs, [0, tok], [False, True])
+    tok = int(np.asarray(nxt)[1])
+    toks.append(tok)
+  assert toks == want
+
+
+# -- who keeps the exact plan ---------------------------------------------------
+
+
+#: one period of Kimi-Linear's 3:1 pattern (KDA, KDA, KDA, MLA), layer 1
+#: dense, toy widths: the keys of tests/test_kimi_linear.py's TOY
+KIMI_TOY = dict(
+    vocab_size=257, hidden_size=64, intermediate_size=96,
+    num_hidden_layers=4, num_attention_heads=2, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, q_lora_rank=None,
+    mla_use_nope=True, rms_norm_eps=1e-5, first_k_dense_replace=1,
+    linear_attn_config=dict(full_attn_layers=[4], kda_layers=[1, 2, 3],
+                            head_dim=16, num_heads=2,
+                            short_conv_kernel_size=4),
+    kda_low_rank_dim=8, moe_intermediate_size=32, num_experts=4,
+    experts_first=4, num_experts_published=32, num_experts_per_token=4,
+    num_shared_experts=1, routed_scaling_factor=2.446)
+
+
+@pytest.fixture(scope="module")
+def kimi_toy():
+  cfg = fam.program_config(KIMI_TOY, 128, dtype=jnp.float32)
+  return cfg, fam.program_params(7, KIMI_TOY), KIMI_TOY["vocab_size"]
+
+
+def test_a_recurrent_model_keeps_the_exact_plan(kimi_toy):
+  """KDA state and convolution tail have no position axis: a padded token
+  would be integrated into them. Such a config gets the exact plan over the
+  exact plan's sizes, and its program takes no ``n_valid``."""
+  cfg, params, vocab = kimi_toy
+  assert cfg.recurrent_state
+  dec = SlotDecoder(cfg, 2)
+  assert not dec.padded_prefill and dec.buckets == EXACT_BUCKETS
+  for n in (1, 67, 95, 100):
+    assert dec.plan(n) == [(b, b) for b in chunk_plan(n, EXACT_BUCKETS)]
+  calls, inner = [], dec._prefill_fn
+  dec._prefill_fn = lambda *a: (calls.append(a), inner(*a))[1]
+  prompt = np.random.default_rng(3).integers(0, vocab, 23, dtype=np.int32)
+  acc = dict(prefill_chunks=0, prefill_tokens=0, prefill_padded_tokens=0,
+             t_prefill_sync_s=0.0)
+  cache, _ = dec.prefill(params, prompt, acc=acc)
+  assert [a[2].shape[1] for a in calls] == [16, 4, 2, 1]
+  assert all(a[3] is None for a in calls)
+  assert (acc["prefill_chunks"], acc["prefill_tokens"],
+          acc["prefill_padded_tokens"]) == (4, 23, 0)
+  assert set(_cursors(cache)) == {23}
+
+
+@pytest.mark.parametrize("model", ["attention", "recurrent"])
+def test_engine_prefill_counters_add_up(model, kimi_toy):
+  """``prefill_tokens`` = tokens the chunks computed, padding included;
+  ``prefill_padded_tokens`` the padding among them; ``prefill_chunks`` the
+  dispatches: real tokens = the prompts', and a recurrent model pads 0."""
+  if model == "recurrent":
+    cfg, params, vocab = kimi_toy
+  else:
+    cfg, vocab = _tiny(), 64
+    params = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16).params
+  rng = np.random.default_rng(11)
+  prompts = [rng.integers(1, vocab, n, dtype=np.int32)
+             for n in (3, 16, 17, 23, 33, 40)]
+  with ServingEngine(params, cfg, num_slots=2, eos_id=None) as eng:
+    assert eng.buckets == eng.decoder.buckets
+    rids = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    for rid in rids:
+      assert len(eng.result(rid, timeout=300)) > 0
+  st = eng.stats
+  plans = [eng.decoder.plan(len(p)) for p in prompts]
+  assert st["prefills"] == len(prompts)
+  assert st["prefill_chunks"] == sum(len(plan) for plan in plans)
+  assert st["prefill_tokens"] == sum(s for plan in plans for s, _ in plan)
+  assert st["prefill_tokens"] - st["prefill_padded_tokens"] \
+      == sum(len(p) for p in prompts)
+  if model == "recurrent":
+    assert st["prefill_padded_tokens"] == 0
+    assert st["prefill_chunks"] == sum(len(chunk_plan(len(p), EXACT_BUCKETS))
+                                       for p in prompts)
+  else:
+    # max_seq_len 48: 33 and 40 find no bucket that ends inside the row
+    assert st["prefill_padded_tokens"] == (16 - 3) + (32 - 17) + (32 - 23) \
+        + (16 - 1) + (16 - 8)
+    assert st["prefill_chunks"] == 4 + 2 + 2

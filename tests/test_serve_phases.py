@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models import transformer as tfm
 from tensorflowonspark_tpu.obs import spans as spans_mod
-from tensorflowonspark_tpu.serving import ServingEngine, chunk_plan
+from tensorflowonspark_tpu.serving import ServingEngine
 
 PHASE_KEYS = ("t_reap_s", "t_idle_s", "t_admit_s", "t_prefill_s",
               "t_prefill_sync_s", "t_insert_s", "t_decode_prep_s",
@@ -31,8 +31,10 @@ COUNT_KEYS = ("decode_dispatches", "prefill_chunks")
 @pytest.fixture(scope="module")
 def toy():
   # wide enough that a dispatch outweighs the Python between two regions
-  cfg = tfm.TransformerConfig(vocab_size=64, num_layers=2, num_heads=2,
-                              d_model=64, d_ff=256, max_seq_len=96,
+  # (a prompt is ONE chunk since the padded prefill plan, so a run is
+  # mostly decode passes, each a few lines of Python outside any counter)
+  cfg = tfm.TransformerConfig(vocab_size=64, num_layers=4, num_heads=2,
+                              d_model=256, d_ff=1024, max_seq_len=96,
                               remat=False, dtype=jnp.float32)
   return cfg, tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16)
 
@@ -175,8 +177,12 @@ def test_dispatch_counters_are_exact(toy, spec_depth):
   per_dispatch = eng.horizon if spec_depth == 0 \
       else eng._spec_rounds * spec_depth
   assert st["prefills"] == len(prompts)
-  assert st["prefill_chunks"] == sum(
-      len(chunk_plan(len(p), eng.buckets)) for p in prompts)
+  plans = [eng.decoder.plan(len(p), 0, eng.buckets) for p in prompts]
+  assert st["prefill_chunks"] == sum(len(plan) for plan in plans)
+  assert st["prefill_chunks"] == len(prompts)    # each fits one bucket
+  assert st["prefill_tokens"] == sum(seg for plan in plans for seg, _ in plan)
+  assert st["prefill_tokens"] - st["prefill_padded_tokens"] \
+      == sum(len(p) for p in prompts)
   assert st["decode_dispatches"] > 0
   assert st["decode_dispatches"] * per_dispatch == st["steps"]
 

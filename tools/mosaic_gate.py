@@ -760,16 +760,18 @@ def _i32(*shape):
 
 #: serving.slots.DEFAULT_BUCKETS, spelled out so listing the targets
 #: imports nothing; smoke_prefill checks the two stay equal
-SMOKE_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
+SMOKE_BUCKETS = (512, 256, 128, 64, 32, 16)
 
 
 def smoke_prefill(bucket: int):
-  """One bucketed prefill chunk program (fresh-cache flash branch and
-  warm-cache dense branch live in the same cond)."""
+  """One prefill chunk program of the padded plan (the chunk's true length
+  is a traced scalar; fresh-cache flash branch and warm-cache dense branch
+  live in the same cond)."""
   from tensorflowonspark_tpu.serving.slots import DEFAULT_BUCKETS
   assert tuple(DEFAULT_BUCKETS) == SMOKE_BUCKETS, DEFAULT_BUCKETS
   dec, params, row, _ = _smoke_decoder(paged=False)
-  return dec._prefill_fn, (params, row, _i32(1, bucket))
+  assert dec.padded_prefill
+  return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
 
 
 def t_smoke_insert():
@@ -791,11 +793,9 @@ def _smoke_step_many(paged: bool):
   return _step_many_target(dec, params, slabs)
 
 
-def t_gpt2l_step_many():
-  """The benchmark's serving step at its real size: gpt2-large (36 x 1280
-  x 20 heads of 64, vocab 50257, bf16 matrices), 16 slots x 1024, horizon
-  4 — the one size at which the compiler's fast-memory staging of a 3 GB
-  slab shows (PERF.md section 6, PR 25)."""
+def _gpt2l_decoder():
+  """(SlotDecoder, abstract bf16 params) of the benchmark's serving cells:
+  gpt2-large (36 x 1280 x 20 heads of 64, vocab 50257), 16 slots x 1024."""
   import jax
   import jax.numpy as jnp
   from flax.core import meta
@@ -809,8 +809,28 @@ def t_gpt2l_step_many():
       lambda x: x.astype(jnp.bfloat16) if x.ndim > 1 else x,
       meta.unbox(dec.model.init(
           jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+  return dec, params
+
+
+def t_gpt2l_step_many():
+  """The benchmark's serving step at its real size, horizon 4 — the one
+  size at which the compiler's fast-memory staging of a 3 GB slab shows
+  (PERF.md section 6, PR 25)."""
+  import jax
+  dec, params = _gpt2l_decoder()
   return _step_many_target(dec, params,
                            _on_chip0(jax.eval_shape(dec.init_slabs)))
+
+
+def t_gpt2l_prefill_512():
+  """The benchmark's largest prefill program at its real size: a padded
+  512-token chunk (PERF.md section 6, PR 27); only the last real row may
+  reach the 50257-wide head."""
+  import jax
+  from tensorflowonspark_tpu.models import transformer as tfm
+  dec, params = _gpt2l_decoder()
+  row = _on_chip0(jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1)))
+  return dec._prefill_fn, (params, row, _i32(1, 512), _i32())
 
 
 #: the benchmark cell kimi-linear-serve-backlog: slots x max_seq
@@ -918,6 +938,7 @@ TARGETS = {
     "smoke_paged_insert": t_smoke_paged_insert,
     "smoke_paged_step_many": t_smoke_paged_step_many,
     "gpt2l_step_many": t_gpt2l_step_many,
+    "gpt2l_prefill_512": t_gpt2l_prefill_512,
     "serving_decode_kimi_linear": t_serving_decode_kimi_linear,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
