@@ -4,17 +4,9 @@
 
 PY ?= python
 
-.PHONY: test lint analyze analyze-cold check native bench serve-bench \
-	train-bench \
-	train-bench-smoke dryrun mosaic-gate validate clean chaos chaos-serve \
-	serve-bench-chaos serve-bench-prefix obs-smoke obs-top-smoke \
-	bench-check fleet-chaos serve-bench-fleet serve-bench-fleet-smoke \
-	serve-bench-fleet-xhost serve-bench-fleet-xhost-smoke \
-	feed-bench-graph feed-bench-graph-smoke feed-bench-wire \
-	feed-bench-wire-smoke slo-smoke elastic-chaos \
-	train-bench-groups train-bench-groups-smoke deploy-chaos \
-	serve-bench-deploy serve-bench-deploy-smoke chip-smoke \
-	chip-smoke-refuses-cpu
+.PHONY: test lint analyze analyze-cold check native dryrun mosaic-gate \
+	validate clean chaos chaos-serve obs-smoke obs-top-smoke fleet-chaos \
+	slo-smoke elastic-chaos deploy-chaos chip-smoke chip-smoke-refuses-cpu
 
 # the end-of-round ritual: lint gate + full suite + multichip dryrun +
 # deviceless Mosaic-lowering gate (real TPU kernel compile, no chip)
@@ -63,83 +55,19 @@ slo-smoke:
 	env JAX_PLATFORMS=cpu \
 	  $(PY) tools/slo_report.py --smoke
 
-# bench trajectory gate: newest history.jsonl record per series vs the
-# trailing median (tools/bench_history.py; benches append on --json-out)
-bench-check:
-	$(PY) tools/bench_history.py --check
-
-# paired fixed-depth prefetcher (DataFeed + _FetchPipeline + inline
-# maps) vs the autotuned datapipe graph on the skewed hot-stage-rotating
-# workload, both feeding the fused train loop at unroll=8; gates:
-# bit-identical loss trajectories across sides (deterministic mode, the
-# autotuner live), zero fetch-dominant stall windows on the graph side,
-# and >=1.2x median delivered rows/s; writes the committed artifact + a
-# feed_bench_graph history line
-feed-bench-graph:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/feed_bench.py --graph --steps 240 --batch 64 \
-	  --chunk 256 --graph-heavy 120 --graph-light 4 \
-	  --json-out bench_artifacts/feed_bench_graph.json
-
-# datapipe graph plumbing check: tiny paired run, bit-parity gated
-feed-bench-graph-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/feed_bench.py --graph --smoke
-
-feed-bench-wire:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/feed_bench.py --wire --steps 120 --batch 64 \
-	  --chunk 128 --json-out bench_artifacts/feed_bench_wire.json
-
-feed-bench-wire-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/feed_bench.py --wire --smoke
-
-# paired per-step vs fused train-loop comparison at the dispatch-
-# dominated harness shape; writes the committed artifact + history line
-train-bench:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/train_bench.py \
-	  --json-out bench_artifacts/train_bench_fused.json
-
-# train-loop fusion plumbing check: tiny paired run, bit-parity asserted
-train-bench-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/train_bench.py --smoke
-
 # elastic-training fault injection only (TOS_CHAOS_GROUP): whole-group
 # kill mid-training with no global stall, eviction + re-admit catch-up,
 # resharded restore — docs/ROBUSTNESS.md §Elastic training; tier-1
 elastic-chaos:
 	$(PY) -m pytest tests/test_groups.py -q -m chaos
 
-# cross-group sync overhead: N groups no-sync vs synced every --unroll
-# steps (parallel.groups), paired reps, interchangeability gated; writes
-# the artifact + a train_bench_groups history line
-train-bench-groups:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/train_bench.py --groups 2 \
-	  --json-out bench_artifacts/train_bench_groups.json
-
-# elastic-groups plumbing check: tiny paired run, interchangeability
-# (bit-identical post-sync params) asserted
-train-bench-groups-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/train_bench.py --groups 2 --smoke
-
 # fast pre-commit gate: static analysis + style + the fast test subset +
-# the obs plumbing smokes + the train-loop fusion smoke + the serving
-# fleet (replica-kill chaos suite + router/zero-shed-swap bench smoke +
-# the cross-host plane smoke over real executor processes) +
-# the datapipe graph smoke (bit-parity through the autotuned executor) +
-# the elastic-training plane (group-kill chaos suite + groups bench smoke)
+# the obs plumbing smokes + the chaos suites of the serving fleet (replica
+# and host kill), the elastic-training plane (group kill) and continuous
+# deployment (controller kill, poisoned candidate)
 # (`--changed` variant for iteration: `python -m tools.analyze --changed`)
-check: analyze obs-smoke obs-top-smoke slo-smoke train-bench-smoke \
-	fleet-chaos serve-bench-fleet-smoke serve-bench-fleet-xhost-smoke \
-	feed-bench-graph-smoke \
-	feed-bench-wire-smoke \
-	elastic-chaos train-bench-groups-smoke deploy-chaos \
-	serve-bench-deploy-smoke chip-smoke-refuses-cpu
+check: analyze obs-smoke obs-top-smoke slo-smoke fleet-chaos \
+	elastic-chaos deploy-chaos chip-smoke-refuses-cpu
 	$(PY) -m pytest tests/test_analyze.py tests/test_utils.py \
 	  tests/test_misc.py -q
 
@@ -181,34 +109,6 @@ chaos-serve:
 fleet-chaos:
 	$(PY) -m pytest tests/test_fleet.py tests/test_remote.py -q -m chaos
 
-# ServingFleet (N replicas + mid-run rolling swap) vs a single engine on
-# the seeded Zipf workload; parity + zero-shed gated; writes the
-# artifact + a serve_bench_fleet history line
-serve-bench-fleet:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --fleet \
-	  --json-out bench_artifacts/serve_bench_fleet.json
-
-# fleet router plumbing check: tiny fleet + swap, parity/zero-shed gated
-serve-bench-fleet-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --fleet --smoke
-
-# the SAME fleet over ServingHost EXECUTOR PROCESSES behind the
-# rendezvous wire: paired in-process vs cross-host, a v1→v2 rolling swap
-# across the process boundary, and a TOS_CHAOS_HOST mid-decode kill leg
-# (ejection + bit-identical failover replay + post-kill zero-shed swap);
-# writes the artifact + a serve_bench_fleet_xhost history line
-serve-bench-fleet-xhost:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --fleet --cross-host \
-	  --json-out bench_artifacts/serve_bench_fleet_xhost.json
-
-# cross-host plane plumbing check: tiny hosts, all four gates
-serve-bench-fleet-xhost-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --fleet --cross-host --smoke
-
 # continuous-deployment fault injection only (TOS_CHAOS_DEPLOY):
 # controller killed at canary/promote/rollback boundaries + poisoned
 # candidates, registry torn publish — docs/ROBUSTNESS.md §Continuous
@@ -216,49 +116,8 @@ serve-bench-fleet-xhost-smoke:
 deploy-chaos:
 	$(PY) -m pytest tests/test_deploy.py -q -m chaos
 
-# the full train→serve rollout drive: registry publish → canary →
-# verify → promote with a chaos kill mid-promote (resume converges,
-# zero-shed + version consistency + parity gated) plus a poisoned
-# candidate quarantined by VERIFY; writes the artifact + a
-# serve_bench_deploy history line
-serve-bench-deploy:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --deploy \
-	  --json-out bench_artifacts/serve_bench_deploy.json
-
-# deploy plumbing check: tiny registry + fleet + controller, all gates
-serve-bench-deploy-smoke:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --deploy --smoke
-
-# degraded goodput + recovery latency under injected serving faults,
-# paired against a clean pass (parity re-verified); writes the artifact
-# + a serve_bench_chaos history line
-serve-bench-chaos:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --chaos \
-	  --json-out bench_artifacts/serve_bench_chaos.json
-
 native:
 	$(MAKE) -C native
-
-bench:
-	$(PY) bench.py
-
-# continuous (serving.ServingEngine) vs static batching on the seeded
-# mixed-length workload; writes the committed artifact
-serve-bench:
-	$(PY) tools/serve_bench.py --compare \
-	  --json-out bench_artifacts/serve_bench_continuous.json
-
-# the decode-speed stack on a shared-system-prompt workload: paged KV at
-# equal HBM (more slots), +prefix cache, +self-speculative decode —
-# per-stage bit-parity gates; writes the committed artifact + a
-# serve_bench_prefix history line
-serve-bench-prefix:
-	env JAX_PLATFORMS=cpu \
-	  $(PY) tools/serve_bench.py --prefix-workload \
-	  --json-out bench_artifacts/serve_bench_prefix.json
 
 # AOT-compile every Pallas kernel + the full fused train step against a
 # deviceless v5e topology (real Mosaic lowering via local libtpu; no chip

@@ -2,9 +2,9 @@
 """chip_smoke.py — the quickest proof that this system still starts on the chip.
 
 Drives the repo's main path once, through the entry points a user calls, at
-the full width of the one model ``bench.py`` runs at full width (the
-GPT-2-small-class decoder: 12 layers, d_model 768, 12 heads of 64, d_ff 3072,
-vocab 32000, bf16, ~124M parameters; weights random from ``--seed``):
+the full width of a GPT-2-small-class decoder (12 layers, d_model 768, 12
+heads of 64, d_ff 3072, vocab 32000, bf16, ~124M parameters; weights random
+from ``--seed``):
 
 * **train** — ``cluster.run(LocalEngine(num_executors=1), ..., ENGINE input
   mode, feed_transport="shm", train_unroll=K)``; the driver feeds token rows
@@ -59,9 +59,9 @@ if _REPO not in sys.path:
   sys.path.insert(0, _REPO)
 
 # ---------------------------------------------------------------------------
-# What runs. Widths are the published ones of the bench.py transformer cell
-# (bench.TFM_*); nothing about them is cut on the chip. --rehearse swaps in
-# toy widths for the CPU.
+# What runs. Widths are those of the 12-layer train step that
+# tools/mosaic_gate.py compiles (TFM_*); nothing about them is cut on the
+# chip. --rehearse swaps in toy widths for the CPU.
 # ---------------------------------------------------------------------------
 
 FULL = dict(
@@ -147,7 +147,7 @@ def model_config(conf, max_seq_len: int, **overrides):
 
 
 def lm_loss_fn(cfg, mesh=None):
-  """``loss_fn(params, tokens)`` — the bench.py transformer step's loss."""
+  """``loss_fn(params, tokens)`` — the causal LM loss of the train step."""
   from tensorflowonspark_tpu.models import transformer as tfm
   model = tfm.Transformer(cfg, mesh)
 

@@ -1161,7 +1161,7 @@ class GraphExecutor(object):
 
   def stage_summary(self) -> Dict[str, dict]:
     """Per-stage worker/depth/counter view (autotuner decisions land
-    here; ``feed_bench --graph`` prints it)."""
+    here; the obs mirror and tests/test_datapipe.py read it)."""
     out = {"src": dict(self._src_stats, workers=len(self._source_threads),
                        depth=self._buffers[0].capacity)}
     for stage in self._stages:

@@ -199,7 +199,7 @@ class DataFeed(object):
     self._pipeline_depth = max(0, pipeline_depth)
     self._pipeline: Optional[_FetchPipeline] = None
     #: per-stage accounting (seconds / counts), filled on the hot path —
-    #: tools/feed_bench.py reads this for its breakdown (snapshot it with
+    #: tests/test_datafeed.py reads these counts (snapshot it with
     #: :meth:`stats_snapshot`, never by zeroing: the fetch thread keeps
     #: read-modify-writing these entries)
     self.stats = {"fetch_s": 0.0, "decode_s": 0.0, "assemble_s": 0.0,

@@ -136,7 +136,7 @@ class StepTimer(object):
 
 # bf16 peak FLOP/s per chip by TPU generation (public spec sheets; v5e:
 # Google Cloud documentation "TPU v5e", 197 TFLOP/s). THE one peak table:
-# bench.py and the tools key into it through chip_peak_bf16_flops
+# only tests/test_profiler.py still keys into it (ROADMAP D12)
 PEAK_BF16_FLOPS = {"v4": 275e12, "v5e": 197e12, "v5p": 459e12, "v6e": 918e12}
 
 

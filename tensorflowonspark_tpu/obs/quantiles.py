@@ -20,7 +20,7 @@ benches share instead: a KLL-style compactor-stack sketch —
   first compaction (streams shorter than ``k`` are stored outright) and
   off by at most :attr:`rank_error` observations after — the sketch
   TRACKS the bound as it compacts, so a consumer can assert against it
-  (``serve_bench --smoke`` does exactly that against the sorted list).
+  (``tests/test_slo.py`` does exactly that against the sorted list).
 
 Compaction is DETERMINISTIC (per-level alternating parity instead of
 KLL's coin flip): the same stream always yields the same sketch, so

@@ -46,11 +46,11 @@ Decode-speed stack (docs/PERFORMANCE.md §"Paged KV, prefix cache &
 speculative decode"): ``TOS_SERVE_PAGE_SIZE`` pages the KV slab,
 ``TOS_SERVE_PREFIX_PAGES`` turns on prefix sharing over it, and
 ``TOS_SERVE_SPEC_DEPTH`` enables self-speculative decoding — each stage
-independently gated on ``serve_bench`` bit-parity.
+independently gated on bit-parity in ``tests/test_serving.py``.
 
 See docs/PERFORMANCE.md §Serving for the static-vs-continuous batching
 story, docs/ROBUSTNESS.md for the failure model and chaos knobs, and
-``tools/serve_bench.py --compare`` / ``--chaos`` for the measurements.
+``BENCHMARK.json`` / ``PERF.md`` for the measurements on the chip.
 """
 
 from tensorflowonspark_tpu.serving.engine import (            # noqa: F401

@@ -33,8 +33,8 @@ provable instead of assumed: ``kill`` at a state boundary raises
 fleet mid-transition — and :meth:`resume` must then converge every
 replica to ONE consistent version with zero shed; ``poison`` corrupts
 the candidate's params at the canary build, which VERIFY must catch
-(parity) and quarantine, never promote. ``tools/serve_bench.py
---deploy`` (make deploy-chaos / serve-bench-deploy-smoke) gates all of
+(parity) and quarantine, never promote. ``tests/test_deploy.py``
+(``TestDeployChaos``, also ``make deploy-chaos``) gates all of
 it in tier-1.
 
 All waits are timeout-bounded (TOS001); the watch thread is a daemon

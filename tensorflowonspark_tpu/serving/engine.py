@@ -47,8 +47,8 @@ Usage::
     # or: for tok in eng.stream(rid): ...
     eng.drain(timeout=30)                       # or eng.stop()
 
-The engine is FAST (this PR's decode-speed stack, each stage gated on
-``serve_bench`` parity and composable with the self-healing surface):
+The engine is FAST (the decode-speed stack, each stage gated on bit
+parity in tests/test_serving.py, composable with the self-healing surface):
 
 * paged KV slab — ``page_size > 0`` swaps the per-slot ``max_seq_len``
   HBM reservation for a page pool + per-slot page tables
@@ -283,7 +283,7 @@ class ServingEngine(object):
     self._crash_streak = 0
     self._tok_rate = 0.0                   # EMA tokens/s over decode passes
     #: bounded record of crash recoveries: {t, duration_s, replayed,
-    #: poisoned, streak, error} — serve_bench --chaos reads recovery
+    #: poisoned, streak, error} — tests/test_serving.py reads recovery
     #: latency off this
     self.restart_log: List[dict] = []
     # counters ONLY (monotonic): StatsSnapshot.delta subtracts these, so
@@ -379,7 +379,7 @@ class ServingEngine(object):
   def stats_snapshot(self) -> obs_metrics.StatsSnapshot:
     """Subtraction baseline over the LIVE ``stats`` dict — the safe way
     to read per-pass deltas while the loop thread keeps mutating it
-    (obs.metrics.StatsSnapshot; serve_bench uses this)."""
+    (obs.metrics.StatsSnapshot; benchmarks/runners/serve_engine.py)."""
     return obs_metrics.snapshot_stats(self.stats)
 
   # -- lifecycle ------------------------------------------------------------

@@ -346,7 +346,7 @@ class ServingFleet(object):
       self._obs_m[key].inc(n)
 
   def stats_snapshot(self) -> obs_metrics.StatsSnapshot:
-    """Subtraction baseline over the live stats dict (serve_bench)."""
+    """Subtraction baseline over the live stats dict (serving.deploy)."""
     return obs_metrics.snapshot_stats(self.stats)
 
   def _event(self, kind: str, **fields) -> None:

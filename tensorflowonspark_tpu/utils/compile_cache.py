@@ -2,8 +2,8 @@
 
 Every process of this repo that is about to jit calls :func:`setup` first:
 node bring-up (both the foreground and the spawned background runner),
-the serving host, ``bench.py``, ``chip_smoke.py`` and the ``tools/*_bench``
-launchers. The rule:
+the serving host, ``chip_smoke.py`` and the runners under
+``benchmarks/runners/``. The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads the variable itself, and
   this code sets NO directory of its own (whoever runs the program decides

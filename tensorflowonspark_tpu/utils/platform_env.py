@@ -2,7 +2,7 @@
 
 The program runs two ways (README, Testing): on the CPU for tests — a
 virtual multi-device platform, which is what this module sets up — and on
-the chip (``python chip_smoke.py``, ``bench.py``), where nothing here is
+the chip (``chip_smoke.py``, ``benchmarks/run.py``), where nothing here is
 called and JAX takes the TPU it finds.
 
 One shared implementation for tests (``tests/conftest.py``), the driver

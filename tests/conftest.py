@@ -6,7 +6,7 @@ by splitting the CPU into 8 virtual XLA devices. Must run before jax's
 backend initializes — the shared helper raises if it's too late.
 
 This is one of the two ways the program runs (README, Testing): here on the
-CPU; on the chip through ``python chip_smoke.py`` / ``bench.py``, where none
+CPU; on the chip through ``chip_smoke.py`` / ``benchmarks/run.py``, where none
 of this applies.
 """
 

@@ -346,6 +346,7 @@ def test_sigkill_mid_training_recovers_and_resumes(tmp_path):
   configured backoff cap."""
   num_steps = 4
   hb = 0.25
+  backoff, backoff_cap = 0.2, 1.0
   engine = LocalEngine(
       num_executors=2,
       env={chaos.ENV_KILL: "train-step@0#2"})   # kill executor 0 at step 2
@@ -356,7 +357,7 @@ def test_sigkill_mid_training_recovers_and_resumes(tmp_path):
         tf_args={"ckpt_root": str(tmp_path), "num_steps": num_steps},
         input_mode=InputMode.FILES, reservation_timeout=60,
         heartbeat_interval=hb, max_restarts=2,
-        restart_backoff=0.2, restart_backoff_cap=1.0)
+        restart_backoff=backoff, restart_backoff_cap=backoff_cap)
     c.shutdown(timeout=300)     # must NOT raise: the failure was recovered
     elapsed = time.monotonic() - t0
 
@@ -379,9 +380,16 @@ def test_sigkill_mid_training_recovers_and_resumes(tmp_path):
     assert sup is not None and sup.restarts == {0: 1}, sup.restarts
     kinds = [e["kind"] for e in sup.events if e["executor_id"] == 0]
     assert kinds[:3] == ["detected-dead", "relaunched", "recovered"], kinds
-    # detection → relaunch gap is bounded by the backoff cap (+ jitter slack)
-    ev = {e["kind"]: e["t"] for e in sup.events if e["executor_id"] == 0}
-    assert ev["relaunched"] - ev["detected-dead"] <= 1.0 * 1.5 + 0.5
+    # the supervisor's own sleep between detection and relaunch, as the
+    # relaunch event records it: full jitter round restart_backoff, never
+    # over the cap. The wall-clock gap holds that sleep plus the dead
+    # hub's quarantine and the spawn, which a loaded box stretches:
+    # `elapsed` bounds those
+    ev = {e["kind"]: e for e in sup.events if e["executor_id"] == 0}
+    slept = ev["relaunched"]["backoff_s"]
+    assert 0.5 * backoff - 1e-3 <= slept, slept
+    assert slept <= min(backoff_cap, 1.5 * backoff) + 1e-3, slept
+    assert ev["relaunched"]["t"] - ev["detected-dead"]["t"] >= slept - 1e-2
     assert elapsed < 120, "recovery path took pathologically long"
   finally:
     engine.stop()

@@ -334,12 +334,23 @@ class TestFeedGraphParity:
     assert feed._pipeline is None         # graph owns the channel now
 
 
+def _tl_scale(v, i):
+  return (v * 2.0).astype(np.float32), i
+
+
+def _tl_shift(v, i):
+  return (v + 1.0).astype(np.float32), i
+
+
 class TestTrainLoopIntegration:
-  def test_graph_drives_fused_loop_bit_identical(self, hub):
+  @pytest.mark.parametrize("maps", [False, True])
+  def test_graph_drives_fused_loop_bit_identical(self, hub, maps):
     """from_feed(...).slab(B, K) -> make_train_loop(unroll=K) produces
     the exact PR 9 trajectory (losses AND params), through a real hub,
     with the autotuner enabled — autotuning may change THROUGHPUT,
-    never values."""
+    never values. ``maps``: two columnar map stages ride the graph's
+    worker pools, against the same maps applied to the rows before the
+    fixed-depth path sees them."""
     import jax
     import optax
     from flax.training import train_state as ts
@@ -355,10 +366,17 @@ class TestTrainLoopIntegration:
       rows.append((np.concatenate([x, x @ w_true]), i))
     chunks = [rows[i:i + 5] for i in range(0, len(rows), 5)]
 
-    def fill(h):
+    def mapped(c):
+      if not maps:
+        return c
+      v, i = _tl_shift(*_tl_scale(np.stack([r[0] for r in c]),
+                                  [r[1] for r in c]))
+      return list(zip(v, i))
+
+    def fill(h, pre=False):
       q = h.get_queue("input")
       for i, c in enumerate(chunks):
-        put_rows_chunk(q, c, timeout=5)
+        put_rows_chunk(q, mapped(c) if pre else c, timeout=5)
         if i == 2:
           q.put(EndPartition())
       q.put(None)
@@ -386,7 +404,7 @@ class TestTrainLoopIntegration:
         losses.extend(np.asarray(out).reshape(-1).tolist())
       return losses, jax.tree.map(np.asarray, state.params)
 
-    fill(hub)
+    fill(hub, pre=True)
     feed = DataFeed(hub, input_mapping={"c0": "v", "c1": "i"},
                     pipeline_depth=0)
     # slab_batches yields {"v","i"}; the loop only consumes "v"
@@ -397,10 +415,19 @@ class TestTrainLoopIntegration:
       fill(h2)
       feed2 = DataFeed(h2, input_mapping={"c0": "v", "c1": "i"},
                        pipeline_depth=0)
-      ds = Dataset.from_feed(feed2).slab(4, 4)
-      got_losses, got_params = run(ds.batches(autotune=True))
+      ds = Dataset.from_feed(feed2)
+      if maps:
+        ds = ds.map(_tl_scale, columnar=True).map(_tl_shift, columnar=True)
+      ex = ds.slab(4, 4).start(deterministic=True, autotune=True)
+      got_losses, got_params = run(ex.batches())
+      stages = ex.stage_summary()
     finally:
       h2.shutdown()
+
+    # the executor ran every declared stage as a live one
+    assert set(stages) >= ({"src", "map0", "map1"} if maps else {"src"})
+    assert all(d["workers"] >= 1 and d["depth"] >= 1
+               for d in stages.values())
 
     assert got_losses == ref_losses
     for k in ref_params:
@@ -415,8 +442,9 @@ class TestExecutor:
     # Marked slow (tier-1 budget audit): the assertion that the tuner
     # OBSERVES a hot stage within the run is wall-clock-sampled and
     # flakes when the shared CI box is saturated; the autotuned graph's
-    # determinism + parity stay tier-1-pinned via the feed_bench --graph
-    # smoke and test_autotune_off_keeps_declared_plan. Runs in
+    # determinism + parity stay tier-1-pinned via
+    # test_graph_drives_fused_loop_bit_identical[True] (map stages, the
+    # tuner live) and test_autotune_off_keeps_declared_plan. Runs in
     # `make test`.
     monkeypatch.setenv(datapipe.ENV_DATA_AUTOTUNE_INTERVAL, "0.05")
     chunks = [[(np.full(8, 16 * c + i, np.float32), 16 * c + i)
@@ -635,6 +663,12 @@ def _pd_filter(x, y):
   return np.asarray(y) % 3 != 0
 
 
+def _pd_int_map(x, y):
+  # stays int32 with 16 distinct values: the mapped column is dict-able,
+  # so the codec is priced on the pushed segment's real output
+  return (x[:, :16] % 16).astype(np.int32), y
+
+
 class TestPushdown:
   """Feeder-side transform pushdown (split_pushdown / FeederSegment):
   the pushable map/filter prefix applied FEEDER-side before the wire
@@ -643,8 +677,8 @@ class TestPushdown:
   order. Covered on both transports (hub queue and shm ring), with the
   end-of-feed tail and EndPartition boundaries included."""
 
-  def _graph(self, src):
-    return (src.map(_pd_map, columnar=True)
+  def _graph(self, src, fn=_pd_map):
+    return (src.map(fn, columnar=True)
             .filter(_pd_filter, columnar=True))
 
   def test_split_carves_the_stateless_prefix(self):
@@ -737,11 +771,23 @@ class TestPushdown:
         q.put(EndPartition())
     q.put(None)
 
-  def _fill_pushed(self, q, chunks, segment):
+  def _int_rows(self, n=400):
+    """Rows the wire codec can shrink: low-cardinality int pixels
+    (dict-able once mapped) and a monotone id (delta-able)."""
+    idx = np.arange(n, dtype=np.int64)
+    px = ((idx[:, None] * 2654435761 + np.arange(64)[None, :] * 40503)
+          % 256).astype(np.int32)
+    return [(px[i], int(i)) for i in range(n)]
+
+  def _fill_pushed(self, q, chunks, segment, sizer=None, stats=None):
     from tensorflowonspark_tpu import node
     run = segment.compile()
     for i, c in enumerate(chunks):
-      node._flush_chunk(q, c, run, None, 5)
+      buf = list(c)
+      while buf:   # a byte budget re-cuts the source chunk into envelopes
+        n = sizer.rows if sizer is not None else len(buf)
+        node._flush_chunk(q, buf[:n], run, sizer, 5, stats=stats)
+        del buf[:n]
       if i == 3:
         q.put(EndPartition())
     q.put(None)
@@ -760,27 +806,49 @@ class TestPushdown:
         assert a[k].dtype == b[k].dtype
         np.testing.assert_array_equal(a[k], b[k])
 
-  @pytest.mark.parametrize("train_mode", [True, False])
-  def test_pushdown_parity_queue_transport(self, hub, train_mode):
-    rows = self._rows()
-    chunks = [rows[i:i + 5] for i in range(0, len(rows), 5)]
+  @pytest.mark.parametrize("train_mode,wire", [
+      (True, "raw"), (False, "raw"), (True, "compress"), (True, "adaptive")])
+  def test_pushdown_parity_queue_transport(self, hub, monkeypatch,
+                                           train_mode, wire):
+    """The four legs of the wire plane against the raw consumer-side
+    graph: pushdown alone (``raw``), pushdown + the per-column wire
+    encodings (``compress``), and those + the envelope byte budget,
+    which moves the chunk boundaries (``adaptive``). The wire plane
+    moves computation and re-encodes bytes; it never changes a batch."""
+    from tensorflowonspark_tpu import node
+    from tensorflowonspark_tpu.control import chunkcodec
+    if wire == "raw":
+      rows, size, fn = self._rows(), 5, _pd_map
+    else:
+      rows, size, fn = self._int_rows(), 50, _pd_int_map
+      monkeypatch.setenv(chunkcodec.ENV_FEED_WIRE_ENCODINGS, "")
+    chunks = [rows[i:i + size] for i in range(0, len(rows), size)]
     self._fill_raw(hub.get_queue("input"), chunks)
     feed = DataFeed(hub, input_mapping={"c0": "x", "c1": "y"},
                     pipeline_depth=0, train_mode=train_mode)
-    ref = self._batches(self._graph(Dataset.from_feed(feed)).batch(8))
+    ref = self._batches(self._graph(Dataset.from_feed(feed), fn).batch(8))
 
+    monkeypatch.delenv(chunkcodec.ENV_FEED_WIRE_ENCODINGS, raising=False)
+    meta = {"feed_target_bytes": 512 if wire == "adaptive" else None}
+    _, _, sizer = node._feed_plan(meta, size)
+    picks = {}
     h2 = feedhub.start(b"k", ["input", "output", "error"], mode="local")
     try:
-      tmpl = self._graph(Dataset.pipeline()).batch(8)
+      tmpl = self._graph(Dataset.pipeline(), fn).batch(8)
       seg, rest = tmpl.split_pushdown()
       assert seg is not None
-      self._fill_pushed(h2.get_queue("input"), chunks, seg)
+      self._fill_pushed(h2.get_queue("input"), chunks, seg, sizer, picks)
       feed2 = DataFeed(h2, input_mapping={"c0": "x", "c1": "y"},
                        pipeline_depth=0, train_mode=train_mode)
       got = self._batches(rest.bind(feed2))
     finally:
       h2.shutdown()
     self._assert_parity(ref, got)
+    if wire != "raw":   # the codec engaged, and the budget moved the cuts
+      assert any(n for enc, n in picks.items() if enc != "raw"), picks
+      assert (sizer is None) == (wire == "compress")
+      # two columns an envelope: the budget cut more envelopes than chunks
+      assert sizer is None or sum(picks.values()) > 2 * len(chunks)
 
   def test_pushdown_parity_shm_ring_transport(self, hub):
     import uuid

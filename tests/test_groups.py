@@ -174,13 +174,22 @@ class TestSyncWire:
       G.attach_sync_plane(server, sync_timeout=10.0)
       results = {}
 
+      # members join before anyone syncs, as GroupSet's do: a round's
+      # membership is frozen when its first contribution arrives, so a
+      # first sync that beats the other member's arrival merges alone
+      clients = {gid: G.GroupSyncClient(server.addr, gid,
+                                        request_timeout=5.0)
+                 for gid in (0, 1)}
+      for c in clients.values():
+        assert c.join()["ok"]
+
       def member(gid, value, weight):
-        c = G.GroupSyncClient(server.addr, gid, request_timeout=5.0)
         try:
           tree = {"w": np.array([value], "float32")}
-          results[gid] = c.sync(1, tree, weight=weight, step=4, timeout=15.0)
+          results[gid] = clients[gid].sync(1, tree, weight=weight, step=4,
+                                           timeout=15.0)
         finally:
-          c.close()
+          clients[gid].close()
 
       threads = [threading.Thread(target=member, args=(0, 2.0, 1.0)),
                  threading.Thread(target=member, args=(1, 6.0, 3.0))]

@@ -265,26 +265,3 @@ class TestOpsScripts:
       res = subprocess.run(["bash", "-n", s], capture_output=True,
                            text=True)
       assert res.returncode == 0, "%s: %s" % (s, res.stderr)
-
-
-class TestFeedBench:
-  @pytest.mark.slow
-  def test_smoke_end_to_end(self):
-    """The feed-plane benchmark (tools/feed_bench.py) runs its full
-    pipeline — feeder subprocess -> hub/ring -> DataFeed -> jitted step —
-    and reports a finite overhead for at least the queue transport.
-
-    Marked slow (tier-1 budget audit): duplicate of
-    tests/test_tools.py::TestFeedBenchSmoke::test_smoke_runs_end_to_end
-    (same `feed_bench.py --smoke` subprocess), which stays tier-1;
-    this copy runs via `make test`."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    res = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "feed_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=240, cwd=repo)
-    assert res.returncode == 0, res.stderr[-2000:]
-    line = json.loads(res.stdout.strip().splitlines()[-1])
-    assert line["compute_steps_per_sec"] > 0
-    q = line["per_transport"]["queue"]
-    assert "fed_steps_per_sec" in q, line

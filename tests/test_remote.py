@@ -451,11 +451,10 @@ class TestHostChaos:
         np.testing.assert_array_equal(
             out, _reference(state.params, cfg, p, b))
 
-  @pytest.mark.slow
   def test_host_process_kill_mid_decode_fails_over_bit_identical(
       self, tiny_state, tmp_path, monkeypatch):
-    """THE acceptance pin, across a REAL process boundary (slow: spawns
-    executors; `make fleet-chaos` and `make check` carry it): two
+    """THE acceptance pin, across a REAL process boundary (it spawns two
+    executors: 12 s; also in `make fleet-chaos`): two
     ServingHost processes, TOS_CHAOS_HOST SIGKILLs one mid-decode — the
     fleet ejects it, replays its accepted requests bit-identically on
     the survivor (stream positions exactly-once by the position-stamped

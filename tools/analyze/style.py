@@ -27,7 +27,7 @@ import tokenize
 
 MAX_LINE = 100
 
-DEFAULT_PATHS = ["tensorflowonspark_tpu", "tests", "examples", "bench.py",
+DEFAULT_PATHS = ["tensorflowonspark_tpu", "tests", "examples",
                  "__graft_entry__.py", "tools/analyze", "tools/lint.py"]
 
 # python's recognized escapes (str); bytes additionally lack N/u/U

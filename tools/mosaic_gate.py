@@ -598,10 +598,10 @@ def t_pipeline_gpipe():
 
 
 def t_resnet_bench():
-  """The headline bench computation itself (bench._bench_resnet: ResNet-50
-  train_step at batch 128 / 224x224) compiled against the 1-device
-  topology: proves the conv stack lowers. (A deviceless compile cannot be
-  read back from the persistent cache on a chip, so this warms nothing.)"""
+  """The image model's train step (ResNet-50 at batch 128 / 224x224)
+  compiled against the 1-device topology: proves the conv stack lowers.
+  (A deviceless compile cannot be read back from the persistent cache on a
+  chip, so this warms nothing.)"""
   import jax
   import jax.numpy as jnp
   from tensorflowonspark_tpu.models import resnet
@@ -1008,8 +1008,8 @@ def compiled_facts(compiled) -> dict:
 
 def _abs_bench_step(batch, seq, cfg_kwargs, vocab, layers, heads, d_model,
                     d_ff, loss_impl="full"):
-  """(jitted step, abstract args) for a single-chip bench config — the
-  exact `bench._bench_transformer` / `_bench_long_context` computation
+  """(jitted step, abstract args) for a single-chip train-step config:
+  create_state + causal_lm_loss (or the blocked loss) + apply_gradients
   with eval_shape state, pinned to the 1-device topology mesh."""
   import jax
   import jax.numpy as jnp
@@ -1040,22 +1040,64 @@ def _abs_bench_step(batch, seq, cfg_kwargs, vocab, layers, heads, d_model,
   return fn, (abs_state, tokens)
 
 
+# The fusion-switch candidates (ROADMAP S5 / D3: fused QKV, ln/act matmul
+# fusions, fused-vs-flax LayerNorm, s=2048, selective remat, GQA) on the
+# 12-layer transformer train step at its full width. This table is their
+# compile evidence (SWEEP_COMPILE.json); their chip numbers come from the
+# train cell's family, which takes the same overrides
+# (benchmarks/families/gpt2.py `program_config`).
+TFM_LAYERS, TFM_DMODEL, TFM_HEADS, TFM_DFF = 12, 768, 12, 3072
+TFM_VOCAB, TFM_SEQ, TFM_BATCH = 32000, 1024, 16
+SWEEP_CONFIGS = [
+    ("b16_s1024_base", {}),
+    ("b16_s1024_fuseqkv", {"fuse_qkv": True}),
+    ("b16_s1024_flaxln", {"layer_norm_impl": "flax"}),
+    ("b16_s1024_lnmm", {"ln_matmul_impl": "fused"}),
+    ("b16_s1024_lnmm_fuseqkv", {"ln_matmul_impl": "fused",
+                                "fuse_qkv": True}),
+    ("b16_s1024_actmm", {"act_matmul_impl": "fused"}),
+    # everything fused: ln1+QKV, ln2+up, gelu+down each one kernel
+    ("b16_s1024_allfused", {"ln_matmul_impl": "fused", "fuse_qkv": True,
+                            "act_matmul_impl": "fused"}),
+    ("b8_s2048", {"batch": 8, "seq": 2048}),
+    ("b8_s2048_fuseqkv", {"batch": 8, "seq": 2048, "fuse_qkv": True}),
+    ("b8_s2048_allfused", {"batch": 8, "seq": 2048,
+                           "ln_matmul_impl": "fused", "fuse_qkv": True,
+                           "act_matmul_impl": "fused"}),
+    # selective remat: save MXU outputs, recompute elementwise only, to
+    # reach the batches that do not fit without remat
+    ("b24_s1024_rematdots", {"batch": 24, "remat": True,
+                             "remat_policy": "dots"}),
+    ("b32_s1024_rematdots", {"batch": 32, "remat": True,
+                             "remat_policy": "dots"}),
+    ("b32_s1024_rematdots_allfused", {"batch": 32, "remat": True,
+                                      "remat_policy": "dots",
+                                      "ln_matmul_impl": "fused",
+                                      "fuse_qkv": True,
+                                      "act_matmul_impl": "fused"}),
+    # GQA at this shape: 12 query heads on 4 KV heads; with allfused on top
+    ("b16_s1024_gqa4", {"num_kv_heads": 4}),
+    ("b16_s1024_gqa4_allfused", {"num_kv_heads": 4,
+                                 "ln_matmul_impl": "fused",
+                                 "fuse_qkv": True,
+                                 "act_matmul_impl": "fused"}),
+]
+
+
 def run_bench_sweep_gate(json_path):
-  """Compile-validate every TOS_BENCH_SWEEP candidate config (plus the
-  long-context bench) against the deviceless topology, so sweep day on a
-  real chip measures instead of debugging Mosaic rejections."""
-  import bench
+  """Compile-validate every SWEEP_CONFIGS candidate (plus the
+  long-context step) against the deviceless topology, so the day they are
+  measured on a real chip measures instead of debugging Mosaic rejections."""
   results = []
-  entries = [(name, dict(kw)) for name, kw in bench.SWEEP_CONFIGS]
-  for name, kw in entries:
-    batch = kw.pop("batch", bench.TFM_BATCH)
-    seq = kw.pop("seq", bench.TFM_SEQ)
-    kw.setdefault("remat", bench.TFM_REMAT)
+  for name, kw in SWEEP_CONFIGS:
+    kw = dict(kw)
+    batch = kw.pop("batch", TFM_BATCH)
+    seq = kw.pop("seq", TFM_SEQ)
+    kw.setdefault("remat", False)
     t0 = time.perf_counter()
     try:
-      fn, args = _abs_bench_step(batch, seq, kw, bench.TFM_VOCAB,
-                                 bench.TFM_LAYERS, bench.TFM_HEADS,
-                                 bench.TFM_DMODEL, bench.TFM_DFF)
+      fn, args = _abs_bench_step(batch, seq, kw, TFM_VOCAB, TFM_LAYERS,
+                                 TFM_HEADS, TFM_DMODEL, TFM_DFF)
       fn.lower(*args).compile()
       results.append(dict(config=name, ok=True,
                           seconds=round(time.perf_counter() - t0, 2)))
@@ -1067,7 +1109,7 @@ def run_bench_sweep_gate(json_path):
   # the long-context headline config: s=4096 flash + blocked loss
   t0 = time.perf_counter()
   try:
-    fn, args = _abs_bench_step(4, 4096, dict(remat=False), bench.TFM_VOCAB,
+    fn, args = _abs_bench_step(4, 4096, dict(remat=False), TFM_VOCAB,
                                4, 8, 1024, 4096, loss_impl="blocked")
     fn.lower(*args).compile()
     results.append(dict(config="long_context_s4096", ok=True,
@@ -1216,7 +1258,7 @@ def main(argv=None):
   ap.add_argument("--json", default=os.path.join(_REPO, "MOSAIC_GATE.json"))
   ap.add_argument("--list", action="store_true")
   ap.add_argument("--bench-sweep", action="store_true",
-                  help="compile-validate every bench.SWEEP_CONFIGS entry "
+                  help="compile-validate every SWEEP_CONFIGS entry "
                        "instead of the kernel targets; writes "
                        "SWEEP_COMPILE.json")
   ap.add_argument("--tile-sweep", action="store_true",

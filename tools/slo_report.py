@@ -10,10 +10,7 @@ the run MEET its objectives" from the artifacts a run leaves behind:
   availability counters (``serve.submitted/rejected/poisoned``,
   ``fleet.shed``); this tool merges the sketches cluster-wide exactly
   like the live plane and evaluates the same ``obs.slo`` objectives
-  into a compliance table, plus every recorded ``slo_burn`` alert;
-- the bench trajectory (``bench_artifacts/history.jsonl``): newest vs
-  trailing-median value per series, so an SLO regression can be lined
-  up against the bench series that should have caught it.
+  into a compliance table, plus every recorded ``slo_burn`` alert.
 
 Objectives come from the same ``TOS_SLO_*`` knobs the live plane reads
 (``obs.slo.objectives_from_env``) — report-time env declares what to
@@ -28,7 +25,7 @@ and asserts (a) a LINKED request trace (>= 2 spans sharing one
 ``trace_id``, queue/prefill through stream) and (b) a compliant
 objective table — the canary phase's read path, proven end to end.
 
-Usage:  python tools/slo_report.py OBS_DIR [--history PATH] [--json-out F]
+Usage:  python tools/slo_report.py OBS_DIR [--json-out F]
         python tools/slo_report.py --smoke [--keep DIR]
 """
 
@@ -127,26 +124,7 @@ def collect_slo_alerts(procs):
   return out
 
 
-def history_trend(path):
-  """Newest-vs-trailing-median per bench series (bench_history's check
-  math, rendered instead of gated)."""
-  from tools import bench_history
-  series = {}
-  for rec in bench_history.load(path):
-    series.setdefault(rec.get("bench", "?"), []).append(rec)
-  out = {}
-  for bench, recs in sorted(series.items()):
-    vals = [r.get("value") for r in recs if r.get("value") is not None]
-    if not vals:
-      continue
-    trailing = vals[:-1] or vals
-    med = sorted(trailing)[len(trailing) // 2]
-    out[bench] = {"latest": vals[-1], "trailing_median": med,
-                  "n": len(vals)}
-  return out
-
-
-def print_compliance(rows, alerts, trend):
+def print_compliance(rows, alerts):
   w = sys.stderr.write
   if not rows:
     w("no SLO objectives declared (set TOS_SLO_* or pass --ttft-ms/"
@@ -173,11 +151,6 @@ def print_compliance(rows, alerts, trend):
       w("  t=%.2f %s burn %.1f/%.1f\n"
         % (a.get("t", 0.0), ev.get("objective", "?"),
            ev.get("burn_fast") or 0.0, ev.get("burn_slow") or 0.0))
-  if trend:
-    w("bench trajectory (newest vs trailing median):\n")
-    for bench, t in trend.items():
-      w("  %-28s %12.2f vs %12.2f  (n=%d)\n"
-        % (bench, t["latest"], t["trailing_median"], t["n"]))
 
 
 def objectives_from_args(args):
@@ -207,19 +180,11 @@ def run_report(args):
   procs = export.merge_jsonl(export.find_logs(args.obs_dir))
   rows = build_compliance(procs, objectives_from_args(args))
   alerts = collect_slo_alerts(procs)
-  trend = {}
-  hist = args.history
-  if hist is None:
-    default = os.path.join("bench_artifacts", "history.jsonl")
-    hist = default if os.path.exists(default) else ""
-  if hist:
-    trend = history_trend(hist)
-  print_compliance(rows, alerts, trend)
+  print_compliance(rows, alerts)
   result = {"metric": "slo_report", "obs_dir": args.obs_dir,
             "logs": len(procs), "objectives": rows,
             "slo_burn_alerts": len(alerts),
-            "compliant": all(r["compliant"] for r in rows),
-            "bench_history": trend}
+            "compliant": all(r["compliant"] for r in rows)}
   if args.json_out:
     with open(args.json_out, "w") as f:
       json.dump(result, f, indent=2)
@@ -317,7 +282,7 @@ def run_smoke(keep_dir=None):
   objectives = slo_mod.objectives_from_env()
   rows = build_compliance(procs, objectives)
   alerts = collect_slo_alerts(procs)
-  print_compliance(rows, alerts, {})
+  print_compliance(rows, alerts)
 
   wire_names = sorted(o.get("name", "?")
                       for o in (slo_wire or {}).get("objectives") or [])
@@ -348,10 +313,6 @@ def main():
   ap = argparse.ArgumentParser()
   ap.add_argument("obs_dir", nargs="?", default=None,
                   help="directory of obs-*.jsonl logs (TOS_OBS_DIR)")
-  ap.add_argument("--history", default=None,
-                  help="bench history.jsonl to render alongside "
-                       "(default: bench_artifacts/history.jsonl if "
-                       "present; '' disables)")
   ap.add_argument("--ttft-ms", type=float, default=None,
                   help="override: p-quantile TTFT bound in ms")
   ap.add_argument("--e2e-ms", type=float, default=None,
