@@ -52,6 +52,7 @@ def _two_part_softmax(s_cache, s_own, q_pos, seg):
 
 class MLA(nn.Module):
   cfg: object
+  mesh: object = None
 
   @nn.compact
   def __call__(self, x, decode: bool = False):
@@ -99,7 +100,7 @@ class MLA(nn.Module):
       positions = idx + jnp.broadcast_to(jnp.arange(seg), (b, seg))
       q_pos = positions[:1]
     was = cached.value
-    cached.value = tfm._cache_write(was, latent, idx, positions)
+    cached.value = tfm._cache_write(was, latent, idx, positions, self.mesh)
     cursor.value = idx + seg
     own_f = latent.astype(jnp.float32)
 

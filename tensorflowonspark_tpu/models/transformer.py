@@ -13,8 +13,10 @@ Logical axis names map to mesh axes through
   batch -> data+fsdp, sequence -> sequence axis.
 """
 
+import contextlib
 import dataclasses
 import functools
+import threading
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -439,10 +441,31 @@ def _heads_logical(n_heads: int, mesh) -> Optional[str]:
 _MXU_COLS = 128
 
 
-def _cache_write(buf, val, idx, positions):
+_cursor_writes = threading.local()
+
+
+@contextlib.contextmanager
+def cursor_write_tally():
+  """Count the per-slot single-token cache writes of what is TRACED inside
+  the block, on this thread: yields ``{"leaves": n, "dma": m}``, ``m`` of
+  the ``n`` having taken ``ops.cursor_write``'s kernel. ``_cache_write``
+  notes each one on the host while it traces (the traced program never
+  contains the note); ``SlotDecoder`` opens one round a ``step_many``
+  program's trace."""
+  tally = _cursor_writes.open = {"leaves": 0, "dma": 0}
+  try:
+    yield tally
+  finally:
+    _cursor_writes.open = None
+
+
+def _cache_write(buf, val, idx, positions, mesh):
   """Write ``val [b, seg, c]`` into the cache leaf ``buf [b, max, c]`` at
   the cursor ``idx``: one dynamic_update_slice for the shared scalar
-  cursor, a vmapped per-row update (one scatter) for per-slot cursors.
+  cursor; for per-slot cursors and ONE token (the serving decode step) one
+  row a slot, by ``ops.cursor_write``'s DMA kernel where the leaf and the
+  device allow it and by a vmapped update-slice elsewhere: one write, two
+  lowerings, chosen from what the code can observe.
 
   Multi-token per-row writes go through an explicit OOB-dropping
   scatter instead: a speculative verify window may transiently
@@ -455,8 +478,25 @@ def _cache_write(buf, val, idx, positions):
   if idx.ndim == 0:
     return jax.lax.dynamic_update_slice(buf, val, (0, idx, 0))
   if seg == 1:
-    # single-token decode can never overshoot (cursor < max_seq_len
-    # by the submit-time budget check): keep the cheap update-slice
+    # single-token decode can never overshoot (cursor < max_seq_len by the
+    # submit-time budget check), and a frozen lane's cursor == max clamps
+    # onto its own last row in either lowering. The kernel: a lane-dense
+    # leaf of whole row tiles, on ONE device (GSPMD does not partition a
+    # Mosaic call), where "auto" picks Pallas kernels at all. XLA lowers
+    # the vmap to a loop of b bounds-checked update-slices a leaf, a third
+    # of a GPT-2 decode step's device time (PERF.md section 6, PR 29); a
+    # native scatter or b unrolled slices make the compiler write the leaf
+    # back whole from fast memory (PR 25)
+    dma = ((mesh is None or mesh.size == 1)
+           and ops.cursor_write_supports(buf.shape, buf.dtype)
+           and ops.pallas_kernels_enabled())
+    tally = getattr(_cursor_writes, "open", None)
+    if tally is not None:
+      tally["leaves"] += 1
+      tally["dma"] += dma
+    if dma:
+      return ops.cursor_write(buf, val[:, 0], idx,
+                              interpret=ops.pallas_interpret())
     return jax.vmap(
         lambda row, v, i: jax.lax.dynamic_update_slice(
             row, v, (i, 0)))(buf, val, idx)
@@ -822,20 +862,20 @@ class Attention(nn.Module):
       v_own = v_store.astype(jnp.float32) * vs[..., None]
       was.update(k_scale=k_scale.value, v_scale=v_scale.value)
       k_scale.value = _constrain(
-          _cache_write(k_scale.value, ks, idx, positions), kv_spec,
-          self.mesh)
+          _cache_write(k_scale.value, ks, idx, positions, self.mesh),
+          kv_spec, self.mesh)
       v_scale.value = _constrain(
-          _cache_write(v_scale.value, vs, idx, positions), kv_spec,
-          self.mesh)
+          _cache_write(v_scale.value, vs, idx, positions, self.mesh),
+          kv_spec, self.mesh)
     else:
       k_store, v_store = k.astype(cfg.dtype), v.astype(cfg.dtype)
       k_own, v_own = k_store, v_store
     cached_k.value = _constrain(
         _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d), idx,
-                     positions), kv_spec, self.mesh)
+                     positions, self.mesh), kv_spec, self.mesh)
     cached_v.value = _constrain(
         _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), idx,
-                     positions), kv_spec, self.mesh)
+                     positions, self.mesh), kv_spec, self.mesh)
     cursor.value = idx + seg
 
     def _dense_attend(_):
@@ -1206,7 +1246,7 @@ class Block(nn.Module):
     elif self.mixer == "mla":
       from tensorflowonspark_tpu.models import mla
       with jax.named_scope("mla"):
-        x = x + mla.MLA(cfg, name="mla")(y, decode=decode)
+        x = x + mla.MLA(cfg, self.mesh, name="mla")(y, decode=decode)
     else:
       x = x + Attention(cfg, self.mesh, name="attn")(y, positions,
                                                      decode=decode)

@@ -64,3 +64,6 @@ from tensorflowonspark_tpu.ops.act_matmul import (  # noqa: F401
 from tensorflowonspark_tpu.ops.ln_matmul import (  # noqa: F401
     ln_matmul, ln_matmul_sharded,
 )
+from tensorflowonspark_tpu.ops.cursor_write import (  # noqa: F401
+    cursor_write, supports as cursor_write_supports,
+)

@@ -315,6 +315,12 @@ class ServingEngine(object):
                   # which the slab that went in is deleted: its donation
                   # was USED, the program ran in place (_on_slab)
                   "slab_dispatches": 0, "slab_in_place": 0,
+                  # per-slot cursor writes of cache leaves the step_many
+                  # dispatches made (leaves x horizon each), and those of
+                  # them by the DMA kernel and not XLA's loop
+                  # (SlotDecoder.cursor_writes, known when the program
+                  # is traced)
+                  "cursor_leaf_writes": 0, "cursor_leaf_writes_dma": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -1339,6 +1345,9 @@ class ServingEngine(object):
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
       out = self._on_slab(lambda slabs: self.decoder.step_many(
           self.params, slabs, self._last, active, remaining, self.horizon))
+      writes, dma = self.decoder.cursor_writes[self.horizon]
+      self.stats["cursor_leaf_writes"] += writes
+      self.stats["cursor_leaf_writes_dma"] += dma
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
       if self.decoder.counted:       # the step's own sums, beside the tokens

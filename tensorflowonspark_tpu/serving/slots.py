@@ -301,6 +301,10 @@ class SlotDecoder(object):
                                    donate_argnums=0)
     self._step_fn = jax.jit(self._step_impl, donate_argnums=1)
     self._step_many_jits = {}    # horizon -> jitted fused-scan step
+    #: horizon -> (per-slot cursor writes of cache leaves a step_many
+    #: dispatch makes, those of them by ops.cursor_write's DMA kernel):
+    #: written while the program is traced, so there from its first call on
+    self.cursor_writes = {}
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
 
@@ -663,8 +667,12 @@ class SlotDecoder(object):
 
         def body(carry, _):
           slabs, tok, active, remaining = carry
-          slabs, nxt, counts = self._one_step(params, slabs, tok, active,
-                                              count=self.counted)
+          with tfm.cursor_write_tally() as writes:
+            slabs, nxt, counts = self._one_step(params, slabs, tok, active,
+                                                count=self.counted)
+          # on the host, while tracing: the body is one step of _h
+          self.cursor_writes[_h] = (_h * writes["leaves"],
+                                    _h * writes["dma"])
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:

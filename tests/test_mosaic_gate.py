@@ -185,10 +185,15 @@ def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
   """The benchmark's serving step at its real size (gpt2-large, 16 slots
   x 1024, horizon 4): the 3.02 GB slab is aliased whole, temporaries stay
   under 0.5 GB (7.31 GB before PR 25), the entry computation copies no
-  slab leaf, and the compiler copies at most a few of the 72 leaves BACK
-  from fast memory a step: attention reads the cache as it was before the
-  step's write, so the staged copy of a leaf is read-only (71 of 72 came
-  back whole, 3 GB a step, while the write came first)."""
+  slab leaf, and the compiler copies NONE of the 72 leaves back from fast
+  memory: attention reads the cache as it was before the step's write, so
+  the staged copy of a leaf is read-only (71 of 72 came back whole, 3 GB a
+  step, while the write came first), and the write itself is
+  ``ops.cursor_write``'s kernel, pinned to the leaf in HBM (2 leaves came
+  back beside XLA's loop; 68 beside a kernel free to run on the staged
+  copy). One ``while`` is left, the horizon's scan (73 with a loop of 16
+  update-slices a leaf), and the kernel is in: 72 calls beside the 73 of
+  the fused LayerNorm (PERF.md section 6, PR 29)."""
   res = _gate_one("gpt2l_step_many", monkeypatch)
   mb = res["memory_bytes"]
   slab_bytes = 72 * 16 * 1024 * 1280 * 2
@@ -196,8 +201,27 @@ def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
   assert mb["temp"] < 0.5e9, mb
   leaf = "bf16[16,1024,1280]"
   assert leaf not in res["entry_copies"], res["entry_copies"]
-  assert res["copies_back_to_hbm"].get(leaf, 0) <= 4, \
-      res["copies_back_to_hbm"]
+  assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] >= 72 + 73, res
+
+
+def test_cursor_write_compiles_in_hbm_and_in_place(topo, monkeypatch):
+  """``ops.cursor_write`` alone at the benchmark's widths (K and V of one
+  gpt2-large layer, 16 slots x 1024 x 1280 bf16, donated): the aligned
+  16-row tile DMA lowers through Mosaic (a one-row DMA of a packed leaf
+  does not), both leaves are aliased whole, nothing of a leaf's shape is
+  copied at the program's edge or back from fast memory, and no
+  temporary exists: the only traffic is the tiles."""
+  res = _gate_one("cursor_write", monkeypatch)
+  leaf_bytes = 16 * 1024 * 1280 * 2
+  mb = res["memory_bytes"]
+  assert 2 * leaf_bytes <= mb["alias"] < 1.001 * 2 * leaf_bytes, mb
+  assert mb["temp"] == 0, mb
+  leaf = "bf16[16,1024,1280]"
+  assert leaf not in res["entry_copies"], res["entry_copies"]
+  assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["tpu_custom_calls"] == 2 and res["while_loops"] == 0, res
 
 
 def test_gpt2l_padded_prefill_projects_one_row(topo, monkeypatch):
@@ -232,8 +256,9 @@ def test_kimi_linear_step_many_keeps_every_kind_of_leaf_in_place(
   GB; the entry computation copies no slab leaf (a 576-wide latent leaf was
   kept transposed and copied in and out, 14 x 226 MB a dispatch); the
   latent cache never comes back from fast memory (absorbed decode reads it
-  as it was before the step's write), and at most a few of the states do;
-  the grouped expert products are kernels."""
+  as it was before the step's write, and ``ops.cursor_write`` writes its
+  row in HBM: one ``while`` is left, the horizon's scan), and at most a few
+  of the states do; the grouped expert products are kernels."""
   from tools.mosaic_gate import V5E_HBM_BYTES
   res = _gate_one("serving_decode_kimi_linear", monkeypatch)
   mb = res["memory_bytes"]
@@ -248,7 +273,8 @@ def test_kimi_linear_step_many_keeps_every_kind_of_leaf_in_place(
   back = res["copies_back_to_hbm"]
   assert leaves[2] not in back and back.get(leaves[0], 0) <= 8, back
   assert back.get(leaves[1], 0) <= 2, back
-  assert res["tpu_custom_calls"] >= 26 * 3, res["tpu_custom_calls"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] >= 26 * 3 + 7, res["tpu_custom_calls"]
 
 
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):

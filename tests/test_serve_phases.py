@@ -4,6 +4,7 @@ the recorder spans under ``TOS_OBS=1`` and the ``jax.profiler`` trace
 annotations — CPU, toy engine.
 """
 
+import dataclasses
 import glob
 import os
 import subprocess
@@ -185,6 +186,31 @@ def test_dispatch_counters_are_exact(toy, spec_depth):
       == sum(len(p) for p in prompts)
   assert st["decode_dispatches"] > 0
   assert st["decode_dispatches"] * per_dispatch == st["steps"]
+
+
+@pytest.mark.parametrize("stack", ["loop", "dma", "paged"])
+def test_cursor_leaf_writes_advance_by_leaves_times_horizon_a_dispatch(
+    toy, monkeypatch, stack):
+  """``cursor_leaf_writes`` counts the per-slot cursor writes of cache
+  leaves the fused decode dispatches made (K and V of every layer, once a
+  step of the horizon) and ``cursor_leaf_writes_dma`` those of them by
+  ``ops.cursor_write``'s kernel: none on the CPU, all of them where Pallas
+  kernels are on (interpret mode here; the toy's leaves are lane-dense, 2
+  heads x 128). The paged pool writes otherwise and counts nothing."""
+  from tensorflowonspark_tpu import ops
+  cfg, state = toy
+  cfg = dataclasses.replace(cfg, layer_norm_impl="flax",
+                            attention_impl="dense")
+  monkeypatch.setattr(ops, "pallas_kernels_enabled", lambda: stack == "dma")
+  with ServingEngine(state.params, cfg, num_slots=3, eos_id=None,
+                     page_size=8 if stack == "paged" else 0) as eng:
+    _serve(eng, _prompts(6, seed=7, longest=20), 9)
+  st = eng.stats               # read with the loop stopped
+  assert st["decode_dispatches"] > 0
+  a_dispatch = 0 if stack == "paged" else 2 * cfg.num_layers * eng.horizon
+  assert st["cursor_leaf_writes"] == st["decode_dispatches"] * a_dispatch
+  assert st["cursor_leaf_writes_dma"] == \
+      (st["cursor_leaf_writes"] if stack == "dma" else 0)
 
 
 # -- the recorder sink --------------------------------------------------------

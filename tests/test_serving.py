@@ -258,6 +258,63 @@ class TestSlotDecoder:
     assert jax.tree.structure(out) == jax.tree.structure(slabs)
 
 
+class TestCursorWriteLowerings:
+  """The decode step's per-slot cache write has two lowerings
+  (``transformer._cache_write``): ``ops.cursor_write``'s DMA kernel where
+  Pallas kernels are on, XLA's loop elsewhere. Forced onto the kernel
+  (interpret mode here) a ``SlotDecoder`` must emit the loop's tokens and
+  leave the loop's slab, bit for bit."""
+
+  @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                           ids=["f32", "bf16"])
+  def test_same_tokens_and_same_slab_over_three_dispatches(
+      self, monkeypatch, dtype):
+    from tensorflowonspark_tpu import ops
+    # lane-dense leaves (2 heads x 64 = 128) of whole row tiles (48 rows);
+    # LayerNorm and attention pinned off "auto", so that the cache write is
+    # the one thing the switch below moves
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=2, d_model=128, d_ff=256,
+        max_seq_len=48, remat=False, dtype=dtype, layer_norm_impl="flax",
+        attention_impl="dense")
+    params = tfm.create_state(jax.random.PRNGKey(1), cfg, seq_len=16).params
+    rng = np.random.RandomState(5)
+    # slot 0 reaches max_seq_len after 4 tokens and then stays FROZEN at
+    # cursor == max for two more dispatches: its garbage write must clamp
+    # onto its own last row in both lowerings; slot 1 runs out of budget
+    # mid-horizon; slot 2 is never filled (frozen at cursor 0)
+    prompts = [rng.randint(1, 64, (n,)).astype(np.int32) for n in (44, 5)]
+
+    def run(kernels: bool):
+      monkeypatch.setattr(ops, "pallas_kernels_enabled", lambda: kernels)
+      dec = SlotDecoder(cfg, 3, pad_id=PAD)
+      slabs, last = dec.init_slabs(), [PAD] * 3
+      for slot, prompt in enumerate(prompts):
+        row, last[slot] = dec.prefill(params, prompt)
+        slabs = dec.insert(slabs, row, slot)
+      active, left, emitted = [True, True, False], [4, 10, 0], []
+      for _ in range(3):
+        slabs, toks, active, left = dec.step_many(params, slabs, last,
+                                                  active, left, 4)
+        toks = np.asarray(toks)
+        emitted.append(toks)
+        last = list(toks[-1])
+      return np.stack(emitted), slabs, dec.cursor_writes[4]
+
+    toks_loop, slab_loop, writes_loop = run(False)
+    toks_dma, slab_dma, writes_dma = run(True)
+    assert writes_loop == (4 * 4, 0)            # K and V of 2 layers x 4
+    assert writes_dma == (4 * 4, 4 * 4)
+    np.testing.assert_array_equal(toks_dma, toks_loop)
+    assert (toks_loop[0, :, 0] != PAD).all() and \
+        (toks_loop[1:, :, 0] == PAD).all()       # slot 0 froze at max
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(slab_dma)[0],
+                            jax.tree.leaves(slab_loop)):
+      np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                    np.asarray(b, np.float32),
+                                    err_msg=jax.tree_util.keystr(path))
+
+
 class TestServingEngine:
   def test_mixed_length_parity(self, tiny_state):
     """THE acceptance pin: mixed-length, mixed-budget traffic through a

@@ -822,6 +822,24 @@ def t_gpt2l_step_many():
                            _on_chip0(jax.eval_shape(dec.init_slabs)))
 
 
+def t_cursor_write():
+  """``ops.cursor_write`` alone, on K and V of one layer of the benchmark's
+  GPT-2 slab (16 slots x 1024 x 1280, bf16), both donated as the serving
+  step donates its slab. (A donating program that is ONE such call and
+  returns its bare result does not compile: "Different aliasing shapes",
+  the HBM-pinned result against the entry's own layout; any program that
+  returns a tuple does.)"""
+  import jax
+  from tensorflowonspark_tpu.ops.cursor_write import cursor_write
+
+  def layer(k, v, new_k, new_v, idx):
+    return cursor_write(k, new_k, idx), cursor_write(v, new_v, idx)
+
+  leaf, new = _on_chip0(_sh(16, 1024, 1280)), _on_chip0(_sh(16, 1280))
+  return jax.jit(layer, donate_argnums=(0, 1)), (leaf, leaf, new, new,
+                                                 _i32(16))
+
+
 def t_gpt2l_prefill_512():
   """The benchmark's largest prefill program at its real size: a padded
   512-token chunk (PERF.md section 6, PR 27); only the last real row may
@@ -938,6 +956,7 @@ TARGETS = {
     "smoke_paged_insert": t_smoke_paged_insert,
     "smoke_paged_step_many": t_smoke_paged_step_many,
     "gpt2l_step_many": t_gpt2l_step_many,
+    "cursor_write": t_cursor_write,
     "gpt2l_prefill_512": t_gpt2l_prefill_512,
     "serving_decode_kimi_linear": t_serving_decode_kimi_linear,
 }
@@ -985,7 +1004,9 @@ def compiled_facts(compiled) -> dict:
   (``memory_analysis``), whether the Pallas kernels are in
   (``tpu_custom_call``), which collectives the compiler put in, what the
   entry computation copies (:func:`entry_copies`) and what comes back
-  from fast memory (:func:`copies_back_to_hbm`)."""
+  from fast memory (:func:`copies_back_to_hbm`), and how many ``while``
+  loops are left (a vmapped ``dynamic_update_slice`` is one a leaf)."""
+  import re
   facts = {}
   m = compiled.memory_analysis()
   if m is not None:
@@ -997,6 +1018,7 @@ def compiled_facts(compiled) -> dict:
                              - mb["alias"])
   text = compiled.as_text()
   facts["tpu_custom_calls"] = text.count("tpu_custom_call")
+  facts["while_loops"] = len(re.findall(r" while\(", text))
   facts["collectives"] = {
       op: text.count(op + "(") + text.count(op + "-start(")
       for op in ("all-reduce", "all-gather", "reduce-scatter",
