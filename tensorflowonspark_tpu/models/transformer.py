@@ -178,6 +178,30 @@ class TransformerConfig:
   # trained weights route with margins, and the check decides on the widest
   # gap of a served token (PERF.md section 7 asks for a p99 or mean limit).
   act_f32: bool = False
+  # Rotary base (``_rotary``'s theta): positions rotate at theta^(-2i/d).
+  rope_theta: float = 10000.0
+  # Sandwich norms: a norm AFTER each branch too, before it joins the
+  # residual stream (``x += norm(Mix(norm(x))); x += norm(FFN(norm(x)))``:
+  # params ``ln1_out``/``ln2_out`` beside ``ln1``/``ln2``). False: pre-norm.
+  post_norm: bool = False
+  # A looped model (ROADMAP R10): the ``num_layers`` layers run
+  # ``loop_passes`` times a token over the SAME weights, the final norm at
+  # the end of every pass (its output enters the next pass). 1 = every layer
+  # once, the final norm once: the programs traced before the field existed.
+  # The parameter tree holds ONE set of layers whatever the count; the decode
+  # cache holds keys and values PER PASS (pass u of layer l attends that
+  # pass's keys only, so a full forward fixes it: ``cached_k_p<u>`` /
+  # ``cached_v_p<u>``, each the shape a layer's leaf always had) and ONE
+  # cursor a layer, advanced once a token. After each pass but the last an
+  # exit gate ``sigmoid(w . x + b)`` (param ``exit_gate``) reads the normed
+  # stream; a token EXITS at the first pass whose cumulative exit
+  # probability reaches ``loop_exit_threshold`` (the last pass at 1.0, for
+  # every finite gate) and its logits are that pass's. The full forward
+  # honours any threshold in (0, 1]; the cached decode path refuses one
+  # under 1.0 (``loop_refusal``). Each pass runs under ``jax.named_scope``
+  # ``pass_<u>``.
+  loop_passes: int = 1
+  loop_exit_threshold: float = 1.0
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -238,7 +262,21 @@ class TransformerConfig:
           "0 < experts_top_k=%d <= that" % (
               self.experts_first, self.experts_first + self.experts_held,
               self.experts_total, self.experts_top_k))
+    if self.loop_passes < 1 or not 0.0 < self.loop_exit_threshold <= 1.0:
+      raise ValueError(
+          "loop_passes must be >= 1 and loop_exit_threshold in (0, 1], got "
+          "%r and %r" % (self.loop_passes, self.loop_exit_threshold))
+    if self.loop_passes > 1 and (self.non_kv_layers or self.moe_experts
+                                 or "experts" in self.ffn_types):
+      raise ValueError(
+          "loop_passes=%d: only attention + MLP layers keep a cache a pass "
+          "(layer_types %r, ffn_types %r, moe_experts %d)" % (
+              self.loop_passes, self.layer_types, self.ffn_types,
+              self.moe_experts))
     if self.kv_page_size > 0:
+      if self.loop_passes > 1:
+        raise ValueError(loop_refusal(
+            self, "pages", "the paged KV pool (kv_page_size=%d)" % self.kv_page_size))
       if self.non_kv_layers:
         raise ValueError(
             "the paged KV pool (kv_page_size=%d) holds keys and values per "
@@ -284,12 +322,55 @@ class TransformerConfig:
     return self.num_kv_heads or self.num_heads
 
 
-def _rotary(x, positions):
+#: why each serving feature cannot take a looped model yet (``loop_refusal``)
+_LOOP_REFUSALS = {
+    "pages": "the pool holds ONE set of K/V pages a layer, and each of this "
+             "model's passes keeps keys and values of its own (a pool a "
+             "pass does not exist yet)",
+    "prefix": "a prefix's pages are one set a layer, and each of this "
+              "model's passes keeps keys and values of its own (prefix "
+              "pages a pass do not exist yet)",
+    "draft": "the shallow-exit draft is a prefix of LAYERS, and a prefix of "
+             "this model's layers is not a prefix of its passes: the draft "
+             "would skip every layer's later passes and leave their caches "
+             "unwritten (a draft of fewer PASSES does not exist yet)",
+    "early_exit": "a token that exits early writes no keys for its later "
+                  "passes, which later tokens' later passes attend; what "
+                  "they read instead is a policy the configuration does not "
+                  "state",
+}
+
+
+def loop_refusal(cfg, feature: str, what: str) -> str:
+  """The message with which ``what`` (a serving feature, named as its user
+  named it; ``feature`` its key in ``_LOOP_REFUSALS``) refuses a model
+  whose layers run ``cfg.loop_passes`` times."""
+  return "%s cannot serve a model whose %d layers run %d times a token: %s" \
+      % (what, cfg.num_layers, cfg.loop_passes, _LOOP_REFUSALS[feature])
+
+
+def exit_pass(gates, threshold: float):
+  """The pass (1-based) at which each token exits a looped model: ``gates``
+  holds the exit gates after passes 1..n-1 (each ``[...]``, in (0, 1)); the
+  exit distribution is ``p_u = gate_u prod_{j<u}(1 - gate_j)``, the last
+  pass taking what is left, and a token exits at the first pass whose
+  cumulative ``p`` reaches ``threshold``: the last for every finite gate
+  at 1.0."""
+  last = len(gates) + 1
+  stay = jnp.ones_like(gates[0])       # prod_{j<=u}(1 - gate_j)
+  out = jnp.full(gates[0].shape, last, jnp.int32)
+  for u, g in enumerate(gates):
+    stay = stay * (1.0 - g)
+    out = jnp.where((out == last) & (1.0 - stay >= threshold), u + 1, out)
+  return out
+
+
+def _rotary(x, positions, theta: float = 10000.0):
   """Rotary position embedding over the last (head_dim) axis."""
   d = x.shape[-1]
   half = d // 2
   freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                  * (jnp.log(10000.0) / half))
+                  * (jnp.log(theta) / half))
   angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,half]
   cos = jnp.cos(angles)[:, :, None, :]
   sin = jnp.sin(angles)[:, :, None, :]
@@ -701,10 +782,12 @@ class Attention(nn.Module):
   mesh: Optional[Any] = None
 
   @nn.compact
-  def __call__(self, x, positions, decode: bool = False, ln_scale=None):
+  def __call__(self, x, positions, decode: bool = False, ln_scale=None,
+               loop_pass: int = 0):
     """With ``ln_scale`` (requires ``fuse_qkv``), ``x`` is the RAW
     residual stream and ln1 + the QKV projection run as one Pallas kernel
-    (ops.ln_matmul); otherwise ``x`` arrives normalized."""
+    (ops.ln_matmul); otherwise ``x`` arrives normalized. ``loop_pass`` is
+    which of ``cfg.loop_passes`` this call is: the decode cache it owns."""
     cfg = self.cfg
     dense = lambda feats, logical, name: nn.DenseGeneral(  # noqa: E731
         feats, axis=-1, dtype=cfg.dtype, use_bias=False, name=name,
@@ -740,10 +823,10 @@ class Attention(nn.Module):
                 ("embed", heads_axis(cfg.kv_heads), "kv"), "v")(x)
 
     if decode:
-      return self._decode_attend(q, k, v)
+      return self._decode_attend(q, k, v, loop_pass)
 
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
+    q = _rotary(q, positions, cfg.rope_theta)
+    k = _rotary(k, positions, cfg.rope_theta)
 
     interp = ops.pallas_interpret()           # forced-flash CI runs
     if cfg.use_ring_attention and self.mesh is not None:
@@ -786,7 +869,7 @@ class Attention(nn.Module):
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), ("heads", "kv", "embed")))(out)
 
-  def _decode_attend(self, q, k, v):
+  def _decode_attend(self, q, k, v, loop_pass: int = 0):
     """Incremental attention against a KV cache (serving path).
 
     Writes the new keys/values at the cache cursor, attends the query
@@ -807,6 +890,11 @@ class Attention(nn.Module):
     offset (a vmapped update-slice, i.e. one scatter) and masks against
     its own length, so one jitted step can advance in-flight requests
     that are at different positions in their sequences.
+
+    A looped model (``cfg.loop_passes`` > 1) calls this once a pass on the
+    ONE module: pass ``loop_pass`` owns the leaves ``cached_k_p<u>`` /
+    ``cached_v_p<u>`` (scales likewise), every pass reads the one cursor
+    where the token began, and the last pass advances it.
     """
     cfg = self.cfg
     if cfg.kv_page_size > 0:
@@ -815,16 +903,17 @@ class Attention(nn.Module):
     hk = cfg.kv_heads
     quant = cfg.kv_cache_dtype == "int8"
     cache_dt = jnp.int8 if quant else cfg.dtype
+    sfx = "_p%d" % loop_pass if cfg.loop_passes > 1 else ""
     cached_k = self.variable(
-        "cache", "cached_k", jnp.zeros, (b, cfg.max_seq_len, hk * d),
+        "cache", "cached_k" + sfx, jnp.zeros, (b, cfg.max_seq_len, hk * d),
         cache_dt)
     cached_v = self.variable(
-        "cache", "cached_v", jnp.zeros, (b, cfg.max_seq_len, hk * d),
+        "cache", "cached_v" + sfx, jnp.zeros, (b, cfg.max_seq_len, hk * d),
         cache_dt)
     if quant:
-      k_scale = self.variable("cache", "k_scale", jnp.zeros,
+      k_scale = self.variable("cache", "k_scale" + sfx, jnp.zeros,
                               (b, cfg.max_seq_len, hk), jnp.float32)
-      v_scale = self.variable("cache", "v_scale", jnp.zeros,
+      v_scale = self.variable("cache", "v_scale" + sfx, jnp.zeros,
                               (b, cfg.max_seq_len, hk), jnp.float32)
     cursor = self.variable("cache", "index",
                            lambda: jnp.zeros((), jnp.int32))
@@ -835,8 +924,8 @@ class Attention(nn.Module):
       positions = idx[:, None] + jnp.arange(seg)[None, :]
     else:
       positions = idx + jnp.broadcast_to(jnp.arange(seg), (b, seg))
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
+    q = _rotary(q, positions, cfg.rope_theta)
+    k = _rotary(k, positions, cfg.rope_theta)
 
     # tensor-parallel serving: keep the cache sharded on its folded
     # (grouped) heads axis so each chip holds 1/t of the KV bytes — whole
@@ -876,7 +965,8 @@ class Attention(nn.Module):
     cached_v.value = _constrain(
         _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), idx,
                      positions, self.mesh), kv_spec, self.mesh)
-    cursor.value = idx + seg
+    if loop_pass == cfg.loop_passes - 1:
+      cursor.value = idx + seg
 
     def _dense_attend(_):
       # the cache as it was plus the block itself (what the write above
@@ -971,8 +1061,8 @@ class Attention(nn.Module):
     idx = cursor.value
 
     positions = idx[:, None] + jnp.arange(seg)[None, :]        # [b, seg]
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
+    q = _rotary(q, positions, cfg.rope_theta)
+    k = _rotary(k, positions, cfg.rope_theta)
 
     # write: token position -> (page, offset) through the table. A
     # position inside the span but past the slot's allocation resolves
@@ -1191,7 +1281,8 @@ class _LNScale(nn.Module):
 class Block(nn.Module):
   """One pre-norm residual layer: ``x += Mix(norm(x)); x += FFN(norm(x))``.
   ``mixer``/``ffn`` pick the two (``TransformerConfig.layer_types`` /
-  ``ffn_types``); the defaults are the attention + MLP block."""
+  ``ffn_types``); the defaults are the attention + MLP block.
+  ``cfg.post_norm`` norms each branch's output too (:meth:`_sandwich`)."""
   cfg: TransformerConfig
   mesh: Optional[Any] = None
   use_moe: bool = False
@@ -1199,10 +1290,12 @@ class Block(nn.Module):
   ffn: str = "mlp"
 
   @nn.compact
-  def __call__(self, x, positions, decode: bool = False):
+  def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0):
     cfg = self.cfg
     if self.mixer != "attn" or self.ffn != "mlp":
       return self._typed(x, positions, decode)
+    if cfg.post_norm:
+      return self._sandwich(x, positions, decode, loop_pass)
     fuse_ln = cfg.ln_matmul_impl == "fused" and not decode
     if fuse_ln and cfg.fuse_qkv:
       # ln1 + the fused QKV projection as ONE kernel over the raw
@@ -1212,8 +1305,8 @@ class Block(nn.Module):
                                                      ln_scale=scale1)
     else:
       y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
-      x = x + Attention(cfg, self.mesh, name="attn")(y, positions,
-                                                     decode=decode)
+      x = x + Attention(cfg, self.mesh, name="attn")(
+          y, positions, decode=decode, loop_pass=loop_pass)
     act_fused = cfg.act_matmul_impl == "fused" and not decode
     if fuse_ln and not self.use_moe:
       # ln2 + up-projection as ONE kernel over the raw residual stream;
@@ -1227,6 +1320,21 @@ class Block(nn.Module):
         x = x + MoEBlock(cfg, self.mesh, name="moe")(y)
       else:
         x = x + MLPBlock(cfg, self.mesh, act_fused, name="mlp")(y)
+    if decode:
+      return x
+    return _constrain(x, ("batch", "sequence", "embed"), self.mesh)
+
+  def _sandwich(self, x, positions, decode, loop_pass):
+    """The attention + MLP layer with a norm before AND after each branch:
+    ``x += ln1_out(Attn(ln1(x))); x += ln2_out(MLP(ln2(x)))``. The norms
+    compute in float32; what joins the stream is rounded to its dtype."""
+    cfg = self.cfg
+    norm = lambda name: _make_layer_norm(cfg, self.mesh, name)  # noqa: E731
+    y = Attention(cfg, self.mesh, name="attn")(
+        norm("ln1")(x), positions, decode=decode, loop_pass=loop_pass)
+    x = x + norm("ln1_out")(y).astype(x.dtype)
+    y = MLPBlock(cfg, self.mesh, name="mlp")(norm("ln2")(x))
+    x = x + norm("ln2_out")(y).astype(x.dtype)
     if decode:
       return x
     return _constrain(x, ("batch", "sequence", "embed"), self.mesh)
@@ -1341,6 +1449,16 @@ class Transformer(nn.Module):
     if exit_layer is not None and not 1 <= exit_layer <= cfg.num_layers:
       raise ValueError("exit_layer must be in [1, num_layers=%d], got %r"
                        % (cfg.num_layers, exit_layer))
+    if cfg.loop_passes > 1:
+      if exit_layer is not None:
+        raise ValueError(loop_refusal(
+            cfg, "draft", "speculative decoding's shallow exit (exit_layer=%d)"
+            % exit_layer))
+      if decode and cfg.loop_exit_threshold < 1.0:
+        raise ValueError(loop_refusal(
+            cfg, "early_exit",
+            "loop_exit_threshold=%r in the cached decode path"
+            % cfg.loop_exit_threshold))
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     emb = TiedEmbed(cfg, self.mesh, name="embed")
     x = emb(tokens)
@@ -1350,21 +1468,26 @@ class Transformer(nn.Module):
     block = Block
     if cfg.remat and not decode:
       block = _remat_block(cfg)
+    layers = []
     for i in range(cfg.num_layers if exit_layer is None else exit_layer):
       use_moe = (cfg.moe_experts > 0
                  and i % cfg.moe_every == cfg.moe_every - 1)
-      layer = block(cfg, self.mesh, use_moe,
-                    cfg.layer_types[i] if cfg.layer_types else "attn",
-                    cfg.ffn_types[i] if cfg.ffn_types else "mlp",
-                    name="layer_%d" % i)
-      x = layer(x, positions, True) if decode else layer(x, positions)
-
-    if logits_at is not None:
-      # one position (a traced scalar): only that row goes through the
-      # final norm and the head, the result is [batch, 1, vocab] — a
-      # padded prefill chunk wants the logits of its last REAL token
-      x = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
-    x = _make_layer_norm(cfg, self.mesh, "ln_f")(x)
+      layers.append(block(cfg, self.mesh, use_moe,
+                          cfg.layer_types[i] if cfg.layer_types else "attn",
+                          cfg.ffn_types[i] if cfg.ffn_types else "mlp",
+                          name="layer_%d" % i))
+    ln_f = _make_layer_norm(cfg, self.mesh, "ln_f")
+    if cfg.loop_passes == 1:
+      for layer in layers:
+        x = layer(x, positions, True) if decode else layer(x, positions)
+      if logits_at is not None:
+        # one position (a traced scalar): only that row goes through the
+        # final norm and the head, the result is [batch, 1, vocab] — a
+        # padded prefill chunk wants the logits of its last REAL token
+        x = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
+      x = ln_f(x)
+    else:
+      x = self._loop(x, positions, decode, layers, ln_f, logits_at)
     if return_hidden:
       # pre-projection hidden states for the fused blocked loss
       # (:func:`causal_lm_loss_blocked`) — callers project against the
@@ -1376,6 +1499,35 @@ class Transformer(nn.Module):
     # tied output projection (attend to the embedding table)
     logits = emb.attend(x.astype(cfg.dtype))
     return logits.astype(jnp.float32)
+
+  def _loop(self, x, positions, decode, layers, ln_f, logits_at):
+    """``cfg.loop_passes`` passes over the one set of ``layers``, the final
+    norm closing each pass; returns the normed stream of each token's EXIT
+    pass (``exit_pass``: the last at the published threshold 1.0), at
+    ``logits_at`` alone where that is given. The exit pass of every token
+    is sown under ``counters/exit_pass`` ``[batch, seq]``: where a caller
+    collects it the gates are computed, elsewhere the compiler drops them."""
+    cfg = self.cfg
+    n, gates, normed = cfg.loop_passes, [], []
+    gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate")
+    for u in range(n):
+      with jax.named_scope("pass_%d" % u):
+        for layer in layers:
+          x = layer(x, positions, True, u) if decode else layer(x, positions)
+        y = ln_f(x)                          # float32, [batch, seq, d]
+        if u < n - 1:
+          gates.append(jax.nn.sigmoid(gate(y)[..., 0]))
+          x = y.astype(x.dtype)
+        if cfg.loop_exit_threshold < 1.0 or u == n - 1:
+          normed.append(y)
+    exits = exit_pass(gates, cfg.loop_exit_threshold)        # [batch, seq]
+    self.sow("counters", "exit_pass", exits)
+    out = normed[-1]
+    for u, y in enumerate(normed[:-1]):
+      out = jnp.where((exits == u + 1)[..., None], y, out)
+    if logits_at is not None:
+      out = lax.dynamic_slice_in_dim(out, logits_at, 1, axis=1)
+    return out
 
 
 @functools.lru_cache(maxsize=8)

@@ -156,6 +156,13 @@ _DEFAULT_MAX_QUEUED_TOKENS = 1 << 20
 _DEFAULT_MAX_RESTARTS = 5
 _DEFAULT_RESTART_BACKOFF = 0.05
 _DEFAULT_POISON_CRASHES = 2
+
+#: ``SlotDecoder.step_many``'s fifth member (a model that counts) -> the
+#: key of ``ServingEngine.stats`` each of its sums is added to
+_STEP_COUNTERS = {"held": "moe_assignments_held",
+                  "touched": "moe_experts_touched",
+                  "context": "live_context_tokens",
+                  "exit_pass": "loop_exit_pass_sum"}
 #: restart backoff never exceeds this many seconds
 _BACKOFF_CAP = 2.0
 #: retry-after hint while the tokens/s EMA is still cold (no decode has
@@ -239,6 +246,10 @@ class ServingEngine(object):
                           else _env_int(ENV_SERVE_SPEC_DEPTH, 0))
     spec_layers = int(spec_layers if spec_layers is not None
                       else _env_int(ENV_SERVE_SPEC_LAYERS, 0))
+    if self.prefix_pages > 0 and cfg.loop_passes > 1:
+      raise ValueError(slots_lib.tfm.loop_refusal(
+          cfg, "prefix", "the shared-prefix cache (prefix_pages=%d)"
+          % self.prefix_pages))
     if self.prefix_pages > 0 and cfg.non_kv_layers:
       raise ValueError(
           "the shared-prefix cache (prefix_pages=%d) reuses a prompt "
@@ -311,6 +322,10 @@ class ServingEngine(object):
                   # the live lanes' caches held when each step began
                   "moe_assignments_held": 0, "moe_experts_touched": 0,
                   "live_context_tokens": 0,
+                  # a looped model (likewise counted): the pass at which
+                  # its exit gates let each live lane's token go, summed;
+                  # over live_slot_steps it is the mean exit pass
+                  "loop_exit_pass_sum": 0,
                   # calls of a slab-returning program, and those after
                   # which the slab that went in is deleted: its donation
                   # was USED, the program ran in place (_on_slab)
@@ -1351,10 +1366,8 @@ class ServingEngine(object):
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
       if self.decoder.counted:       # the step's own sums, beside the tokens
-        for key, name in (("moe_assignments_held", "held"),
-                          ("moe_experts_touched", "touched"),
-                          ("live_context_tokens", "context")):
-          self.stats[key] += int(np.asarray(out[4][name]))
+        for name, value in out[4].items():
+          self.stats[_STEP_COUNTERS[name]] += int(np.asarray(value))
     lanes: List[tuple] = []
     freed: List[int] = []
     # ONE region round the whole harvest: _harvest runs per token

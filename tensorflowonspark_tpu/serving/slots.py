@@ -248,9 +248,9 @@ class SlotDecoder(object):
     self.mesh = mesh
     self.page_size = int(page_size)
     self.paged = self.page_size > 0
-    #: whether the model counts (its expert layers sow ``counters``):
-    #: step_many then returns a fifth member
-    self.counted = "experts" in cfg.ffn_types
+    #: whether the model counts (its expert layers, or its loop, sow
+    #: ``counters``): step_many then returns a fifth member
+    self.counted = "experts" in cfg.ffn_types or cfg.loop_passes > 1
     if self.paged:
       pps = int(pages_per_slot) or -(-cfg.max_seq_len // self.page_size)
       pool = int(num_pages) or num_slots * pps + 1
@@ -274,6 +274,17 @@ class SlotDecoder(object):
           "the cache cursor back; this model's KDA layers keep a recurrent "
           "state with no position axis, which a cursor cannot unwind"
           % self.spec_depth)
+    if cfg.loop_passes > 1:
+      # a looped model: what cannot take a cache a pass is refused by name
+      # (the paged pool above, by TransformerConfig itself)
+      if self.spec_depth:
+        raise ValueError(tfm.loop_refusal(
+            cfg, "draft",
+            "speculative decoding (spec_depth=%d)" % self.spec_depth))
+      if cfg.loop_exit_threshold < 1.0:
+        raise ValueError(tfm.loop_refusal(
+            cfg, "early_exit", "loop_exit_threshold=%r in a serving slab"
+            % cfg.loop_exit_threshold))
     self.spec_layers = int(spec_layers) or max(1, cfg.num_layers // 2)
     if self.spec_depth and not 1 <= self.spec_layers <= cfg.num_layers:
       raise ValueError(
@@ -575,11 +586,13 @@ class SlotDecoder(object):
 
   def _one_step(self, params, slabs, tok, active, count: bool = False):
     """One token a lane: ``(new_slabs, next_tokens, counts)``. With
-    ``count`` (a model whose expert layers sow ``counters``) ``counts``
-    holds int32 sums over LIVE lanes, else it is ``None``: ``held``
-    assignments to experts held here, ``touched`` held experts that got at
-    least one live token (summed over expert layers), ``context`` tokens
-    the live lanes' caches held before the step."""
+    ``count`` (a model that sows ``counters``) ``counts`` holds int32 sums
+    over LIVE lanes, else it is ``None``: ``context`` tokens the live
+    lanes' caches held before the step; of expert layers ``held``
+    assignments to experts held here and ``touched`` held experts that got
+    at least one live token (summed over expert layers); of a looped model
+    ``exit_pass``, the pass at which its gates let each live lane's token
+    exit."""
     logits, mutated = self.slab_model.apply(
         {"params": params, "cache": slabs}, tok[:, None], decode=True,
         mutable=["cache", "counters"] if count else ["cache"])
@@ -587,15 +600,20 @@ class SlotDecoder(object):
     counts = None
     if count:
       sown = mutated["counters"]
-      held = _sown(sown, "held")                       # [slots] a layer
-      hit = _sown(sown, "hit")                         # [slots, held]
-      counts = dict(
-          held=sum(jnp.sum(jnp.where(active, x, 0)) for x in held),
-          touched=sum(jnp.sum(jnp.any(
-              jnp.logical_and(x, active[:, None]), axis=0), dtype=jnp.int32)
-                      for x in hit),
-          context=jnp.sum(jnp.where(
-              active, _cursor_leaf(slabs).astype(jnp.int32), 0)))
+      counts = {}
+      if "experts" in self.cfg.ffn_types:
+        held = _sown(sown, "held")                     # [slots] a layer
+        hit = _sown(sown, "hit")                       # [slots, held]
+        counts.update(
+            held=sum(jnp.sum(jnp.where(active, x, 0)) for x in held),
+            touched=sum(jnp.sum(jnp.any(
+                jnp.logical_and(x, active[:, None]), axis=0),
+                                dtype=jnp.int32) for x in hit))
+      counts["context"] = jnp.sum(jnp.where(
+          active, _cursor_leaf(slabs).astype(jnp.int32), 0))
+      if self.cfg.loop_passes > 1:
+        (exits,) = _sown(sown, "exit_pass")            # [slots, 1]
+        counts["exit_pass"] = jnp.sum(jnp.where(active, exits[:, 0], 0))
 
     def freeze(path, new, old):
       # inactive slots must not advance: undo their cursor bump so the

@@ -277,6 +277,27 @@ def test_kimi_linear_step_many_keeps_every_kind_of_leaf_in_place(
   assert res["tpu_custom_calls"] >= 26 * 3 + 7, res["tpu_custom_calls"]
 
 
+def test_looped_step_many_keeps_a_cache_a_pass_in_place(topo, monkeypatch):
+  """The cell ouro-serve-backlog's decode step at its published widths and
+  its 4 passes, 8 slots x 512, horizon 4, with 4 of the 48 layers (the
+  whole program compiles for minutes; ``python -m tools.mosaic_gate
+  --targets serving_decode_ouro`` is that compile): each pass of each layer
+  owns a K and a V leaf, 32 leaves of 8 x 512 x 2048 here, all aliased,
+  none copied at the program's edge or back from fast memory, every cursor
+  write ``ops.cursor_write``'s kernel (the model's norms are RMSNorms, no
+  kernel of their own: 32 custom calls), one ``while`` (the horizon's
+  scan), the passes unrolled inside it."""
+  res = _gate_one("serving_decode_ouro_4_layers", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 4 * 4 * 2 * 8 * 512 * 2048 * 2
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  leaf = "bf16[8,512,2048]"
+  assert leaf not in res["entry_copies"], res["entry_copies"]
+  assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] == 32, res
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

@@ -903,6 +903,68 @@ def t_serving_decode_kimi_linear():
   return _step_many_target(dec, params, slabs)
 
 
+#: the benchmark cell ouro-serve-backlog: slots x max_seq
+OURO_SLOTS, OURO_MAX_SEQ = 8, 512
+
+
+def ouro_cfg(max_seq: int = OURO_MAX_SEQ, layers: int = 48):
+  """Ouro-2.6B as ``benchmarks/configs/ouro-2.6b.json`` has it (published
+  widths, all 48 layers, 4 passes over them, whole vocabulary), spelled out
+  so that the gate needs nothing of ``benchmarks/``;
+  ``benchmarks/tests/test_ouro.py`` keeps the two equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  return tfm.TransformerConfig(
+      vocab_size=49152, num_layers=layers, num_heads=16, d_model=2048,
+      d_ff=5632,
+      max_seq_len=max_seq, remat=False, dtype=jnp.bfloat16, norm="rms",
+      norm_eps=1e-6, mlp_act="swiglu", tie_embeddings=False,
+      attn_head_dim=128, rope_theta=1e6, post_norm=True, loop_passes=4,
+      loop_exit_threshold=1.0)
+
+
+def ouro_decoder(slots: int = OURO_SLOTS, max_seq: int = OURO_MAX_SEQ,
+                 layers: int = 48):
+  """(SlotDecoder, abstract params, row cache, slab) at the cell's sizes:
+  bf16 matrices, float32 norm scales and exit gate."""
+  import jax
+  import jax.numpy as jnp
+  from flax.core import meta
+  from tensorflowonspark_tpu.models import transformer as tfm
+  from tensorflowonspark_tpu.serving import slots as slots_lib
+  dec = slots_lib.SlotDecoder(ouro_cfg(max_seq, layers), slots)
+  params = _on_chip0(jax.eval_shape(lambda: jax.tree_util.tree_map_with_path(
+      lambda p, x: x if p[-1].key == "scale" or p[0].key == "exit_gate"
+      else x.astype(jnp.bfloat16),
+      meta.unbox(dec.model.init(
+          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+  row = _on_chip0(jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1)))
+  return dec, params, row, _on_chip0(jax.eval_shape(dec.init_slabs))
+
+
+def t_serving_decode_ouro():
+  """The cell ouro-serve-backlog's decode step at its real size: 48 layers
+  run 4 times a token over shared weights, 384 K/V leaves of 8 x 512 x 2048
+  in the one slab, horizon 4."""
+  dec, params, _, slabs = ouro_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
+def t_serving_decode_ouro_4_layers():
+  """The same step with 4 of the 48 layers (32 of the 384 leaves), every
+  width and the 4 passes as published: what tier-1 compiles (the whole
+  program takes two and a half minutes)."""
+  dec, params, _, slabs = ouro_decoder(layers=4)
+  return _step_many_target(dec, params, slabs)
+
+
+def t_ouro_prefill_512():
+  """The same cell's largest prefill program: a padded 512-token chunk
+  through 192 layer applications into a row cache of 384 leaves."""
+  dec, params, row, _ = ouro_decoder()
+  return dec._prefill_fn, (params, row, _i32(1, 512), _i32())
+
+
 def t_smoke_step_many():
   return _smoke_step_many(paged=False)
 
@@ -959,6 +1021,9 @@ TARGETS = {
     "cursor_write": t_cursor_write,
     "gpt2l_prefill_512": t_gpt2l_prefill_512,
     "serving_decode_kimi_linear": t_serving_decode_kimi_linear,
+    "serving_decode_ouro": t_serving_decode_ouro,
+    "serving_decode_ouro_4_layers": t_serving_decode_ouro_4_layers,
+    "ouro_prefill_512": t_ouro_prefill_512,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
