@@ -523,21 +523,36 @@ _MXU_COLS = 128
 
 
 _cursor_writes = threading.local()
+_attn_reads = threading.local()
 
 
 @contextlib.contextmanager
+def _tally(local, *keys):
+  """Open one round of trace-time counting on this thread: the traced code
+  finds the dict at ``local.open`` and notes into it on the host (the
+  traced program never contains the note)."""
+  tally = local.open = dict.fromkeys(keys, 0)
+  try:
+    yield tally
+  finally:
+    local.open = None
+
+
 def cursor_write_tally():
   """Count the per-slot single-token cache writes of what is TRACED inside
   the block, on this thread: yields ``{"leaves": n, "dma": m}``, ``m`` of
   the ``n`` having taken ``ops.cursor_write``'s kernel. ``_cache_write``
-  notes each one on the host while it traces (the traced program never
-  contains the note); ``SlotDecoder`` opens one round a ``step_many``
-  program's trace."""
-  tally = _cursor_writes.open = {"leaves": 0, "dma": 0}
-  try:
-    yield tally
-  finally:
-    _cursor_writes.open = None
+  notes each one while it traces; ``SlotDecoder`` opens one round a
+  ``step_many`` program's trace."""
+  return _tally(_cursor_writes, "leaves", "dma")
+
+
+def decode_attention_tally():
+  """The same for the per-slot single-token cache READS
+  (``_cached_attention`` with ``lengths``, one a layer application):
+  yields ``{"reads": n, "ragged": m}``, ``m`` of the ``n`` having taken
+  ``ops.decode_attention``'s kernel, which stops at each slot's cursor."""
+  return _tally(_attn_reads, "reads", "ragged")
 
 
 def _cache_write(buf, val, idx, positions, mesh):
@@ -679,7 +694,7 @@ class Proj(nn.Module):
 
 
 def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, lengths=None, mesh=None):
   """Masked softmax attention of a query block over a KV cache AND the
   block's own keys/values, which the cache does not hold yet.
 
@@ -712,8 +727,33 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   and out of that layout at every program's edge. A wide block reshapes
   its (one-row) cache to 4-D instead. Probabilities stay f32 all the way
   into V either way.
+
+  ``lengths [b]`` are per-slot cursors (the serving slab's decode step: slot
+  ``i``'s cache holds ``lengths[i]`` rows, its query sits at that position).
+  With them and ONE token a slot the same attention has a second lowering,
+  ``ops.decode_attention``: a kernel that brings only the blocks of live
+  rows from HBM where the contraction below reads all ``max`` positions and
+  masks (three quarters of a GPT-2 serving step's K/V bytes: PERF.md section
+  6, PR 31). Chosen from what the code can observe, as ``_cache_write``
+  chooses: bf16 leaves of whole lanes and whole blocks, no window, no int8
+  scales, ONE device (GSPMD does not partition a Mosaic call), where "auto"
+  picks Pallas kernels at all; every other input keeps the dense path.
   """
   b, seg, h, d = q.shape
+  if lengths is not None and seg == 1:
+    ragged = (not window and k_scale is None
+              and (mesh is None or mesh.size == 1)
+              and ops.decode_attention_supports(
+                  (b, h, d), q.dtype, cached_k.shape, cached_k.dtype)
+              and ops.pallas_kernels_enabled())
+    tally = getattr(_attn_reads, "open", None)
+    if tally is not None:
+      tally["reads"] += 1
+      tally["ragged"] += ragged
+    if ragged:
+      return ops.decode_attention(
+          q[:, 0], k[:, 0], v[:, 0], cached_k, cached_v, lengths,
+          interpret=ops.pallas_interpret())[:, None].astype(q.dtype)
   mx = cached_k.shape[1]
   hk = cached_k.shape[2] // d
   g = h // hk
@@ -974,7 +1014,8 @@ class Attention(nn.Module):
       # length
       return _cached_attention(
           q, k_own, v_own, q_pos=positions if vec else positions[:1],
-          window=cfg.attention_window, **was)
+          window=cfg.attention_window, lengths=idx if vec else None,
+          mesh=self.mesh, **was)
 
     # PREFILL fast path: a fresh-cache multi-token segment attends only
     # within itself (causal), so the flash kernel runs it O(seg²)-tiled

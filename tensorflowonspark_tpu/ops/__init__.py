@@ -67,3 +67,6 @@ from tensorflowonspark_tpu.ops.ln_matmul import (  # noqa: F401
 from tensorflowonspark_tpu.ops.cursor_write import (  # noqa: F401
     cursor_write, supports as cursor_write_supports,
 )
+from tensorflowonspark_tpu.ops.decode_attention import (  # noqa: F401
+    decode_attention, supports as decode_attention_supports,
+)

@@ -336,6 +336,11 @@ class ServingEngine(object):
                   # (SlotDecoder.cursor_writes, known when the program
                   # is traced)
                   "cursor_leaf_writes": 0, "cursor_leaf_writes_dma": 0,
+                  # per-slot single-token cache reads of the same
+                  # dispatches (layer applications x horizon each), and
+                  # those of them by the attention kernel that stops at
+                  # each slot's cursor (SlotDecoder.attn_reads)
+                  "decode_attn_reads": 0, "decode_attn_reads_ragged": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -1363,6 +1368,9 @@ class ServingEngine(object):
       writes, dma = self.decoder.cursor_writes[self.horizon]
       self.stats["cursor_leaf_writes"] += writes
       self.stats["cursor_leaf_writes_dma"] += dma
+      reads, ragged = self.decoder.attn_reads[self.horizon]
+      self.stats["decode_attn_reads"] += reads
+      self.stats["decode_attn_reads_ragged"] += ragged
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
       if self.decoder.counted:       # the step's own sums, beside the tokens

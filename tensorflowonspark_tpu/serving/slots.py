@@ -316,6 +316,10 @@ class SlotDecoder(object):
     #: dispatch makes, those of them by ops.cursor_write's DMA kernel):
     #: written while the program is traced, so there from its first call on
     self.cursor_writes = {}
+    #: horizon -> (per-slot single-token cache reads a step_many dispatch
+    #: makes: one a layer application a step, those of them by
+    #: ops.decode_attention's kernel, which stops at each slot's cursor)
+    self.attn_reads = {}
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
 
@@ -592,9 +596,19 @@ class SlotDecoder(object):
     assignments to experts held here and ``touched`` held experts that got
     at least one live token (summed over expert layers); of a looped model
     ``exit_pass``, the pass at which its gates let each live lane's token
-    exit."""
+    exit.
+
+    An inactive lane (free, or finished inside this horizon) runs its pad
+    token at cursor 0 of its own slot: the row it writes lands where the
+    next insert overwrites, and an attention that stops at the cursor
+    (``ops.decode_attention``) reads none of the rows the lane's last
+    request left. The paged pool keeps its cursors: the first page of a
+    lane's table may be a shared prefix's."""
+    going_in = slabs if self.paged else tree_map_with_path(
+        lambda p, leaf: jnp.where(active, leaf, 0) if _is_index(p) else leaf,
+        slabs)
     logits, mutated = self.slab_model.apply(
-        {"params": params, "cache": slabs}, tok[:, None], decode=True,
+        {"params": params, "cache": going_in}, tok[:, None], decode=True,
         mutable=["cache", "counters"] if count else ["cache"])
     new_cache = mutated["cache"]
     counts = None
@@ -685,12 +699,14 @@ class SlotDecoder(object):
 
         def body(carry, _):
           slabs, tok, active, remaining = carry
-          with tfm.cursor_write_tally() as writes:
+          with tfm.cursor_write_tally() as writes, \
+              tfm.decode_attention_tally() as reads:
             slabs, nxt, counts = self._one_step(params, slabs, tok, active,
                                                 count=self.counted)
           # on the host, while tracing: the body is one step of _h
           self.cursor_writes[_h] = (_h * writes["leaves"],
                                     _h * writes["dma"])
+          self.attn_reads[_h] = (_h * reads["reads"], _h * reads["ragged"])
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:

@@ -193,7 +193,11 @@ def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
   back beside XLA's loop; 68 beside a kernel free to run on the staged
   copy). One ``while`` is left, the horizon's scan (73 with a loop of 16
   update-slices a leaf), and the kernel is in: 72 calls beside the 73 of
-  the fused LayerNorm (PERF.md section 6, PR 29)."""
+  the fused LayerNorm (PERF.md section 6, PR 29). Since PR 31 the read is a
+  kernel too, ``ops.decode_attention``, one call a layer: it brings the
+  live blocks of both leaves from HBM itself, BEFORE the in-place write of
+  the same leaves, and the compiler neither stages a leaf for it nor copies
+  one to keep the old value; temporaries did not grow (64 MB before)."""
   res = _gate_one("gpt2l_step_many", monkeypatch)
   mb = res["memory_bytes"]
   slab_bytes = 72 * 16 * 1024 * 1280 * 2
@@ -203,7 +207,32 @@ def test_gpt2l_step_many_keeps_the_slab_in_hbm_and_in_place(topo,
   assert leaf not in res["entry_copies"], res["entry_copies"]
   assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
   assert res["while_loops"] == 1, res
-  assert res["tpu_custom_calls"] >= 72 + 73, res
+  assert res["tpu_custom_calls"] == 72 + 73 + 36, res
+  assert mb["temp"] < 70e6, mb
+
+
+@pytest.mark.parametrize("name,slots,max_seq,width", [
+    ("decode_attention", 16, 1024, 1280),
+    ("decode_attention_ouro", 8, 512, 2048)])
+def test_decode_attention_reads_beside_the_in_place_write(
+    topo, monkeypatch, name, slots, max_seq, width):
+  """``ops.decode_attention`` and ``ops.cursor_write`` on K and V of one
+  layer at the benchmark's widths (gpt2-large: 16 x 1024 x 20 heads of 64,
+  a head half a vreg wide; Ouro: 8 x 512 x 16 heads of 128), the leaves
+  donated: the kernel's hand-driven block DMAs, its dynamic trip count and
+  its folded output lower through Mosaic; both leaves are aliased whole
+  onto the write's results although the read takes them as they were;
+  nothing of a leaf's shape is copied at the program's edge or back from
+  fast memory; no temporary exists."""
+  res = _gate_one(name, monkeypatch)
+  leaf_bytes = slots * max_seq * width * 2
+  mb = res["memory_bytes"]
+  assert 2 * leaf_bytes <= mb["alias"] < 1.001 * 2 * leaf_bytes, mb
+  assert mb["temp"] == 0, mb
+  leaf = "bf16[%d,%d,%d]" % (slots, max_seq, width)
+  assert leaf not in res["entry_copies"], res["entry_copies"]
+  assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["tpu_custom_calls"] == 3 and res["while_loops"] == 0, res
 
 
 def test_cursor_write_compiles_in_hbm_and_in_place(topo, monkeypatch):
@@ -284,9 +313,11 @@ def test_looped_step_many_keeps_a_cache_a_pass_in_place(topo, monkeypatch):
   --targets serving_decode_ouro`` is that compile): each pass of each layer
   owns a K and a V leaf, 32 leaves of 8 x 512 x 2048 here, all aliased,
   none copied at the program's edge or back from fast memory, every cursor
-  write ``ops.cursor_write``'s kernel (the model's norms are RMSNorms, no
-  kernel of their own: 32 custom calls), one ``while`` (the horizon's
-  scan), the passes unrolled inside it."""
+  write ``ops.cursor_write``'s kernel and every read
+  ``ops.decode_attention``'s, one a pass of a layer (the model's norms are
+  RMSNorms, no kernel of their own: 32 + 16 custom calls), one ``while``
+  (the horizon's scan), the passes unrolled inside it; temporaries did not
+  grow with the read kernel (116 MB before PR 31)."""
   res = _gate_one("serving_decode_ouro_4_layers", monkeypatch)
   mb = res["memory_bytes"]
   slab_bytes = 4 * 4 * 2 * 8 * 512 * 2048 * 2
@@ -295,7 +326,8 @@ def test_looped_step_many_keeps_a_cache_a_pass_in_place(topo, monkeypatch):
   assert leaf not in res["entry_copies"], res["entry_copies"]
   assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
   assert res["while_loops"] == 1, res
-  assert res["tpu_custom_calls"] == 32, res
+  assert res["tpu_custom_calls"] == 32 + 16, res
+  assert mb["temp"] < 120e6, mb
 
 
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):

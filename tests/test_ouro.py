@@ -274,9 +274,11 @@ def test_engine_counts_exit_passes_and_cursor_writes(toy):
                               horizon=4).start()
   try:
     outs = eng.generate(prompts, max_new_tokens=9)
-    stats = dict(eng.stats)
   finally:
     eng.stop()
+  # read with the loop stopped: generate() returns from inside the last
+  # dispatch's harvest, before that dispatch is counted
+  stats = dict(eng.stats)
   for p, out in zip(prompts, outs):
     want = np.asarray(tfm.greedy_generate_kv(
         params, cfg, jnp.asarray(p)[None], 9))[0]
@@ -285,6 +287,10 @@ def test_engine_counts_exit_passes_and_cursor_writes(toy):
   assert stats["loop_exit_pass_sum"] == 4 * stats["live_slot_steps"]
   assert stats["cursor_leaf_writes"] \
       == stats["decode_dispatches"] * PASSES * LAYERS * 2 * 4
+  # one cache read a layer a PASS a step; the dense one on the CPU
+  assert stats["decode_attn_reads"] \
+      == stats["decode_dispatches"] * PASSES * LAYERS * 4
+  assert stats["decode_attn_reads_ragged"] == 0
   assert stats["slab_in_place"] == stats["slab_dispatches"] > 0
   assert stats["prefill_chunks"] == stats["prefills"] == 3
 

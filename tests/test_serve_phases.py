@@ -213,6 +213,34 @@ def test_cursor_leaf_writes_advance_by_leaves_times_horizon_a_dispatch(
       (st["cursor_leaf_writes"] if stack == "dma" else 0)
 
 
+@pytest.mark.parametrize("stack", ["dense", "ragged", "f32", "paged"])
+def test_decode_attn_reads_advance_by_layers_times_horizon_a_dispatch(
+    toy, monkeypatch, stack):
+  """``decode_attn_reads`` counts the per-slot single-token cache reads the
+  fused decode dispatches made (a layer's attention over its K and V
+  leaves, once a step of the horizon) and ``decode_attn_reads_ragged`` those
+  of them by ``ops.decode_attention``'s kernel, which stops at each slot's
+  cursor: none on the CPU, all of them where Pallas kernels are on and the
+  leaves are bf16 of whole blocks (interpret mode here), none again for
+  float32 leaves. The paged pool reads otherwise and counts nothing."""
+  from tensorflowonspark_tpu import ops
+  cfg, state = toy
+  cfg = dataclasses.replace(
+      cfg, layer_norm_impl="flax", attention_impl="dense", max_seq_len=128,
+      dtype=jnp.float32 if stack == "f32" else jnp.bfloat16)
+  monkeypatch.setattr(ops, "pallas_kernels_enabled",
+                      lambda: stack in ("ragged", "f32"))
+  with ServingEngine(state.params, cfg, num_slots=3, eos_id=None,
+                     page_size=8 if stack == "paged" else 0) as eng:
+    _serve(eng, _prompts(6, seed=7, longest=20), 9)
+  st = eng.stats               # read with the loop stopped
+  assert st["decode_dispatches"] > 0
+  a_dispatch = 0 if stack == "paged" else cfg.num_layers * eng.horizon
+  assert st["decode_attn_reads"] == st["decode_dispatches"] * a_dispatch
+  assert st["decode_attn_reads_ragged"] == \
+      (st["decode_attn_reads"] if stack == "ragged" else 0)
+
+
 # -- the recorder sink --------------------------------------------------------
 
 

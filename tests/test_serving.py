@@ -279,10 +279,13 @@ class TestCursorWriteLowerings:
         attention_impl="dense")
     params = tfm.create_state(jax.random.PRNGKey(1), cfg, seq_len=16).params
     rng = np.random.RandomState(5)
-    # slot 0 reaches max_seq_len after 4 tokens and then stays FROZEN at
-    # cursor == max for two more dispatches: its garbage write must clamp
-    # onto its own last row in both lowerings; slot 1 runs out of budget
-    # mid-horizon; slot 2 is never filled (frozen at cursor 0)
+    # slot 0 reaches max_seq_len after 4 tokens and then stays FROZEN for
+    # two more dispatches, slot 1 runs out of budget mid-horizon, slot 2 is
+    # never filled: a frozen lane runs at cursor 0 of its own slot (since
+    # PR 31; at its last cursor before, where cursor == max must clamp onto
+    # the slot's last row, which tests/test_cursor_write.py still holds
+    # both lowerings to), and its garbage row must land in the same place
+    # in both lowerings
     prompts = [rng.randint(1, 64, (n,)).astype(np.int32) for n in (44, 5)]
 
     def run(kernels: bool):

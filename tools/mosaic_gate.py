@@ -840,6 +840,37 @@ def t_cursor_write():
                                                  _i32(16))
 
 
+def _decode_attention_target(slots: int, max_seq: int, heads: int, d: int):
+  """``ops.decode_attention`` and ``ops.cursor_write`` on K and V of ONE
+  layer of a serving slab, donated as the serving step donates its slab:
+  the read takes the leaves as they were, the write is in place."""
+  import jax
+  from tensorflowonspark_tpu import ops
+
+  def layer(k, v, q, new_k, new_v, idx):
+    o = ops.decode_attention(q, new_k, new_v, k, v, idx)
+    flat = lambda x: x.reshape(slots, heads * d)  # noqa: E731
+    return (ops.cursor_write(k, flat(new_k), idx),
+            ops.cursor_write(v, flat(new_v), idx), o)
+
+  leaf = _on_chip0(_sh(slots, max_seq, heads * d))
+  new = _on_chip0(_sh(slots, heads, d))
+  return jax.jit(layer, donate_argnums=(0, 1)), (leaf, leaf, new, new, new,
+                                                 _i32(slots))
+
+
+def t_decode_attention():
+  """The decode step's attention kernel beside the cursor write at the
+  GPT-2 cells' widths: 16 slots x 1024 x 20 heads of 64 (a head is half a
+  vreg's lanes: the output leaves folded onto 128)."""
+  return _decode_attention_target(16, 1024, 20, 64)
+
+
+def t_decode_attention_ouro():
+  """The same at the Ouro cell's widths: 8 slots x 512 x 16 heads of 128."""
+  return _decode_attention_target(OURO_SLOTS, OURO_MAX_SEQ, 16, 128)
+
+
 def t_gpt2l_prefill_512():
   """The benchmark's largest prefill program at its real size: a padded
   512-token chunk (PERF.md section 6, PR 27); only the last real row may
@@ -1019,6 +1050,8 @@ TARGETS = {
     "smoke_paged_step_many": t_smoke_paged_step_many,
     "gpt2l_step_many": t_gpt2l_step_many,
     "cursor_write": t_cursor_write,
+    "decode_attention": t_decode_attention,
+    "decode_attention_ouro": t_decode_attention_ouro,
     "gpt2l_prefill_512": t_gpt2l_prefill_512,
     "serving_decode_kimi_linear": t_serving_decode_kimi_linear,
     "serving_decode_ouro": t_serving_decode_ouro,
