@@ -202,6 +202,33 @@ class TransformerConfig:
   # ``pass_<u>``.
   loop_passes: int = 1
   loop_exit_threshold: float = 1.0
+  # Attention PER LAYER (ROADMAP R3): ``layer_windows[i]`` is attention layer
+  # i's sliding window (0 = full causal; the convention of
+  # ``attention_window``, which () leaves in force for every layer) and
+  # ``layer_rope[i]`` whether the layer rotates its queries and keys (()
+  # = every layer does; False = no positional term at all in that layer).
+  # Each attention runs under ``jax.named_scope`` ``attn_window`` /
+  # ``attn_full`` when ``layer_windows`` is given.
+  layer_windows: tuple = ()
+  layer_rope: tuple = ()
+  # RMSNorm over each head's ``head_dim`` of the queries and of the keys,
+  # before the rotation: one learned scale of ``head_dim`` each, shared by
+  # the heads (params ``attn/q_norm``, ``attn/k_norm``).
+  qk_norm: bool = False
+  # A sigmoid gate on the attention output: ``g = x W_gate`` (``num_heads x
+  # head_dim`` wide, from the layer's normed input, param ``attn/gate``)
+  # multiplies the heads' concatenated output elementwise before ``out``.
+  attn_gate: bool = False
+  # The embedding's output is multiplied by this (computed in float32,
+  # rounded once): sqrt(d_model) for a muP-parametrised model.
+  embed_scale: float = 1.0
+  # The decode cache of a layer with a window holds a RING of the window's
+  # rows (``ring_rows``) and not ``max_seq_len``: position p lives in row
+  # ``p % rows``, one token a step under per-slot cursors. The serving slab
+  # sets it on ITS config for a model with ``layer_windows``, as it sets
+  # ``kv_page_size`` (serving/slots.py); the prefill's one-row cache and
+  # ``greedy_generate_kv`` keep every position and mask.
+  kv_ring: bool = False
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -273,6 +300,21 @@ class TransformerConfig:
           "(layer_types %r, ffn_types %r, moe_experts %d)" % (
               self.loop_passes, self.layer_types, self.ffn_types,
               self.moe_experts))
+    for name, per_layer in (("layer_windows", self.layer_windows),
+                            ("layer_rope", self.layer_rope)):
+      if per_layer and (len(per_layer) != self.num_layers
+                        or any(int(w) < 0 for w in per_layer)):
+        raise ValueError("%s must give each of the %d layers a value >= 0, "
+                         "got %r" % (name, self.num_layers, per_layer))
+    for asked, feature, what in (
+        (self.kv_ring and self.kv_cache_dtype == "int8", "int8",
+         "kv_cache_dtype='int8'"),
+        (self.kv_ring and self.loop_passes > 1, "loop",
+         "loop_passes=%d" % self.loop_passes),
+        (self.kv_page_size > 0 and self.ring_layers, "pages",
+         "the paged KV pool (kv_page_size=%d)" % self.kv_page_size)):
+      if asked:
+        raise ValueError(ring_refusal(what, feature))
     if self.kv_page_size > 0:
       if self.loop_passes > 1:
         raise ValueError(loop_refusal(
@@ -321,6 +363,25 @@ class TransformerConfig:
   def kv_heads(self) -> int:
     return self.num_kv_heads or self.num_heads
 
+  def ring_rows(self, window: int) -> int:
+    """Rows of the RING a decode cache holds for an attention ``window``, 0
+    where the cache keeps every position (no window, or a ring as long as
+    the row): the window rounded up to whole blocks of
+    ``ops.decode_attention`` (128 rows), a window under one block (toy
+    sizes, where the kernel does not engage) to whole 16-row tiles of
+    ``ops.cursor_write``."""
+    if not window:
+      return 0
+    tile = 128 if window >= 128 else 16
+    rows = -(-int(window) // tile) * tile
+    return rows if rows < self.max_seq_len else 0
+
+  @property
+  def ring_layers(self) -> tuple:
+    """The layers (by index) whose window a serving slab holds as a ring."""
+    return tuple(i for i, w in enumerate(self.layer_windows)
+                 if self.ring_rows(w))
+
 
 #: why each serving feature cannot take a looped model yet (``loop_refusal``)
 _LOOP_REFUSALS = {
@@ -339,6 +400,34 @@ _LOOP_REFUSALS = {
                   "they read instead is a policy the configuration does not "
                   "state",
 }
+
+
+#: why each serving feature cannot take window layers held as RINGS yet
+_RING_REFUSALS = {
+    "pages": "the pool gives every layer a page for every position a slot "
+             "holds, and a window layer keeps only its last window's rows "
+             "(a pool of two lifetimes, whole-context and window, does not "
+             "exist yet)",
+    "prefix": "a prefix's pages hold every layer's keys and values at every "
+              "position of the prefix, and a window layer keeps only its last "
+              "window's rows (prefix sharing over window layers does not "
+              "exist yet)",
+    "draft": "a rejected draft rolls the cursor back, and the rows it wrote "
+             "into a ring have overwritten the window's oldest positions, "
+             "which the rolled-back step attends (speculation over a ring "
+             "does not exist yet)",
+    "int8": "an int8 ring (values with a scale leaf a row, both indexed "
+            "modulo the ring) is not built",
+    "loop": "a ring a pass is not built",
+}
+
+
+def ring_refusal(what: str, feature: str) -> str:
+  """The message with which ``what`` (a serving feature, named as its user
+  named it; ``feature`` its key in ``_RING_REFUSALS``) refuses a model with
+  per-layer windows (``TransformerConfig.layer_windows``)."""
+  return "%s cannot serve a model whose window layers keep a ring of their " \
+      "window's rows (layer_windows): %s" % (what, _RING_REFUSALS[feature])
 
 
 def loop_refusal(cfg, feature: str, what: str) -> str:
@@ -693,8 +782,30 @@ class Proj(nn.Module):
                           kernel, self.cfg)
 
 
+def ring_positions(cursor, rows: int):
+  """The position each row of a ring of ``rows`` rows holds when the next
+  token's is ``cursor [...]``: ``[..., rows]``, row ``r`` the newest position
+  ``p < cursor`` with ``p % rows == r``; negative where the row was never
+  written."""
+  last = cursor[..., None] - 1
+  return last - jnp.mod(last - jnp.arange(rows), rows)
+
+
+def _ring_skip(cursor, rows: int, window: int):
+  """``[2, b]``: the first row and the count of the cyclic run of a ring's
+  rows that hold positions OUTSIDE the window of the query at ``cursor``.
+  The ring holds positions ``max(cursor - rows, 0) .. cursor - 1``, the
+  oldest in row ``oldest % rows``; the query attends those above ``cursor -
+  window``. With ``rows == window`` that is the one row the step is about
+  to overwrite (position ``cursor - rows``), once the ring is full."""
+  oldest = jnp.maximum(cursor - rows, 0)
+  return jnp.stack([oldest % rows,
+                    jnp.clip(cursor - window - oldest + 1, 0, rows)])
+
+
 def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
-                      k_scale=None, v_scale=None, lengths=None, mesh=None):
+                      k_scale=None, v_scale=None, lengths=None, mesh=None,
+                      ring: bool = False):
   """Masked softmax attention of a query block over a KV cache AND the
   block's own keys/values, which the cache does not hold yet.
 
@@ -738,10 +849,21 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   chooses: bf16 leaves of whole lanes and whole blocks, no window, no int8
   scales, ONE device (GSPMD does not partition a Mosaic call), where "auto"
   picks Pallas kernels at all; every other input keeps the dense path.
+
+  ``ring``: the cache is a RING of ``max`` rows (``TransformerConfig.
+  kv_ring``; one token, per-slot cursors): row ``r`` holds the newest
+  position ``p < cursor`` with ``p % max == r``. Keys carry their rotary
+  position and a softmax does not care in which order it meets its keys, so
+  the ring is read as it lies; what has to go is what the window excludes:
+  with ``max == window`` the ONE row that the step is about to overwrite
+  (position ``cursor - max``), which the kernel takes as a run of rows to
+  skip (``_ring_skip``) and the dense path as a mask over each row's
+  position.
   """
   b, seg, h, d = q.shape
+  mx = cached_k.shape[1]
   if lengths is not None and seg == 1:
-    ragged = (not window and k_scale is None
+    ragged = ((ring or not window) and k_scale is None
               and (mesh is None or mesh.size == 1)
               and ops.decode_attention_supports(
                   (b, h, d), q.dtype, cached_k.shape, cached_k.dtype)
@@ -752,9 +874,10 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
       tally["ragged"] += ragged
     if ragged:
       return ops.decode_attention(
-          q[:, 0], k[:, 0], v[:, 0], cached_k, cached_v, lengths,
+          q[:, 0], k[:, 0], v[:, 0], cached_k, cached_v,
+          jnp.minimum(lengths, mx) if ring else lengths,
+          skip=_ring_skip(lengths, mx, window) if ring else None,
           interpret=ops.pallas_interpret())[:, None].astype(q.dtype)
-  mx = cached_k.shape[1]
   hk = cached_k.shape[2] // d
   g = h // hk
   folded = seg * h <= _MXU_COLS
@@ -784,7 +907,11 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   s_own = s_own.reshape(b, seg, h, seg) * scale
 
   k_pos, own_pos = jnp.arange(mx), jnp.arange(seg)
+  if ring:
+    k_pos = ring_positions(q_pos[:, :1], mx)             # [b, 1, max]
   keep_cache = k_pos < q_pos[:, :1, None]       # written before the block
+  if ring:
+    keep_cache = jnp.logical_and(keep_cache, k_pos >= 0)
   causal = own_pos[None, :] <= own_pos[:, None]
   if window:
     # sliding window: entries older than the window are masked (they stay
@@ -817,9 +944,20 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   return (o / total[..., None]).astype(q.dtype)
 
 
+#: rows of its one-row cache a later prefill chunk attends a call of the
+#: flash kernel (``Attention._decode_attend``): K and V blocks of 0.5 MB at
+#: 8 KV heads of 128. A row no longer than one block keeps the dense branch
+_ROW_BLOCK = 2048
+
+
 class Attention(nn.Module):
+  """``window`` / ``rope``: this layer's sliding window (None:
+  ``cfg.attention_window``) and whether it rotates its queries and keys
+  (``TransformerConfig.layer_windows`` / ``layer_rope``)."""
   cfg: TransformerConfig
   mesh: Optional[Any] = None
+  window: Optional[int] = None
+  rope: bool = True
 
   @nn.compact
   def __call__(self, x, positions, decode: bool = False, ln_scale=None,
@@ -829,6 +967,7 @@ class Attention(nn.Module):
     (ops.ln_matmul); otherwise ``x`` arrives normalized. ``loop_pass`` is
     which of ``cfg.loop_passes`` this call is: the decode cache it owns."""
     cfg = self.cfg
+    win = cfg.attention_window if self.window is None else self.window
     dense = lambda feats, logical, name: nn.DenseGeneral(  # noqa: E731
         feats, axis=-1, dtype=cfg.dtype, use_bias=False, name=name,
         kernel_init=nn.with_logical_partitioning(
@@ -862,11 +1001,23 @@ class Attention(nn.Module):
       v = dense((cfg.kv_heads, cfg.head_dim),
                 ("embed", heads_axis(cfg.kv_heads), "kv"), "v")(x)
 
-    if decode:
-      return self._decode_attend(q, k, v, loop_pass)
+    if cfg.qk_norm:
+      q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)(
+          t).astype(t.dtype) for name, t in (("q_norm", q), ("k_norm", k)))
+    gate = None
+    if cfg.attn_gate:
+      if ln_scale is not None:
+        raise ValueError("attn_gate reads the normed input; the ln-fused "
+                         "attention hands over the raw stream")
+      gate = dense((cfg.num_heads, cfg.head_dim),
+                   ("embed", heads_axis(cfg.num_heads), "kv"), "gate")(x)
 
-    q = _rotary(q, positions, cfg.rope_theta)
-    k = _rotary(k, positions, cfg.rope_theta)
+    if decode:
+      return self._decode_attend(q, k, v, loop_pass, win, gate)
+
+    if self.rope:
+      q = _rotary(q, positions, cfg.rope_theta)
+      k = _rotary(k, positions, cfg.rope_theta)
 
     interp = ops.pallas_interpret()           # forced-flash CI runs
     if cfg.use_ring_attention and self.mesh is not None:
@@ -877,40 +1028,43 @@ class Attention(nn.Module):
       local_seq = q.shape[1] // max(1, seq_shards)
       out = ra.ring_attention(q, k, v, self.mesh, causal=True,
                               use_flash=_flash_eligible(cfg, local_seq),
-                              interpret=interp,
-                              window=cfg.attention_window or None)
+                              interpret=interp, window=win or None)
     else:
       if _flash_eligible(cfg, q.shape[1]):
         # the flash kernels consume grouped KV natively (grouped-aware
         # BlockSpec; cross-head dK/dV accumulation in the backward grid).
         # Under a >1-device mesh the kernel maps per shard: the TPU
         # compiler refuses to partition a Mosaic kernel on its own
-        win = cfg.attention_window or None
         if self.mesh is None or self.mesh.size == 1:
           out = ops.flash_attention(q, k, v, causal=True, interpret=interp,
-                                    window=win)
+                                    window=win or None)
         else:
-          out = ops.flash_attention_sharded(q, k, v, self.mesh, causal=True,
-                                            interpret=interp, window=win)
+          out = ops.flash_attention_sharded(
+              q, k, v, self.mesh, causal=True, interpret=interp,
+              window=win or None)
       else:
         # the dense reference attends at full head count: broadcast each
         # KV head to its query group (XLA fuses the repeat)
         out = ra.full_attention(q, _expand_kv(k, cfg.num_heads),
                                 _expand_kv(v, cfg.num_heads), causal=True,
-                                window=cfg.attention_window or None)
+                                window=win or None)
 
-    return self._out_proj(out)
+    return self._out_proj(out, gate)
 
-  def _out_proj(self, out):
+  def _out_proj(self, out, gate=None):
     cfg = self.cfg
+    if gate is not None:
+      out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
     return nn.DenseGeneral(
         cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, use_bias=False,
         name="out",
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), ("heads", "kv", "embed")))(out)
 
-  def _decode_attend(self, q, k, v, loop_pass: int = 0):
-    """Incremental attention against a KV cache (serving path).
+  def _decode_attend(self, q, k, v, loop_pass: int = 0, win: int = 0,
+                     gate=None):
+    """Incremental attention against a KV cache (serving path); ``win`` the
+    layer's window, ``gate`` its output gate's pre-activation.
 
     Writes the new keys/values at the cache cursor, attends the query
     block against everything cached before it plus the block itself
@@ -935,37 +1089,58 @@ class Attention(nn.Module):
     ONE module: pass ``loop_pass`` owns the leaves ``cached_k_p<u>`` /
     ``cached_v_p<u>`` (scales likewise), every pass reads the one cursor
     where the token began, and the last pass advances it.
+
+    Under ``cfg.kv_ring`` a layer with a window keeps a RING of
+    ``cfg.ring_rows(win)`` rows in place of ``max_seq_len``: position ``p``
+    in row ``p % rows``, one token a step under per-slot cursors (the
+    serving slab; ``SlotDecoder.insert`` turns a prefilled row into one).
+    The cursor stays the token's true position.
+
+    A later chunk of a LONG row (``idx > 0``, a row of several
+    ``_ROW_BLOCK``s) attends its cache through the flash kernel in blocks of
+    rows, from the window's lower edge to its own last row: the dense
+    branch's float32 scores of ``seg x heads x max_seq_len`` are 1.6 GB at
+    512 x 48 x 16384.
     """
     cfg = self.cfg
     if cfg.kv_page_size > 0:
-      return self._decode_attend_paged(q, k, v)
+      return self._decode_attend_paged(q, k, v, win, gate)
     b, seg, h, d = q.shape
     hk = cfg.kv_heads
     quant = cfg.kv_cache_dtype == "int8"
     cache_dt = jnp.int8 if quant else cfg.dtype
     sfx = "_p%d" % loop_pass if cfg.loop_passes > 1 else ""
+    ring = cfg.ring_rows(win) if cfg.kv_ring else 0
+    rows = ring or cfg.max_seq_len
     cached_k = self.variable(
-        "cache", "cached_k" + sfx, jnp.zeros, (b, cfg.max_seq_len, hk * d),
-        cache_dt)
+        "cache", "cached_k" + sfx, jnp.zeros, (b, rows, hk * d), cache_dt)
     cached_v = self.variable(
-        "cache", "cached_v" + sfx, jnp.zeros, (b, cfg.max_seq_len, hk * d),
-        cache_dt)
+        "cache", "cached_v" + sfx, jnp.zeros, (b, rows, hk * d), cache_dt)
     if quant:
       k_scale = self.variable("cache", "k_scale" + sfx, jnp.zeros,
                               (b, cfg.max_seq_len, hk), jnp.float32)
       v_scale = self.variable("cache", "v_scale" + sfx, jnp.zeros,
                               (b, cfg.max_seq_len, hk), jnp.float32)
-    cursor = self.variable("cache", "index",
-                           lambda: jnp.zeros((), jnp.int32))
+    # a slab with rings is slot-shaped by construction, as a paged one: its
+    # cursors are born a vector
+    cursor = self.variable(
+        "cache", "index", jnp.zeros, (b,) if cfg.kv_ring else (), jnp.int32)
     idx = cursor.value
     vec = idx.ndim == 1          # per-slot cursors (serving slab decode)
+    if ring and not (vec and seg == 1):
+      raise ValueError(
+          "a ring of %d rows (kv_ring, window %d) takes one token a step "
+          "under per-slot cursors, got %d tokens under a %s cursor"
+          % (ring, win, seg, "per-slot" if vec else "shared"))
 
     if vec:
       positions = idx[:, None] + jnp.arange(seg)[None, :]
     else:
       positions = idx + jnp.broadcast_to(jnp.arange(seg), (b, seg))
-    q = _rotary(q, positions, cfg.rope_theta)
-    k = _rotary(k, positions, cfg.rope_theta)
+    if self.rope:
+      q = _rotary(q, positions, cfg.rope_theta)
+      k = _rotary(k, positions, cfg.rope_theta)
+    at = idx % ring if ring else idx       # the row the token's K/V take
 
     # tensor-parallel serving: keep the cache sharded on its folded
     # (grouped) heads axis so each chip holds 1/t of the KV bytes — whole
@@ -1000,10 +1175,10 @@ class Attention(nn.Module):
       k_store, v_store = k.astype(cfg.dtype), v.astype(cfg.dtype)
       k_own, v_own = k_store, v_store
     cached_k.value = _constrain(
-        _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d), idx,
+        _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d), at,
                      positions, self.mesh), kv_spec, self.mesh)
     cached_v.value = _constrain(
-        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), idx,
+        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), at,
                      positions, self.mesh), kv_spec, self.mesh)
     if loop_pass == cfg.loop_passes - 1:
       cursor.value = idx + seg
@@ -1014,8 +1189,8 @@ class Attention(nn.Module):
       # length
       return _cached_attention(
           q, k_own, v_own, q_pos=positions if vec else positions[:1],
-          window=cfg.attention_window, lengths=idx if vec else None,
-          mesh=self.mesh, **was)
+          window=win, lengths=idx if vec else None, mesh=self.mesh,
+          ring=bool(ring), **was)
 
     # PREFILL fast path: a fresh-cache multi-token segment attends only
     # within itself (causal), so the flash kernel runs it O(seg²)-tiled
@@ -1046,24 +1221,50 @@ class Attention(nn.Module):
     if use_flash_prefill:
       from tensorflowonspark_tpu.ops import flash_attention
 
+      interp = ops.pallas_interpret()
+
       def _flash_prefill(_):
-        interp = ops.pallas_interpret()
-        win = cfg.attention_window or None
         if single:
           return flash_attention(q, k, v, causal=True, interpret=interp,
-                                 window=win).astype(q.dtype)
+                                 window=win or None).astype(q.dtype)
         # heads_consistent (above) is what flash_attention_sharded's
         # own both-divide rule needs to shard heads here
         return ops.flash_attention_sharded(
             q, k, v, self.mesh, causal=True, interpret=interp,
-            window=win).astype(q.dtype)
+            window=win or None).astype(q.dtype)
 
-      out = lax.cond(idx == 0, _flash_prefill, _dense_attend, None)
+      def _blocked_attend(_):
+        # the row AS WRITTEN above holds this chunk too, so one causal mask
+        # over absolute positions covers cache and chunk; a block wholly
+        # behind the window or past the chunk is never touched
+        from tensorflowonspark_tpu.ops.flash_attention import (
+            NEG_INF, flash_attention_block, merge_partials)
+        first = jnp.maximum(idx - (win - 1), 0) // _ROW_BLOCK if win else 0
+
+        def one_block(j, partial):
+          base = j * _ROW_BLOCK
+          kj, vj = (lax.dynamic_slice_in_dim(
+              c.value, base, _ROW_BLOCK, axis=1).reshape(b, _ROW_BLOCK, hk, d)
+                    for c in (cached_k, cached_v))
+          return merge_partials(*partial, *flash_attention_block(
+              q, kj, vj, idx, base, causal=True, interpret=interp,
+              window=win or None))
+
+        out, _ = lax.fori_loop(
+            first, (idx + seg - 1) // _ROW_BLOCK + 1, one_block,
+            (jnp.zeros(q.shape, jnp.float32),
+             jnp.full((b, h, seg), NEG_INF, jnp.float32)))
+        return out.astype(q.dtype)
+
+      long_row = (single and not quant and cfg.max_seq_len > _ROW_BLOCK
+                  and cfg.max_seq_len % _ROW_BLOCK == 0)
+      out = lax.cond(idx == 0, _flash_prefill,
+                     _blocked_attend if long_row else _dense_attend, None)
     else:
       out = _dense_attend(None)
-    return self._out_proj(out)
+    return self._out_proj(out, gate)
 
-  def _decode_attend_paged(self, q, k, v):
+  def _decode_attend_paged(self, q, k, v, win: int = 0, gate=None):
     """Incremental attention against a PAGED KV cache (serving slabs).
 
     Per layer the cache is a page POOL — ``pages_k``/``pages_v``
@@ -1102,8 +1303,9 @@ class Attention(nn.Module):
     idx = cursor.value
 
     positions = idx[:, None] + jnp.arange(seg)[None, :]        # [b, seg]
-    q = _rotary(q, positions, cfg.rope_theta)
-    k = _rotary(k, positions, cfg.rope_theta)
+    if self.rope:
+      q = _rotary(q, positions, cfg.rope_theta)
+      k = _rotary(k, positions, cfg.rope_theta)
 
     # write: token position -> (page, offset) through the table. A
     # position inside the span but past the slot's allocation resolves
@@ -1143,12 +1345,12 @@ class Attention(nn.Module):
     q_pos = idx[:, None, None] + jnp.arange(seg)[None, :, None]
     k_pos = jnp.arange(span)[None, None, :]
     keep = k_pos <= q_pos                                  # [b, seg, span]
-    if cfg.attention_window:
-      keep = jnp.logical_and(keep, k_pos > q_pos - cfg.attention_window)
+    if win:
+      keep = jnp.logical_and(keep, k_pos > q_pos - win)
     scores = jnp.where(keep[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vf)
-    return self._out_proj(o.reshape(b, seg, h, d).astype(q.dtype))
+    return self._out_proj(o.reshape(b, seg, h, d).astype(q.dtype), gate)
 
 
 class _UpKernel(nn.Module):
@@ -1329,6 +1531,18 @@ class Block(nn.Module):
   use_moe: bool = False
   mixer: str = "attn"
   ffn: str = "mlp"
+  window: Optional[int] = None      # the attention's (Attention.window)
+  rope: bool = True
+
+  def _attend(self, y, positions, decode, loop_pass: int = 0, **kw):
+    """This layer's attention over the normed ``y``; a model with per-layer
+    windows runs it under ``jax.named_scope`` ``attn_window`` /
+    ``attn_full``."""
+    attn = Attention(self.cfg, self.mesh, self.window, self.rope, name="attn")
+    scope = jax.named_scope("attn_window" if self.window else "attn_full") \
+        if self.cfg.layer_windows else contextlib.nullcontext()
+    with scope:
+      return attn(y, positions, decode=decode, loop_pass=loop_pass, **kw)
 
   @nn.compact
   def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0):
@@ -1342,12 +1556,10 @@ class Block(nn.Module):
       # ln1 + the fused QKV projection as ONE kernel over the raw
       # residual stream (param paths unchanged: ln1/scale, attn/qkv)
       scale1 = _LNScale(cfg.d_model, name="ln1")()
-      x = x + Attention(cfg, self.mesh, name="attn")(x, positions,
-                                                     ln_scale=scale1)
+      x = x + self._attend(x, positions, False, ln_scale=scale1)
     else:
       y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
-      x = x + Attention(cfg, self.mesh, name="attn")(
-          y, positions, decode=decode, loop_pass=loop_pass)
+      x = x + self._attend(y, positions, decode, loop_pass)
     act_fused = cfg.act_matmul_impl == "fused" and not decode
     if fuse_ln and not self.use_moe:
       # ln2 + up-projection as ONE kernel over the raw residual stream;
@@ -1371,8 +1583,7 @@ class Block(nn.Module):
     compute in float32; what joins the stream is rounded to its dtype."""
     cfg = self.cfg
     norm = lambda name: _make_layer_norm(cfg, self.mesh, name)  # noqa: E731
-    y = Attention(cfg, self.mesh, name="attn")(
-        norm("ln1")(x), positions, decode=decode, loop_pass=loop_pass)
+    y = self._attend(norm("ln1")(x), positions, decode, loop_pass)
     x = x + norm("ln1_out")(y).astype(x.dtype)
     y = MLPBlock(cfg, self.mesh, name="mlp")(norm("ln2")(x))
     x = x + norm("ln2_out")(y).astype(x.dtype)
@@ -1383,28 +1594,33 @@ class Block(nn.Module):
   def _typed(self, x, positions, decode):
     """A layer whose mixer or feed-forward is not the default pair. Their
     modules are imported HERE, so a model of plain blocks never loads
-    them; each runs under its own ``jax.named_scope``."""
+    them; each runs under its own ``jax.named_scope``. With
+    ``cfg.post_norm`` each branch's output is normed too (``ln1_out`` /
+    ``ln2_out``), as in :meth:`_sandwich`."""
     cfg = self.cfg
     if cfg.act_f32:
       x = x.astype(jnp.float32)    # the residual stream is not rounded
-    y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
+    norm = lambda name: _make_layer_norm(cfg, self.mesh, name)  # noqa: E731
+    joins = lambda y, name: x + (                               # noqa: E731
+        norm(name)(y).astype(x.dtype) if cfg.post_norm else y)
+    y = norm("ln1")(x)
     if self.mixer == "kda":
       from tensorflowonspark_tpu.models import kda
       with jax.named_scope("kda"):
-        x = x + kda.KDA(cfg, name="kda")(y, decode=decode)
+        x = joins(kda.KDA(cfg, name="kda")(y, decode=decode), "ln1_out")
     elif self.mixer == "mla":
       from tensorflowonspark_tpu.models import mla
       with jax.named_scope("mla"):
-        x = x + mla.MLA(cfg, self.mesh, name="mla")(y, decode=decode)
+        x = joins(mla.MLA(cfg, self.mesh, name="mla")(y, decode=decode),
+                  "ln1_out")
     else:
-      x = x + Attention(cfg, self.mesh, name="attn")(y, positions,
-                                                     decode=decode)
-    y = _make_layer_norm(cfg, self.mesh, "ln2")(x)
+      x = joins(self._attend(y, positions, decode), "ln1_out")
+    y = norm("ln2")(x)
     if self.ffn == "experts":
       from tensorflowonspark_tpu.models import experts
       with jax.named_scope("moe"):
-        return x + experts.HeldExperts(cfg, name="moe")(y)
-    return x + MLPBlock(cfg, self.mesh, name="mlp")(y)
+        return joins(experts.HeldExperts(cfg, name="moe")(y), "ln2_out")
+    return joins(MLPBlock(cfg, self.mesh, name="mlp")(y), "ln2_out")
 
 
 def _remat_block(cfg: TransformerConfig):
@@ -1503,6 +1719,8 @@ class Transformer(nn.Module):
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     emb = TiedEmbed(cfg, self.mesh, name="embed")
     x = emb(tokens)
+    if cfg.embed_scale != 1.0:
+      x = (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
     if not decode:
       x = _constrain(x, ("batch", "sequence", "embed"), self.mesh)
 
@@ -1516,6 +1734,8 @@ class Transformer(nn.Module):
       layers.append(block(cfg, self.mesh, use_moe,
                           cfg.layer_types[i] if cfg.layer_types else "attn",
                           cfg.ffn_types[i] if cfg.ffn_types else "mlp",
+                          cfg.layer_windows[i] if cfg.layer_windows else None,
+                          bool(cfg.layer_rope[i]) if cfg.layer_rope else True,
                           name="layer_%d" % i))
     ln_f = _make_layer_norm(cfg, self.mesh, "ln_f")
     if cfg.loop_passes == 1:

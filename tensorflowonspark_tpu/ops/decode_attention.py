@@ -28,6 +28,13 @@ cache does not hold yet, so there is one softmax over cache and own part;
 the probabilities go into V as three bf16 terms that sum to the f32 number
 (three exact MXU passes, what ``transformer._cache_contract`` does). Each
 head's own ``d`` lanes of ``probs @ V`` are the output.
+
+A leaf may be a RING (``TransformerConfig.kv_ring``: a window layer's last
+rows, position ``p`` in row ``p % max``): its live rows are still the rows
+below ``lengths`` (``min(cursor, max)``), in whatever order, but for a run of
+them that the window excludes, foremost the row the step is about to
+overwrite. ``skip [2, b]`` names that cyclic run a slot (first row, count);
+its scores are masked as the rows past the cursor are.
 """
 
 import functools
@@ -82,8 +89,10 @@ def supports(q_shape, q_dtype, cache_shape, cache_dtype) -> bool:
           and _vmem_bytes(b, h, d, c) <= VMEM_BUDGET)
 
 
-def _kernel(len_ref, q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, acc, sem, *, g, d, scale):
+def _kernel(len_ref, *refs, g, d, scale, ring):
+  skip_ref = refs[0] if ring else None      # [2 * slots]: first rows, counts
+  (q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
+   k_buf, v_buf, acc, sem) = refs[1:] if ring else refs
   slots, mx = k_hbm.shape[:2]
   hp, c = acc.shape
   w = o_ref.shape[2]
@@ -167,8 +176,15 @@ def _kernel(len_ref, q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q, k_buf[buf], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         left = n - j * block                             # live rows in here
-        s = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < left, s, -1e30)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = col < left
+        if ring:
+          # rows [first, first + count) of the ring, cyclically, are outside
+          # the window
+          behind = col + (j * block - skip_ref[i])
+          behind = jnp.where(behind < 0, behind + mx, behind)
+          keep = jnp.logical_and(keep, behind >= skip_ref[slots + i])
+        s = jnp.where(keep, s, -1e30)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha, p = jnp.exp(m - m_new), jnp.exp(s - m_new)
         l = alpha * l + p.sum(axis=-1, keepdims=True)
@@ -202,14 +218,17 @@ def _kernel(len_ref, q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
 # jitted under the name a reader of a device trace should see (the rule
 # ops/layer_norm.py's launchers state): the innermost jit names the kernel
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention(q, k, v, cached_k, cached_v, lengths, interpret=False):
+def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
+                     interpret=False):
   """Softmax attention of one query token a slot over that slot's cache
   rows below ``lengths[i]`` AND the token's own key and value: ``q [b, h,
   d]`` (rotated), ``k`` / ``v`` ``[b, kv_heads, d]`` as the cache will hold
   them, ``cached_k`` / ``cached_v`` ``[b, max, kv_heads * d]`` as they were
   before the step's write, ``lengths [b]`` int32 (clamped into ``[0,
-  max]``). Query head ``i`` reads KV head ``i // g``. Returns ``[b, h, d]``
-  float32. The shapes must pass :func:`supports`."""
+  max]``). Query head ``i`` reads KV head ``i // g``. ``skip [2, b]`` int32
+  (a ring leaf): for each slot the first row and the count of a cyclic run
+  of rows that is not attended. Returns ``[b, h, d]`` float32. The shapes
+  must pass :func:`supports`."""
   if not supports(q.shape, q.dtype, cached_k.shape, cached_k.dtype):
     raise ValueError(
         "decode_attention takes bf16 queries [b, h, d] over bf16 leaves "
@@ -226,12 +245,15 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, interpret=False):
                  ((0, 0), (0, hp - h), (0, 0)))
   hbm = pl.BlockSpec(memory_space=pltpu.HBM)
   vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+  ring = skip is not None
+  scalars = (lengths.astype(jnp.int32),) + (
+      (skip.astype(jnp.int32).reshape(2 * b),) if ring else ())
   o = pl.pallas_call(
-      functools.partial(_kernel, g=g, d=d, scale=1.0 / (d ** 0.5)),
+      functools.partial(_kernel, g=g, d=d, scale=1.0 / (d ** 0.5), ring=ring),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           # ONE grid step, the slots a loop inside it (a grid over slots
           # with the chain's state in SMEM took the same time on the chip)
-          num_scalar_prefetch=1, grid=(1,),
+          num_scalar_prefetch=len(scalars), grid=(1,),
           in_specs=[vmem, vmem, vmem, hbm, hbm], out_specs=vmem,
           scratch_shapes=[pltpu.VMEM((2, BLOCK, c), cached_k.dtype),
                           pltpu.VMEM((2, BLOCK, c), cached_v.dtype),
@@ -242,7 +264,7 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, interpret=False):
           vmem_limit_bytes=VMEM_BUDGET + (8 << 20)),
       interpret=interpret,
       name="decode_attention",
-  )(lengths.astype(jnp.int32), q_bd, k.reshape(b, 1, c).astype(q.dtype),
+  )(*scalars, q_bd, k.reshape(b, 1, c).astype(q.dtype),
     v.reshape(b, 1, c).astype(q.dtype), cached_k, cached_v)
   o = o[:, :h]
   if w == d:

@@ -162,7 +162,8 @@ _DEFAULT_POISON_CRASHES = 2
 _STEP_COUNTERS = {"held": "moe_assignments_held",
                   "touched": "moe_experts_touched",
                   "context": "live_context_tokens",
-                  "exit_pass": "loop_exit_pass_sum"}
+                  "exit_pass": "loop_exit_pass_sum",
+                  "window_context": "window_context_tokens"}
 #: restart backoff never exceeds this many seconds
 _BACKOFF_CAP = 2.0
 #: retry-after hint while the tokens/s EMA is still cold (no decode has
@@ -250,6 +251,10 @@ class ServingEngine(object):
       raise ValueError(slots_lib.tfm.loop_refusal(
           cfg, "prefix", "the shared-prefix cache (prefix_pages=%d)"
           % self.prefix_pages))
+    if self.prefix_pages > 0 and cfg.ring_layers:
+      raise ValueError(slots_lib.tfm.ring_refusal(
+          "the shared-prefix cache (prefix_pages=%d)" % self.prefix_pages,
+          "prefix"))
     if self.prefix_pages > 0 and cfg.non_kv_layers:
       raise ValueError(
           "the shared-prefix cache (prefix_pages=%d) reuses a prompt "
@@ -326,6 +331,11 @@ class ServingEngine(object):
                   # its exit gates let each live lane's token go, summed;
                   # over live_slot_steps it is the mean exit pass
                   "loop_exit_pass_sum": 0,
+                  # a model whose window layers hold rings (likewise
+                  # counted): the rows ONE window layer has to read for
+                  # each live lane's step, min(cursor, window), summed
+                  # (live_context_tokens is a full layer's)
+                  "window_context_tokens": 0,
                   # calls of a slab-returning program, and those after
                   # which the slab that went in is deleted: its donation
                   # was USED, the program ran in place (_on_slab)

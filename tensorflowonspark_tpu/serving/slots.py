@@ -233,6 +233,21 @@ class SlotDecoder(object):
   own row (no operation mixes lanes) and stays bounded (the delta rule is
   a contraction: unit keys, decay and write strength in (0, 1)).
   ``tests/test_kimi_linear.py`` stops a lane mid-horizon and reuses a slot.
+
+  Not every leaf is ``max_seq_len`` rows long either. A model with
+  PER-LAYER windows (``TransformerConfig.layer_windows``) keeps, in the one
+  contiguous slab, a whole-context leaf pair for each full layer and a RING
+  of the window's rows (``TransformerConfig.ring_rows``) for each window
+  layer, under the one cursor a layer: the slab's model carries ``kv_ring``
+  in its config as a paged slab's carries ``kv_page_size``. The prefill's
+  one-row cache stays POSITIONAL (every position of ``max_seq_len``, the
+  window a mask): a padded tail chunk writes rows past the cursor, which in
+  a ring would be live rows of the window, and a multi-token chunk would
+  have to read rows it is overwriting; ``insert`` moves the last rows of a
+  window layer's row into the ring, position ``p`` to row ``p % rows``.
+  What a ring cannot take is refused at construction, by name
+  (``transformer.ring_refusal``): the paged pool and the prefix cache,
+  speculative decoding, an int8 cache.
   """
 
   def __init__(self, cfg, num_slots: int, pad_id: int = 0, eos_id=None,
@@ -248,9 +263,18 @@ class SlotDecoder(object):
     self.mesh = mesh
     self.page_size = int(page_size)
     self.paged = self.page_size > 0
+    #: the windows of the layers the slab holds as RINGS (empty: none)
+    self.ring_windows = tuple(cfg.layer_windows[i] for i in cfg.ring_layers)
     #: whether the model counts (its expert layers, or its loop, sow
-    #: ``counters``): step_many then returns a fifth member
-    self.counted = "experts" in cfg.ffn_types or cfg.loop_passes > 1
+    #: ``counters``; its window layers hold rings): step_many then returns a
+    #: fifth member
+    self.counted = ("experts" in cfg.ffn_types or cfg.loop_passes > 1
+                    or bool(self.ring_windows))
+    # what a ring cannot take is refused by name: the paged pool and an int8
+    # cache by TransformerConfig itself (the slab's config, below)
+    if self.ring_windows and int(spec_depth) > 0:
+      raise ValueError(tfm.ring_refusal(
+          "speculative decoding (spec_depth=%d)" % int(spec_depth), "draft"))
     if self.paged:
       pps = int(pages_per_slot) or -(-cfg.max_seq_len // self.page_size)
       pool = int(num_pages) or num_slots * pps + 1
@@ -264,7 +288,8 @@ class SlotDecoder(object):
     else:
       self.pages_per_slot = 0
       self.num_pages = 0
-      self.slab_cfg = cfg
+      self.slab_cfg = dataclasses.replace(cfg, kv_ring=True) \
+          if self.ring_windows else cfg
     self.spec_depth = int(spec_depth)
     if self.spec_depth < 0:
       raise ValueError("spec_depth must be >= 0, got %d" % self.spec_depth)
@@ -292,7 +317,7 @@ class SlotDecoder(object):
           % (cfg.num_layers, self.spec_layers))
     self.model = tfm.Transformer(cfg, mesh=mesh)
     self.slab_model = tfm.Transformer(self.slab_cfg, mesh=mesh) \
-        if self.paged else self.model
+        if self.slab_cfg is not cfg else self.model
     # THE place the prefill plan is chosen, from what the config's layer
     # types say of the cache: every leaf indexed by position (K/V, int8
     # K/V with scales, the MLA latent) -> the tail is padded and masked by
@@ -459,8 +484,16 @@ class SlotDecoder(object):
   def _insert_impl(self, slabs, row, slot):
     obs_device.note_trace("serve.insert")
 
+    n = _cursor_leaf(row).astype(jnp.int32) if self.ring_windows else None
+
     def ins(s, r):
       if r.ndim == s.ndim:        # [1, ...] row leaf into [S, ...] slab
+        if r.shape[1:] != s.shape[1:]:
+          # a window layer's positional row into its ring: each ring row
+          # takes the position it holds at cursor n (below 0: never written;
+          # whatever lands there is not attended)
+          r = jnp.take(r, jnp.maximum(
+              tfm.ring_positions(n, s.shape[1]), 0), axis=1)
         return lax.dynamic_update_slice(
             s, r.astype(s.dtype), (slot,) + (0,) * (s.ndim - 1))
       # scalar cursor -> one element of the vector cursor
@@ -596,7 +629,9 @@ class SlotDecoder(object):
     assignments to experts held here and ``touched`` held experts that got
     at least one live token (summed over expert layers); of a looped model
     ``exit_pass``, the pass at which its gates let each live lane's token
-    exit.
+    exit; of a model whose window layers hold rings ``window_context``, the
+    rows ONE window layer has to read for the step, ``min(cursor, window)``
+    (the mean over the window layers, should their windows differ).
 
     An inactive lane (free, or finished inside this horizon) runs its pad
     token at cursor 0 of its own slot: the row it writes lands where the
@@ -613,7 +648,7 @@ class SlotDecoder(object):
     new_cache = mutated["cache"]
     counts = None
     if count:
-      sown = mutated["counters"]
+      sown = mutated.get("counters", {})   # rings alone sow nothing
       counts = {}
       if "experts" in self.cfg.ffn_types:
         held = _sown(sown, "held")                     # [slots] a layer
@@ -623,8 +658,12 @@ class SlotDecoder(object):
             touched=sum(jnp.sum(jnp.any(
                 jnp.logical_and(x, active[:, None]), axis=0),
                                 dtype=jnp.int32) for x in hit))
-      counts["context"] = jnp.sum(jnp.where(
-          active, _cursor_leaf(slabs).astype(jnp.int32), 0))
+      cursor = _cursor_leaf(slabs).astype(jnp.int32)
+      counts["context"] = jnp.sum(jnp.where(active, cursor, 0))
+      if self.ring_windows:
+        counts["window_context"] = sum(
+            jnp.sum(jnp.where(active, jnp.minimum(cursor, w), 0))
+            for w in self.ring_windows) // len(self.ring_windows)
       if self.cfg.loop_passes > 1:
         (exits,) = _sown(sown, "exit_pass")            # [slots, 1]
         counts["exit_pass"] = jnp.sum(jnp.where(active, exits[:, 0], 0))

@@ -330,6 +330,50 @@ def test_looped_step_many_keeps_a_cache_a_pass_in_place(topo, monkeypatch):
   assert mb["temp"] < 120e6, mb
 
 
+def test_ring_step_many_keeps_rings_and_the_whole_context_leaf_in_place(
+    topo, monkeypatch):
+  """The cell trinity-serve-backlog's decode step at its real size (1 dense
+  + 4 expert layers at published widths, 32 held experts a layer, 24 slots x
+  16384, horizon 4): ONE slab of a whole-context leaf pair (24 x 16384 x
+  1024) and four ring pairs (24 x 4096 x 1024), 3.22 GB, is aliased whole;
+  with 8.64 GB of weights beside it the program fits the chip; no leaf of
+  either length is copied at the program's edge or comes back from fast
+  memory (the kernel reads a ring as it was, its skipped row included, and
+  the cursor write lands in row ``cursor % 4096`` in HBM); one ``while`` is
+  left, the horizon's scan; 10 cursor writes and 5 attention reads a step
+  are kernels."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("serving_decode_trinity", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 2 * 24 * 1024 * 2 * (16384 + 4 * 4096)
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.6e9, mb
+  assert res["device_bytes"] < 0.8 * V5E_HBM_BYTES, res["device_bytes"]
+  for leaf in ("bf16[24,16384,1024]", "bf16[24,4096,1024]"):
+    assert leaf not in res["entry_copies"], res["entry_copies"]
+    assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] >= 10 + 5, res["tpu_custom_calls"]
+
+
+def test_later_prefill_chunk_of_a_long_row_builds_no_score_tensor(
+    topo, monkeypatch):
+  """The same cell's 512-token prefill chunk into a positional row of 16384
+  (one program for a cursor at 0 and above it): the later chunk attends the
+  row in blocks through the flash kernel, so the dense branch's float32
+  scores of 512 x 48 x 16384 (1.6 GB, several times over) do not exist:
+  temporaries stay under 0.2 GB, and the program fits beside the resident
+  3.22 GB slab."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("trinity_prefill_512", monkeypatch)
+  mb = res["memory_bytes"]
+  assert mb["temp"] < 0.2e9, mb
+  slab_bytes = 2 * 24 * 1024 * 2 * (16384 + 4 * 4096)
+  assert res["device_bytes"] + slab_bytes < 0.85 * V5E_HBM_BYTES, res
+  # the flash kernel a layer (first chunk) and again a layer (later chunks)
+  assert res["tpu_custom_calls"] >= 10, res["tpu_custom_calls"]
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

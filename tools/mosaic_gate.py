@@ -996,6 +996,80 @@ def t_ouro_prefill_512():
   return dec._prefill_fn, (params, row, _i32(1, 512), _i32())
 
 
+#: the benchmark cell trinity-serve-backlog: slots x max_seq
+TRINITY_SLOTS, TRINITY_MAX_SEQ = 24, 16384
+
+
+def trinity_cfg(max_seq: int = TRINITY_MAX_SEQ):
+  """Trinity-Large-Preview as ``benchmarks/configs/
+  trinity-large-preview.json`` cuts it to one chip's share (published
+  widths, published layers 6-10: 1 dense + 4 expert layers, sliding,
+  sliding, full, sliding, sliding; 32 of 256 experts held, 1/8 of the
+  vocabulary), spelled out so that the gate needs nothing of
+  ``benchmarks/``; ``benchmarks/tests/test_trinity.py`` keeps the two
+  equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  sliding = (True, True, False, True, True)
+  return tfm.TransformerConfig(
+      vocab_size=25024, num_layers=5, num_heads=48, num_kv_heads=8,
+      attn_head_dim=128, d_model=3072, d_ff=12288, max_seq_len=max_seq,
+      remat=False, dtype=jnp.bfloat16,
+      ffn_types=("mlp",) + ("experts",) * 4,
+      layer_windows=tuple(4096 if s else 0 for s in sliding),
+      layer_rope=sliding, qk_norm=True, attn_gate=True,
+      embed_scale=3072 ** 0.5, rope_theta=10000.0, post_norm=True,
+      norm="rms", norm_eps=1e-5, mlp_act="swiglu", tie_embeddings=False,
+      experts_total=256, experts_held=32, experts_first=0, experts_top_k=4,
+      experts_d_ff=3072, experts_shared=1, experts_scale=2.448)
+
+
+def trinity_decoder(slots: int = TRINITY_SLOTS,
+                    max_seq: int = TRINITY_MAX_SEQ):
+  """(SlotDecoder, abstract params, row cache, slab) at the cell's sizes:
+  bf16 matrices, float32 norm scales, router and router bias."""
+  import jax
+  import jax.numpy as jnp
+  from flax.core import meta
+  from tensorflowonspark_tpu.models import transformer as tfm
+  from tensorflowonspark_tpu.serving import slots as slots_lib
+  dec = slots_lib.SlotDecoder(trinity_cfg(max_seq), slots)
+  f32 = ("scale", "router", "router_bias")
+  params = _on_chip0(jax.eval_shape(lambda: jax.tree_util.tree_map_with_path(
+      lambda p, x: x if p[-1].key in f32 else x.astype(jnp.bfloat16),
+      meta.unbox(dec.model.init(
+          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+  row = _on_chip0(jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1)))
+  return dec, params, row, _on_chip0(jax.eval_shape(dec.init_slabs))
+
+
+def t_serving_decode_trinity():
+  """The cell trinity-serve-backlog's decode step at its real size: one
+  whole-context leaf pair of 24 x 16384 x 1024 and four ring pairs of 24 x
+  4096 x 1024 in the one slab (3.22 GB), 5 attention reads a step by the
+  kernel that stops at the cursor (the rings with their skipped row), 32
+  held experts a layer, horizon 4."""
+  dec, params, _, slabs = trinity_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
+def t_trinity_prefill_512():
+  """The same cell's largest prefill program: a padded 512-token chunk into
+  a positional row of 16384; at a cursor above 0 (the same program: the
+  cond's other branch) it attends the row in blocks of 2048 through the flash
+  kernel, so no float32 score tensor of 512 x 48 x 16384 exists."""
+  dec, params, row, _ = trinity_decoder()
+  return dec._prefill_fn, (params, row, _i32(1, 512), _i32())
+
+
+def t_trinity_insert():
+  """The same cell's insert: a positional row into a whole-context leaf
+  pair and four rings (a gather of the window's last rows), the slab
+  donated."""
+  dec, _, row, slabs = trinity_decoder()
+  return dec._insert_fn, (slabs, row, _i32())
+
+
 def t_smoke_step_many():
   return _smoke_step_many(paged=False)
 
@@ -1057,6 +1131,9 @@ TARGETS = {
     "serving_decode_ouro": t_serving_decode_ouro,
     "serving_decode_ouro_4_layers": t_serving_decode_ouro_4_layers,
     "ouro_prefill_512": t_ouro_prefill_512,
+    "serving_decode_trinity": t_serving_decode_trinity,
+    "trinity_prefill_512": t_trinity_prefill_512,
+    "trinity_insert": t_trinity_insert,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
