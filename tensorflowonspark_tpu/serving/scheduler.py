@@ -49,8 +49,9 @@ import numpy as np
 
 from tensorflowonspark_tpu.obs import spans as spans_mod
 
-#: comma list overriding the default prefill bucket sizes
-#: (``serving.slots.DEFAULT_BUCKETS``)
+#: comma list overriding the default prefill bucket sizes (what
+#: ``serving.slots.SlotDecoder.buckets`` chose from the model and the
+#: row's length: ``DEFAULT_BUCKETS`` and a long row's larger shapes)
 ENV_SERVE_BUCKETS = "TOS_SERVE_BUCKETS"
 
 _request_ids = itertools.count(1)

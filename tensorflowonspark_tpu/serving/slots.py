@@ -24,12 +24,14 @@ Jitted functions owning the slab:
 
 * :meth:`SlotDecoder.prefill` — run one request's prompt through the
   model on a fresh single-row cache, in bucket-shaped chunks so the jit
-  cache holds at most ``len(buckets)`` prefill shapes. A prompt is ONE
-  program where it can be: whole chunks of the largest bucket, then the
-  tail PADDED with ``pad_id`` up to the smallest bucket that holds it
-  (:func:`padded_plan`); the program takes the tail's true length,
-  leaves the cursor there and reads the first token off the last REAL
-  row. Padding needs no other mask: a padded query sits after every
+  cache holds at most ``len(buckets)`` prefill shapes (six for rows up to
+  4096 positions, one more each time the row doubles past that:
+  :func:`row_buckets`). A prompt is ONE program where it can be: whole
+  chunks of the largest bucket, then the tail PADDED with ``pad_id`` up to
+  the smallest bucket that holds it (:func:`padded_plan`), so a prompt is
+  eight chunks at most in a power-of-two row; the program takes the tail's
+  true length, leaves the cursor there and reads the first token off the
+  last REAL row. Padding needs no other mask: a padded query sits after every
   real one, so under the causal mask no real position reads it, and its
   K/V land past the cursor, where decode masks them and overwrites them
   one by one before it attends them (``_set_cache_cursor``'s free
@@ -101,13 +103,42 @@ from tensorflowonspark_tpu.utils import chaos
 #: prompt wants ONE chunk and nothing under 16; powers of two keep the
 #: padding under half (30% of the benchmark's mix, 12.8 ms a prompt; the
 #: four shapes 512/128/32/16: 49%, 13.9 ms); each shape is one more
-#: 36-layer program to compile (11-20 s) or load at every start.
+#: 36-layer program to compile (11-20 s) or load at every start. This is the
+#: BASE ladder: a long row's grows upward from it (:func:`row_buckets`).
 DEFAULT_BUCKETS = (512, 256, 128, 64, 32, 16)
+
+#: how much of a row the padded plan's largest chunk may be: the ladder is
+#: doubled upward from 512 while a shape is at most ``max_seq_len //
+#: ROW_CHUNK_DIVISOR``. A chunk of ANY size is one pass over the weights, so a
+#: prompt is then never more than eight chunks however long the row (its
+#: length a power of two: a 12288-token prompt of a 16384-long row is 6
+#: programs of 2048 tokens and not 24 of 512), while each further shape is
+#: one more program to trace and to compile or load at a start (1.7 s each,
+#: warm), which only a long row repays: rows up to 4096 keep the six shapes
+#: to the letter. Why 8 (chip runs at Trinity-Large-Preview's widths, 8.64 GB
+#: of weights in 5 layers, rows of 16384; PERF.md section 6, PR 35): one
+#: chunk at a cursor of 8192 takes the device 29.4 ms at 512 tokens, 39.6 at
+#: 1024 and 61.6 at 2048 (57, 39 and 30 us a token); the serving cell
+#: prefills 21,485 prompt tokens/s under a ladder that tops out at 512,
+#: 29,405 at 1024 (a divisor of 16) and 37,220 at 2048.
+ROW_CHUNK_DIVISOR = 8
 
 #: chunk sizes of the EXACT decomposition (:func:`chunk_plan`), which
 #: models with recurrent layers keep; 1 must be reachable so every length
 #: decomposes.
 EXACT_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
+
+
+def row_buckets(max_seq_len: int):
+  """The padded plan's chunk shapes for a row of ``max_seq_len`` positions:
+  :data:`DEFAULT_BUCKETS`, extended upward by doubling while the shape is at
+  most ``max_seq_len // ROW_CHUNK_DIVISOR``. ``row_buckets(4096)`` is
+  ``DEFAULT_BUCKETS``; ``row_buckets(16384)`` adds 1024 and 2048 above
+  it."""
+  buckets, top = DEFAULT_BUCKETS, 2 * max(DEFAULT_BUCKETS)
+  while top <= max_seq_len // ROW_CHUNK_DIVISOR:
+    buckets, top = (top,) + buckets, 2 * top
+  return buckets
 
 
 def chunk_plan(plen: int, buckets: Sequence[int] = EXACT_BUCKETS):
@@ -324,8 +355,10 @@ class SlotDecoder(object):
     # the cursor; a recurrent state or convolution tail would integrate a
     # padded token -> the exact decomposition, the programs it always had
     self.padded_prefill = not cfg.recurrent_state
-    #: the chunk shapes :meth:`prefill` compiles when its caller names none
-    self.buckets = DEFAULT_BUCKETS if self.padded_prefill else EXACT_BUCKETS
+    #: the chunk shapes :meth:`prefill` compiles when its caller names none:
+    #: a padded plan's ladder follows the row's length
+    self.buckets = row_buckets(cfg.max_seq_len) if self.padded_prefill \
+        else EXACT_BUCKETS
     # jit caches retrace per chunk shape (bounded by the bucket set) /
     # once for insert+step (fixed slab shapes)
     self._prefill_fn = jax.jit(self._prefill_impl)

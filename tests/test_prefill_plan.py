@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import kimi_linear_family as fam
+import trinity_family
 from tensorflowonspark_tpu.models import transformer as tfm
 from tensorflowonspark_tpu.serving import (
     DEFAULT_BUCKETS, EXACT_BUCKETS, ServingEngine, SlotDecoder, chunk_plan,
@@ -24,6 +25,11 @@ POOL = {16: [(16, 16)], 23: [(32, 23)], 33: [(64, 33)], 47: [(64, 47)],
         521: [(512, 512), (16, 9)], 768: [(512, 512), (256, 256)]}
 POOL_WEIGHTS = (3, 4, 6, 8, 10, 12, 13, 11, 8, 6, 4, 3)
 B = max(DEFAULT_BUCKETS)
+#: the long-row pool's eight prompt lengths and their weights
+#: (benchmarks/traffic/serve-backlog-16k.json), in rows of 16384
+LONG_POOL = (256, 512, 1024, 2048, 4096, 6144, 8192, 12288)
+LONG_POOL_WEIGHTS = (3, 5, 6, 6, 5, 4, 3, 2)
+LONG_ROW = 16384
 
 
 # -- the plan -----------------------------------------------------------------
@@ -92,14 +98,97 @@ def test_padded_plan_rejects_an_empty_prompt():
     padded_plan(0, 1024)
 
 
+# -- the ladder follows the row ---------------------------------------------------
+
+
+def _row_decoder(max_seq):
+  return SlotDecoder(_tiny(max_seq_len=max_seq), 1)
+
+
+@pytest.mark.parametrize("max_seq,top", [
+    (48, 512), (512, 512), (1024, 512), (4096, 512), (8191, 512),
+    (8192, 1024), (16384, 2048), (32768, 4096)])
+def test_the_ladder_of_shapes_follows_the_rows_length(max_seq, top):
+  """Rows up to 4096 keep the six shapes to the letter; past that the
+  ladder doubles upward while a shape is at most an eighth of the row, so
+  no prompt is more than eight chunks in a row whose length is a power of
+  two (a row that is no multiple of its shapes ends in exact pieces)."""
+  dec = _row_decoder(max_seq)
+  assert dec.padded_prefill
+  assert dec.buckets[0] == top
+  assert dec.buckets[-len(DEFAULT_BUCKETS):] == DEFAULT_BUCKETS
+  assert all(a == 2 * b for a, b in zip(dec.buckets, dec.buckets[1:]))
+  if max_seq <= 4096:
+    assert dec.buckets == DEFAULT_BUCKETS
+  if max_seq >= 512 and max_seq & (max_seq - 1) == 0:
+    assert len(dec.plan(max_seq - 1)) == min(8, max_seq // 512)
+
+
+def test_the_long_rows_ladder_spelled_out():
+  dec = _row_decoder(LONG_ROW)
+  assert dec.buckets == (2048, 1024, 512, 256, 128, 64, 32, 16)
+
+
+@pytest.mark.parametrize("n,chunks", zip(LONG_POOL, (1, 1, 1, 1, 2, 3, 4, 6)))
+def test_long_pool_prompt_is_whole_chunks_of_the_rows_ladder(n, chunks):
+  """Each length of the long-row pool is a whole number of its shapes:
+  nothing is padded, and the 12288-token prompt is 6 programs, each ending
+  inside the row."""
+  dec = _row_decoder(LONG_ROW)
+  plan = dec.plan(n)
+  assert len(plan) == chunks
+  assert all(shape == valid for shape, valid in plan)
+  assert sum(valid for _, valid in plan) == n
+  assert {shape for shape, _ in plan} <= {256, 512, 1024, 2048}
+  assert len(padded_plan(n, LONG_ROW)) == -(-n // B)      # what it was
+
+
+def test_long_pool_is_two_programs_a_prompt_and_no_padding():
+  dec = _row_decoder(LONG_ROW)
+  plans = [dec.plan(n) for n in LONG_POOL]
+  programs = np.dot([len(p) for p in plans], LONG_POOL_WEIGHTS)
+  assert programs == 66
+  assert programs / sum(LONG_POOL_WEIGHTS) == pytest.approx(1.94, abs=0.005)
+  assert np.dot([-(-n // B) for n in LONG_POOL], LONG_POOL_WEIGHTS) == 228
+  assert sum(s - v for p in plans for s, v in p) == 0
+  # 26 of the 34 prompts, 97% of the prompt tokens, take the new shapes
+  new = [w for p, w in zip(plans, LONG_POOL_WEIGHTS) if p[0][0] > B]
+  assert sum(new) == 26
+  tokens = np.dot(LONG_POOL, LONG_POOL_WEIGHTS)
+  assert np.dot(LONG_POOL[2:], LONG_POOL_WEIGHTS[2:]) / tokens > 0.96
+
+
+@pytest.mark.parametrize("offset", [0, 2048, 12288, 14336, 15000, 16000])
+def test_long_rows_plan_ends_inside_the_row_at_any_offset(offset):
+  """``room`` is respected under the longer ladder too: whatever is left of
+  a 16384-row, no chunk ends past it and only the last is padded."""
+  dec = _row_decoder(LONG_ROW)
+  for n in (1, 17, 300, 1025, 2047, 2048, 2049, LONG_ROW - offset - 1):
+    if not 1 <= n < LONG_ROW - offset:
+      continue
+    plan = dec.plan(n, offset)
+    assert sum(v for _, v in plan) == n
+    assert all(s == v for s, v in plan[:-1])
+    assert offset + sum(v for _, v in plan[:-1]) + plan[-1][0] <= LONG_ROW
+    assert {s for s, _ in plan} <= set(dec.buckets) | {1}
+
+
+@pytest.mark.parametrize("n,plan", sorted(POOL.items()))
+def test_short_rows_pool_plans_as_it_did(n, plan):
+  """The 1024-row pool through ``SlotDecoder.plan``: the table above."""
+  dec = _row_decoder(1024)
+  assert dec.plan(n) == plan
+
+
 # -- the program: padded against exact ----------------------------------------
 
 
 def _tiny(**kw):
   kw.setdefault("dtype", jnp.float32)
   kw.setdefault("num_heads", 2)
+  kw.setdefault("max_seq_len", 48)
   return tfm.TransformerConfig(vocab_size=64, num_layers=2, d_model=32,
-                               d_ff=64, max_seq_len=48, remat=False, **kw)
+                               d_ff=64, remat=False, **kw)
 
 
 VARIANTS = {
@@ -214,6 +303,42 @@ def test_padded_tail_after_a_prefix_cache_resume():
     tok = int(np.asarray(nxt)[1])
     toks.append(tok)
   assert toks == want
+
+
+def test_a_ladder_four_times_as_wide_prefills_the_same_row(monkeypatch):
+  """A window + full model (tests/trinity_family.py at toy width: window 8,
+  a row of three ``_ROW_BLOCK``s of 32, later chunks through the blocked
+  flash kernel in interpret mode): a prompt prefilled in chunks of 16 and in
+  chunks of 4 gives the same first token, the same row cache and the same
+  decoded continuation out of the ring slab. The narrow plan has a chunk
+  edge either side of the window (4, 12) and of a block edge (28, 36), the
+  wide one past the window (16) and either side of the second block edge
+  (48, 75)."""
+  from test_trinity import MAX_SEQ, TOY
+  monkeypatch.setattr(tfm, "_ROW_BLOCK", 32)
+  cfg = trinity_family.program_config(TOY, MAX_SEQ, dtype=jnp.float32,
+                                      attention_impl="flash")
+  params = trinity_family.program_params(7, TOY)
+  n = 75
+  prompt = np.random.default_rng(5).integers(
+      0, TOY["vocab_size"], n, dtype=np.int32)
+  dec = SlotDecoder(cfg, 1)
+  assert dec.padded_prefill and dec.ring_windows
+  wide, narrow = (16, 8, 4), (4,)
+  assert dec.plan(n, 0, wide) == [(16, 16)] * 4 + [(16, 11)]
+  assert dec.plan(n, 0, narrow) == [(4, 4)] * 18 + [(4, 3)]
+  want_cache, want_first = dec.prefill(params, prompt, narrow)
+  cache, first = dec.prefill(params, prompt, wide)
+  assert set(_cursors(cache)) == set(_cursors(want_cache)) == {n}
+  for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+    np.testing.assert_allclose(_written(a, n), _written(b, n), atol=1e-5,
+                               rtol=1e-5)
+  assert first == want_first
+  want = trinity_family.reference_logits(
+      trinity_family.make_weights(7, TOY), prompt[None], TOY)
+  assert first == int(np.argmax(np.asarray(want)[0, -1]))
+  assert _decode_from(dec, params, cache, first) \
+      == _decode_from(dec, params, want_cache, want_first)
 
 
 # -- who keeps the exact plan ---------------------------------------------------

@@ -1053,13 +1053,20 @@ def t_serving_decode_trinity():
   return _step_many_target(dec, params, slabs)
 
 
-def t_trinity_prefill_512():
-  """The same cell's largest prefill program: a padded 512-token chunk into
-  a positional row of 16384; at a cursor above 0 (the same program: the
-  cond's other branch) it attends the row in blocks of 2048 through the flash
-  kernel, so no float32 score tensor of 512 x 48 x 16384 exists."""
+#: the three largest shapes of that cell's prefill ladder
+#: (``serving.slots.row_buckets(16384)``; trinity_prefill checks it)
+TRINITY_BUCKETS = (2048, 1024, 512)
+
+
+def trinity_prefill(bucket: int):
+  """One of the same cell's largest prefill programs: a padded chunk of
+  ``bucket`` tokens into a positional row of 16384; at a cursor above 0 (the
+  same program: the cond's other branch) it attends the row in blocks of 2048
+  through the flash kernel, so no float32 score tensor of bucket x 48 x 16384
+  exists."""
   dec, params, row, _ = trinity_decoder()
-  return dec._prefill_fn, (params, row, _i32(1, 512), _i32())
+  assert dec.buckets[:len(TRINITY_BUCKETS)] == TRINITY_BUCKETS, dec.buckets
+  return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
 
 
 def t_trinity_insert():
@@ -1132,11 +1139,12 @@ TARGETS = {
     "serving_decode_ouro_4_layers": t_serving_decode_ouro_4_layers,
     "ouro_prefill_512": t_ouro_prefill_512,
     "serving_decode_trinity": t_serving_decode_trinity,
-    "trinity_prefill_512": t_trinity_prefill_512,
     "trinity_insert": t_trinity_insert,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
+TARGETS.update({"trinity_prefill_%d" % b: (lambda b=b: trinity_prefill(b))
+                for b in TRINITY_BUCKETS})
 
 #: HBM of one v5e chip (Google Cloud "TPU v5e": 16 GB)
 V5E_HBM_BYTES = 16 * 1024 ** 3
