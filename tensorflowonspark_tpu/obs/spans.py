@@ -255,16 +255,85 @@ class Region(object):
   one clock reading, ``dur`` set on exit) and the writable ``attrs`` of its
   recorder span, for values known only once the work is done."""
 
-  __slots__ = ("t0", "dur", "attrs", "_counted")
+  __slots__ = ("t0", "dur", "attrs", "_counted", "_empty")
 
   def __init__(self, attrs: dict):
     self.attrs = attrs
-    self.t0 = self.dur = self._counted = 0.0
+    self.t0 = self.dur = self._counted = self._empty = 0.0
+
+
+def empty_key(key: str) -> str:
+  """The counter of known-drained seconds beside a phase's ``t_*_s``
+  counter: ``t_insert_s`` -> ``empty_insert_s``. The prefix is not ``t_``,
+  so a reader that takes every ``t_*`` key for a phase still can."""
+  return "empty_" + key[2:]
+
+
+class DeviceQueue(object):
+  """What ONE dispatching thread can vouch for about its device's queue.
+
+  The thread that issues every program of a device, and is the only one to
+  wait for them, knows without a profiler each interval in which nothing it
+  dispatched can still be running: from the return of a blocking read of
+  its NEWEST program's output (one stream, in order: the newest done means
+  all done) to the return of its next dispatch call. :meth:`dispatched` and
+  :meth:`drained` mark the two edges; :func:`region` charges the seconds
+  between them to the innermost open region that has a counter,
+  ``acc[empty_key(key)]``.
+
+  The sum bounds the device's idle time from below as far as the host can
+  see: launch latency, gaps inside a program and the copy back are idle it
+  cannot vouch for, and a read of an OLDER program (a newer one in flight)
+  proves nothing and counts nothing. One caveat: the runtime may start a
+  program somewhat before its dispatch call returns (on a v5e at about two
+  thirds of a ``step_many`` call: PERF.md section 6, PR 36), so a dispatch
+  phase's share can read over the idle time inside that phase. Owned by
+  one thread; no lock.
+  """
+
+  __slots__ = ("seq", "_since", "_total")
+
+  def __init__(self):
+    self.seq = 0              # the newest dispatch's number
+    self._since = None        # drained since this instant; None: not known
+    self._total = 0.0         # closed intervals, seconds
+
+  @property
+  def known_drained(self) -> bool:
+    return self._since is not None
+
+  def dispatched(self) -> int:
+    """A dispatch call of the owning thread RETURNED: closes the open
+    interval, marks the device possibly busy and returns the dispatch's
+    number, to hand to :meth:`drained` once its output has been read."""
+    self.unknown()
+    self.seq += 1
+    return self.seq
+
+  def drained(self, seq: int) -> None:
+    """A blocking read of dispatch ``seq``'s output returned (or its output
+    was seen ready). Only the newest dispatch's read empties the queue."""
+    if seq == self.seq and self._since is None:
+      self._since = time.monotonic()
+
+  def unknown(self) -> None:
+    """Back to "not known drained" (a crash, a fresh loop thread): what was
+    vouched for up to now stays counted, nothing after it is."""
+    if self._since is not None:
+      self._total += time.monotonic() - self._since
+      self._since = None
+
+  def empty_at(self, t: float) -> float:
+    """Known-drained seconds up to ``t``, a ``time.monotonic()`` reading no
+    older than the last edge. Never decreases."""
+    return self._total if self._since is None \
+        else self._total + (t - self._since)
 
 
 @contextlib.contextmanager
 def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
-           trace: Optional[str] = None, record: bool = True, **attrs):
+           trace: Optional[str] = None, record: bool = True,
+           queue: Optional[DeviceQueue] = None, **attrs):
   """One timed region of the calling thread, written to three sinks::
 
       with region("serve.insert", acc=stats, key="t_insert_s"):
@@ -274,7 +343,10 @@ def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
     seconds — its duration less what regions nested inside it put into
     their own counters — so the keys of one thread partition its wall
     time and no second is counted twice (a nested region without a
-    counter stays in its parent's);
+    counter stays in its parent's). With ``queue`` (the thread's
+    :class:`DeviceQueue`) ``acc[empty_key(key)]`` grows, by the same rule
+    and on the same two clock readings, by the seconds of them in which
+    the device was known drained: never more than the self seconds;
   * trace clock, on while a ``jax.profiler`` session is live: the region
     is a ``TraceAnnotation`` on the host plane of the same ``.xplane.pb``
     as the device's ``XLA Ops`` line;
@@ -286,17 +358,25 @@ def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
   parent = getattr(_tls, "top", None)
   with _annotation(name):
     _tls.top = r
-    r.t0 = time.monotonic()
+    r.t0 = t0 = time.monotonic()
+    e0 = queue.empty_at(t0) if queue is not None else 0.0
     try:
       yield r
     finally:
-      r.dur = dur = time.monotonic() - r.t0
+      t1 = time.monotonic()
+      r.dur = dur = t1 - t0
       _tls.top = parent
       if acc is not None:
-        acc[key] += dur - r._counted
+        own = dur - r._counted
+        acc[key] += own
         r._counted = dur
+        if queue is not None:
+          empty = queue.empty_at(t1) - e0
+          acc[empty_key(key)] += min(max(empty - r._empty, 0.0), own)
+          r._empty = empty
       if parent is not None:
         parent._counted += r._counted
+        parent._empty += r._empty
       if record:
         rec = active()
         if rec is not None:

@@ -358,7 +358,25 @@ class ServingEngine(object):
                   "t_prefill_s": 0.0, "t_prefill_sync_s": 0.0,
                   "t_insert_s": 0.0, "t_decode_prep_s": 0.0,
                   "t_decode_dispatch_s": 0.0, "t_decode_fetch_s": 0.0,
-                  "t_decode_harvest_s": 0.0}
+                  "t_decode_harvest_s": 0.0,
+                  # beside each: the seconds of it in which the device was
+                  # KNOWN drained (obs.spans.DeviceQueue): nothing the loop
+                  # dispatched could still be running. Never over its
+                  # t_*_s; their sum is a lower bound of the device's idle
+                  # time (docs/OBSERVABILITY.md)
+                  "empty_reap_s": 0.0, "empty_idle_s": 0.0,
+                  "empty_admit_s": 0.0, "empty_prefill_s": 0.0,
+                  "empty_prefill_sync_s": 0.0, "empty_insert_s": 0.0,
+                  "empty_decode_prep_s": 0.0,
+                  "empty_decode_dispatch_s": 0.0,
+                  "empty_decode_fetch_s": 0.0,
+                  "empty_decode_harvest_s": 0.0}
+    # the loop thread is the only dispatcher of this engine's programs and
+    # the only thread that waits for them: it marks every dispatch call's
+    # return and every blocking read's return here, and the regions charge
+    # the drained seconds between the two to their phase
+    self._devq = obs_spans.DeviceQueue()
+    self._slab_seq = 0                     # the dispatch self._slabs is of
     # obs seam (docs/OBSERVABILITY.md): every loop-thread phase is one
     # obs.spans.region — counter (stats above, always on), trace
     # annotation (while a jax.profiler session is live) and recorder
@@ -444,6 +462,7 @@ class ServingEngine(object):
     if self._slabs is not None:
       return
     self._slabs = self.decoder.init_slabs()
+    self._slab_seq = self._devq.dispatched()
     if self.decoder.paged:
       self._pool = sched.PagePool(self.decoder.num_pages)
       self._prefix = sched.PrefixCache(self.page_size, self.prefix_pages) \
@@ -457,11 +476,13 @@ class ServingEngine(object):
     slab donated; JAX only WARNS when a donation turns out unusable (an
     output whose shape or layout no longer matches) and then copies the
     slab in silence, so the counters say what happened: the slab that went
-    in is deleted exactly when the program took it over. Loop thread
-    only: the slab is lifecycle-fenced (start() and stop() touch it before
-    the thread exists and after its join), so no lock is held here."""
+    in is deleted exactly when the program took it over. The call's
+    return is a dispatch of the loop's device queue. Loop thread only: the
+    slab is lifecycle-fenced (start() and stop() touch it before the
+    thread exists and after its join), so no lock is held here."""
     old = self._slabs
     out = op(old)
+    self._slab_seq = self._devq.dispatched()
     self._slabs = out[0] if isinstance(out, tuple) else out
     self.stats["slab_dispatches"] += 1
     self.stats["slab_in_place"] += slots_lib.consumed(old)
@@ -475,6 +496,7 @@ class ServingEngine(object):
     self._draining = False
     self._crash_streak = 0
     self._queue.reopen()
+    self._devq.unknown()                   # a fresh loop vouches for nothing
     self._ensure_slabs()
     self._thread = threading.Thread(target=self._loop, daemon=True,
                                     name="tos-serving-engine")
@@ -909,15 +931,16 @@ class ServingEngine(object):
         # reap/admit/idle run every pass of an IDLE engine too: counter
         # and annotation only, never the bounded recorder
         with obs_spans.region("serve.reap", self.stats, "t_reap_s",
-                              record=False):
+                              record=False, queue=self._devq):
           self._reap()
         with obs_spans.region("serve.admit", self.stats, "t_admit_s",
-                              record=False):
+                              record=False, queue=self._devq):
           self._admit()
         if not any(r is not None for r in self._slots):
           # idle: bounded block until work arrives (TOS001)
           with obs_spans.region("serve.idle", self.stats, "t_idle_s",
-                                record=False):
+                                record=False, queue=self._devq):
+            self._vouch_idle()
             self._queue.wait_nonempty(timeout=self._poll)
           continue
         self._decode_once()
@@ -926,6 +949,18 @@ class ServingEngine(object):
         # terminal failures are forwarded to every waiter by _die
         if not self._recover(e):
           return
+
+  def _vouch_idle(self) -> None:
+    """An idle pass whose newest dispatch nobody read (a freed lane's
+    ``reset_slots``, a slab rebuilt after a crash): its output is the
+    slab, and once every leaf of that is ready the queue is drained.
+    Looked at, never waited for. (Not before a dispatch: behind an
+    ``insert`` the look comes too early to find it done, and costs 0.3 ms
+    a pass over a slab of 108 leaves: PERF.md section 6, PR 36.)"""
+    q = self._devq
+    if not q.known_drained and self._slab_seq == q.seq \
+        and slots_lib.ready(self._slabs):
+      q.drained(q.seq)
 
   # -- crash-replay recovery -------------------------------------------------
 
@@ -959,6 +994,7 @@ class ServingEngine(object):
     if adm is not None:
       victims.append(adm)
     self._last[:] = self.pad_id
+    self._devq.unknown()                   # whatever was running, or failed
     self._slabs = None                     # fresh slab next iteration
     # the crash took the slab's pages with it: allocator, prefix trie
     # and per-request page lists rebuild with the slab (_ensure_slabs);
@@ -1209,7 +1245,7 @@ class ServingEngine(object):
                                 req.started_at - req.submitted_at,
                                 trace=req.trace_id, rid=req.rid)
       with obs_spans.region("serve.prefill", self.stats, "t_prefill_s",
-                            trace=req.trace_id,
+                            trace=req.trace_id, queue=self._devq,
                             record=self._rec is not None, rid=req.rid,
                             prompt_len=len(req.prompt), slot=slot,
                             shared_tokens=shared_tokens):
@@ -1220,11 +1256,12 @@ class ServingEngine(object):
           self._count("prefix_hits")
           row = self.decoder.gather_pages(self._slabs, table,
                                           shared_tokens)
+          self._devq.dispatched()
           resume = (row, shared_tokens)
         row_cache, first = self.decoder.prefill(
             self.params, req.prompt, self.buckets, resume=resume,
             trace=req.trace_id if self._detail else None,
-            acc=self.stats)
+            acc=self.stats, queue=self._devq)
       if req.prefill_done_at is None:   # replays keep the original stamp
         req.prefill_done_at = time.monotonic()
       self.stats["prefills"] += 1
@@ -1266,7 +1303,8 @@ class ServingEngine(object):
   def _phase(self, name: str, key: str):
     """A per-dispatch phase of the loop thread: counter and annotation
     always, recorder span only with ``TOS_OBS_TRACE_DETAIL``."""
-    return obs_spans.region(name, self.stats, key, record=self._detail)
+    return obs_spans.region(name, self.stats, key, record=self._detail,
+                            queue=self._devq)
 
   def _mark_admitting(self, req: sched.Request) -> None:
     self._admitting = req
@@ -1383,6 +1421,8 @@ class ServingEngine(object):
       self.stats["decode_attn_reads_ragged"] += ragged
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
+      # the step's read returned: the rest of the region is empty time
+      self._devq.drained(self._slab_seq)
       if self.decoder.counted:       # the step's own sums, beside the tokens
         for name, value in out[4].items():
           self.stats[_STEP_COUNTERS[name]] += int(np.asarray(value))
@@ -1424,6 +1464,8 @@ class ServingEngine(object):
               self.params, slabs, self._last, active, remaining, rounds))
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(toks)          # [rounds, spec_depth, num_slots]
+      # the step's read returned: the rest of the region is empty time
+      self._devq.drained(self._slab_seq)
       counts = np.asarray(counts)      # [rounds, num_slots]
       n_acc, n_rej = int(np.asarray(acc).sum()), int(np.asarray(rej).sum())
     lanes: List[tuple] = []

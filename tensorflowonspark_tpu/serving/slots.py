@@ -231,6 +231,12 @@ def consumed(slabs) -> bool:
   return all(leaf.is_deleted() for leaf in jax.tree.leaves(slabs))
 
 
+def ready(slabs) -> bool:
+  """Whether the programs that produced ``slabs`` have all finished: every
+  leaf's buffer is ready. Never blocks."""
+  return all(leaf.is_ready() for leaf in jax.tree.leaves(slabs))
+
+
 class SlotDecoder(object):
   """Jitted slab operations for one (config, num_slots) serving shape.
 
@@ -432,7 +438,7 @@ class SlotDecoder(object):
     return [(b, b) for b in chunk_plan(n, buckets)]
 
   def prefill(self, params, prompt, buckets=None, resume=None, trace=None,
-              acc=None) -> Tuple[object, int]:
+              acc=None, queue=None) -> Tuple[object, int]:
     """Prefill one prompt into a fresh [1, ...] row cache.
 
     Returns ``(row_cache, first_token)``: the warm cache (cursor at
@@ -456,11 +462,15 @@ class SlotDecoder(object):
     annotations always; recorder spans when ``trace`` (a request trace
     id) is given — the chunk-plan phase of the request waterfall. Chunk
     dispatches are async, so a chunk region measures dispatch-to-dispatch
-    time; the enclosing ``serve.prefill`` span carries the true synced
-    total. ``acc`` (the engine's ``stats``) counts the dispatches in
-    ``prefill_chunks``, the tokens they computed in ``prefill_tokens``,
-    the padding among them in ``prefill_padded_tokens`` and the wait in
-    ``t_prefill_sync_s``.
+    time (the benchmark's ``prefill_dispatch_ms.backlog`` is its mean, d
+    ``t_prefill_s`` / d ``prefill_chunks``); the enclosing
+    ``serve.prefill`` span carries the true synced total. ``acc`` (the
+    engine's ``stats``) counts the dispatches in ``prefill_chunks``, the
+    tokens they computed in ``prefill_tokens``, the padding among them in
+    ``prefill_padded_tokens`` and the wait in ``t_prefill_sync_s``.
+    ``queue`` (the calling thread's ``obs.spans.DeviceQueue``, with ``acc``)
+    is told of each chunk's dispatch and of the end of the wait, and the
+    sync region's tail after the read goes to ``empty_prefill_sync_s``.
     """
     plen = len(prompt)
     if plen + 1 > self.cfg.max_seq_len:
@@ -492,7 +502,7 @@ class SlotDecoder(object):
       acc["prefill_chunks"] += len(plan)
       acc["prefill_tokens"] += sum(seg for seg, _ in plan)
       acc["prefill_padded_tokens"] += sum(seg - n for seg, n in plan)
-    nxt = None
+    nxt = seq = None
     for seg, n in plan:
       with obs_spans.region("serve.prefill.chunk", trace=trace,
                             record=trace is not None, chunk=seg,
@@ -504,12 +514,17 @@ class SlotDecoder(object):
         cache, nxt = self._prefill_fn(
             params, cache, tokens,
             np.int32(n) if self.padded_prefill else None)
+        seq = None if queue is None else queue.dispatched()
       off += n
     with obs_spans.region("serve.prefill.sync", acc, "t_prefill_sync_s",
-                          trace=trace, record=trace is not None):
+                          trace=trace, record=trace is not None,
+                          queue=queue):
       # waits for the last chunk; fetched whole, because indexing the
       # device array would be two more eager programs (slice, squeeze)
-      first = int(np.asarray(nxt)[0])
+      head = np.asarray(nxt)
+      if queue is not None:
+        queue.drained(seq)           # the wait is over: the rest is empty
+      first = int(head[0])
     return cache, first
 
   # -- slot insert ----------------------------------------------------------

@@ -27,6 +27,7 @@ PHASE_KEYS = ("t_reap_s", "t_idle_s", "t_admit_s", "t_prefill_s",
               "t_decode_dispatch_s", "t_decode_fetch_s",
               "t_decode_harvest_s")
 COUNT_KEYS = ("decode_dispatches", "prefill_chunks")
+EMPTY_KEYS = tuple(spans_mod.empty_key(k) for k in PHASE_KEYS)
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +79,108 @@ def test_region_self_time_partitions_nested_regions(monkeypatch):
   assert sum(acc.values()) == outer.dur
 
 
+def _scripted_clock(monkeypatch, ticks):
+  ticks = iter(ticks)
+  monkeypatch.setattr(spans_mod.time, "monotonic", lambda: next(ticks))
+
+
+def test_device_queue_counts_from_a_read_to_the_next_dispatch(monkeypatch):
+  """Known drained: from the return of a read of the NEWEST dispatch to the
+  return of the next dispatch call, and never before the first read."""
+  _scripted_clock(monkeypatch, [10.0,       # drained(1)
+                                12.5,       # dispatched() -> 2
+                                20.0,       # drained(2)
+                                21.0])      # unknown()
+  q = spans_mod.DeviceQueue()
+  assert not q.known_drained and q.empty_at(5.0) == 0.0
+  assert q.dispatched() == 1 and not q.known_drained   # nothing was open
+  assert q.empty_at(9.0) == 0.0
+  q.drained(1)
+  assert q.known_drained and q.empty_at(11.0) == 1.0
+  assert q.dispatched() == 2 and q.seq == 2
+  assert not q.known_drained and q.empty_at(15.0) == 2.5
+  q.drained(2)
+  q.drained(2)                        # a second read of it: no clock, no-op
+  assert q.empty_at(20.5) == 3.0
+  q.unknown()
+  assert not q.known_drained and q.empty_at(30.0) == 3.5
+  q.unknown()                         # idempotent, reads no clock
+
+
+def test_device_queue_ignores_the_read_of_an_older_dispatch(monkeypatch):
+  """Two programs in flight (what dispatching the next step before fetching
+  the last one's tokens will do): the older one's read returning proves
+  nothing, so nothing is counted until the newest has been read."""
+  _scripted_clock(monkeypatch, [7.0])  # the one read that counts
+  q = spans_mod.DeviceQueue()
+  first, second = q.dispatched(), q.dispatched()
+  q.drained(first)                    # out of order: a newer one in flight
+  assert not q.known_drained and q.empty_at(100.0) == 0.0
+  q.drained(second)
+  assert q.known_drained and q.empty_at(9.0) == 2.0
+
+
+def test_device_queue_forgets_across_a_crash(monkeypatch):
+  """A crash between ``dispatched()`` and ``drained()``: the read never
+  returned, the loop marks the queue unknown, and the dead program's number
+  can no longer drain it."""
+  _scripted_clock(monkeypatch, [])    # no edge here reads the clock
+  q = spans_mod.DeviceQueue()
+  seq = q.dispatched()
+  q.unknown()                         # _recover
+  later = q.dispatched()              # the rebuilt engine's first program
+  q.drained(seq)                      # a stale number
+  assert later == seq + 1 and not q.known_drained
+  assert q.empty_at(1e9) == 0.0
+
+
+def test_region_charges_drained_seconds_to_the_innermost_counter(monkeypatch):
+  """``empty_*`` follows the rule of self time on the same clock readings:
+  each drained second to the innermost open region that has a counter, a
+  wait is never empty, what follows the read in its region is."""
+  _scripted_clock(monkeypatch, [
+      0.0,              # outer in
+      1.0,              # dispatch in (not known drained: dispatched()
+      3.0,              # reads no clock), dispatch out
+      3.0,              # fetch in
+      7.0,              # drained(): the wait took 4 s
+      8.0,              # fetch out: 1 s of tail
+      8.0,              # bare in (no counter)
+      9.0, 9.5,         # inner in/out (counted, inside bare)
+      10.0,             # bare out
+      11.0,             # redispatch in (entered drained)
+      12.0,             # dispatched(): empty to the call's return
+      13.0,             # redispatch out
+      14.0])            # outer out
+  keys = ("t_outer_s", "t_dispatch_s", "t_fetch_s", "t_inner_s")
+  acc = {k: 0.0 for k in keys + tuple(map(spans_mod.empty_key, keys))}
+  q = spans_mod.DeviceQueue()
+
+  def reg(name, key=None):
+    return spans_mod.region(name, acc if key else None, key, record=False,
+                            queue=q)
+
+  with reg("outer", "t_outer_s"):
+    with reg("dispatch", "t_dispatch_s"):
+      seq = q.dispatched()
+    with reg("fetch", "t_fetch_s"):
+      q.drained(seq)
+    with reg("bare"):
+      with reg("inner", "t_inner_s"):
+        pass
+    with reg("redispatch", "t_dispatch_s"):
+      q.dispatched()
+  assert acc == dict(
+      t_outer_s=14.0 - 2.0 - 5.0 - 0.5 - 2.0, t_dispatch_s=4.0,
+      t_fetch_s=5.0, t_inner_s=0.5,
+      # outer: bare's 2 s less inner's 0.5, and 10 -> 11 between regions
+      empty_outer_s=2.5, empty_dispatch_s=1.0, empty_fetch_s=1.0,
+      empty_inner_s=0.5)
+  total = sum(acc[spans_mod.empty_key(k)] for k in keys)
+  assert total == q.empty_at(99.0) == 5.0
+  assert all(acc[spans_mod.empty_key(k)] <= acc[k] for k in keys)
+
+
 def test_region_records_like_span_when_the_plane_is_on():
   rec = spans_mod.activate()
   try:
@@ -122,8 +225,16 @@ def test_phase_keys_exist_at_construction(toy):
     assert eng.stats[k] == 0.0 and isinstance(eng.stats[k], float)
   for k in COUNT_KEYS:
     assert eng.stats[k] == 0 and isinstance(eng.stats[k], int)
+  for k in EMPTY_KEYS:
+    assert eng.stats[k] == 0.0 and isinstance(eng.stats[k], float)
+  assert EMPTY_KEYS[5] == "empty_insert_s" and len(set(EMPTY_KEYS)) == 10
+  # a reader that takes every t_* key for a phase still gets the ten
+  assert sorted(k for k in eng.stats if k.startswith("t_")) \
+      == sorted(PHASE_KEYS)
   # the benchmark's delta surface carries every one of them
-  assert set(PHASE_KEYS + COUNT_KEYS) <= set(eng.stats_snapshot().delta())
+  delta = eng.stats_snapshot().delta()
+  assert set(PHASE_KEYS + COUNT_KEYS + EMPTY_KEYS) <= set(delta)
+  assert all(delta[k] == 0.0 for k in EMPTY_KEYS)
 
 
 def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
@@ -165,6 +276,114 @@ def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
   assert delta["t_decode_dispatch_s"] > 0 and delta["t_prefill_s"] > 0
   assert sum(delta.values()) == pytest.approx(lives[1], rel=0.02)
   assert sum(delta.values()) <= lives[1]
+
+
+@pytest.mark.parametrize("stack", ["plain", "spec", "paged"])
+def test_empty_counters_hold_their_invariants_under_a_watcher(toy, stack):
+  """The four invariants of the ``empty_*_s`` counters, sampled by a watcher
+  thread while the loop serves (``dict(stats)`` is one atomic copy): never
+  over the phase's ``t_*_s``, never decreasing; their sum is the queue's own
+  total, no second twice and none outside a region; a wait is never empty;
+  and an idle engine's ``empty_idle_s`` follows ``t_idle_s``."""
+  cfg, state = toy
+  kw = dict(plain={}, spec=dict(spec_depth=2, spec_layers=1),
+            paged=dict(page_size=8))[stack]
+  eng = ServingEngine(state.params, cfg, num_slots=4, eos_id=None, **kw)
+  known_at_exit, inner = [], eng._loop
+
+  def loop():
+    inner()
+    known_at_exit.append(eng._devq.empty_at(time.monotonic()))
+
+  eng._loop = loop
+  prompts = _prompts(24, seed=9)
+  with eng:                             # cold: every shape compiles
+    _serve(eng, prompts[:8], 10)
+  seen, stop = [], threading.Event()
+
+  def watch():
+    while not stop.is_set():
+      seen.append(dict(eng.stats))
+      time.sleep(0.002)
+
+  watcher = threading.Thread(target=watch, daemon=True)
+  with eng:                             # warm: a fresh loop thread
+    base = dict(eng.stats)
+    known0 = eng._devq.empty_at(time.monotonic())
+    watcher.start()
+    _serve(eng, prompts, 10)
+    # the engine empties: the first idle pass may still find a freed lane's
+    # reset_slots running, every later one vouches for the whole wait
+    time.sleep(0.1)
+    idle0 = dict(eng.stats)
+    time.sleep(0.5)
+    idle1 = dict(eng.stats)
+  stop.set()
+  watcher.join(10)
+  assert not watcher.is_alive() and len(seen) > 5
+  for st in seen:
+    for t_key, e_key in zip(PHASE_KEYS, EMPTY_KEYS):
+      assert 0.0 <= st[e_key] <= st[t_key] + 1e-9, (t_key, st)
+  for a, b in zip(seen, seen[1:]):
+    assert all(b[k] >= a[k] for k in EMPTY_KEYS)
+  st = eng.stats                        # the loop has stopped
+  delta = {k: st[k] - base[k] for k in PHASE_KEYS + EMPTY_KEYS}
+  # every known-drained second of the run is in one counter, but for the
+  # few lines of a loop pass that no region covers (the 2% of the closure
+  # test above)
+  known = known_at_exit[1] - known0
+  empty = sum(delta[k] for k in EMPTY_KEYS)
+  assert empty <= known + 1e-9
+  assert empty == pytest.approx(known, rel=0.02)
+  # something was counted where the loop dispatches into a drained device
+  assert delta["empty_prefill_s"] > 0 and delta["empty_decode_harvest_s"] > 0
+  # the waits: all but the tail after the read returned is NOT empty
+  for key in ("decode_fetch", "prefill_sync"):
+    assert delta["empty_%s_s" % key] < 0.5 * delta["t_%s_s" % key], key
+  # idle: the device is drained for as long as the engine waits
+  d_idle = idle1["t_idle_s"] - idle0["t_idle_s"]
+  assert d_idle > 0.3
+  # (a copy may fall between a pass's two additions: one poll of slack)
+  assert idle1["empty_idle_s"] - idle0["empty_idle_s"] \
+      == pytest.approx(d_idle, abs=eng._poll + 1e-3)
+
+
+def test_a_crash_leaves_the_queue_unknown_until_the_next_read(
+    toy, monkeypatch):
+  """``_recover`` marks the queue not known drained, so the backoff, the
+  slab's rebuild and the replayed prefill's dispatches count nothing until
+  a read of the newest program returns; the engine then counts again."""
+  from tensorflowonspark_tpu.utils import chaos
+  cfg, state = toy
+  monkeypatch.setenv("TOS_CHAOS_SERVE", "decode#2:raise")
+  chaos.reset()
+  marks = []
+  try:
+    eng = ServingEngine(state.params, cfg, num_slots=2, eos_id=None,
+                        restart_backoff=0.2)
+    recover = eng._recover
+
+    def recording(error):
+      ok = recover(error)
+      # on return: the backoff has passed, nothing is vouched for
+      marks.append((eng._devq.known_drained,
+                    sum(eng.stats[k] for k in EMPTY_KEYS)))
+      return ok
+
+    eng._recover = recording
+    with eng:
+      _serve(eng, _prompts(3, seed=13, longest=20), 8)
+      st = dict(eng.stats)
+  finally:
+    monkeypatch.delenv("TOS_CHAOS_SERVE")
+    chaos.reset()
+  assert st["engine_restarts"] == 1 and len(marks) == 1
+  known, counted = marks[0]
+  assert not known
+  # the 0.2 s of backoff are in no counter, and the engine counted on
+  assert sum(st[k] for k in EMPTY_KEYS) > counted
+  for t_key, e_key in zip(PHASE_KEYS, EMPTY_KEYS):
+    assert 0.0 <= st[e_key] <= st[t_key] + 1e-9
 
 
 @pytest.mark.parametrize("spec_depth", [0, 2])
