@@ -34,6 +34,15 @@ Two forms of the same recurrence, leaving the same state:
   steps) and from call to call. ``exp(G_t - G_i)`` is taken of the
   DIFFERENCE, masked to ``t >= i`` first, so no factor overflows however
   fast a channel decays.
+
+A chunk may end in PADDING (``n_valid`` real tokens of ``seg``: a prompt's
+tail padded up to a prefill bucket, ``serving/slots.py``). A padded token
+neither decays nor writes (``log a = 0, b = 0``: ``S' = S`` and ``b k u^T =
+0``, the same tokens ``chunk_rule`` fills a last block up with), so the
+state after the chunk is the state after its last real token, and the
+convolution tail is taken at the true length, ``window[n_valid : n_valid +
+taps - 1]`` of the old tail followed by the chunk. What the layer returns at
+a padded position is of no use to anyone, and nothing reads it.
 """
 
 import flax.linen as nn
@@ -155,16 +164,20 @@ class KDA(nn.Module):
   d_model]``. ``decode=True`` carries ``kda_state`` and ``conv_tail`` in
   the ``cache`` collection: a call resumes from what they hold, whatever
   the cursor of the model's other layers says, and leaves them as the
-  per-token recurrence over its tokens would."""
+  per-token recurrence over its tokens would: over the first ``n_valid``
+  of them (a traced int32 scalar, at least 1) where that is given, the
+  rest being padding; ``None`` = every token is real."""
   cfg: object
 
   @nn.compact
-  def __call__(self, x, decode: bool = False):
+  def __call__(self, x, decode: bool = False, n_valid=None):
     cfg = self.cfg
     h, dk, taps, rank = (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
                          cfg.kda_rank)
     width = h * dk
     b, seg, _ = x.shape
+    if seg == 1:
+      n_valid = None             # one token is real: the decode step's form
 
     def dense(feats, name):
       return tfm.Proj(cfg, (feats,), name=name)
@@ -189,7 +202,10 @@ class KDA(nn.Module):
       tail = self.variable("cache", "conv_tail", jnp.zeros,
                            (b, taps - 1, 3 * width), qkv.dtype)
       window = jnp.concatenate([tail.value, qkv], axis=1)
-      tail.value = window[:, seg:]
+      # the old tail leads the window, so a chunk with fewer real tokens
+      # than the tail is long keeps the old tail's last rows before them
+      tail.value = window[:, seg:] if n_valid is None else \
+          lax.dynamic_slice_in_dim(window, n_valid, taps - 1, axis=1)
       s0 = state.value
     else:
       window = jnp.pad(qkv, [(0, 0), (taps - 1, 0), (0, 0)])
@@ -204,6 +220,10 @@ class KDA(nn.Module):
     # log a = -exp(A_log_h) softplus(f(x) + dt_bias), one value a channel
     la = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
         (decay_in + dt_bias).reshape(b, seg, h, dk))
+    if n_valid is not None:
+      real = jnp.arange(seg) < n_valid
+      la = jnp.where(real[:, None, None], la, 0.0)
+      beta = jnp.where(real[:, None], beta, 0.0)
 
     if decode and seg == 1:
       new, o = recurrent_step(s0, q[:, 0], k[:, 0], v[:, 0],

@@ -1545,10 +1545,11 @@ class Block(nn.Module):
       return attn(y, positions, decode=decode, loop_pass=loop_pass, **kw)
 
   @nn.compact
-  def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0):
+  def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0,
+               n_valid=None):
     cfg = self.cfg
     if self.mixer != "attn" or self.ffn != "mlp":
-      return self._typed(x, positions, decode)
+      return self._typed(x, positions, decode, n_valid)
     if cfg.post_norm:
       return self._sandwich(x, positions, decode, loop_pass)
     fuse_ln = cfg.ln_matmul_impl == "fused" and not decode
@@ -1591,12 +1592,14 @@ class Block(nn.Module):
       return x
     return _constrain(x, ("batch", "sequence", "embed"), self.mesh)
 
-  def _typed(self, x, positions, decode):
+  def _typed(self, x, positions, decode, n_valid=None):
     """A layer whose mixer or feed-forward is not the default pair. Their
     modules are imported HERE, so a model of plain blocks never loads
     them; each runs under its own ``jax.named_scope``. With
     ``cfg.post_norm`` each branch's output is normed too (``ln1_out`` /
-    ``ln2_out``), as in :meth:`_sandwich`."""
+    ``ln2_out``), as in :meth:`_sandwich`. ``n_valid`` (how many of the
+    chunk's tokens are real) goes to the one mixer whose cache has no
+    position axis for a cursor to mask: "kda"."""
     cfg = self.cfg
     if cfg.act_f32:
       x = x.astype(jnp.float32)    # the residual stream is not rounded
@@ -1607,7 +1610,8 @@ class Block(nn.Module):
     if self.mixer == "kda":
       from tensorflowonspark_tpu.models import kda
       with jax.named_scope("kda"):
-        x = joins(kda.KDA(cfg, name="kda")(y, decode=decode), "ln1_out")
+        x = joins(kda.KDA(cfg, name="kda")(y, decode=decode,
+                                           n_valid=n_valid), "ln1_out")
     elif self.mixer == "mla":
       from tensorflowonspark_tpu.models import mla
       with jax.named_scope("mla"):
@@ -1694,14 +1698,22 @@ class Transformer(nn.Module):
   @nn.compact
   def __call__(self, tokens, decode: bool = False,
                return_hidden: bool = False,
-               exit_layer: Optional[int] = None, logits_at=None):
+               exit_layer: Optional[int] = None, logits_at=None,
+               n_valid=None):
     """``exit_layer`` (static) runs only the first N blocks before the
     final norm + tied projection — the SHALLOW-EXIT draft of
     self-speculative decoding (serving/slots.py): the draft is a prefix
     of the target's own layers, so it shares params and KV slabs and
     needs no second model. Untouched layers' cache entries pass through
     an ``apply`` unchanged (flax keeps unvisited collection entries), so
-    a shallow decode step advances only the visited layers' cursors."""
+    a shallow decode step advances only the visited layers' cursors.
+
+    ``n_valid`` (a traced int32 scalar, ``decode`` only; ``None`` = all):
+    how many of ``tokens [batch, seq]`` are real, the rest being a padded
+    prefill chunk's padding behind them. Layers whose cache is indexed by
+    position need no telling (the caller's cursor masks what the padding
+    wrote); a recurrent layer ("kda") leaves its state and convolution
+    tail where the last real token left them."""
     cfg = self.cfg
     if exit_layer is not None and not 1 <= exit_layer <= cfg.num_layers:
       raise ValueError("exit_layer must be in [1, num_layers=%d], got %r"
@@ -1740,7 +1752,8 @@ class Transformer(nn.Module):
     ln_f = _make_layer_norm(cfg, self.mesh, "ln_f")
     if cfg.loop_passes == 1:
       for layer in layers:
-        x = layer(x, positions, True) if decode else layer(x, positions)
+        x = layer(x, positions, True, 0, n_valid) if decode \
+            else layer(x, positions)
       if logits_at is not None:
         # one position (a traced scalar): only that row goes through the
         # final norm and the head, the result is [batch, 1, vocab] — a

@@ -13,8 +13,8 @@ Public surface:
   :func:`~tensorflowonspark_tpu.serving.slots.padded_plan` /
   :func:`~tensorflowonspark_tpu.serving.slots.chunk_plan` — the jitted
   device ops and the bucketed-prefill policy (a prompt's tail padded to
-  a bucket and masked by the cursor; the exact decomposition for models
-  with recurrent layers).
+  a bucket and masked by the cursor, in a recurrent layer by the
+  chunk's true length; the exact decomposition it replaced).
 * :class:`~tensorflowonspark_tpu.serving.scheduler.Request` /
   :class:`~tensorflowonspark_tpu.serving.scheduler.RequestQueue` — the
   host-side bookkeeping (bounded, closable admission queue).

@@ -35,12 +35,13 @@ Jitted functions owning the slab:
   real one, so under the causal mask no real position reads it, and its
   K/V land past the cursor, where decode masks them and overwrites them
   one by one before it attends them (``_set_cache_cursor``'s free
-  rollback). A model with RECURRENT layers (``cfg.recurrent_state``: a
-  KDA state and convolution tail have no position axis, so a padded
-  token would be integrated into them) keeps the exact decomposition
-  (:func:`chunk_plan` over ``EXACT_BUCKETS``). The first chunk is a
-  fresh-cache prefill (flash-eligible on TPU); later chunks ride the
-  warm-cache ``idx > 0`` dense branch of the same cond.
+  rollback). A RECURRENT layer's cache (``cfg.recurrent_state``: a KDA
+  state and convolution tail) has no position axis for the cursor to
+  mask, so the layer itself takes the true length: a padded token neither
+  decays nor writes and the tail is taken where the real tokens end
+  (``models/kda.py``), and such a model pads like every other. The first
+  chunk is a fresh-cache prefill (flash-eligible on TPU); later chunks
+  ride the warm-cache ``idx > 0`` dense branch of the same cond.
 * :meth:`SlotDecoder.insert` — scatter that row cache into the slab at a
   freed slot (``lax.dynamic_update_slice`` on every leaf) and set the
   slot's cursor to the prompt length.
@@ -123,9 +124,9 @@ DEFAULT_BUCKETS = (512, 256, 128, 64, 32, 16)
 #: 29,405 at 1024 (a divisor of 16) and 37,220 at 2048.
 ROW_CHUNK_DIVISOR = 8
 
-#: chunk sizes of the EXACT decomposition (:func:`chunk_plan`), which
-#: models with recurrent layers keep; 1 must be reachable so every length
-#: decomposes.
+#: chunk sizes of the EXACT decomposition (:func:`chunk_plan`: no padding,
+#: what every model ran before the padded plan and the equality tests still
+#: steer a decoder to); 1 must be reachable so every length decomposes.
 EXACT_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
 
 
@@ -356,15 +357,17 @@ class SlotDecoder(object):
     self.slab_model = tfm.Transformer(self.slab_cfg, mesh=mesh) \
         if self.slab_cfg is not cfg else self.model
     # THE place the prefill plan is chosen, from what the config's layer
-    # types say of the cache: every leaf indexed by position (K/V, int8
-    # K/V with scales, the MLA latent) -> the tail is padded and masked by
-    # the cursor; a recurrent state or convolution tail would integrate a
-    # padded token -> the exact decomposition, the programs it always had
-    self.padded_prefill = not cfg.recurrent_state
+    # types say of the cache. Every kind there is can mask a padded tail: a
+    # leaf indexed by position (K/V, int8 K/V with scales, the MLA latent)
+    # by the cursor, a recurrent state and convolution tail by the true
+    # length their layer takes (models/kda.py) -> the tail is padded. False
+    # (the exact decomposition, whose program takes no n_valid) is what a
+    # cache kind that can do neither would get here, and what the equality
+    # tests steer a decoder to
+    self.padded_prefill = True
     #: the chunk shapes :meth:`prefill` compiles when its caller names none:
     #: a padded plan's ladder follows the row's length
-    self.buckets = row_buckets(cfg.max_seq_len) if self.padded_prefill \
-        else EXACT_BUCKETS
+    self.buckets = row_buckets(cfg.max_seq_len)
     # jit caches retrace per chunk shape (bounded by the bucket set) /
     # once for insert+step (fixed slab shapes)
     self._prefill_fn = jax.jit(self._prefill_impl)
@@ -417,7 +420,7 @@ class SlotDecoder(object):
     logits, mutated = self.model.apply(
         {"params": params, "cache": cache}, tokens, decode=True,
         mutable=["cache"],
-        logits_at=None if n_valid is None else n_valid - 1)
+        logits_at=None if n_valid is None else n_valid - 1, n_valid=n_valid)
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     if n_valid is None:
       return mutated["cache"], nxt
@@ -431,7 +434,7 @@ class SlotDecoder(object):
     """The chunks :meth:`prefill` runs ``n`` prompt tokens as, the first
     of them at position ``offset`` of the row: ``[(shape, valid), ...]``
     (:func:`padded_plan`; the exact :func:`chunk_plan`, ``valid ==
-    shape``, for a model with recurrent layers)."""
+    shape``, for a decoder steered off the padded plan)."""
     buckets = self.buckets if buckets is None else buckets
     if self.padded_prefill:
       return padded_plan(n, self.cfg.max_seq_len - offset, buckets)
@@ -444,10 +447,10 @@ class SlotDecoder(object):
     Returns ``(row_cache, first_token)``: the warm cache (cursor at
     ``len(prompt)``) and the first generated token g1. Chunks follow
     :meth:`plan` over ``buckets`` (default: ``self.buckets``): the tail
-    padded up to a bucket and masked by the cursor, or, for a model with
-    recurrent layers, the exact decomposition. Only the LAST chunk's
-    token matters. Entries a padded chunk wrote past the cursor are
-    harmless: nothing attends them before decode overwrites them.
+    padded up to a bucket and masked by the cursor (in a recurrent layer,
+    by the chunk's true length). Only the LAST chunk's token matters.
+    Entries a padded chunk wrote past the cursor are harmless: nothing
+    attends them before decode overwrites them.
 
     ``resume=(row_cache, start)`` skips the first ``start`` prompt
     tokens: the given warm cache already holds their KV (the

@@ -110,6 +110,15 @@ def _layer_module(toy, kind):
   return toy["params"]["layer_%d" % i][kind], w, z
 
 
+def _zero_layer_cache(mod, x):
+  """The all-zeros ``cache`` collection of a mixer module for batch
+  ``x.shape[0]``."""
+  return jax.tree.map(
+      lambda s: jnp.zeros(s.shape, s.dtype),
+      jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0), x[:, :1],
+                                      decode=True)["cache"]))
+
+
 def test_kda_layer_over_calls_of_32_4_1_carries_state_and_tail(toy):
   """The module through its cache in calls of 32 + 4 + 1 tokens (chunkwise,
   chunkwise, recurrent) equals the reference's per-token recurrence over the
@@ -121,10 +130,7 @@ def test_kda_layer_over_calls_of_32_4_1_carries_state_and_tail(toy):
   want = fam._kda(x, w, z, "f32")
 
   def through_cache(pieces):
-    cache = jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype),
-        jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0), x[:, :1],
-                                        decode=True)["cache"]))
+    cache = _zero_layer_cache(mod, x)
     outs, off = [], 0
     for n in pieces:
       y, mut = step(cache, x[:, off:off + n])
@@ -148,6 +154,48 @@ def test_kda_layer_over_calls_of_32_4_1_carries_state_and_tail(toy):
   assert float(jnp.max(jnp.abs(cache["kda_state"]))) > 1e-3
 
 
+def _bucket(n):
+  return min(b for b in serving.DEFAULT_BUCKETS if b >= n)
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["fresh", "warm"])
+@pytest.mark.parametrize("n_valid", [1, 2, 3, 5, 16, 17, 63, 64, 65, 100])
+def test_kda_layer_masks_a_padded_tail_out_of_state_and_tail(toy, n_valid,
+                                                             warm):
+  """A chunk padded up to its prefill bucket (16 .. 128; the padding is
+  noise as large as the tokens) with ``n_valid`` real tokens leaves the
+  ``kda_state`` and ``conv_tail``, and gives the outputs at the real
+  positions, of the unpadded call on those tokens alone: a padded token
+  neither decays nor writes, and the tail is taken at the true length. Fewer
+  real tokens than the tail is long (1, 2 of 3) keep rows of the tail the
+  cache held, a WARM one (7 tokens went before) included; one real token is
+  checked against the recurrent form, 17 .. 100 against other blocks."""
+  params, _, _ = _layer_module(toy, "kda")
+  mod = kda.KDA(toy["cfg"])
+  seg = _bucket(n_valid)
+  x = jax.random.normal(jax.random.PRNGKey(n_valid),
+                        (2, 7 + seg, TOY["hidden_size"]))
+  apply = jax.jit(lambda c, t, n=None: mod.apply(
+      {"params": params, "cache": c}, t, decode=True, n_valid=n,
+      mutable=["cache"]))
+  cache = _zero_layer_cache(mod, x)
+  if warm:
+    cache = apply(cache, x[:, :7])[1]["cache"]
+    assert float(jnp.max(jnp.abs(cache["conv_tail"]))) > 1e-3
+  chunk = x[:, 7:]
+  want_y, want = apply(cache, chunk[:, :n_valid])
+  got_y, got = apply(cache, chunk, jnp.int32(n_valid))
+  np.testing.assert_allclose(got_y[:, :n_valid], want_y, atol=2e-5, rtol=2e-5)
+  for name in ("kda_state", "conv_tail"):
+    np.testing.assert_allclose(got["cache"][name], want["cache"][name],
+                               atol=2e-5, rtol=2e-5)
+  # the mask is what does it: unmasked, the padding is integrated
+  if n_valid < seg:
+    _, loose = apply(cache, chunk)
+    assert float(jnp.max(jnp.abs(
+        loose["cache"]["kda_state"] - want["cache"]["kda_state"]))) > 1e-3
+
+
 # -- MLA ----------------------------------------------------------------------
 
 
@@ -166,10 +214,7 @@ def test_mla_absorbed_decode_through_the_cache_equals_the_full_forward(toy):
       atol=2e-5, rtol=2e-5)
   step = jax.jit(lambda c, t: mod.apply(
       {"params": params, "cache": c}, t, decode=True, mutable=["cache"]))
-  cache = jax.tree.map(
-      lambda s: jnp.zeros(s.shape, s.dtype),
-      jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0), x[:, :1],
-                                      decode=True)["cache"]))
+  cache = _zero_layer_cache(mod, x)
   assert cache["cached_kv"].shape == (2, MAX_SEQ, 128)    # 32 + 8 -> 128
   outs, off = [], 0
   for n in (80, 16, 1, 1, 2):

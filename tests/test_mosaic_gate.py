@@ -306,6 +306,37 @@ def test_kimi_linear_step_many_keeps_every_kind_of_leaf_in_place(
   assert res["tpu_custom_calls"] >= 26 * 3 + 7, res["tpu_custom_calls"]
 
 
+@pytest.mark.parametrize("bucket,temp_max", [(512, 1.5e9), (256, 1.0e9)])
+def test_kimi_linear_padded_prefill_fits_beside_the_slab(
+    topo, monkeypatch, bucket, temp_max):
+  """The same cell's two largest prefill programs under the padded plan (a
+  chunk of 512 or 256 tokens with a traced true length: the 20 KDA layers
+  mask the padding out of their float32 state and take the convolution
+  tail where the real tokens end): each fits beside the resident 3.92 GB
+  slab, takes no more than the exact plan's 512-token program did (10.18
+  GB, 1.39 of it temporaries; 10.15 / 9.62 when written), keeps its 26
+  grouped expert products as kernels, scans the blocks of 64 (one ``while``
+  a KDA layer), and only the last REAL row reaches the 20480-wide head: no
+  [bucket, vocab] block of logits exists in the compiled program."""
+  import re
+  monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
+  from tools.mosaic_gate import TARGETS, V5E_HBM_BYTES, compiled_facts
+  fn, args = TARGETS["kimi_linear_prefill_%d" % bucket]()
+  compiled = fn.lower(*args).compile()
+  facts = compiled_facts(compiled)
+  state, tail, latent = (48 * 32 * 128 * 128 * 4, 48 * 3 * 12288 * 4,
+                         48 * 4096 * 640 * 2)
+  slab_bytes = 20 * (state + tail) + 7 * latent
+  assert facts["device_bytes"] < 10.2e9, facts
+  assert facts["device_bytes"] + slab_bytes < 0.85 * V5E_HBM_BYTES, facts
+  assert facts["memory_bytes"]["temp"] < temp_max, facts
+  assert facts["tpu_custom_calls"] >= 26 * 3, facts
+  assert facts["while_loops"] == 20, facts
+  text = compiled.as_text()
+  assert "20480" in text
+  assert not re.search(r"\[(1,)?%d,20480\]" % bucket, text)
+
+
 def test_looped_step_many_keeps_a_cache_a_pass_in_place(topo, monkeypatch):
   """The cell ouro-serve-backlog's decode step at its published widths and
   its 4 passes, 8 slots x 512, horizon 4, with 4 of the 48 layers (the

@@ -1,20 +1,22 @@
 """The padded prefill plan (serving/slots.py): a prompt's tail padded up to
 a bucket and masked by the cursor, so a prompt is one program where it can
-be, and the exact decomposition that models with recurrent layers keep.
-CPU, toy widths, real models.
+be, whatever the model's cache kinds: a recurrent layer masks the padding
+out of its state itself. CPU, toy widths, real models.
 """
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.tree_util import tree_flatten_with_path
 
 import kimi_linear_family as fam
 import trinity_family
 from tensorflowonspark_tpu.models import transformer as tfm
 from tensorflowonspark_tpu.serving import (
-    DEFAULT_BUCKETS, EXACT_BUCKETS, ServingEngine, SlotDecoder, chunk_plan,
-    padded_plan)
+    DEFAULT_BUCKETS, ServingEngine, SlotDecoder, chunk_plan, padded_plan)
 
 #: the benchmark pool's twelve prompt lengths and their weights
 #: (benchmarks/traffic/serve-backlog.json), and the plan each gets in a
@@ -219,7 +221,6 @@ def _decode_from(dec, params, cache, first, steps=6):
 
 
 def _cursors(cache):
-  from jax.tree_util import tree_flatten_with_path
   return [int(leaf) for path, leaf in tree_flatten_with_path(cache)[0]
           if getattr(path[-1], "key", None) == "index"]
 
@@ -341,7 +342,7 @@ def test_a_ladder_four_times_as_wide_prefills_the_same_row(monkeypatch):
       == _decode_from(dec, params, want_cache, want_first)
 
 
-# -- who keeps the exact plan ---------------------------------------------------
+# -- a recurrent model pads too ------------------------------------------------
 
 
 #: one period of Kimi-Linear's 3:1 pattern (KDA, KDA, KDA, MLA), layer 1
@@ -365,34 +366,100 @@ def kimi_toy():
   return cfg, fam.program_params(7, KIMI_TOY), KIMI_TOY["vocab_size"]
 
 
-def test_a_recurrent_model_keeps_the_exact_plan(kimi_toy):
-  """KDA state and convolution tail have no position axis: a padded token
-  would be integrated into them. Such a config gets the exact plan over the
-  exact plan's sizes, and its program takes no ``n_valid``."""
+def test_a_recurrent_model_takes_the_padded_plan(kimi_toy):
+  """KDA state and convolution tail have no position axis for the cursor to
+  mask; the layer takes the chunk's true length instead (models/kda.py), so
+  such a config gets the ladder and the plan everyone gets: a 23-token
+  prompt is ONE program of shape 32 with ``n_valid`` 23."""
   cfg, params, vocab = kimi_toy
   assert cfg.recurrent_state
   dec = SlotDecoder(cfg, 2)
-  assert not dec.padded_prefill and dec.buckets == EXACT_BUCKETS
-  for n in (1, 67, 95, 100):
-    assert dec.plan(n) == [(b, b) for b in chunk_plan(n, EXACT_BUCKETS)]
+  assert dec.padded_prefill and dec.buckets == DEFAULT_BUCKETS
+  for n in (1, 67, 95, 100, 127):
+    assert dec.plan(n) == padded_plan(n, 128)
+  assert dec.plan(95) == [(128, 95)] and dec.plan(3) == [(16, 3)]
   calls, inner = [], dec._prefill_fn
   dec._prefill_fn = lambda *a: (calls.append(a), inner(*a))[1]
   prompt = np.random.default_rng(3).integers(0, vocab, 23, dtype=np.int32)
   acc = dict(prefill_chunks=0, prefill_tokens=0, prefill_padded_tokens=0,
              t_prefill_sync_s=0.0)
   cache, _ = dec.prefill(params, prompt, acc=acc)
-  assert [a[2].shape[1] for a in calls] == [16, 4, 2, 1]
-  assert all(a[3] is None for a in calls)
+  assert [(a[2].shape[1], int(a[3])) for a in calls] == [(32, 23)]
   assert (acc["prefill_chunks"], acc["prefill_tokens"],
-          acc["prefill_padded_tokens"]) == (4, 23, 0)
+          acc["prefill_padded_tokens"]) == (1, 32, 9)
   assert set(_cursors(cache)) == {23}
+
+
+@pytest.fixture(scope="module")
+def kimi_decoders(kimi_toy):
+  """(the padded decoder, one steered to the exact plan, the reference's
+  weights): one jit cache each for every case below."""
+  exact = SlotDecoder(kimi_toy[0], 2)
+  exact.padded_prefill = False              # the tests' own steering
+  return SlotDecoder(kimi_toy[0], 2), exact, fam.make_weights(7, KIMI_TOY)
+
+
+def _decode_many(dec, params, cache, first, slot=1, horizons=2):
+  """``first`` and the tokens two ``step_many`` dispatches of horizon 4 emit
+  for the row in ``slot`` of a two-slot slab whose other lane is free."""
+  slabs = dec.insert(dec.init_slabs(), cache, slot)
+  live = np.arange(2) == slot
+  last, toks = np.where(live, first, 0).astype(np.int32), [first]
+  left = np.where(live, 4 * horizons, 0).astype(np.int32)
+  for _ in range(horizons):
+    slabs, out, *_ = dec.step_many(params, slabs, last, left > 0, left, 4)
+    out = np.asarray(out)
+    toks.extend(int(t) for t in out[:, slot])
+    last, left = out[-1], np.maximum(left - 4, 0)
+  return toks
+
+
+@pytest.mark.parametrize("n,buckets,plan", [
+    (n, None, None) for n in (1, 2, 3, 5, 16, 17, 63, 64, 65, 100)] + [
+        (21, (16, 8), [(16, 16), (8, 5)]),    # a whole chunk, then a padded
+        (127, None, [(128, 127)]),            # the longest prompt of the row
+        (5, (256,), [(1, 1)] * 5),    # no bucket fits the row: exact pieces
+])
+def test_recurrent_padded_prefill_equals_the_exact_plan(kimi_toy,
+                                                        kimi_decoders, n,
+                                                        buckets, plan):
+  """The recurrent model's padded program against the steered exact
+  decomposition of the same prompt: the same KDA state and convolution tail
+  (whole: they have no positions), the same latent cache on [0, n), the
+  cursor at n, the same first token; and ``step_many`` from the padded row
+  emits the tokens it emits from the exact row, each the float32 reference's
+  own first choice at its position (the full forward)."""
+  cfg, params, vocab = kimi_toy
+  dec, exact, weights = kimi_decoders
+  prompt = np.random.default_rng(n).integers(0, vocab, n, dtype=np.int32)
+  want_cache, want_first = exact.prefill(params, prompt, (64, 16, 4, 2, 1))
+  if plan is not None:
+    assert dec.plan(n, 0, buckets) == plan
+  else:
+    assert len(dec.plan(n)) == 1 and dec.plan(n)[0][1] == n
+  cache, first = dec.prefill(params, prompt, buckets)
+  assert set(_cursors(cache)) == set(_cursors(want_cache)) == {n}
+  for (path, a), b in zip(tree_flatten_with_path(cache)[0],
+                          jax.tree.leaves(want_cache)):
+    whole = getattr(path[-1], "key", None) in ("kda_state", "conv_tail")
+    np.testing.assert_allclose(
+        np.asarray(a) if whole else _written(a, n),
+        np.asarray(b) if whole else _written(b, n), atol=1e-4, rtol=1e-4)
+  assert first == want_first
+  toks = _decode_many(dec, params, cache, first)
+  assert toks == _decode_many(exact, params, want_cache, want_first)
+  if n + len(toks) <= cfg.max_seq_len:
+    seq = np.concatenate([prompt, toks]).astype(np.int32)[None]
+    z = np.asarray(fam.reference_logits(weights, seq, KIMI_TOY))[0]
+    served = z[np.arange(n - 1, seq.shape[1] - 1), seq[0, n:]]
+    assert float(np.max(z[n - 1:-1].max(axis=-1) - served)) < 1e-3
 
 
 @pytest.mark.parametrize("model", ["attention", "recurrent"])
 def test_engine_prefill_counters_add_up(model, kimi_toy):
   """``prefill_tokens`` = tokens the chunks computed, padding included;
   ``prefill_padded_tokens`` the padding among them; ``prefill_chunks`` the
-  dispatches: real tokens = the prompts', and a recurrent model pads 0."""
+  dispatches: real tokens = the prompts', whatever the model's cache kinds."""
   if model == "recurrent":
     cfg, params, vocab = kimi_toy
   else:
@@ -402,7 +469,7 @@ def test_engine_prefill_counters_add_up(model, kimi_toy):
   prompts = [rng.integers(1, vocab, n, dtype=np.int32)
              for n in (3, 16, 17, 23, 33, 40)]
   with ServingEngine(params, cfg, num_slots=2, eos_id=None) as eng:
-    assert eng.buckets == eng.decoder.buckets
+    assert eng.buckets == eng.decoder.buckets == DEFAULT_BUCKETS
     rids = [eng.submit(p, max_new_tokens=5) for p in prompts]
     for rid in rids:
       assert len(eng.result(rid, timeout=300)) > 0
@@ -414,11 +481,86 @@ def test_engine_prefill_counters_add_up(model, kimi_toy):
   assert st["prefill_tokens"] - st["prefill_padded_tokens"] \
       == sum(len(p) for p in prompts)
   if model == "recurrent":
-    assert st["prefill_padded_tokens"] == 0
-    assert st["prefill_chunks"] == sum(len(chunk_plan(len(p), EXACT_BUCKETS))
-                                       for p in prompts)
+    # max_seq_len 128: every prompt is one program
+    assert st["prefill_padded_tokens"] == (16 - 3) + (32 - 17) + (32 - 23) \
+        + (64 - 33) + (64 - 40)
+    assert st["prefill_chunks"] == len(prompts)
   else:
     # max_seq_len 48: 33 and 40 find no bucket that ends inside the row
     assert st["prefill_padded_tokens"] == (16 - 3) + (32 - 17) + (32 - 23) \
         + (16 - 1) + (16 - 8)
     assert st["prefill_chunks"] == 4 + 2 + 2
+
+
+# -- whom the true length does not touch ---------------------------------------
+
+
+class _Untold(object):
+  """A decoder's model whose ``apply`` is never told ``n_valid``: what
+  ``Transformer.__call__`` traced before it took the argument."""
+
+  def __init__(self, model):
+    self.model = model
+
+  def apply(self, *args, n_valid=None, **kw):
+    return self.model.apply(*args, **kw)
+
+
+def _sha(lowered):
+  return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+def _prefill_sha(cfg, seg, told):
+  from flax.core import meta
+  dec = SlotDecoder(cfg, 2)
+  params = jax.eval_shape(lambda: meta.unbox(dec.model.init(
+      jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+  row = jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1))
+  if not told:
+    dec.model = _Untold(dec.model)
+  i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)        # noqa: E731
+  return _sha(jax.jit(dec._prefill_impl).lower(params, row, i32(1, seg),
+                                               i32()))
+
+
+def _plain_cfg(model):
+  if model == "trinity":
+    from test_trinity import MAX_SEQ, TOY
+    return trinity_family.program_config(TOY, MAX_SEQ)
+  looped = dict(loop_passes=4, norm="rms") if model == "ouro" else {}
+  return _tiny(dtype=jnp.bfloat16, max_seq_len=512, **looped)
+
+
+@pytest.mark.parametrize("model", ["gpt2", "ouro", "trinity"])
+def test_the_true_length_changes_no_program_of_a_positional_model(model):
+  """The padded prefill program of a model whose every cache leaf has a
+  position axis (plain attention, a looped model's cache a pass, window and
+  full layers) lowers to the same text whether ``Transformer.__call__`` is
+  handed the chunk's true length or not: only a "kda" mixer reads it."""
+  cfg = _plain_cfg(model)
+  assert not cfg.recurrent_state
+  assert _prefill_sha(cfg, 32, told=True) == _prefill_sha(cfg, 32, told=False)
+
+
+def test_the_true_length_reaches_the_recurrent_chunk_and_not_the_step(
+    kimi_toy):
+  """The recurrent model: the mask IS in a padded chunk's program (told and
+  untold differ), and is in no one-token program: ``step_many``'s model
+  call lowers to the same text with ``n_valid`` handed in as without (the
+  ``seg == 1`` form takes its one token as real)."""
+  cfg = kimi_toy[0]
+  assert _prefill_sha(cfg, 32, told=True) != _prefill_sha(cfg, 32, told=False)
+  dec = SlotDecoder(cfg, 2)
+  slabs = jax.eval_shape(dec.init_slabs)
+  params = jax.eval_shape(lambda: kimi_toy[1])
+
+  def step(told):
+    def one(params, slabs, tok, n):
+      return dec.slab_model.apply(
+          {"params": params, "cache": slabs}, tok[:, None], decode=True,
+          mutable=["cache"], **(dict(n_valid=n) if told else {}))
+    return _sha(jax.jit(one).lower(
+        params, slabs, jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)))
+
+  assert step(True) == step(False)

@@ -934,6 +934,28 @@ def t_serving_decode_kimi_linear():
   return _step_many_target(dec, params, slabs)
 
 
+#: the two largest shapes of that cell's prefill ladder
+#: (``serving.slots.row_buckets(4096)``; kimi_linear_prefill checks it): its
+#: mix's twelve prompt lengths run 1.30 chunks of 512 and 0.28 of 256 a prompt
+KIMI_LINEAR_BUCKETS = (512, 256)
+
+
+def kimi_linear_prefill(bucket: int, padded: bool = True):
+  """One of the same cell's largest prefill programs: a chunk of ``bucket``
+  tokens of which a traced ``n_valid`` are real (the KDA layers mask the
+  rest out of their state and take their convolution tail at the true
+  length; the cursor masks the latent cache), into a row of 20 float32
+  states and tails and 7 latent leaves of 4096 rows. ``padded=False`` is the
+  exact plan's program of the same shape (no ``n_valid``: the [512, vocab]
+  logits block, no mask), which the cell ran until PR 37."""
+  dec, params, row, _ = kimi_linear_decoder()
+  assert dec.padded_prefill
+  assert dec.buckets[:len(KIMI_LINEAR_BUCKETS)] == KIMI_LINEAR_BUCKETS, \
+      dec.buckets
+  return dec._prefill_fn, (params, row, _i32(1, bucket),
+                           _i32() if padded else None)
+
+
 #: the benchmark cell ouro-serve-backlog: slots x max_seq
 OURO_SLOTS, OURO_MAX_SEQ = 8, 512
 
@@ -1145,6 +1167,11 @@ TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
 TARGETS.update({"trinity_prefill_%d" % b: (lambda b=b: trinity_prefill(b))
                 for b in TRINITY_BUCKETS})
+TARGETS.update({"kimi_linear_prefill_%d" % b:
+                (lambda b=b: kimi_linear_prefill(b))
+                for b in KIMI_LINEAR_BUCKETS})
+TARGETS["kimi_linear_prefill_512_exact"] = \
+    lambda: kimi_linear_prefill(512, padded=False)
 
 #: HBM of one v5e chip (Google Cloud "TPU v5e": 16 GB)
 V5E_HBM_BYTES = 16 * 1024 ** 3
