@@ -216,7 +216,8 @@ class TransformerConfig:
   # the heads (params ``attn/q_norm``, ``attn/k_norm``).
   qk_norm: bool = False
   # A sigmoid gate on the attention output: ``g = x W_gate`` (``num_heads x
-  # head_dim`` wide, from the layer's normed input, param ``attn/gate``)
+  # head_dim`` wide (the VALUES' width where ``attn_v_head_dim`` gives one),
+  # from the layer's normed input, param ``attn/gate``)
   # multiplies the heads' concatenated output elementwise before ``out``.
   attn_gate: bool = False
   # The embedding's output is multiplied by this (computed in float32,
@@ -229,6 +230,33 @@ class TransformerConfig:
   # ``kv_page_size`` (serving/slots.py); the prefill's one-row cache and
   # ``greedy_generate_kv`` keep every position and mask.
   kv_ring: bool = False
+  # Heads whose VALUES have another width than their keys: queries and keys
+  # are ``head_dim`` wide, values (and each head's output, so ``attn/out``'s
+  # input) ``attn_v_head_dim``; 0 = ``head_dim``. The decode cache's K leaf
+  # is then ``kv_heads * head_dim`` wide and its V leaf ``kv_heads *
+  # attn_v_head_dim``.
+  attn_v_head_dim: int = 0
+  # KV heads PER LAYER, beside ``layer_windows``: ``layer_kv_heads[i]`` is
+  # attention layer i's (0 or () = ``num_kv_heads``'s rule), so a window
+  # layer may keep more heads over its few rows than a full layer over all.
+  layer_kv_heads: tuple = ()
+  # The ROTATED part of a head: the first ``rope_dim`` dims of every query and
+  # key head rotate (half-split inside that part), the rest pass; 0 = all of
+  # ``head_dim``. ``layer_rope_theta[i]`` is layer i's rotary base (0 or () =
+  # ``rope_theta``).
+  rope_dim: int = 0
+  layer_rope_theta: tuple = ()
+  # A learned attention SINK in the layers named: ``layer_sink[i]`` true gives
+  # layer i one scalar a query head (param ``attn/sink``, ``[num_heads]``
+  # float32) that joins the softmax's DENOMINATOR and nothing else: ``p_ij =
+  # exp(s_ij) / (sum_j' exp(s_ij') + exp(sink_h))``, so a head's probabilities
+  # sum to less than one. Equivalently ``o = o_plain x sigmoid(lse - sink)``
+  # with ``lse`` the plain softmax's log-sum-exp: how the flash partials take
+  # it, once, after their last merge. () = no layer has one.
+  layer_sink: tuple = ()
+  # The values are multiplied by this as they are projected (before the cache
+  # holds them): 1.0 = as they come.
+  attn_value_scale: float = 1.0
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -301,11 +329,23 @@ class TransformerConfig:
               self.loop_passes, self.layer_types, self.ffn_types,
               self.moe_experts))
     for name, per_layer in (("layer_windows", self.layer_windows),
-                            ("layer_rope", self.layer_rope)):
+                            ("layer_rope", self.layer_rope),
+                            ("layer_kv_heads", self.layer_kv_heads),
+                            ("layer_rope_theta", self.layer_rope_theta),
+                            ("layer_sink", self.layer_sink)):
       if per_layer and (len(per_layer) != self.num_layers
-                        or any(int(w) < 0 for w in per_layer)):
+                        or any(w < 0 for w in per_layer)):
         raise ValueError("%s must give each of the %d layers a value >= 0, "
                          "got %r" % (name, self.num_layers, per_layer))
+    if any(hk and self.num_heads % hk for hk in self.layer_kv_heads):
+      raise ValueError("each of layer_kv_heads %r must divide num_heads (%d)"
+                       % (self.layer_kv_heads, self.num_heads))
+    if self.attn_v_head_dim < 0 or self.rope_dim < 0 or self.rope_dim % 2 \
+        or self.rope_dim > self.head_dim:
+      raise ValueError(
+          "attn_v_head_dim must be >= 0 and rope_dim an even number of a "
+          "head's %d dims (0 = all), got %d and %d"
+          % (self.head_dim, self.attn_v_head_dim, self.rope_dim))
     for asked, feature, what in (
         (self.kv_ring and self.kv_cache_dtype == "int8", "int8",
          "kv_cache_dtype='int8'"),
@@ -315,6 +355,17 @@ class TransformerConfig:
          "the paged KV pool (kv_page_size=%d)" % self.kv_page_size)):
       if asked:
         raise ValueError(ring_refusal(what, feature))
+    if self.wide_heads:
+      for asked, feature, what in (
+          (self.fuse_qkv, "fuse_qkv", "fuse_qkv"),
+          (self.ln_matmul_impl == "fused", "fuse_qkv",
+           "ln_matmul_impl='fused'"),
+          (self.kv_cache_dtype == "int8", "int8", "kv_cache_dtype='int8'"),
+          (self.kv_page_size > 0, "pages",
+           "the paged KV pool (kv_page_size=%d)" % self.kv_page_size),
+          (self.use_ring_attention, "mesh", "use_ring_attention")):
+        if asked:
+          raise ValueError(heads_refusal(self, what, feature))
     if self.kv_page_size > 0:
       if self.loop_passes > 1:
         raise ValueError(loop_refusal(
@@ -362,6 +413,20 @@ class TransformerConfig:
   @property
   def kv_heads(self) -> int:
     return self.num_kv_heads or self.num_heads
+
+  @property
+  def v_head_dim(self) -> int:
+    return self.attn_v_head_dim or self.head_dim
+
+  @property
+  def wide_heads(self) -> bool:
+    """Whether some attention layer's heads are not ONE width for queries,
+    keys and values under ONE KV head count and a plain softmax: values of
+    another width, KV heads by layer, or a sink. What takes a head to be
+    one ``head_dim`` throughout refuses such a model (``heads_refusal``)."""
+    return bool(self.v_head_dim != self.head_dim or any(self.layer_sink)
+                or any(hk and hk != self.kv_heads
+                       for hk in self.layer_kv_heads))
 
   def ring_rows(self, window: int) -> int:
     """Rows of the RING a decode cache holds for an attention ``window``, 0
@@ -422,6 +487,33 @@ _RING_REFUSALS = {
 }
 
 
+#: why each feature cannot take attention heads whose keys and values differ
+#: in width, whose KV head count differs by layer, or that carry a sink
+_HEADS_REFUSALS = {
+    "fuse_qkv": "the fused projection is ONE kernel of (num_heads + 2 x "
+                "kv_heads) heads of one head_dim (a fused projection of two "
+                "widths is not built)",
+    "int8": "an int8 cache quantizes K and V per head under one layout of "
+            "scales, untried for leaves of two widths",
+    "pages": "the pool's pages are [page, kv_heads, head_dim] for K and V "
+             "alike in every layer (pages of two widths or two head counts do "
+             "not exist yet)",
+    "mesh": "the sharded flash kernel and the ring shard q, k and v under "
+            "one spec of one head width and one KV head count (a mesh for "
+            "such heads is not built)",
+}
+
+
+def heads_refusal(cfg, what: str, feature: str) -> str:
+  """The message with which ``what`` (``feature`` its key in
+  ``_HEADS_REFUSALS``) refuses a model with ``cfg.wide_heads``."""
+  return "%s cannot take a model whose heads have keys of %d and values of " \
+      "%d dims, KV heads %r by layer and sinks %r (attn_v_head_dim, " \
+      "layer_kv_heads, layer_sink): %s" % (
+          what, cfg.head_dim, cfg.v_head_dim, cfg.layer_kv_heads or None,
+          cfg.layer_sink or None, _HEADS_REFUSALS[feature])
+
+
 def ring_refusal(what: str, feature: str) -> str:
   """The message with which ``what`` (a serving feature, named as its user
   named it; ``feature`` its key in ``_RING_REFUSALS``) refuses a model with
@@ -454,8 +546,13 @@ def exit_pass(gates, threshold: float):
   return out
 
 
-def _rotary(x, positions, theta: float = 10000.0):
-  """Rotary position embedding over the last (head_dim) axis."""
+def _rotary(x, positions, theta: float = 10000.0, dims: int = 0):
+  """Rotary position embedding over the last (head_dim) axis; with ``dims``
+  over its first ``dims`` alone (``TransformerConfig.rope_dim``), the rest
+  passing as they are."""
+  if dims and dims < x.shape[-1]:
+    return jnp.concatenate(
+        [_rotary(x[..., :dims], positions, theta), x[..., dims:]], axis=-1)
   d = x.shape[-1]
   half = d // 2
   freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
@@ -639,9 +736,11 @@ def cursor_write_tally():
 def decode_attention_tally():
   """The same for the per-slot single-token cache READS
   (``_cached_attention`` with ``lengths``, one a layer application):
-  yields ``{"reads": n, "ragged": m}``, ``m`` of the ``n`` having taken
-  ``ops.decode_attention``'s kernel, which stops at each slot's cursor."""
-  return _tally(_attn_reads, "reads", "ragged")
+  yields ``{"reads": n, "ragged": m, "ring": r}``, ``m`` of the ``n`` having
+  taken ``ops.decode_attention``'s kernel, which stops at each slot's
+  cursor, and ``r`` of the ``n`` reading a RING leaf
+  (``TransformerConfig.kv_ring``)."""
+  return _tally(_attn_reads, "reads", "ragged", "ring")
 
 
 def _cache_write(buf, val, idx, positions, mesh):
@@ -803,9 +902,45 @@ def _ring_skip(cursor, rows: int, window: int):
                     jnp.clip(cursor - window - oldest + 1, 0, rows)])
 
 
+def _sink_rescale(out, lse, sink):
+  """A plain softmax's output ``out [b, s, h, dv]`` with log-sum-exp ``lse
+  [b, h, s]`` turned into the softmax with a SINK's: ``sink [h]`` joins each
+  head's denominator, ``o = o_plain x sigmoid(lse - sink)``
+  (``TransformerConfig.layer_sink``). Applied ONCE, after the last merge of
+  partials: the sink is no key of any block."""
+  keep = jax.nn.sigmoid(lse - sink.astype(jnp.float32)[None, :, None])
+  return out.astype(jnp.float32) * jnp.swapaxes(keep, 1, 2)[..., None]
+
+
+def _full_attention_sink(q, k, v, window, sink):
+  """``ring_attention.full_attention`` (causal, the dense reference: K and V
+  at the full head count) for a layer with a sink: the same masked scores,
+  their softmax rescaled by the sink's share (``_sink_rescale``)."""
+  s, d = q.shape[1], q.shape[3]
+  scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                      k.astype(jnp.float32)) / (d ** 0.5)
+  at = jnp.arange(s)
+  keep = at[None, :] <= at[:, None]
+  if window:
+    keep = jnp.logical_and(keep, at[None, :] > at[:, None] - window)
+  scores = jnp.where(keep[None, None], scores, ra.NEG_INF)
+  out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
+                   v.astype(jnp.float32))
+  return _sink_rescale(out, jax.nn.logsumexp(scores, axis=-1),
+                       sink).astype(q.dtype)
+
+
+def _flash_attention_sink(q, k, v, window, sink, interpret):
+  """The flash FORWARD of a whole (fresh) block for a layer with a sink: the
+  block partial's ``(o, lse)`` rescaled once (``_sink_rescale``)."""
+  return _sink_rescale(*ops.flash_attention_block(
+      q, k, v, 0, 0, causal=True, interpret=interpret,
+      window=window or None), sink).astype(q.dtype)
+
+
 def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
                       k_scale=None, v_scale=None, lengths=None, mesh=None,
-                      ring: bool = False):
+                      ring: bool = False, sink=None):
   """Masked softmax attention of a query block over a KV cache AND the
   block's own keys/values, which the cache does not hold yet.
 
@@ -859,24 +994,35 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   (position ``cursor - max``), which the kernel takes as a run of rows to
   skip (``_ring_skip``) and the dense path as a mask over each row's
   position.
+
+  The VALUES may have another width than the keys (``v`` ``[b, seg, kv_heads,
+  dv]``, ``cached_v`` ``[b, max, kv_heads * dv]``; ``TransformerConfig.
+  attn_v_head_dim``): the scores contract over ``d``, the output is ``dv``
+  wide. ``sink [h]`` (``TransformerConfig.layer_sink``) joins each head's
+  softmax DENOMINATOR beside the cache's and the block's own entries, in
+  both lowerings.
   """
   b, seg, h, d = q.shape
+  dv = v.shape[-1]
   mx = cached_k.shape[1]
   if lengths is not None and seg == 1:
     ragged = ((ring or not window) and k_scale is None
               and (mesh is None or mesh.size == 1)
               and ops.decode_attention_supports(
-                  (b, h, d), q.dtype, cached_k.shape, cached_k.dtype)
+                  (b, h, d), q.dtype, cached_k.shape, cached_k.dtype,
+                  cached_v.shape)
               and ops.pallas_kernels_enabled())
     tally = getattr(_attn_reads, "open", None)
     if tally is not None:
       tally["reads"] += 1
       tally["ragged"] += ragged
+      tally["ring"] += bool(ring)
     if ragged:
       return ops.decode_attention(
           q[:, 0], k[:, 0], v[:, 0], cached_k, cached_v,
           jnp.minimum(lengths, mx) if ring else lengths,
           skip=_ring_skip(lengths, mx, window) if ring else None,
+          sink=sink,
           interpret=ops.pallas_interpret())[:, None].astype(q.dtype)
   hk = cached_k.shape[2] // d
   g = h // hk
@@ -924,23 +1070,28 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   s_own = jnp.where(causal[None, :, None, :], s_own, -1e30)
   # ONE softmax over both parts (a query always keeps its own entry)
   top = jnp.maximum(s_cache.max(axis=-1), s_own.max(axis=-1))[..., None]
+  if sink is not None:
+    sink = sink.astype(jnp.float32)[None, None, :, None]  # [1, 1, h, 1]
+    top = jnp.maximum(top, sink)
   e_cache, e_own = jnp.exp(s_cache - top), jnp.exp(s_own - top)
   total = e_cache.sum(axis=-1) + e_own.sum(axis=-1)      # [b, seg, h]
+  if sink is not None:
+    total = total + jnp.exp(sink - top)[..., 0]
   if v_scale is not None:
     e_cache = e_cache * per_head(v_scale)
   if folded:
     o = _cache_contract("bnk,bkc->bnc", e_cache.reshape(b, seg * h, mx),
                         cached_v)
-    o = (o.reshape(b, seg, h, hk, d) * own).sum(axis=3)
+    o = (o.reshape(b, seg, h, hk, dv) * own).sum(axis=3)
   else:
     o = jnp.einsum(
         "bqhgk,bkhd->bqhgd", e_cache.reshape(b, seg, hk, g, mx),
-        cached_v.reshape(b, mx, hk, d).astype(jnp.float32)).reshape(
-            b, seg, h, d)
+        cached_v.reshape(b, mx, hk, dv).astype(jnp.float32)).reshape(
+            b, seg, h, dv)
   o = o + jnp.einsum(
       "bqhgk,bkhd->bqhgd", e_own.reshape(b, seg, hk, g, seg),
       v.astype(jnp.float32),
-      precision=lax.Precision.HIGHEST).reshape(b, seg, h, d)
+      precision=lax.Precision.HIGHEST).reshape(b, seg, h, dv)
   return (o / total[..., None]).astype(q.dtype)
 
 
@@ -953,11 +1104,17 @@ _ROW_BLOCK = 2048
 class Attention(nn.Module):
   """``window`` / ``rope``: this layer's sliding window (None:
   ``cfg.attention_window``) and whether it rotates its queries and keys
-  (``TransformerConfig.layer_windows`` / ``layer_rope``)."""
+  (``TransformerConfig.layer_windows`` / ``layer_rope``); ``kv_heads`` /
+  ``theta``: its KV head count and rotary base where they are the layer's own
+  (``layer_kv_heads`` / ``layer_rope_theta``; 0 = the model's); ``sink``:
+  whether its softmax has a learned sink (``layer_sink``)."""
   cfg: TransformerConfig
   mesh: Optional[Any] = None
   window: Optional[int] = None
   rope: bool = True
+  kv_heads: int = 0
+  theta: float = 0.0
+  sink: bool = False
 
   @nn.compact
   def __call__(self, x, positions, decode: bool = False, ln_scale=None,
@@ -996,10 +1153,19 @@ class Attention(nn.Module):
       q = dense((cfg.num_heads, cfg.head_dim),
                 ("embed", heads_axis(cfg.num_heads), "kv"), "q")(x)
       # GQA: K/V carry only kv_heads heads (= num_heads unless configured)
-      k = dense((cfg.kv_heads, cfg.head_dim),
-                ("embed", heads_axis(cfg.kv_heads), "kv"), "k")(x)
-      v = dense((cfg.kv_heads, cfg.head_dim),
-                ("embed", heads_axis(cfg.kv_heads), "kv"), "v")(x)
+      hk = self.kv_heads or cfg.kv_heads
+      k = dense((hk, cfg.head_dim), ("embed", heads_axis(hk), "kv"), "k")(x)
+      v = dense((hk, cfg.v_head_dim), ("embed", heads_axis(hk), "kv"), "v")(x)
+    if cfg.attn_value_scale != 1.0:
+      v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
+    sink = self.param("sink", nn.initializers.zeros, (cfg.num_heads,),
+                      jnp.float32) if self.sink else None
+    #: heads the one-width kernels and shardings cannot take
+    wide = sink is not None or v.shape[-1] != q.shape[-1]
+    if wide and self.mesh is not None and self.mesh.size > 1:
+      raise ValueError(heads_refusal(
+          cfg, "a mesh of %d devices" % self.mesh.size, "mesh"))
+    theta = self.theta or cfg.rope_theta
 
     if cfg.qk_norm:
       q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)(
@@ -1009,15 +1175,15 @@ class Attention(nn.Module):
       if ln_scale is not None:
         raise ValueError("attn_gate reads the normed input; the ln-fused "
                          "attention hands over the raw stream")
-      gate = dense((cfg.num_heads, cfg.head_dim),
+      gate = dense((cfg.num_heads, cfg.v_head_dim),
                    ("embed", heads_axis(cfg.num_heads), "kv"), "gate")(x)
 
     if decode:
-      return self._decode_attend(q, k, v, loop_pass, win, gate)
+      return self._decode_attend(q, k, v, loop_pass, win, gate, theta, sink)
 
     if self.rope:
-      q = _rotary(q, positions, cfg.rope_theta)
-      k = _rotary(k, positions, cfg.rope_theta)
+      q = _rotary(q, positions, theta, cfg.rope_dim)
+      k = _rotary(k, positions, theta, cfg.rope_dim)
 
     interp = ops.pallas_interpret()           # forced-flash CI runs
     if cfg.use_ring_attention and self.mesh is not None:
@@ -1030,18 +1196,28 @@ class Attention(nn.Module):
                               use_flash=_flash_eligible(cfg, local_seq),
                               interpret=interp, window=win or None)
     else:
-      if _flash_eligible(cfg, q.shape[1]):
+      # heads of two widths have the flash FORWARD only (its backward
+      # refuses them by name, ops/flash_attention.py): "auto" keeps such
+      # layers, and those with a sink, on the dense path, which trains; a
+      # forced "flash" takes the forward
+      if _flash_eligible(cfg, q.shape[1]) \
+          and not (wide and cfg.attention_impl == "auto"):
         # the flash kernels consume grouped KV natively (grouped-aware
         # BlockSpec; cross-head dK/dV accumulation in the backward grid).
         # Under a >1-device mesh the kernel maps per shard: the TPU
         # compiler refuses to partition a Mosaic kernel on its own
-        if self.mesh is None or self.mesh.size == 1:
+        if sink is not None:
+          out = _flash_attention_sink(q, k, v, win, sink, interp)
+        elif self.mesh is None or self.mesh.size == 1:
           out = ops.flash_attention(q, k, v, causal=True, interpret=interp,
                                     window=win or None)
         else:
           out = ops.flash_attention_sharded(
               q, k, v, self.mesh, causal=True, interpret=interp,
               window=win or None)
+      elif sink is not None:
+        out = _full_attention_sink(q, _expand_kv(k, cfg.num_heads),
+                                   _expand_kv(v, cfg.num_heads), win, sink)
       else:
         # the dense reference attends at full head count: broadcast each
         # KV head to its query group (XLA fuses the repeat)
@@ -1062,9 +1238,12 @@ class Attention(nn.Module):
             nn.initializers.lecun_normal(), ("heads", "kv", "embed")))(out)
 
   def _decode_attend(self, q, k, v, loop_pass: int = 0, win: int = 0,
-                     gate=None):
+                     gate=None, theta: float = 10000.0, sink=None):
     """Incremental attention against a KV cache (serving path); ``win`` the
-    layer's window, ``gate`` its output gate's pre-activation.
+    layer's window, ``gate`` its output gate's pre-activation, ``theta`` its
+    rotary base, ``sink`` its softmax's sink (``[num_heads]`` or None). The K
+    leaf is as wide as the layer's KV heads times the KEYS' head width, the
+    V leaf times the VALUES' (``q``, ``k`` and ``v`` say which).
 
     Writes the new keys/values at the cache cursor, attends the query
     block against everything cached before it plus the block itself
@@ -1106,7 +1285,7 @@ class Attention(nn.Module):
     if cfg.kv_page_size > 0:
       return self._decode_attend_paged(q, k, v, win, gate)
     b, seg, h, d = q.shape
-    hk = cfg.kv_heads
+    hk, dv = v.shape[2:]
     quant = cfg.kv_cache_dtype == "int8"
     cache_dt = jnp.int8 if quant else cfg.dtype
     sfx = "_p%d" % loop_pass if cfg.loop_passes > 1 else ""
@@ -1115,7 +1294,7 @@ class Attention(nn.Module):
     cached_k = self.variable(
         "cache", "cached_k" + sfx, jnp.zeros, (b, rows, hk * d), cache_dt)
     cached_v = self.variable(
-        "cache", "cached_v" + sfx, jnp.zeros, (b, rows, hk * d), cache_dt)
+        "cache", "cached_v" + sfx, jnp.zeros, (b, rows, hk * dv), cache_dt)
     if quant:
       k_scale = self.variable("cache", "k_scale" + sfx, jnp.zeros,
                               (b, cfg.max_seq_len, hk), jnp.float32)
@@ -1138,8 +1317,8 @@ class Attention(nn.Module):
     else:
       positions = idx + jnp.broadcast_to(jnp.arange(seg), (b, seg))
     if self.rope:
-      q = _rotary(q, positions, cfg.rope_theta)
-      k = _rotary(k, positions, cfg.rope_theta)
+      q = _rotary(q, positions, theta, cfg.rope_dim)
+      k = _rotary(k, positions, theta, cfg.rope_dim)
     at = idx % ring if ring else idx       # the row the token's K/V take
 
     # tensor-parallel serving: keep the cache sharded on its folded
@@ -1178,7 +1357,7 @@ class Attention(nn.Module):
         _cache_write(cached_k.value, k_store.reshape(b, seg, hk * d), at,
                      positions, self.mesh), kv_spec, self.mesh)
     cached_v.value = _constrain(
-        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * d), at,
+        _cache_write(cached_v.value, v_store.reshape(b, seg, hk * dv), at,
                      positions, self.mesh), kv_spec, self.mesh)
     if loop_pass == cfg.loop_passes - 1:
       cursor.value = idx + seg
@@ -1190,7 +1369,7 @@ class Attention(nn.Module):
       return _cached_attention(
           q, k_own, v_own, q_pos=positions if vec else positions[:1],
           window=win, lengths=idx if vec else None, mesh=self.mesh,
-          ring=bool(ring), **was)
+          ring=bool(ring), sink=sink, **was)
 
     # PREFILL fast path: a fresh-cache multi-token segment attends only
     # within itself (causal), so the flash kernel runs it O(seg²)-tiled
@@ -1224,6 +1403,8 @@ class Attention(nn.Module):
       interp = ops.pallas_interpret()
 
       def _flash_prefill(_):
+        if sink is not None:
+          return _flash_attention_sink(q, k, v, win, sink, interp)
         if single:
           return flash_attention(q, k, v, causal=True, interpret=interp,
                                  window=win or None).astype(q.dtype)
@@ -1244,16 +1425,18 @@ class Attention(nn.Module):
         def one_block(j, partial):
           base = j * _ROW_BLOCK
           kj, vj = (lax.dynamic_slice_in_dim(
-              c.value, base, _ROW_BLOCK, axis=1).reshape(b, _ROW_BLOCK, hk, d)
-                    for c in (cached_k, cached_v))
+              c.value, base, _ROW_BLOCK, axis=1).reshape(b, _ROW_BLOCK, hk, w)
+                    for c, w in ((cached_k, d), (cached_v, dv)))
           return merge_partials(*partial, *flash_attention_block(
               q, kj, vj, idx, base, causal=True, interpret=interp,
               window=win or None))
 
-        out, _ = lax.fori_loop(
+        out, lse = lax.fori_loop(
             first, (idx + seg - 1) // _ROW_BLOCK + 1, one_block,
-            (jnp.zeros(q.shape, jnp.float32),
+            (jnp.zeros((b, seg, h, dv), jnp.float32),
              jnp.full((b, h, seg), NEG_INF, jnp.float32)))
+        if sink is not None:     # once, after the last merge
+          out = _sink_rescale(out, lse, sink)
         return out.astype(q.dtype)
 
       long_row = (single and not quant and cfg.max_seq_len > _ROW_BLOCK
@@ -1533,12 +1716,16 @@ class Block(nn.Module):
   ffn: str = "mlp"
   window: Optional[int] = None      # the attention's (Attention.window)
   rope: bool = True
+  kv_heads: int = 0                 # Attention.kv_heads / theta / sink
+  theta: float = 0.0
+  sink: bool = False
 
   def _attend(self, y, positions, decode, loop_pass: int = 0, **kw):
     """This layer's attention over the normed ``y``; a model with per-layer
     windows runs it under ``jax.named_scope`` ``attn_window`` /
     ``attn_full``."""
-    attn = Attention(self.cfg, self.mesh, self.window, self.rope, name="attn")
+    attn = Attention(self.cfg, self.mesh, self.window, self.rope,
+                     self.kv_heads, self.theta, self.sink, name="attn")
     scope = jax.named_scope("attn_window" if self.window else "attn_full") \
         if self.cfg.layer_windows else contextlib.nullcontext()
     with scope:
@@ -1748,6 +1935,10 @@ class Transformer(nn.Module):
                           cfg.ffn_types[i] if cfg.ffn_types else "mlp",
                           cfg.layer_windows[i] if cfg.layer_windows else None,
                           bool(cfg.layer_rope[i]) if cfg.layer_rope else True,
+                          cfg.layer_kv_heads[i] if cfg.layer_kv_heads else 0,
+                          cfg.layer_rope_theta[i] if cfg.layer_rope_theta
+                          else 0.0,
+                          bool(cfg.layer_sink[i]) if cfg.layer_sink else False,
                           name="layer_%d" % i))
     ln_f = _make_layer_norm(cfg, self.mesh, "ln_f")
     if cfg.loop_passes == 1:

@@ -35,6 +35,14 @@ below ``lengths`` (``min(cursor, max)``), in whatever order, but for a run of
 them that the window excludes, foremost the row the step is about to
 overwrite. ``skip [2, b]`` names that cyclic run a slot (first row, count);
 its scores are masked as the rows past the cursor are.
+
+The VALUES may have another width than the keys (``cached_v [b, max, kv_heads
+* dv]``, keys of 192 against values of 128): the scores want only the K leaf's
+minor axis in whole lanes (one contraction against the block as stored), the
+output folds onto the values' lanes. ``sink [h]`` is a learned scalar a query
+head that joins the softmax's DENOMINATOR and nothing else: it opens the
+online softmax beside the step's own key, as one more score whose value is
+zero (so it is counted once, whatever the number of blocks).
 """
 
 import functools
@@ -62,39 +70,50 @@ def _padded_heads(h: int) -> int:
   return -(-h // 16) * 16
 
 
-def _vmem_bytes(b: int, h: int, d: int, c: int) -> int:
+def _vmem_bytes(b: int, h: int, dv: int, c: int, cv: int) -> int:
   """K and V blocks double-buffered, every slot's expanded query, own key
   and value and folded output, the f32 output rows and the three-term
-  product of one slot."""
+  product of one slot (``c`` the K leaf's lanes, ``cv`` the V leaf's)."""
   hp = _padded_heads(h)
-  return (4 * BLOCK * c * 2 + b * (hp + 2 * 16) * c * 2
-          + b * hp * max(d, LANES) * 4 + (1 + 3) * hp * c * 4)
+  return (2 * BLOCK * (c + cv) * 2 + b * ((hp + 16) * c + 16 * cv) * 2
+          + b * hp * max(dv, LANES) * 4 + (1 + 3) * hp * cv * 4)
 
 
-def supports(q_shape, q_dtype, cache_shape, cache_dtype) -> bool:
+def supports(q_shape, q_dtype, cache_shape, cache_dtype,
+             v_shape=None) -> bool:
   """Whether :func:`decode_attention` can take queries ``[b, h, d]`` over
-  cache leaves ``[b, max, kv_heads * d]``: both bf16, the minor axis whole
-  lanes, the position axis whole blocks, a head's ``d`` lanes a divisor or
-  a multiple of a vreg's 128 (the output leaves the kernel folded onto
-  ``max(d, 128)`` lanes), whole query groups, and the blocks in VMEM."""
-  if len(q_shape) != 3 or len(cache_shape) != 3:
+  cache leaves ``[b, max, kv_heads * d]`` (K) and ``v_shape`` ``[b, max,
+  kv_heads * dv]`` (V; None = the K leaf's): both bf16, each minor axis whole
+  lanes, the position axis whole blocks, a VALUE head's ``dv`` lanes a
+  divisor or a multiple of a vreg's 128 (the output leaves the kernel folded
+  onto ``max(dv, 128)`` lanes; the keys' ``d`` is only contracted over), whole
+  query groups, and the blocks in VMEM."""
+  v_shape = cache_shape if v_shape is None else v_shape
+  if len(q_shape) != 3 or len(cache_shape) != 3 or len(v_shape) != 3:
     return False
   b, h, d = q_shape
   _, mx, c = cache_shape
+  cv = v_shape[2]
+  if c % d or cv % (c // d) or tuple(v_shape[:2]) != (b, mx):
+    return False
+  hk = c // d
+  dv = cv // hk
   return (jnp.dtype(q_dtype) == jnp.bfloat16
           and jnp.dtype(cache_dtype) == jnp.bfloat16
-          and cache_shape[0] == b and c % LANES == 0 and c % d == 0
-          and h % (c // d) == 0 and (d % LANES == 0 or LANES % d == 0)
+          and cache_shape[0] == b and c % LANES == 0 and cv % LANES == 0
+          and h % hk == 0 and (dv % LANES == 0 or LANES % dv == 0)
           and mx % BLOCK == 0
-          and _vmem_bytes(b, h, d, c) <= VMEM_BUDGET)
+          and _vmem_bytes(b, h, dv, c, cv) <= VMEM_BUDGET)
 
 
-def _kernel(len_ref, *refs, g, d, scale, ring):
+def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
   skip_ref = refs[0] if ring else None      # [2 * slots]: first rows, counts
+  refs = refs[1:] if ring else refs
+  sink_ref = refs[0] if sunk else None      # [hp, LANES] f32, lanes alike
   (q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
-   k_buf, v_buf, acc, sem) = refs[1:] if ring else refs
+   k_buf, v_buf, acc, sem) = refs[1:] if sunk else refs
   slots, mx = k_hbm.shape[:2]
-  hp, c = acc.shape
+  hp, c = acc.shape          # the V leaf's lanes; d a VALUE head's
   w = o_ref.shape[2]
   block = BLOCK
 
@@ -145,19 +164,33 @@ def _kernel(len_ref, *refs, g, d, scale, ring):
 
     v_own = jnp.broadcast_to(v_own_ref[i].astype(jnp.float32), (hp, c))
 
-    @pl.when(blocks == 0)
-    def _():                  # nothing cached: the token attends itself
-      o_ref[i] = fold(v_own)
-
-    @pl.when(blocks > 0)
-    def _():
-      q = q_ref[i]                                      # [hp, c] bf16
-      # the step's own key and value open the softmax: max = its score,
-      # sum = 1, output = its value
+    def opening():
+      """(q, max, sum) as the step's own key opens the softmax: max = its
+      score, sum = 1, output = its value; beside a SINK, one more score
+      whose value is zero, max = the larger of the two."""
+      q = q_ref[i]                                      # [hp, c_k] bf16
       s_own = jnp.sum(
           q.astype(jnp.float32) * k_own_ref[i].astype(jnp.float32),
           axis=-1, keepdims=True) * scale                # [hp, 1]
-      acc[...] = v_own
+      if not sunk:
+        acc[...] = v_own
+        return q, s_own, jnp.ones_like(s_own)
+      b_h = sink_ref[...][:, :1]
+      m = jnp.maximum(s_own, b_h)
+      acc[...] = v_own * jnp.exp(s_own - m)
+      return q, m, jnp.exp(s_own - m) + jnp.exp(b_h - m)
+
+    @pl.when(blocks == 0)
+    def _():                  # nothing cached: the token attends itself
+      if sunk:                # and the sink takes its share of that
+        total = opening()[2]
+        o_ref[i] = fold(acc[...] / total)
+      else:
+        o_ref[i] = fold(v_own)
+
+    @pl.when(blocks > 0)
+    def _():
+      q, m_own, l_own = opening()
 
       def one_block(j, carry):
         m, l = carry
@@ -206,8 +239,7 @@ def _kernel(len_ref, *refs, g, d, scale, ring):
         acc[...] = alpha * acc[...] + (pv[:hp] + pv[hp:2 * hp] + pv[2 * hp:])
         return m_new, l
 
-      _, total = jax.lax.fori_loop(0, blocks, one_block,
-                                   (s_own, jnp.ones_like(s_own)))
+      _, total = jax.lax.fori_loop(0, blocks, one_block, (m_own, l_own))
       o_ref[i] = fold(acc[...] / total)
 
     return first + blocks
@@ -219,7 +251,7 @@ def _kernel(len_ref, *refs, g, d, scale, ring):
 # ops/layer_norm.py's launchers state): the innermost jit names the kernel
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
-                     interpret=False):
+                     sink=None, interpret=False):
   """Softmax attention of one query token a slot over that slot's cache
   rows below ``lengths[i]`` AND the token's own key and value: ``q [b, h,
   d]`` (rotated), ``k`` / ``v`` ``[b, kv_heads, d]`` as the cache will hold
@@ -227,17 +259,22 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
   before the step's write, ``lengths [b]`` int32 (clamped into ``[0,
   max]``). Query head ``i`` reads KV head ``i // g``. ``skip [2, b]`` int32
   (a ring leaf): for each slot the first row and the count of a cyclic run
-  of rows that is not attended. Returns ``[b, h, d]`` float32. The shapes
-  must pass :func:`supports`."""
-  if not supports(q.shape, q.dtype, cached_k.shape, cached_k.dtype):
+  of rows that is not attended. ``v`` / ``cached_v`` may be ``dv`` wide a
+  head where the keys are ``d``; ``sink [h]`` float32 joins each head's
+  softmax denominator. Returns ``[b, h, dv]`` float32. The shapes must pass
+  :func:`supports`."""
+  if not supports(q.shape, q.dtype, cached_k.shape, cached_k.dtype,
+                  cached_v.shape):
     raise ValueError(
         "decode_attention takes bf16 queries [b, h, d] over bf16 leaves "
         "[b, max, kv_heads * d] of whole lanes and whole blocks of %d rows, "
-        "got %s %s over %s %s" % (BLOCK, q.dtype, q.shape, cached_k.dtype,
-                                  cached_k.shape))
-  b, h, d = q.shape
-  c = cached_k.shape[2]
-  hk = c // d
+        "got %s %s over %s %s and %s" % (BLOCK, q.dtype, q.shape,
+                                         cached_k.dtype, cached_k.shape,
+                                         cached_v.shape))
+  b, h, dk = q.shape
+  c, cv = cached_k.shape[2], cached_v.shape[2]
+  hk = c // dk
+  d = cv // hk                 # a VALUE head's lanes: what the output folds on
   g, hp, w = h // hk, _padded_heads(h), max(d, LANES)
   # head i's d values in KV head i // g's lanes, zeros elsewhere
   own = jnp.repeat(jnp.eye(hk, dtype=q.dtype), g, axis=0)[None, :, :, None]
@@ -245,27 +282,32 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
                  ((0, 0), (0, hp - h), (0, 0)))
   hbm = pl.BlockSpec(memory_space=pltpu.HBM)
   vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-  ring = skip is not None
+  ring, sunk = skip is not None, sink is not None
   scalars = (lengths.astype(jnp.int32),) + (
       (skip.astype(jnp.int32).reshape(2 * b),) if ring else ())
+  sinks = (jnp.broadcast_to(jnp.pad(
+      sink.astype(jnp.float32), (0, hp - h))[:, None], (hp, LANES)),) \
+      if sunk else ()
   o = pl.pallas_call(
-      functools.partial(_kernel, g=g, d=d, scale=1.0 / (d ** 0.5), ring=ring),
+      functools.partial(_kernel, g=g, d=d, scale=1.0 / (dk ** 0.5), ring=ring,
+                        sunk=sunk),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           # ONE grid step, the slots a loop inside it (a grid over slots
           # with the chain's state in SMEM took the same time on the chip)
           num_scalar_prefetch=len(scalars), grid=(1,),
-          in_specs=[vmem, vmem, vmem, hbm, hbm], out_specs=vmem,
+          in_specs=[vmem] * len(sinks) + [vmem, vmem, vmem, hbm, hbm],
+          out_specs=vmem,
           scratch_shapes=[pltpu.VMEM((2, BLOCK, c), cached_k.dtype),
-                          pltpu.VMEM((2, BLOCK, c), cached_v.dtype),
-                          pltpu.VMEM((hp, c), jnp.float32),
+                          pltpu.VMEM((2, BLOCK, cv), cached_v.dtype),
+                          pltpu.VMEM((hp, cv), jnp.float32),
                           pltpu.SemaphoreType.DMA((2, 2))]),
       out_shape=jax.ShapeDtypeStruct((b, hp, w), jnp.float32),
       compiler_params=pltpu.CompilerParams(
           vmem_limit_bytes=VMEM_BUDGET + (8 << 20)),
       interpret=interpret,
       name="decode_attention",
-  )(*scalars, q_bd, k.reshape(b, 1, c).astype(q.dtype),
-    v.reshape(b, 1, c).astype(q.dtype), cached_k, cached_v)
+  )(*scalars, *sinks, q_bd, k.reshape(b, 1, c).astype(q.dtype),
+    v.reshape(b, 1, cv).astype(q.dtype), cached_k, cached_v)
   o = o[:, :h]
   if w == d:
     return o

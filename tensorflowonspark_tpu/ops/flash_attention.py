@@ -23,6 +23,11 @@ accepts cotangents for both outputs (the lse cotangent folds into Δ).
 ``interpret=True`` runs the same kernels on CPU for tests. Layout:
 [batch, seq, heads, head_dim].
 
+The FORWARD takes values of another width than the keys (``v [batch, seq,
+kv_heads, dv]``: scores contract over ``head_dim``, the output is ``dv``
+wide); the backward kernels take one ``head_dim`` and refuse such heads by
+name.
+
 Grouped-query attention is native: K/V may carry ``heads / g`` heads (KV
 head j serves query heads [j·g, (j+1)·g) — the blocked convention shared
 with ``parallel.ring_attention.expand_heads``). The forward and dQ
@@ -150,7 +155,7 @@ def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
   m0 = jnp.full((blk_q, 1), NEG_INF, jnp.float32)
   l0 = jnp.zeros((blk_q, 1), jnp.float32)
-  acc0 = jnp.zeros((blk_q, q.shape[-1]), jnp.float32)
+  acc0 = jnp.zeros((blk_q, v_ref.shape[-1]), jnp.float32)   # the VALUES' width
   hi = _causal_k_hi(qi, q_base, k_base, blk_q, blk_k, n_kblocks) \
       if causal else n_kblocks
   lo = _window_k_lo(qi, q_base, k_base, blk_q, blk_k, window, n_kblocks) \
@@ -490,7 +495,7 @@ def _check_window(window, causal):
 def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
               window=None):
   b, s_q, h, d = q.shape
-  s_kv = k.shape[1]
+  s_kv, dv = k.shape[1], v.shape[3]     # values may be another width (forward)
   hk, g = _group(q, k)
   blk_q, blk_k = _blocks(s_q, s_kv, blk_q, blk_k)
   scale = 1.0 / (d ** 0.5)
@@ -508,15 +513,15 @@ def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
           in_specs=[
               pl.BlockSpec((1, blk_q, d), lambda i, j, *_: (i, j, 0)),
               pl.BlockSpec((1, s_kv, d), _kv_row_map(h, hk, g)),
-              pl.BlockSpec((1, s_kv, d), _kv_row_map(h, hk, g)),
+              pl.BlockSpec((1, s_kv, dv), _kv_row_map(h, hk, g)),
           ],
           out_specs=[
-              pl.BlockSpec((1, blk_q, d), lambda i, j, *_: (i, j, 0)),
+              pl.BlockSpec((1, blk_q, dv), lambda i, j, *_: (i, j, 0)),
               pl.BlockSpec((1, blk_q, LANES), lambda i, j, *_: (i, j, 0)),
           ],
       ),
       out_shape=[
-          jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
+          jax.ShapeDtypeStruct((b * h, s_q, dv), q.dtype),
           jax.ShapeDtypeStruct((b * h, s_q, LANES), jnp.float32),
       ],
       interpret=interpret,
@@ -564,6 +569,12 @@ def _bwd_impl(q, k, v, out, lse, g, g_lse, q_base, kv_base, causal, blk_q,
               blk_k, interpret, bwd="fused", window=None):
   window = _check_window(window, causal)
   b, s_q, h, d = q.shape
+  if v.shape[3] != d:
+    raise ValueError(
+        "the flash BACKWARD kernels take one head_dim for q, k and v, got "
+        "keys of %d and values of %d dims: only the forward is built for "
+        "such heads (train them through the dense attention)"
+        % (d, v.shape[3]))
   s_kv = k.shape[1]
   hk, grp = _group(q, k)
   scale = 1.0 / (d ** 0.5)

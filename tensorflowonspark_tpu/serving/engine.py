@@ -351,6 +351,9 @@ class ServingEngine(object):
                   # those of them by the attention kernel that stops at
                   # each slot's cursor (SlotDecoder.attn_reads)
                   "decode_attn_reads": 0, "decode_attn_reads_ragged": 0,
+                  # those of them over a RING leaf (a window layer's): what
+                  # splits a trace's %decode_attention calls by leaf kind
+                  "decode_attn_reads_ring": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -1416,9 +1419,10 @@ class ServingEngine(object):
       writes, dma = self.decoder.cursor_writes[self.horizon]
       self.stats["cursor_leaf_writes"] += writes
       self.stats["cursor_leaf_writes_dma"] += dma
-      reads, ragged = self.decoder.attn_reads[self.horizon]
+      reads, ragged, ring = self.decoder.attn_reads[self.horizon]
       self.stats["decode_attn_reads"] += reads
       self.stats["decode_attn_reads_ragged"] += ragged
+      self.stats["decode_attn_reads_ring"] += ring
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
       # the step's read returned: the rest of the region is empty time

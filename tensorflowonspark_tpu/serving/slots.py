@@ -385,7 +385,9 @@ class SlotDecoder(object):
     self.cursor_writes = {}
     #: horizon -> (per-slot single-token cache reads a step_many dispatch
     #: makes: one a layer application a step, those of them by
-    #: ops.decode_attention's kernel, which stops at each slot's cursor)
+    #: ops.decode_attention's kernel, which stops at each slot's cursor,
+    #: those of them over a RING leaf: a reader of a device trace splits the
+    #: kernel's calls by the leaf they read with it)
     self.attn_reads = {}
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
@@ -796,7 +798,8 @@ class SlotDecoder(object):
           # on the host, while tracing: the body is one step of _h
           self.cursor_writes[_h] = (_h * writes["leaves"],
                                     _h * writes["dma"])
-          self.attn_reads[_h] = (_h * reads["reads"], _h * reads["ragged"])
+          self.attn_reads[_h] = tuple(
+              _h * reads[k] for k in ("reads", "ragged", "ring"))
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:

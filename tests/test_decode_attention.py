@@ -161,7 +161,7 @@ def test_cached_attention_picks_the_lowering_from_what_it_observes(
   with tfm.decode_attention_tally() as tally:
     got = tfm._cached_attention(q, k, v, ck, cv, q_pos=lengths[:, None],
                                 lengths=lengths, **kwargs)
-  assert tally == {"reads": 1, "ragged": int(ragged)}, case
+  assert tally == {"reads": 1, "ragged": int(ragged), "ring": 0}, case
   assert got.dtype == dense.dtype and got.shape == dense.shape
   if ragged:
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -190,14 +190,14 @@ def test_other_reads_keep_the_dense_path_and_are_not_tallied(monkeypatch):
     tfm._cached_attention(
         two(q), two(k), two(v), ck, cv, lengths=lengths,
         q_pos=lengths[:, None] + jnp.arange(2))
-  assert tally == {"reads": 0, "ragged": 0}
+  assert tally == {"reads": 0, "ragged": 0, "ring": 0}
   scales = jnp.ones((2, MAX, 4), jnp.float32)
   with tfm.decode_attention_tally() as tally:
     tfm._cached_attention(q, k.astype(jnp.float32), v.astype(jnp.float32),
                           ck.astype(jnp.int8), cv.astype(jnp.int8),
                           q_pos=lengths[:, None], lengths=lengths,
                           k_scale=scales, v_scale=scales)
-  assert tally == {"reads": 1, "ragged": 0}
+  assert tally == {"reads": 1, "ragged": 0, "ring": 0}
 
 
 class TestThroughTheSlotDecoder:
@@ -237,8 +237,8 @@ class TestThroughTheSlotDecoder:
   def test_same_tokens_and_the_reads_counted(self, monkeypatch):
     toks_dense, reads_dense, _ = self._run(monkeypatch, False)
     toks_ragged, reads_ragged, writes = self._run(monkeypatch, True)
-    assert reads_dense == (2 * 4, 0)              # a layer a step x horizon
-    assert reads_ragged == (2 * 4, 2 * 4)
+    assert reads_dense == (2 * 4, 0, 0)           # a layer a step x horizon
+    assert reads_ragged == (2 * 4, 2 * 4, 0)      # none of them over a ring
     assert writes == (4 * 4, 4 * 4)               # K and V of each beside it
     np.testing.assert_array_equal(toks_ragged, toks_dense)
     assert (toks_dense[:, :, 0] != 0).all()       # slot 0 ran all 12

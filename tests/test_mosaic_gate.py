@@ -408,6 +408,62 @@ def test_later_prefill_chunk_of_a_long_row_builds_no_score_tensor(
   assert res["tpu_custom_calls"] >= 10, res["tpu_custom_calls"]
 
 
+def test_mimo_step_many_keeps_four_leaf_shapes_in_place(topo, monkeypatch):
+  """The cell mimo-serve-backlog's decode step at its real size (1 dense + 6
+  expert layers at published widths, 16 held experts a layer, 48 slots x
+  16384, horizon 4): ONE slab of two whole-context leaf pairs (48 x 16384 x
+  768 K and x 512 V) and five ring pairs (48 x 128 x 1536 and x 1024), 4.18
+  GB, is aliased whole; with 6.87 GB of weights beside it the program fits the
+  chip; no leaf of any of the FOUR shapes is copied at the program's edge or
+  comes back from fast memory; one ``while`` is left, the horizon's scan; all
+  7 attention reads a step took the kernel that stops at the cursor (keys of
+  192 against values of 128, the 5 over a ring counted as such) and all 14
+  leaf writes the DMA kernel: a read fallen to the dense contraction or a
+  leaf copied fails HERE and not in a chip run."""
+  from tools.mosaic_gate import V5E_HBM_BYTES, mimo_decoder
+  res = _gate_one("serving_decode_mimo", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 48 * (2 * 2560 * 16384 + 5 * 5120 * 128)
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.3e9, mb
+  assert res["device_bytes"] < 0.7 * V5E_HBM_BYTES, res["device_bytes"]
+  for leaf in ("bf16[48,16384,768]", "bf16[48,16384,512]",
+               "bf16[48,128,1536]", "bf16[48,128,1024]"):
+    assert leaf not in res["entry_copies"], res["entry_copies"]
+    assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] >= 14 + 7, res["tpu_custom_calls"]
+  # which lowering each read and write took: the trace-time tallies of the
+  # same program (lowering alone, seconds)
+  dec, params, _, slabs = mimo_decoder()
+  from tools.mosaic_gate import _step_many_target
+  fn, args = _step_many_target(dec, params, slabs)
+  fn.lower(*args)
+  assert dec.attn_reads[4] == (7 * 4, 7 * 4, 5 * 4)
+  assert dec.cursor_writes[4] == (14 * 4, 14 * 4)
+
+
+@pytest.mark.parametrize("bucket,temp_max", [(2048, 0.9e9), (256, 0.2e9)])
+def test_mimo_prefill_chunk_builds_no_score_tensor(topo, monkeypatch, bucket,
+                                                   temp_max):
+  """The same cell's largest prefill chunk (2048 tokens) and its 256-token
+  one into a positional row of 16384 (0.50 GB: leaves of four widths; one
+  program for a cursor at 0 and above it): the first chunk attends itself
+  and a later chunk the row in blocks through the flash FORWARD at keys of 192
+  / values of 128, so the dense branch's float32 scores of chunk x 64 x 16384
+  (8.6 GB at 2048 tokens) do not exist: temporaries stay under 0.9 GB (0.67 /
+  0.03 when written), and the program fits beside the resident slab of 48
+  slots (4.18 GB)."""
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  res = _gate_one("mimo_prefill_%d" % bucket, monkeypatch)
+  mb = res["memory_bytes"]
+  assert mb["temp"] < temp_max, mb
+  slab_48 = 48 * (2 * 2560 * 16384 + 5 * 5120 * 128)
+  assert res["device_bytes"] + slab_48 < 0.8 * V5E_HBM_BYTES, res
+  # the flash kernel a layer (first chunk) and again a layer (later chunks)
+  assert res["tpu_custom_calls"] >= 14, res["tpu_custom_calls"]
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

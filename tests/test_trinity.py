@@ -282,7 +282,7 @@ def test_ring_read_by_the_decode_kernel_equals_the_dense_path(
   monkeypatch.setattr(ops, "pallas_interpret", lambda: True)
   with tfm.decode_attention_tally() as reads:
     kernel = tfm._cached_attention(q, k, v, ck, cv, **args)
-  assert reads == {"reads": 1, "ragged": 1}
+  assert reads == {"reads": 1, "ragged": 1, "ring": 1}
   # bf16 outputs of the same f32 mathematics: one rounding apart at most
   np.testing.assert_allclose(np.asarray(kernel, np.float32),
                              np.asarray(dense, np.float32), atol=2e-2)

@@ -1046,23 +1046,29 @@ def trinity_cfg(max_seq: int = TRINITY_MAX_SEQ):
       experts_d_ff=3072, experts_shared=1, experts_scale=2.448)
 
 
-def trinity_decoder(slots: int = TRINITY_SLOTS,
-                    max_seq: int = TRINITY_MAX_SEQ):
-  """(SlotDecoder, abstract params, row cache, slab) at the cell's sizes:
-  bf16 matrices, float32 norm scales, router and router bias."""
+def _sparse_decoder(cfg, slots: int):
+  """(SlotDecoder, abstract params, row cache, slab) of a model with held
+  experts at a cell's sizes: bf16 matrices, float32 norm scales, router,
+  router bias and sinks."""
   import jax
   import jax.numpy as jnp
   from flax.core import meta
   from tensorflowonspark_tpu.models import transformer as tfm
   from tensorflowonspark_tpu.serving import slots as slots_lib
-  dec = slots_lib.SlotDecoder(trinity_cfg(max_seq), slots)
-  f32 = ("scale", "router", "router_bias")
+  dec = slots_lib.SlotDecoder(cfg, slots)
+  f32 = ("scale", "router", "router_bias", "sink")
   params = _on_chip0(jax.eval_shape(lambda: jax.tree_util.tree_map_with_path(
       lambda p, x: x if p[-1].key in f32 else x.astype(jnp.bfloat16),
       meta.unbox(dec.model.init(
           jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
   row = _on_chip0(jax.eval_shape(lambda: tfm._zero_cache(dec.model, 1)))
   return dec, params, row, _on_chip0(jax.eval_shape(dec.init_slabs))
+
+
+def trinity_decoder(slots: int = TRINITY_SLOTS,
+                    max_seq: int = TRINITY_MAX_SEQ):
+  """:func:`_sparse_decoder` at the Trinity cell's sizes."""
+  return _sparse_decoder(trinity_cfg(max_seq), slots)
 
 
 def t_serving_decode_trinity():
@@ -1097,6 +1103,68 @@ def t_trinity_insert():
   donated."""
   dec, _, row, slabs = trinity_decoder()
   return dec._insert_fn, (slabs, row, _i32())
+
+
+#: the benchmark cell mimo-serve-backlog: slots x max_seq
+MIMO_SLOTS, MIMO_MAX_SEQ = 48, 16384
+
+
+def mimo_cfg(max_seq: int = MIMO_MAX_SEQ):
+  """MiMo-V2-Flash as ``benchmarks/configs/mimo-v2-flash.json`` cuts it to
+  one chip's share (published widths, published layers 0-6: the dense layer
+  with full attention, then expert layers window x4, full, window; 16 of 256
+  experts held, 1/8 of the vocabulary), spelled out so that the gate needs
+  nothing of ``benchmarks/``; ``benchmarks/tests/test_mimo_v2_flash.py``
+  keeps the two equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  window = (False, True, True, True, True, False, True)
+  return tfm.TransformerConfig(
+      vocab_size=19072, num_layers=7, num_heads=64, attn_head_dim=192,
+      attn_v_head_dim=128, layer_kv_heads=tuple(8 if w else 4 for w in window),
+      rope_dim=64, layer_rope_theta=tuple(1e4 if w else 5e6 for w in window),
+      layer_sink=window, attn_value_scale=0.707,
+      d_model=4096, d_ff=16384, max_seq_len=max_seq, remat=False,
+      dtype=jnp.bfloat16, ffn_types=("mlp",) + ("experts",) * 6,
+      layer_windows=tuple(128 if w else 0 for w in window),
+      norm="rms", norm_eps=1e-5, mlp_act="swiglu", tie_embeddings=False,
+      experts_total=256, experts_held=16, experts_first=0, experts_top_k=8,
+      experts_d_ff=2048, experts_shared=0, experts_scale=1.0)
+
+
+def mimo_decoder(slots: int = MIMO_SLOTS, max_seq: int = MIMO_MAX_SEQ):
+  """:func:`_sparse_decoder` at the MiMo cell's sizes."""
+  return _sparse_decoder(mimo_cfg(max_seq), slots)
+
+
+def t_serving_decode_mimo():
+  """The cell mimo-serve-backlog's decode step at its real size: two
+  whole-context leaf pairs of 48 x 16384 x 768 (K) and x 512 (V) and five
+  ring pairs of 48 x 128 x 1536 and x 1024 in the one slab (4.18 GB: FOUR
+  leaf shapes), 7 attention reads a step by the kernel that stops at the
+  cursor (keys of 192 against values of 128; the rings ONE block with their
+  skipped row and a sink a head), 14 leaf writes, 16 held experts a layer,
+  horizon 4."""
+  dec, params, _, slabs = mimo_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
+#: the prefill shapes step zero compiles: the ladder's largest and 256
+MIMO_BUCKETS = (2048, 256)
+
+
+def mimo_prefill(bucket: int):
+  """One of the same cell's prefill programs: a padded chunk of ``bucket``
+  tokens into a positional row of 16384 (0.50 GB: leaves of four widths). The
+  first chunk attends itself through the flash FORWARD at keys of 192 /
+  values of 128 (a window layer's sink applied from ``(o, lse)``); at a cursor
+  above 0 (the same program: the cond's other branch) it attends the row in
+  blocks of 2048 through the same kernel, so no float32 score tensor of
+  bucket x 64 x 16384 exists."""
+  dec, params, row, _ = mimo_decoder()
+  assert set(MIMO_BUCKETS) <= set(dec.buckets) \
+      and dec.buckets[0] == MIMO_BUCKETS[0], dec.buckets
+  return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
 
 
 def t_smoke_step_many():
@@ -1162,11 +1230,14 @@ TARGETS = {
     "ouro_prefill_512": t_ouro_prefill_512,
     "serving_decode_trinity": t_serving_decode_trinity,
     "trinity_insert": t_trinity_insert,
+    "serving_decode_mimo": t_serving_decode_mimo,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
 TARGETS.update({"trinity_prefill_%d" % b: (lambda b=b: trinity_prefill(b))
                 for b in TRINITY_BUCKETS})
+TARGETS.update({"mimo_prefill_%d" % b: (lambda b=b: mimo_prefill(b))
+                for b in MIMO_BUCKETS})
 TARGETS.update({"kimi_linear_prefill_%d" % b:
                 (lambda b=b: kimi_linear_prefill(b))
                 for b in KIMI_LINEAR_BUCKETS})
