@@ -70,6 +70,13 @@ parity in tests/test_serving.py, composable with the self-healing surface):
   ``greedy_generate_kv`` would emit, so bit-parity (and crash replay,
   which leans on it) survives the speedup.
 
+A pass of the loop (``_pass``) dispatches in the order the device can run
+and reads in the order the device finishes: the decode step first, then ONE
+admission's chunks and insert queued behind it unread (for a lane that is
+free, or that the step's budget arithmetic certifies free), then the step's
+tokens, then the admission's first token — the host's dispatch calls pass
+while the chip works (docs/PERFORMANCE.md).
+
 All waits are timeout-bounded (TOS001) and the loop thread is a daemon
 (TOS007). Config knobs ride registered ``TOS_*`` env vars (TOS008):
 ``TOS_SERVE_SLOTS``, ``TOS_SERVE_BUCKETS``, ``TOS_SERVE_POLL``,
@@ -180,6 +187,21 @@ def _env_int(name: str, default: int) -> int:
 
 def _env_float(name: str, default: float) -> float:
   return float(os.environ.get(name, str(default)))
+
+
+class _Admission(object):
+  """One request between its pop and its lane: what the dispatches of its
+  prefill left for the read and the insert that follow them."""
+
+  __slots__ = ("req", "slot", "pages", "table", "shared_tokens", "row",
+               "head", "seq", "inserted")
+
+  def __init__(self, req: sched.Request, slot: int):
+    self.req, self.slot = req, slot
+    self.pages = self.table = None         # the paged pool's, in token order
+    self.shared_tokens = 0                 # of them a cached prefix's
+    self.row = self.head = self.seq = None  # SlotDecoder.prefill_chunks
+    self.inserted = False
 
 
 class ServingEngine(object):
@@ -295,7 +317,12 @@ class ServingEngine(object):
     self._thread: Optional[threading.Thread] = None
     self._loop_error: Optional[BaseException] = None
     self._draining = False
+    # the ONE request popped and not yet in a lane (queue depth, drain and
+    # _recover read it), and the request a crash is blamed on: the same
+    # while the admission's own calls run, nobody while the admission waits
+    # unread behind a decode step (_admit_behind)
     self._admitting: Optional[sched.Request] = None
+    self._blame: Optional[sched.Request] = None
     self._crash_streak = 0
     self._tok_rate = 0.0                   # EMA tokens/s over decode passes
     #: bounded record of crash recoveries: {t, duration_s, replayed,
@@ -315,11 +342,16 @@ class ServingEngine(object):
                   "prefix_evictions": 0, "spec_accepted": 0,
                   "spec_rejected": 0,
                   # device dispatches: one per _decode_once, one per
-                  # prefill chunk (SlotDecoder.prefill counts them, and
+                  # prefill chunk (SlotDecoder.prefill_chunks counts them, and
                   # beside them the tokens the chunks computed and how
                   # many of those were a padded tail's padding)
                   "decode_dispatches": 0, "prefill_chunks": 0,
                   "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                  # of prefill_chunks, those dispatched while a decode step
+                  # of the same pass was unread (_admit_behind), and of
+                  # prefills, the admissions into a lane that step was
+                  # certain to free and had not freed yet
+                  "prefill_chunks_behind_decode": 0, "admits_ahead": 0,
                   # a model with held experts (SlotDecoder.counted), summed
                   # over LIVE lanes by step_many on the device: (token,
                   # expert) assignments to experts held here, held experts
@@ -928,30 +960,42 @@ class ServingEngine(object):
   # -- engine loop ----------------------------------------------------------
 
   def _loop(self) -> None:
+    self._blame = None                     # a fresh loop blames nobody yet
     while not self._stop_evt.is_set():
       try:
-        self._ensure_slabs()               # rebuilt after a crash
-        # reap/admit/idle run every pass of an IDLE engine too: counter
-        # and annotation only, never the bounded recorder
-        with obs_spans.region("serve.reap", self.stats, "t_reap_s",
-                              record=False, queue=self._devq):
-          self._reap()
-        with obs_spans.region("serve.admit", self.stats, "t_admit_s",
-                              record=False, queue=self._devq):
-          self._admit()
-        if not any(r is not None for r in self._slots):
-          # idle: bounded block until work arrives (TOS001)
-          with obs_spans.region("serve.idle", self.stats, "t_idle_s",
-                                record=False, queue=self._devq):
-            self._vouch_idle()
-            self._queue.wait_nonempty(timeout=self._poll)
-          continue
-        self._decode_once()
-        self._crash_streak = 0             # a full decode pass = healthy
+        self._pass()
       except BaseException as e:  # noqa: BLE001 - crash-replay recovery;
         # terminal failures are forwarded to every waiter by _die
         if not self._recover(e):
           return
+
+  def _pass(self) -> None:
+    """One pass of the loop: it dispatches in the order the device can run
+    and reads in the order the device finishes. With a live lane the decode
+    step goes first and ONE admission is queued behind it
+    (:meth:`_admit_behind`); the step's tokens are read and harvested, then
+    the admission's first token (:meth:`_admit`), and further free lanes
+    are admitted one at a time. With no live lane there is no step to queue
+    behind: the pass only admits, or waits for work."""
+    self._ensure_slabs()                   # rebuilt after a crash
+    # reap/admit/idle run every pass of an IDLE engine too: counter
+    # and annotation only, never the bounded recorder
+    with obs_spans.region("serve.reap", self.stats, "t_reap_s",
+                          record=False, queue=self._devq):
+      self._reap()
+    behind = None
+    if any(r is not None for r in self._slots):
+      behind = self._decode_once()
+      self._crash_streak = 0               # a full decode pass = healthy
+    with obs_spans.region("serve.admit", self.stats, "t_admit_s",
+                          record=False, queue=self._devq):
+      self._admit(behind)
+    if not any(r is not None for r in self._slots):
+      # idle: bounded block until work arrives (TOS001)
+      with obs_spans.region("serve.idle", self.stats, "t_idle_s",
+                            record=False, queue=self._devq):
+        self._vouch_idle()
+        self._queue.wait_nonempty(timeout=self._poll)
 
   def _vouch_idle(self) -> None:
     """An idle pass whose newest dispatch nobody read (a freed lane's
@@ -994,6 +1038,7 @@ class ServingEngine(object):
       victims = [r for r in self._slots if r is not None]
       self._slots = [None] * self.num_slots
       adm, self._admitting = self._admitting, None
+    blame, self._blame = self._blame, None
     if adm is not None:
       victims.append(adm)
     self._last[:] = self.pad_id
@@ -1005,11 +1050,12 @@ class ServingEngine(object):
     self._pool = None
     self._prefix = None
     self._req_pages = {}
-    # blame: a crash during admission implicates exactly the request
-    # being prefilled; a crash mid-decode cannot be attributed and
-    # implicates every in-flight lane
+    # blame: a crash during admission's own calls implicates exactly the
+    # request being prefilled; a crash mid-decode (an admission may wait
+    # unread behind the step) cannot be attributed and implicates every
+    # in-flight lane and that admission
     for req in victims:
-      if adm is None or req is adm:
+      if blame is None or req is blame:
         req.crash_count += 1
     now = time.monotonic()
     replay: List[sched.Request] = []
@@ -1207,101 +1253,190 @@ class ServingEngine(object):
       for p in pages:
         self._pool.unref(p)
 
-  def _admit(self) -> None:
-    """Prefill queued requests into free slots (EOS-freed or virgin)."""
+  def _begin_admission(self, slot: int) -> Optional["_Admission"]:
+    """Pop the next live request for lane ``slot`` and page it in. ``None``
+    when the queue is empty, or the pool cannot host the request now (it is
+    back at the head of the queue: the next completion frees pages)."""
+    req = None
+    while req is None:
+      # on_pop marks the request mid-admission ATOMICALLY with the
+      # pop (under the queue lock): crash-safe for _recover, and
+      # drain's idle check can never observe the in-neither gap
+      req = self._queue.pop_nowait(on_pop=self._mark_admitting)
+      if req is None:
+        return None
+      now = time.monotonic()
+      if req.cancelled.is_set() or req.expired(now):
+        # the admission-time deadline check: fail WITHOUT a slot
+        self._fail_reaped(req, now)
+        self._admission_over()
+        req = None
+    adm = _Admission(req, slot)
+    if self.decoder.paged:
+      alloc = self._alloc_pages(req)
+      if alloc is None:
+        # pool exhausted: requeue AHEAD of the backlog (it was already
+        # admitted; bounds don't re-apply) and stop admitting — the
+        # next completion frees pages and admission resumes
+        self._queue.push_front(req)
+        self._admission_over()
+        return None
+      adm.pages, _, adm.shared_tokens = alloc
+      adm.table = adm.pages + [0] * (self.decoder.pages_per_slot
+                                     - len(adm.pages))
+    if req.started_at is None:
+      req.started_at = time.monotonic()
+      if self._rec is not None:
+        # the queue-wait phase of the waterfall: submit → admitted.
+        # Recorded once, at FIRST admission (a crash-replay
+        # re-admission is not a second client-visible queue wait)
+        self._rec.record_span("serve.queue", req.submitted_at,
+                              req.started_at - req.submitted_at,
+                              trace=req.trace_id, rid=req.rid)
+    return adm
+
+  def _prefill_span(self, adm: "_Admission"):
+    req = adm.req
+    return obs_spans.region("serve.prefill", self.stats, "t_prefill_s",
+                            trace=req.trace_id, queue=self._devq,
+                            record=self._rec is not None, rid=req.rid,
+                            prompt_len=len(req.prompt), slot=adm.slot,
+                            shared_tokens=adm.shared_tokens)
+
+  def _dispatch_chunks(self, adm: "_Admission") -> None:
+    """Dispatch the admission's prefill (inside its ``serve.prefill``
+    region) and read nothing."""
+    req, resume = adm.req, None
+    if adm.shared_tokens:
+      # prefix hit: rebuild the warm row cache from the shared pages
+      # and prefill only the tail — the O(prefix) work is skipped
+      self._count("prefix_hits")
+      row = self.decoder.gather_pages(self._slabs, adm.table,
+                                      adm.shared_tokens)
+      self._devq.dispatched()
+      resume = (row, adm.shared_tokens)
+    adm.row, adm.head, adm.seq = self.decoder.prefill_chunks(
+        self.params, req.prompt, self.buckets, resume=resume,
+        trace=self._chunk_trace(req), acc=self.stats, queue=self._devq)
+
+  def _chunk_trace(self, req: sched.Request) -> Optional[str]:
+    return req.trace_id if self._detail else None
+
+  def _read_first(self, adm: "_Admission") -> int:
+    return self.decoder.prefill_first(
+        adm.head, adm.seq, trace=self._chunk_trace(adm.req), acc=self.stats,
+        queue=self._devq)
+
+  def _insert(self, adm: "_Admission") -> None:
+    """Dispatch the row's insert into its lane. It needs the row and not
+    the first token, so it may be queued behind the chunks unread."""
+    with self._phase("serve.insert", "t_insert_s"):
+      if self.decoder.paged:
+        self._on_slab(lambda slabs: self.decoder.insert_pages(
+            slabs, adm.row, adm.slot, adm.table, start=adm.shared_tokens))
+      else:
+        self._on_slab(lambda slabs: self.decoder.insert(
+            slabs, adm.row, adm.slot))
+    adm.inserted = True
+
+  def _seat(self, adm: "_Admission", first: int) -> None:
+    """The admission's first token is read: emit it and hand the lane to
+    the request (a request that ends at its first token leaves the lane
+    free; a row already inserted for it is read by nobody, as a finished
+    lane's is)."""
+    req, slot = adm.req, adm.slot
+    if req.prefill_done_at is None:     # replays keep the original stamp
+      req.prefill_done_at = time.monotonic()
+    self.stats["prefills"] += 1
+    if self._obs_m is not None:
+      self._obs_m["prefills"].inc()
+    if not req.emit(first):
+      self.stats["replay_mismatches"] += 1
+    self.stats["emitted_tokens"] += 1
+    if self._finished(req, first):
+      self._complete(req)
+      if adm.pages is not None:  # never inserted: nothing else holds them
+        for p in adm.pages:
+          self._pool.unref(p)
+      self._admission_over()
+      return                     # the lane stays free for the next request
+    if self._slots[slot] is not None:
+      raise RuntimeError(
+          "lane %d was taken ahead for request %d, but the dispatch it was "
+          "queued behind did not free it" % (slot, req.rid))
+    if not adm.inserted:
+      self._insert(adm)
+    if self.decoder.paged:
+      if self._prefix is not None:
+        # the prompt's full pages become shareable: the cache takes
+        # its own ref on each newly cached page, outliving this
+        # request; then the LRU budget is enforced
+        for p in self._prefix.register(req.prompt, adm.pages):
+          self._pool.ref(p)
+        over = self._prefix.over_budget
+        if over:
+          self._evict_prefix(over)
+      self._req_pages[req.rid] = adm.pages
+    with self._lock:
+      self._slots[slot] = req
+    self._admission_over()
+    self._last[slot] = first
+
+  def _admit(self, behind: Optional["_Admission"] = None) -> None:
+    """Read the first token of ``behind`` (the admission
+    :meth:`_admit_behind` queued behind this pass's decode step, harvested
+    by now) and seat it; then prefill queued requests into the lanes still
+    free (EOS-freed or virgin), one at a time: dispatch, read, insert."""
+    if behind is not None:
+      self._blame = behind.req   # from here on a fault is the admission's
+      self._seat(behind, self._read_first(behind))
     for slot in range(self.num_slots):
       if self._slots[slot] is not None:
         continue
-      req = None
-      while req is None:
-        # on_pop marks the request mid-admission ATOMICALLY with the
-        # pop (under the queue lock): crash-safe for _recover, and
-        # drain's idle check can never observe the in-neither gap
-        req = self._queue.pop_nowait(on_pop=self._mark_admitting)
-        if req is None:
-          return
-        now = time.monotonic()
-        if req.cancelled.is_set() or req.expired(now):
-          # the admission-time deadline check: fail WITHOUT a slot
-          self._fail_reaped(req, now)
-          self._admitting = None
-          req = None
-      pages, shared_tokens, table = None, 0, None
-      if self.decoder.paged:
-        alloc = self._alloc_pages(req)
-        if alloc is None:
-          # pool exhausted: requeue AHEAD of the backlog (it was already
-          # admitted; bounds don't re-apply) and stop admitting — the
-          # next completion frees pages and admission resumes
-          self._queue.push_front(req)
-          self._admitting = None
-          return
-        pages, _, shared_tokens = alloc
-        table = pages + [0] * (self.decoder.pages_per_slot - len(pages))
-      if req.started_at is None:
-        req.started_at = time.monotonic()
-        if self._rec is not None:
-          # the queue-wait phase of the waterfall: submit → admitted.
-          # Recorded once, at FIRST admission (a crash-replay
-          # re-admission is not a second client-visible queue wait)
-          self._rec.record_span("serve.queue", req.submitted_at,
-                                req.started_at - req.submitted_at,
-                                trace=req.trace_id, rid=req.rid)
-      with obs_spans.region("serve.prefill", self.stats, "t_prefill_s",
-                            trace=req.trace_id, queue=self._devq,
-                            record=self._rec is not None, rid=req.rid,
-                            prompt_len=len(req.prompt), slot=slot,
-                            shared_tokens=shared_tokens):
-        resume = None
-        if shared_tokens:
-          # prefix hit: rebuild the warm row cache from the shared pages
-          # and prefill only the tail — the O(prefix) work is skipped
-          self._count("prefix_hits")
-          row = self.decoder.gather_pages(self._slabs, table,
-                                          shared_tokens)
-          self._devq.dispatched()
-          resume = (row, shared_tokens)
-        row_cache, first = self.decoder.prefill(
-            self.params, req.prompt, self.buckets, resume=resume,
-            trace=req.trace_id if self._detail else None,
-            acc=self.stats, queue=self._devq)
-      if req.prefill_done_at is None:   # replays keep the original stamp
-        req.prefill_done_at = time.monotonic()
-      self.stats["prefills"] += 1
-      if self._obs_m is not None:
-        self._obs_m["prefills"].inc()
-      if not req.emit(first):
-        self.stats["replay_mismatches"] += 1
-      self.stats["emitted_tokens"] += 1
-      if self._finished(req, first):
-        self._complete(req)
-        if pages is not None:    # never inserted: nothing else holds them
-          for p in pages:
-            self._pool.unref(p)
-        self._admitting = None
-        continue                 # slot stays free for the next request
-      with self._phase("serve.insert", "t_insert_s"):
-        if self.decoder.paged:
-          self._on_slab(lambda slabs: self.decoder.insert_pages(
-              slabs, row_cache, slot, table, start=shared_tokens))
-        else:
-          self._on_slab(lambda slabs: self.decoder.insert(
-              slabs, row_cache, slot))
-      if self.decoder.paged:
-        if self._prefix is not None:
-          # the prompt's full pages become shareable: the cache takes
-          # its own ref on each newly cached page, outliving this
-          # request; then the LRU budget is enforced
-          for p in self._prefix.register(req.prompt, pages):
-            self._pool.ref(p)
-          over = self._prefix.over_budget
-          if over:
-            self._evict_prefix(over)
-        self._req_pages[req.rid] = pages
-      with self._lock:
-        self._slots[slot] = req
-      self._admitting = None
-      self._last[slot] = first
+      adm = self._begin_admission(slot)
+      if adm is None:
+        return
+      with self._prefill_span(adm):
+        self._dispatch_chunks(adm)
+        first = self._read_first(adm)
+      self._seat(adm, first)
+
+  def _admit_behind(self, remaining, certain: int) -> Optional["_Admission"]:
+    """Queue ONE admission behind the decode dispatch that was just made
+    and read nothing: the host's chunk and insert calls pass while the
+    device runs the step, and on the in-order device they run after it.
+
+    The lane is one that is free, or one the dispatch is CERTAIN to leave
+    free: its request's budget (``remaining``, as the dispatch was given
+    it) ends within ``certain`` tokens, the least the dispatch emits for a
+    live lane, whatever EOS does. An inactive lane of the step writes only
+    where the next insert overwrites (``SlotDecoder._one_step``), so the
+    row may be inserted behind the step before the old request has been
+    harvested; ``_slots`` keeps the old request until the harvest frees
+    the lane, and :meth:`_admit` seats the new one after it. The paged
+    pool releases pages and resets page tables in the harvest, so it takes
+    a free lane only and inserts after its read. At most one admission is
+    ever in flight unread (``_admitting``)."""
+    with obs_spans.region("serve.admit", self.stats, "t_admit_s",
+                          record=False, queue=self._devq):
+      slot = next((i for i, r in enumerate(self._slots) if r is None), None)
+      ahead = slot is None and not self.decoder.paged
+      if ahead:
+        slot = next((i for i in range(self.num_slots)
+                     if remaining[i] <= certain), None)
+      adm = None if slot is None else self._begin_admission(slot)
+      if adm is None:
+        return None
+      chunks = self.stats["prefill_chunks"]
+      with self._prefill_span(adm):
+        self._dispatch_chunks(adm)
+      self.stats["prefill_chunks_behind_decode"] += \
+          self.stats["prefill_chunks"] - chunks
+      self.stats["admits_ahead"] += ahead
+      if not self.decoder.paged:
+        self._insert(adm)
+      self._blame = None         # a fault in the step's read is nobody's
+    return adm
 
   def _phase(self, name: str, key: str):
     """A per-dispatch phase of the loop thread: counter and annotation
@@ -1310,7 +1445,10 @@ class ServingEngine(object):
                             queue=self._devq)
 
   def _mark_admitting(self, req: sched.Request) -> None:
-    self._admitting = req
+    self._admitting = self._blame = req
+
+  def _admission_over(self) -> None:
+    self._admitting = self._blame = None
 
   def _finished(self, req: sched.Request, token: int) -> bool:
     if self.eos_id is not None and int(token) == self.eos_id:
@@ -1337,8 +1475,10 @@ class ServingEngine(object):
       if req.queue_wait is not None:
         q["queue_wait_ms"].observe(req.queue_wait * 1e3)
 
-  def _decode_once(self) -> None:
-    """One fused ``horizon``-step dispatch + host-side harvest.
+  def _decode_once(self) -> Optional[_Admission]:
+    """One fused ``horizon``-step dispatch + host-side harvest, with one
+    admission queued behind the dispatch where a lane allows it: returned
+    unread, for :meth:`_admit` to seat.
 
     The device scan carries each lane's EOS/budget done-mask; the host
     replays the identical stop rule over the returned ``[horizon,
@@ -1355,9 +1495,9 @@ class ServingEngine(object):
              for r in self._slots], np.int32)
         dec.attrs["active"] = int(active.sum())
       if self.spec_depth > 0:
-        steps, lanes = self._decode_spec(active, remaining)
+        steps, lanes, behind = self._decode_spec(active, remaining)
       else:
-        steps, lanes = self._decode_plain(active, remaining)
+        steps, lanes, behind = self._decode_plain(active, remaining)
     self.stats["decode_dispatches"] += 1
     t0, dt = dec.t0, dec.dur         # the one clock reading of the pass
     emitted = self.stats["emitted_tokens"] - tokens_before
@@ -1387,6 +1527,7 @@ class ServingEngine(object):
       if self._pool is not None:
         m["kv_pages_in_use"].set(self._pool.in_use)
         m["kv_pages_free"].set(self._pool.free_pages)
+    return behind
 
   def _harvest(self, req, tok: int, slot: int, freed: List[int]) -> bool:
     """Record one emitted token; on the request's stop, free its slot
@@ -1408,11 +1549,13 @@ class ServingEngine(object):
 
   def _decode_plain(self, active, remaining):
     """The non-speculative fused horizon (SlotDecoder.step_many).
-    Returns ``(steps, lanes)`` — ``lanes`` is the slot-attributed
+    Returns ``(steps, lanes, behind)`` — ``lanes`` is the slot-attributed
     ``(slot, trace_id, emitted)`` list for the per-request decode spans,
-    built only while the recorder is live (zero work otherwise). The
-    three phases of a dispatch are regions: the call returning, the wait
-    for the token matrix, and the host's harvest of it."""
+    built only while the recorder is live (zero work otherwise);
+    ``behind`` the admission :meth:`_admit_behind` queued behind the step,
+    or ``None``. The three phases of a dispatch are regions: the call
+    returning, the wait for the token matrix, and the host's harvest of
+    it."""
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
       out = self._on_slab(lambda slabs: self.decoder.step_many(
           self.params, slabs, self._last, active, remaining, self.horizon))
@@ -1423,10 +1566,14 @@ class ServingEngine(object):
       self.stats["decode_attn_reads"] += reads
       self.stats["decode_attn_reads_ragged"] += ragged
       self.stats["decode_attn_reads_ring"] += ring
+    step_seq = self._slab_seq
+    # a budget that ends within the horizon ends inside the scan
+    behind = self._admit_behind(remaining, self.horizon)
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(out[1])                   # [horizon, num_slots]
-      # the step's read returned: the rest of the region is empty time
-      self._devq.drained(self._slab_seq)
+      # the step's read returned: the rest of the region is empty time,
+      # unless the admission's programs are queued behind the step
+      self._devq.drained(step_seq)
       if self.decoder.counted:       # the step's own sums, beside the tokens
         for name, value in out[4].items():
           self.stats[_STEP_COUNTERS[name]] += int(np.asarray(value))
@@ -1449,7 +1596,7 @@ class ServingEngine(object):
         if self._detail:
           lanes.append((slot, req.trace_id, emitted))
       self._reset_freed(freed)
-    return self.horizon, lanes
+    return self.horizon, lanes, behind
 
   def _decode_spec(self, active, remaining):
     """The self-speculative fused dispatch (SlotDecoder.step_spec).
@@ -1459,17 +1606,22 @@ class ServingEngine(object):
     stop rule per token (the step_many contract), so the two views
     cannot diverge. Accepted/rejected draft verdicts feed the
     ``spec_accepted``/``spec_rejected`` counters. Returns ``(steps,
-    lanes)`` like :meth:`_decode_plain`.
+    lanes, behind)`` like :meth:`_decode_plain`.
     """
     k, rounds = self.spec_depth, self._spec_rounds
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
       _, toks, counts, acc, rej, _, _ = self._on_slab(
           lambda slabs: self.decoder.step_spec(
               self.params, slabs, self._last, active, remaining, rounds))
+    step_seq = self._slab_seq
+    # every round emits at least one token a live lane: a budget of at
+    # most ``rounds`` ends inside the dispatch
+    behind = self._admit_behind(remaining, rounds)
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(toks)          # [rounds, spec_depth, num_slots]
-      # the step's read returned: the rest of the region is empty time
-      self._devq.drained(self._slab_seq)
+      # the step's read returned: the rest of the region is empty time,
+      # unless the admission's programs are queued behind the step
+      self._devq.drained(step_seq)
       counts = np.asarray(counts)      # [rounds, num_slots]
       n_acc, n_rej = int(np.asarray(acc).sum()), int(np.asarray(rej).sum())
     lanes: List[tuple] = []
@@ -1501,4 +1653,4 @@ class ServingEngine(object):
         if self._detail:
           lanes.append((slot, req.trace_id, emitted))
       self._reset_freed(freed)
-    return rounds * k, lanes
+    return rounds * k, lanes, behind

@@ -444,10 +444,24 @@ class SlotDecoder(object):
 
   def prefill(self, params, prompt, buckets=None, resume=None, trace=None,
               acc=None, queue=None) -> Tuple[object, int]:
-    """Prefill one prompt into a fresh [1, ...] row cache.
+    """Prefill one prompt into a fresh [1, ...] row cache and wait for it:
+    :meth:`prefill_chunks`, then :meth:`prefill_first` of what it returns.
+    ``(row_cache, first_token)``."""
+    cache, head, seq = self.prefill_chunks(params, prompt, buckets, resume,
+                                           trace, acc, queue)
+    return cache, self.prefill_first(head, seq, trace, acc, queue)
 
-    Returns ``(row_cache, first_token)``: the warm cache (cursor at
-    ``len(prompt)``) and the first generated token g1. Chunks follow
+  def prefill_chunks(self, params, prompt, buckets=None, resume=None,
+                     trace=None, acc=None, queue=None):
+    """Dispatch one prompt's prefill into a fresh [1, ...] row cache and
+    read nothing.
+
+    Returns ``(row_cache, head, seq)``: the warm cache (cursor at
+    ``len(prompt)``), the last chunk's ``[1]`` token array still on the
+    device (the first generated token g1: :meth:`prefill_first` reads it)
+    and that chunk's number in ``queue`` (``None`` without one). The row
+    needs no read to be inserted: a caller may queue the ``insert`` and
+    whatever else behind the chunks and read the token later. Chunks follow
     :meth:`plan` over ``buckets`` (default: ``self.buckets``): the tail
     padded up to a bucket and masked by the cursor (in a recurrent layer,
     by the chunk's true length). Only the LAST chunk's token matters.
@@ -462,20 +476,16 @@ class SlotDecoder(object):
     must run through the model to yield g1).
 
     Each chunk dispatch (the host's slice-and-pad of its tokens included)
-    is a ``serve.prefill.chunk`` region and the wait for the last chunk a
-    ``serve.prefill.sync`` region (``obs.spans.region``): trace
-    annotations always; recorder spans when ``trace`` (a request trace
+    is a ``serve.prefill.chunk`` region (``obs.spans.region``): a trace
+    annotation always; a recorder span when ``trace`` (a request trace
     id) is given — the chunk-plan phase of the request waterfall. Chunk
     dispatches are async, so a chunk region measures dispatch-to-dispatch
     time (the benchmark's ``prefill_dispatch_ms.backlog`` is its mean, d
-    ``t_prefill_s`` / d ``prefill_chunks``); the enclosing
-    ``serve.prefill`` span carries the true synced total. ``acc`` (the
-    engine's ``stats``) counts the dispatches in ``prefill_chunks``, the
-    tokens they computed in ``prefill_tokens``, the padding among them in
-    ``prefill_padded_tokens`` and the wait in ``t_prefill_sync_s``.
-    ``queue`` (the calling thread's ``obs.spans.DeviceQueue``, with ``acc``)
-    is told of each chunk's dispatch and of the end of the wait, and the
-    sync region's tail after the read goes to ``empty_prefill_sync_s``.
+    ``t_prefill_s`` / d ``prefill_chunks``). ``acc`` (the engine's
+    ``stats``) counts the dispatches in ``prefill_chunks``, the tokens they
+    computed in ``prefill_tokens`` and the padding among them in
+    ``prefill_padded_tokens``. ``queue`` (the calling thread's
+    ``obs.spans.DeviceQueue``) is told of each chunk's dispatch.
     """
     plen = len(prompt)
     if plen + 1 > self.cfg.max_seq_len:
@@ -521,16 +531,27 @@ class SlotDecoder(object):
             np.int32(n) if self.padded_prefill else None)
         seq = None if queue is None else queue.dispatched()
       off += n
+    return cache, nxt, seq
+
+  def prefill_first(self, head, seq=None, trace=None, acc=None,
+                    queue=None) -> int:
+    """Wait for a prefill's last chunk and return its token, the first
+    generated one: ``head`` and ``seq`` as :meth:`prefill_chunks` returned
+    them. The wait is a ``serve.prefill.sync`` region (``acc``:
+    ``t_prefill_sync_s``); ``queue`` is told of its end, and the region's
+    tail after the read goes to ``empty_prefill_sync_s`` where the chunk
+    is still the thread's newest dispatch (a program queued behind it,
+    an ``insert``, may be running yet: the read then vouches for
+    nothing)."""
     with obs_spans.region("serve.prefill.sync", acc, "t_prefill_sync_s",
                           trace=trace, record=trace is not None,
                           queue=queue):
-      # waits for the last chunk; fetched whole, because indexing the
-      # device array would be two more eager programs (slice, squeeze)
-      head = np.asarray(nxt)
+      # fetched whole, because indexing the device array would be two more
+      # eager programs (slice, squeeze)
+      head = np.asarray(head)
       if queue is not None:
         queue.drained(seq)           # the wait is over: the rest is empty
-      first = int(head[0])
-    return cache, first
+      return int(head[0])
 
   # -- slot insert ----------------------------------------------------------
 

@@ -276,6 +276,8 @@ def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
   assert delta["t_decode_dispatch_s"] > 0 and delta["t_prefill_s"] > 0
   assert sum(delta.values()) == pytest.approx(lives[1], rel=0.02)
   assert sum(delta.values()) <= lives[1]
+  # under the pass's order: admissions went behind a running decode step
+  assert eng.stats["admits_ahead"] - base["admits_ahead"] > 0
 
 
 @pytest.mark.parametrize("stack", ["plain", "spec", "paged"])
@@ -307,9 +309,11 @@ def test_empty_counters_hold_their_invariants_under_a_watcher(toy, stack):
       time.sleep(0.002)
 
   watcher = threading.Thread(target=watch, daemon=True)
+  # both readings with the loop stopped: inside the `with`, an idle region
+  # already open would charge its seconds from before `known0` at its exit
+  base = dict(eng.stats)
+  known0 = eng._devq.empty_at(time.monotonic())
   with eng:                             # warm: a fresh loop thread
-    base = dict(eng.stats)
-    known0 = eng._devq.empty_at(time.monotonic())
     watcher.start()
     _serve(eng, prompts, 10)
     # the engine empties: the first idle pass may still find a freed lane's
@@ -337,6 +341,9 @@ def test_empty_counters_hold_their_invariants_under_a_watcher(toy, stack):
   assert empty == pytest.approx(known, rel=0.02)
   # something was counted where the loop dispatches into a drained device
   assert delta["empty_prefill_s"] > 0 and delta["empty_decode_harvest_s"] > 0
+  # under the pass's order: the contiguous slab took lanes ahead of a
+  # running step, the paged pool queued chunks behind one or none
+  assert (st["admits_ahead"] - base["admits_ahead"] > 0) == (stack != "paged")
   # the waits: all but the tail after the read returned is NOT empty
   for key in ("decode_fetch", "prefill_sync"):
     assert delta["empty_%s_s" % key] < 0.5 * delta["t_%s_s" % key], key
@@ -547,7 +554,10 @@ def test_profiler_session_alone_shows_the_nested_phases(toy, tmp_path):
   for child, parent in (("serve.decode.harvest", "serve.decode"),
                         ("serve.decode.dispatch", "serve.decode"),
                         ("serve.prefill.chunk", "serve.prefill"),
-                        ("serve.prefill.sync", "serve.prefill"),
+                        # an admission queued behind a decode step reads
+                        # its first token after the harvest, outside its
+                        # serve.prefill but inside the pass's serve.admit
+                        ("serve.prefill.sync", "serve.admit"),
                         ("serve.prefill", "serve.admit"),
                         ("serve.insert", "serve.admit")):
     assert inside(child, parent), (child, parent)
