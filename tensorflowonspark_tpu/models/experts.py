@@ -1,7 +1,9 @@
 """Sparse experts as ONE CHIP'S SHARE of an expert-parallel layer, as one
 feed-forward of ``models.transformer.Block`` (``ffn_types[i] ==
 "experts"``): sigmoid router over the published ``experts_total``, top
-``experts_top_k`` a token with renormalised weights times
+``experts_top_k`` a token (inside its ``experts_groups_kept`` best of
+``experts_groups`` groups, where the router has that limit) with renormalised
+weights times
 ``experts_scale``, gated SiLU experts of ``experts_d_ff``, plus
 ``experts_shared`` shared experts every token passes (dense).
 
@@ -12,8 +14,11 @@ without its exchange, and nothing here stands in for it.
 
 Counters: under ``mutable=["counters"]`` each call sows, per token, how many
 of its assignments went to a held expert (``held [T]``) and which held
-experts it chose (``hit [T, held]``); ``serving.slots`` sums them over live
-lanes. Without the collection the sow is a no-op.
+experts it chose (``hit [T, held]``) and, where the router has a group limit
+(``TransformerConfig.experts_groups``), whether the groups it kept include one
+that a held expert lies in (``group [T]``: a token that kept none of them can
+have no assignment here); ``serving.slots`` sums them over live lanes.
+Without the collection the sow is a no-op.
 """
 
 import flax.linen as nn
@@ -52,8 +57,9 @@ class HeldExperts(nn.Module):
         for name, shape in (("gate", (held, d, f)), ("up", (held, d, f)),
                             ("down", (held, f, d))))
     flat = x.reshape(-1, d)       # the router sees it unrounded
-    experts, weights = ep.route_sigmoid_topk(
-        flat, router, bias, cfg.experts_top_k, cfg.experts_scale)
+    experts, weights, *kept = ep.route_sigmoid_topk(
+        flat, router, bias, cfg.experts_top_k, cfg.experts_scale,
+        cfg.experts_groups, cfg.experts_groups_kept)
     split = None
     if cfg.act_f32 and gate.dtype == jnp.bfloat16:
       split = tfm._bf16_terms
@@ -63,6 +69,12 @@ class HeldExperts(nn.Module):
     local = jnp.where(hit, experts - cfg.experts_first, held)
     self.sow("counters", "hit",
              jnp.any(local[..., None] == jnp.arange(held), axis=1))
+    if kept:
+      # whether the token kept a group that some held expert lies in
+      per = cfg.experts_total // cfg.experts_groups
+      lo, hi = cfg.experts_first // per, \
+          (cfg.experts_first + held - 1) // per + 1
+      self.sow("counters", "group", jnp.any(kept[0][:, lo:hi], axis=1))
     y = y.reshape(x.shape)
     if not cfg.act_f32:
       y = y.astype(cfg.dtype)

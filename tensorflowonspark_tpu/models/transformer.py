@@ -16,12 +16,14 @@ Logical axis names map to mesh axes through
 import contextlib
 import dataclasses
 import functools
+import math
 import threading
 from dataclasses import dataclass
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 import flax.linen as nn
 
@@ -129,8 +131,8 @@ class TransformerConfig:
   embed_lookup: str = "gather"
   # Per-layer block spec (ROADMAP R1). ``layer_types[i]`` names layer i's
   # token mixer: "attn" (the attention above), "kda" (gated delta-rule
-  # linear attention, models/kda.py) or "mla" (latent attention without
-  # rotary positions, models/mla.py); ``ffn_types[i]`` its feed-forward:
+  # linear attention, models/kda.py) or "mla" (latent attention,
+  # models/mla.py: ``mla_*`` below); ``ffn_types[i]`` its feed-forward:
   # "mlp" or "experts" (held sparse experts, models/experts.py). () keeps
   # every layer "attn" and the moe_experts/moe_every rule: GPT-2 is that
   # one spec. The new layers' modules are imported only when asked for.
@@ -257,6 +259,34 @@ class TransformerConfig:
   # The values are multiplied by this as they are projected (before the cache
   # holds them): 1.0 = as they come.
   attn_value_scale: float = 1.0
+  # Latent attention's QUERY through a rank: ``q = W_qb RMSNorm(W_qa x)``
+  # (params ``mla/q_a``, ``mla/q_norm``, ``mla/q_b``) with ``mla_q_rank`` > 0;
+  # 0 = one full map (``mla/q``).
+  mla_q_rank: int = 0
+  # Whether a latent layer ROTATES: the last ``mla_rope_dim`` dims of every
+  # query head and the latent's shared key part, at the token's position
+  # (half-split inside that part). The cache then holds the key part AS
+  # ROTATED, so the absorbed decode reads it as it reads an unrotated one.
+  mla_rope: bool = False
+  # YaRN beside ``rope_theta``: with ``rope_yarn_factor`` > 0 the frequencies
+  # of the rotated dims are blended between ``f_i`` and ``f_i / factor`` by a
+  # ramp over the dims whose wavelength passes ``rope_yarn_original``
+  # positions between ``beta_fast`` and ``beta_slow`` times
+  # (:func:`yarn_frequencies`), and the softmax scale is multiplied by ``(0.1
+  # x mscale_all_dim x ln(factor) + 1)^2`` (:func:`yarn_softmax_factor`; 0 =
+  # not at all). 0 = ``rope_theta``'s frequencies as they are. Built for the
+  # rotated part of latent layers (``mla_rope``).
+  rope_yarn_factor: float = 0.0
+  rope_yarn_original: int = 0
+  rope_yarn_beta_fast: float = 32.0
+  rope_yarn_beta_slow: float = 1.0
+  rope_yarn_mscale_all_dim: float = 0.0
+  # The router's GROUP limit (held experts): the ``experts_total`` experts lie
+  # in ``experts_groups`` groups of consecutive experts; a token may choose
+  # inside its ``experts_groups_kept`` best groups only, a group's score the
+  # sum of its two largest biased scores. 0 = no limit.
+  experts_groups: int = 0
+  experts_groups_kept: int = 0
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -366,6 +396,48 @@ class TransformerConfig:
           (self.use_ring_attention, "mesh", "use_ring_attention")):
         if asked:
           raise ValueError(heads_refusal(self, what, feature))
+    if self.kv_cache_dtype == "int8" and (self.mla_rope or self.mla_q_rank):
+      raise ValueError(
+          "kv_cache_dtype='int8' cannot take a latent layer with a query rank "
+          "or a rotated key part (mla_q_rank=%d, mla_rope=%r): the latent leaf "
+          "is held in the model's dtype, and an int8 latent (one scale a row "
+          "for the normed part, one for the rotated key part) is not built"
+          % (self.mla_q_rank, self.mla_rope))
+    if self.mla_q_rank < 0 or (self.mla_rope and self.mla_rope_dim % 2):
+      raise ValueError(
+          "mla_q_rank must be >= 0 and a rotated mla_rope_dim whole pairs, "
+          "got %d and %d" % (self.mla_q_rank, self.mla_rope_dim))
+    if self.rope_yarn_factor:
+      if self.rope_yarn_factor < 1.0 or self.rope_yarn_original < 1 \
+          or not self.rope_yarn_beta_fast > self.rope_yarn_beta_slow > 0 \
+          or self.rope_yarn_mscale_all_dim < 0:
+        raise ValueError(
+            "rope_yarn_factor=%r needs a factor >= 1, rope_yarn_original >= 1 "
+            "positions, rope_yarn_beta_fast > rope_yarn_beta_slow > 0 and "
+            "rope_yarn_mscale_all_dim >= 0, got %r, %r, %r and %r" % (
+                self.rope_yarn_factor, self.rope_yarn_original,
+                self.rope_yarn_beta_fast, self.rope_yarn_beta_slow,
+                self.rope_yarn_mscale_all_dim))
+      if not self.mla_rope or set(self.layer_types) != {"mla"}:
+        raise ValueError(
+            "rope_yarn_factor=%r scales the frequencies and the softmax of "
+            "the ROTATED PART OF LATENT LAYERS (layer_types all 'mla' with "
+            "mla_rope); this model's layers are %r with mla_rope=%r: an "
+            "attention layer's rotary at scaled frequencies, and its kernels "
+            "at another softmax scale than head_dim^-0.5, are not built" % (
+                self.rope_yarn_factor, self.layer_types or ("attn",),
+                self.mla_rope))
+    if self.experts_groups or self.experts_groups_kept:
+      g, kept = self.experts_groups, self.experts_groups_kept
+      per = self.experts_total // g if g > 0 else 0
+      if not (g > 0 and self.experts_total % g == 0 and per >= 2
+              and 0 < kept <= g and self.experts_top_k <= kept * per):
+        raise ValueError(
+            "experts_groups=%d must divide the router's %d experts into "
+            "groups of at least 2 (a group's score is the sum of its two "
+            "largest), with 0 < experts_groups_kept=%d <= that and room for "
+            "experts_top_k=%d inside the groups kept" % (
+                g, self.experts_total, kept, self.experts_top_k))
     if self.kv_page_size > 0:
       if self.loop_passes > 1:
         raise ValueError(loop_refusal(
@@ -546,17 +618,51 @@ def exit_pass(gates, threshold: float):
   return out
 
 
-def _rotary(x, positions, theta: float = 10000.0, dims: int = 0):
+def yarn_frequencies(theta: float, dims: int, factor: float, original: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0):
+  """YaRN's ``dims // 2`` rotary frequencies (numpy float64, computed while
+  tracing): ``f_i = theta^(-2i/dims)``; a dim turns ``n`` times over
+  ``original`` positions at ``d(n) = dims ln(original / (2 pi n)) / (2 ln
+  theta)``; ``low = floor(d(beta_fast))``, ``high = ceil(d(beta_slow))``
+  (inside ``[0, dims - 1]``), ``r_i = clip((i - low) / (high - low), 0, 1)``;
+  the frequency used is ``f_i (1 - r_i) + (f_i / factor) r_i``: fast dims
+  keep theirs, slow dims are interpolated, the ramp between blends."""
+  half = dims // 2
+  f = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+
+  def turns_at(n):
+    return dims * math.log(original / (2 * math.pi * n)) \
+        / (2 * math.log(theta))
+
+  low = max(math.floor(turns_at(beta_fast)), 0)
+  high = min(math.ceil(turns_at(beta_slow)), dims - 1)
+  r = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+  return f * (1.0 - r) + f / factor * r
+
+
+def yarn_softmax_factor(cfg) -> float:
+  """What YaRN multiplies a latent layer's softmax scale by: ``m^2`` with ``m
+  = 0.1 x rope_yarn_mscale_all_dim x ln(rope_yarn_factor) + 1``; 1.0 without
+  YaRN or with ``rope_yarn_mscale_all_dim`` 0."""
+  if cfg.rope_yarn_factor <= 1.0 or not cfg.rope_yarn_mscale_all_dim:
+    return 1.0
+  return (0.1 * cfg.rope_yarn_mscale_all_dim
+          * math.log(cfg.rope_yarn_factor) + 1.0) ** 2
+
+
+def _rotary(x, positions, theta: float = 10000.0, dims: int = 0, freqs=None):
   """Rotary position embedding over the last (head_dim) axis; with ``dims``
   over its first ``dims`` alone (``TransformerConfig.rope_dim``), the rest
-  passing as they are."""
+  passing as they are. ``freqs`` (``[d // 2]``, e.g.
+  :func:`yarn_frequencies`) replaces ``theta``'s frequencies."""
   if dims and dims < x.shape[-1]:
     return jnp.concatenate(
         [_rotary(x[..., :dims], positions, theta), x[..., dims:]], axis=-1)
   d = x.shape[-1]
   half = d // 2
   freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                  * (jnp.log(theta) / half))
+                  * (jnp.log(theta) / half)) if freqs is None \
+      else jnp.asarray(freqs, jnp.float32)
   angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,half]
   cos = jnp.cos(angles)[:, :, None, :]
   sin = jnp.sin(angles)[:, :, None, :]

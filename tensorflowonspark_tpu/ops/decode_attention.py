@@ -43,6 +43,12 @@ output folds onto the values' lanes. ``sink [h]`` is a learned scalar a query
 head that joins the softmax's DENOMINATOR and nothing else: it opens the
 online softmax beside the step's own key, as one more score whose value is
 zero (so it is counted once, whatever the number of blocks).
+
+K and V may be the SAME leaf (a latent cache, ``models/mla.py``, whose row is
+the shared key of every head and, in its first lanes, their shared value):
+the caller hands it as both and keeps the output's lanes that are values.
+``scale`` replaces ``d^-0.5`` where the scores' scale is not the contracted
+width's (a latent's 640 lanes stand for keys of 192).
 """
 
 import functools
@@ -249,9 +255,9 @@ def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
 
 # jitted under the name a reader of a device trace should see (the rule
 # ops/layer_norm.py's launchers state): the innermost jit names the kernel
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
-                     sink=None, interpret=False):
+                     sink=None, interpret=False, scale=None):
   """Softmax attention of one query token a slot over that slot's cache
   rows below ``lengths[i]`` AND the token's own key and value: ``q [b, h,
   d]`` (rotated), ``k`` / ``v`` ``[b, kv_heads, d]`` as the cache will hold
@@ -261,7 +267,8 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
   (a ring leaf): for each slot the first row and the count of a cyclic run
   of rows that is not attended. ``v`` / ``cached_v`` may be ``dv`` wide a
   head where the keys are ``d``; ``sink [h]`` float32 joins each head's
-  softmax denominator. Returns ``[b, h, dv]`` float32. The shapes must pass
+  softmax denominator. ``scale`` (static) is the scores' scale, None =
+  ``d^-0.5``. Returns ``[b, h, dv]`` float32. The shapes must pass
   :func:`supports`."""
   if not supports(q.shape, q.dtype, cached_k.shape, cached_k.dtype,
                   cached_v.shape):
@@ -289,8 +296,9 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
       sink.astype(jnp.float32), (0, hp - h))[:, None], (hp, LANES)),) \
       if sunk else ()
   o = pl.pallas_call(
-      functools.partial(_kernel, g=g, d=d, scale=1.0 / (dk ** 0.5), ring=ring,
-                        sunk=sunk),
+      functools.partial(
+          _kernel, g=g, d=d, ring=ring, sunk=sunk,
+          scale=1.0 / (dk ** 0.5) if scale is None else scale),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           # ONE grid step, the slots a loop inside it (a grid over slots
           # with the chain's state in SMEM took the same time on the chip)

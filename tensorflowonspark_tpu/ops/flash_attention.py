@@ -491,14 +491,15 @@ def _check_window(window, causal):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k",
-                                             "interpret", "window"))
+                                             "interpret", "window", "scale"))
 def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
-              window=None):
+              window=None, scale=None):
   b, s_q, h, d = q.shape
   s_kv, dv = k.shape[1], v.shape[3]     # values may be another width (forward)
   hk, g = _group(q, k)
   blk_q, blk_k = _blocks(s_q, s_kv, blk_q, blk_k)
-  scale = 1.0 / (d ** 0.5)
+  if scale is None:
+    scale = 1.0 / (d ** 0.5)
   qf, kf, vf = _fold(q), _fold(k), _fold(v)
   qb, kb = _base_arrays(q_base, kv_base)
 
@@ -847,7 +848,7 @@ def flash_attention_block(q, k, v, q_base, kv_base, causal: bool = True,
                           blk_q: int = 256, blk_k: int = 512,
                           interpret: bool = False, bwd: str = None,
                           blk_bwd_q: int = None, blk_bwd_k: int = None,
-                          window: int = None):
+                          window: int = None, scale: float = None):
   """Partial attention of local queries against ONE KV block.
 
   q: [B, Sq, H, D] at absolute positions ``q_base + arange(Sq)``;
@@ -859,8 +860,13 @@ def flash_attention_block(q, k, v, q_base, kv_base, causal: bool = True,
   the lse output). ``window`` composes with the ring: a KV block entirely
   behind the window collapses to zero loop iterations (the bounds are
   computed from the traced bases), so out-of-window ring steps cost only
-  the kernel launch and the merge.
+  the kernel launch and the merge. ``scale`` is the softmax scale where it
+  is not ``head_dim^-0.5`` (a latent layer under YaRN): FORWARD only, the
+  backward kernels keep the default scale and are not reached.
   """
+  if scale is not None:
+    return _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k,
+                     interpret, window, scale)
   return _flash_block_vjp(q, k, v, q_base, kv_base, causal, blk_q, blk_k,
                           interpret, _resolve_bwd(bwd), blk_bwd_q,
                           blk_bwd_k, window)

@@ -222,21 +222,42 @@ def moe_ffn_a2a(params, x, mesh, capacity_factor: float = 2.0,
 # ---------------------------------------------------------------------------
 
 
-def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0):
+def kept_groups(choice, groups: int, kept: int):
+  """The GROUP limit of a router: ``choice [T, E]`` (the scores the selection
+  reads, bias included) lie in ``groups`` groups of ``E // groups``
+  consecutive experts; a group's score is the sum of its two largest, and a
+  token keeps its ``kept`` best groups. Returns ``[T, groups]`` bool."""
+  t, e = choice.shape
+  best2, _ = lax.top_k(choice.reshape(t, groups, e // groups), 2)
+  _, chosen = lax.top_k(jnp.sum(best2, axis=-1), kept)          # [T, kept]
+  return jnp.any(chosen[..., None] == jnp.arange(groups), axis=1)
+
+
+def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
+                       groups: int = 0, groups_kept: int = 0):
   """Sigmoid top-k routing over the router's WHOLE width, in float32 (a
   rounded score moves a near-tie at the k-th place to another expert).
 
   ``x [T, D]``, ``router [D, E]``, ``bias [E]`` (added for the SELECTION
   only). Returns ``(experts [T, k] int32, weights [T, k] f32)``: the k
   largest of ``s + bias`` with ``s = sigmoid(x W)``, weighted ``s_e / sum
-  of the selected s`` times ``scale``."""
+  of the selected s`` times ``scale``. With ``groups`` > 0 the selection is
+  limited to each token's ``groups_kept`` best groups (:func:`kept_groups`):
+  an expert of another group cannot be chosen, whatever its score, and a
+  third member says which groups each token kept (``[T, groups]`` bool)."""
   s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                              router.astype(jnp.float32),
                              precision=lax.Precision.HIGHEST))
-  _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
+  choice = s + bias.astype(jnp.float32)
+  if groups:
+    kept = kept_groups(choice, groups, groups_kept)
+    choice = jnp.where(jnp.repeat(kept, s.shape[-1] // groups, axis=-1),
+                       choice, -jnp.inf)
+  _, experts = lax.top_k(choice, top_k)
   picked = jnp.take_along_axis(s, experts, axis=-1)
-  return experts.astype(jnp.int32), \
-      picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+  out = (experts.astype(jnp.int32),
+         picked / jnp.sum(picked, axis=-1, keepdims=True) * scale)
+  return out + (kept,) if groups else out
 
 
 def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,

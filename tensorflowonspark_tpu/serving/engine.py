@@ -168,6 +168,7 @@ _DEFAULT_POISON_CRASHES = 2
 #: key of ``ServingEngine.stats`` each of its sums is added to
 _STEP_COUNTERS = {"held": "moe_assignments_held",
                   "touched": "moe_experts_touched",
+                  "group": "moe_group_hits",
                   "context": "live_context_tokens",
                   "exit_pass": "loop_exit_pass_sum",
                   "window_context": "window_context_tokens"}
@@ -359,6 +360,9 @@ class ServingEngine(object):
                   # the live lanes' caches held when each step began
                   "moe_assignments_held": 0, "moe_experts_touched": 0,
                   "live_context_tokens": 0,
+                  # under a router's group limit: live tokens (a layer-step)
+                  # whose kept groups include one a held expert lies in
+                  "moe_group_hits": 0,
                   # a looped model (likewise counted): the pass at which
                   # its exit gates let each live lane's token go, summed;
                   # over live_slot_steps it is the mean exit pass

@@ -337,6 +337,16 @@ class SlotDecoder(object):
           "the cache cursor back; this model's KDA layers keep a recurrent "
           "state with no position axis, which a cursor cannot unwind"
           % self.spec_depth)
+    if self.spec_depth and "mla" in cfg.layer_types \
+        and (self.spec_depth + 1) * cfg.num_heads > tfm._MXU_COLS:
+      raise ValueError(
+          "speculative decoding (spec_depth=%d) verifies %d tokens a lane in "
+          "one step, and a latent layer of %d heads reads its leaf ABSORBED "
+          "(as stored) only while tokens x heads <= %d; a wider block expands "
+          "keys and values a head over the WHOLE slab (a verify window over a "
+          "latent leaf of this many heads is not built)"
+          % (self.spec_depth, self.spec_depth + 1, cfg.num_heads,
+             tfm._MXU_COLS))
     if cfg.loop_passes > 1:
       # a looped model: what cannot take a cache a pass is refused by name
       # (the paged pool above, by TransformerConfig itself)
@@ -701,7 +711,9 @@ class SlotDecoder(object):
     over LIVE lanes, else it is ``None``: ``context`` tokens the live
     lanes' caches held before the step; of expert layers ``held``
     assignments to experts held here and ``touched`` held experts that got
-    at least one live token (summed over expert layers); of a looped model
+    at least one live token (summed over expert layers) and, under a group
+    limit, ``group`` tokens whose kept groups include one a held expert lies
+    in; of a looped model
     ``exit_pass``, the pass at which its gates let each live lane's token
     exit; of a model whose window layers hold rings ``window_context``, the
     rows ONE window layer has to read for the step, ``min(cursor, window)``
@@ -732,6 +744,10 @@ class SlotDecoder(object):
             touched=sum(jnp.sum(jnp.any(
                 jnp.logical_and(x, active[:, None]), axis=0),
                                 dtype=jnp.int32) for x in hit))
+        if self.cfg.experts_groups:
+          counts["group"] = sum(
+              jnp.sum(jnp.logical_and(x, active), dtype=jnp.int32)
+              for x in _sown(sown, "group"))                # [slots] a layer
       cursor = _cursor_leaf(slabs).astype(jnp.int32)
       counts["context"] = jnp.sum(jnp.where(active, cursor, 0))
       if self.ring_windows:

@@ -303,15 +303,19 @@ def test_padded_prefill_then_a_wrapping_ring_equals_the_full_forward(window):
   assert totals["context"] == sum(cursors)
   assert totals["window_context"] == sum(min(c, window) for c in cursors)
   assert 0 < totals["touched"] <= totals["held"] <= 2 * 6 * len(cursors)
+  # ONE reference forward over both rows (the shorter one padded with token
+  # 0 behind its end, which a causal model's earlier positions do not see)
+  seqs = np.zeros((2, 37 + budget), np.int32)
   for slot, p in enumerate(prompts):
     want = np.asarray(tfm.greedy_generate_kv(
         params, cfg, jnp.asarray(p)[None], budget))[0, len(p):]
     np.testing.assert_array_equal(np.asarray(got[slot]), want)
-    seq = np.concatenate([p, want])[None]
-    z = np.asarray(fam.reference_logits(toy["weights"], seq,
-                                        toy["config"]))[0]
-    n = len(p)
-    served = z[np.arange(n - 1, seq.shape[1] - 1), seq[0, n:]]
+    seqs[slot, :len(p) + budget] = np.concatenate([p, want])
+  logits = np.asarray(fam.reference_logits(toy["weights"], seqs,
+                                           toy["config"]))
+  for slot, p in enumerate(prompts):
+    n, z = len(p), logits[slot, :len(p) + budget]
+    served = z[np.arange(n - 1, n + budget - 1), seqs[slot, n:n + budget]]
     # float32 on both sides: a served token is the reference's first choice
     # up to summation order (a near-tie may fall the other way by 1e-3)
     assert float(np.max(z[n - 1:-1].max(axis=-1) - served)) < 1e-3
@@ -478,11 +482,13 @@ def test_prefill_chunks_go_through_the_flash_forward(monkeypatch):
   real = tfm._cached_attention
   monkeypatch.setattr(tfm, "_cached_attention",
                       lambda *a, **kw: dense_calls.append(1) or real(*a, **kw))
+  # one program for the five chunks (the cursor is traced)
+  step = jax.jit(lambda c, t: model.apply(
+      {"params": toy["params"], "cache": c}, t, decode=True,
+      mutable=["cache"]))
   outs = []
   for off in range(0, 80, 16):
-    logits, mut = model.apply({"params": toy["params"], "cache": cache},
-                              toks[:, off:off + 16], decode=True,
-                              mutable=["cache"])
+    logits, mut = step(cache, toks[:, off:off + 16])
     cache = mut["cache"]
     outs.append(logits)
   assert not dense_calls

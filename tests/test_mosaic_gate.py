@@ -464,6 +464,63 @@ def test_mimo_prefill_chunk_builds_no_score_tensor(topo, monkeypatch, bucket,
   assert res["tpu_custom_calls"] >= 14, res["tpu_custom_calls"]
 
 
+def test_deepseek_step_many_reads_each_latent_leaf_once_in_place(
+    topo, monkeypatch):
+  """The cell deepseek-v3-serve-backlog's decode step at its real size (1
+  dense + 4 expert layers at published widths, 128 heads, 16 held experts a
+  layer, 24 slots x 16384, horizon 4): ONE slab of five latent leaves of 24 x
+  16384 x 640 (2.52 GB) is aliased whole; with 9.15 GB of weights beside it
+  the program fits the chip; no leaf is copied at the program's edge or comes
+  back from fast memory; one ``while`` is left, the horizon's scan; all 5
+  reads a step took the kernel that stops at the cursor (handed the leaf as K
+  and as V) and all 5 leaf writes the DMA kernel: a read fallen to the dense
+  contraction over all 16384 rows (2.6 ms a layer at 32 slots) fails HERE and
+  not in a chip run."""
+  import tools.mosaic_gate as gate
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  # the decoder the gate builds and lowers: its tallies are that lowering's
+  made, build = [], gate.deepseek_decoder
+  monkeypatch.setattr(gate, "deepseek_decoder",
+                      lambda *a: made.append(build(*a)) or made[-1])
+  res = _gate_one("serving_decode_deepseek", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 24 * 5 * 1280 * 16384
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.7e9, mb
+  assert res["device_bytes"] < 13.7e9 < V5E_HBM_BYTES, res["device_bytes"]
+  leaf = "bf16[24,16384,640]"
+  assert leaf not in res["entry_copies"], res["entry_copies"]
+  assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  assert res["while_loops"] == 1, res
+  assert res["tpu_custom_calls"] >= 5 + 5, res["tpu_custom_calls"]
+  (dec, _, _, _), = made
+  assert dec.attn_reads[4] == (5 * 4, 5 * 4, 0)
+  assert dec.cursor_writes[4] == (5 * 4, 5 * 4)
+
+
+@pytest.mark.parametrize("bucket,temp_max", [
+    (2048, 1.4e9),
+    # the small chunk runs the same code in less memory: outside tier-1
+    pytest.param(256, 0.5e9, marks=pytest.mark.slow)])
+def test_deepseek_prefill_chunk_builds_no_score_tensor(topo, monkeypatch,
+                                                       bucket, temp_max):
+  """The same cell's largest prefill chunk (2048 tokens) and its 256-token
+  one into a positional row of 16384 (0.105 GB: five latent leaves; one
+  program for a cursor at 0 and above it): the first chunk attends itself and
+  a later chunk the row in blocks of 2048, expanded as they are met, through
+  the flash FORWARD at 128 heads with keys of 192 / values of 128, so the
+  dense branch's float32 scores of chunk x 128 x 16384 (17 GB at 2048 tokens)
+  and its expanded row (1.34 GB) do not exist: temporaries stay under 1.4 GB
+  (1.14 / 0.32 when written), and the program fits beside the resident slab
+  of 24 slots (2.52 GB) under the 13.7 GB ISSUE 40's step zero allows."""
+  res = _gate_one("deepseek_prefill_%d" % bucket, monkeypatch)
+  mb = res["memory_bytes"]
+  assert mb["temp"] < temp_max, mb
+  assert res["device_bytes"] + 24 * 5 * 1280 * 16384 < 13.7e9, res
+  # the flash kernel a layer (first chunk) and again a layer (later chunks)
+  assert res["tpu_custom_calls"] >= 10, res["tpu_custom_calls"]
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

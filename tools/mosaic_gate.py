@@ -1167,6 +1167,66 @@ def mimo_prefill(bucket: int):
   return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
 
 
+#: the benchmark cell deepseek-v3-serve-backlog: slots x max_seq
+DEEPSEEK_SLOTS, DEEPSEEK_MAX_SEQ = 24, 16384
+
+
+def deepseek_cfg(max_seq: int = DEEPSEEK_MAX_SEQ):
+  """DeepSeek-V3 as ``benchmarks/configs/deepseek-v3.json`` cuts it to one
+  chip's share (published widths, published layers 0 and 3-6: one dense layer
+  and four expert layers; 16 of 256 experts held, 1/8 of the vocabulary),
+  spelled out so that the gate needs nothing of ``benchmarks/``;
+  ``benchmarks/tests/test_deepseek_v3.py`` keeps the two equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  return tfm.TransformerConfig(
+      vocab_size=16256, num_layers=5, num_heads=128, d_model=7168, d_ff=18432,
+      max_seq_len=max_seq, remat=False, dtype=jnp.bfloat16,
+      layer_types=("mla",) * 5, ffn_types=("mlp",) + ("experts",) * 4,
+      norm="rms", norm_eps=1e-6, mlp_act="swiglu", tie_embeddings=False,
+      mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64, mla_v_dim=128,
+      mla_q_rank=1536, mla_rope=True, rope_theta=10000.0,
+      rope_yarn_factor=40.0, rope_yarn_original=4096, rope_yarn_beta_fast=32.0,
+      rope_yarn_beta_slow=1.0, rope_yarn_mscale_all_dim=1.0,
+      experts_total=256, experts_held=16, experts_first=0, experts_top_k=8,
+      experts_d_ff=2048, experts_shared=1, experts_scale=2.5,
+      experts_groups=8, experts_groups_kept=4)
+
+
+def deepseek_decoder(slots: int = DEEPSEEK_SLOTS,
+                     max_seq: int = DEEPSEEK_MAX_SEQ):
+  """:func:`_sparse_decoder` at the DeepSeek cell's sizes."""
+  return _sparse_decoder(deepseek_cfg(max_seq), slots)
+
+
+def t_serving_decode_deepseek():
+  """The cell deepseek-v3-serve-backlog's decode step at its real size: five
+  latent leaves of 24 x 16384 x 640 in the one slab (2.52 GB), 5 reads a step
+  by the kernel that stops at the cursor, handed the leaf as K and as V (128
+  absorbed query heads over 640 lanes), 5 leaf writes, 16 held experts a layer
+  under the group limit, horizon 4."""
+  dec, params, _, slabs = deepseek_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
+#: the prefill shapes step zero compiles: the ladder's largest and 256
+DEEPSEEK_BUCKETS = (2048, 256)
+
+
+def deepseek_prefill(bucket: int):
+  """One of the same cell's prefill programs: a padded chunk of ``bucket``
+  tokens into a positional row of 16384 (0.105 GB: five latent leaves). The
+  first chunk attends itself through the flash FORWARD at 128 heads with keys
+  of 192 / values of 128 expanded from the latent; at a cursor above 0 (the
+  same program: the cond's other branch) it attends the row in blocks of 2048
+  through the same kernel, each block's latent expanded as it is met, so no
+  float32 score tensor of bucket x 128 x 16384 and no expanded row exist."""
+  dec, params, row, _ = deepseek_decoder()
+  assert set(DEEPSEEK_BUCKETS) <= set(dec.buckets) \
+      and dec.buckets[0] == DEEPSEEK_BUCKETS[0], dec.buckets
+  return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
+
+
 def t_smoke_step_many():
   return _smoke_step_many(paged=False)
 
@@ -1231,6 +1291,7 @@ TARGETS = {
     "serving_decode_trinity": t_serving_decode_trinity,
     "trinity_insert": t_trinity_insert,
     "serving_decode_mimo": t_serving_decode_mimo,
+    "serving_decode_deepseek": t_serving_decode_deepseek,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
@@ -1238,6 +1299,8 @@ TARGETS.update({"trinity_prefill_%d" % b: (lambda b=b: trinity_prefill(b))
                 for b in TRINITY_BUCKETS})
 TARGETS.update({"mimo_prefill_%d" % b: (lambda b=b: mimo_prefill(b))
                 for b in MIMO_BUCKETS})
+TARGETS.update({"deepseek_prefill_%d" % b: (lambda b=b: deepseek_prefill(b))
+                for b in DEEPSEEK_BUCKETS})
 TARGETS.update({"kimi_linear_prefill_%d" % b:
                 (lambda b=b: kimi_linear_prefill(b))
                 for b in KIMI_LINEAR_BUCKETS})
