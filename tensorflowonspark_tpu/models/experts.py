@@ -39,6 +39,7 @@ class _Shared(nn.Module):
 
 class HeldExperts(nn.Module):
   cfg: object
+  mesh: object = None
 
   @nn.compact
   def __call__(self, x):
@@ -63,8 +64,9 @@ class HeldExperts(nn.Module):
     split = None
     if cfg.act_f32 and gate.dtype == jnp.bfloat16:
       split = tfm._bf16_terms
-    y, hit = ep.held_experts_ffn(flat, experts, weights, gate, up, down,
-                                 cfg.experts_first, split)
+    y, hit = ep.held_experts_ffn(
+        flat, experts, weights, gate, up, down, cfg.experts_first, split,
+        self.mesh, getattr(tfm._expert_products, "open", None))
     self.sow("counters", "held", jnp.sum(hit, axis=1, dtype=jnp.int32))
     local = jnp.where(hit, experts - cfg.experts_first, held)
     self.sow("counters", "hit",

@@ -816,6 +816,7 @@ _MXU_COLS = 128
 
 _cursor_writes = threading.local()
 _attn_reads = threading.local()
+_expert_products = threading.local()
 
 
 @contextlib.contextmanager
@@ -847,6 +848,16 @@ def decode_attention_tally():
   cursor, and ``r`` of the ``n`` reading a RING leaf
   (``TransformerConfig.kv_ring``)."""
   return _tally(_attn_reads, "reads", "ragged", "ring")
+
+
+def expert_product_tally():
+  """The same for the grouped products of the held experts
+  (``parallel.expert_parallel.held_experts_ffn``: gate, up and down, three a
+  layer application): yields ``{"products": n, "kernel": m}``, ``m`` of the
+  ``n`` having taken ``ops.expert_product``'s kernel, which reads only the
+  rows that have a group, and not ``lax.ragged_dot``. ``SlotDecoder`` opens
+  one round a ``step_many`` program's trace and one a prefill shape's."""
+  return _tally(_expert_products, "products", "kernel")
 
 
 def _cache_write(buf, val, idx, positions, mesh):
@@ -1916,7 +1927,8 @@ class Block(nn.Module):
     if self.ffn == "experts":
       from tensorflowonspark_tpu.models import experts
       with jax.named_scope("moe"):
-        return joins(experts.HeldExperts(cfg, name="moe")(y), "ln2_out")
+        return joins(experts.HeldExperts(cfg, self.mesh, name="moe")(y),
+                     "ln2_out")
     return joins(MLPBlock(cfg, self.mesh, name="mlp")(y), "ln2_out")
 
 

@@ -70,3 +70,6 @@ from tensorflowonspark_tpu.ops.cursor_write import (  # noqa: F401
 from tensorflowonspark_tpu.ops.decode_attention import (  # noqa: F401
     decode_attention, supports as decode_attention_supports,
 )
+from tensorflowonspark_tpu.ops.expert_product import (  # noqa: F401
+    expert_product, supports as expert_product_supports,
+)

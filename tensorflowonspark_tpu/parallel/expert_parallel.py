@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from tensorflowonspark_tpu import ops
 from tensorflowonspark_tpu.parallel import mesh as mesh_lib
 
 
@@ -261,7 +262,7 @@ def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
 
 
 def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
-                     split=None):
+                     split=None, mesh=None, tally=None):
   """What the experts held here add to the layer: ``sum over a token's
   assignments to experts in [first, first + held) of w * down_e(silu(gate_e
   x) * up_e x)``; assignments to experts held elsewhere add nothing.
@@ -270,10 +271,19 @@ def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
   :func:`route_sigmoid_topk`; ``gate``/``up [held, D, F]``, ``down
   [held, F, D]`` in the compute dtype. The ``T * k`` assignments are sorted
   by expert (those held elsewhere last, outside every group) and multiplied
-  as ONE grouped product a matrix (``lax.ragged_dot``: rows of a group meet
-  that group's expert only, f32 accumulation), whatever the imbalance:
-  there is no capacity, so no token is dropped, and an expert nobody chose
-  is a group of no rows. ``split`` turns an activation into the list of
+  as ONE grouped product a matrix (rows of a group meet that group's expert
+  only, f32 accumulation), whatever the imbalance: there is no capacity, so
+  no token is dropped, and an expert nobody chose is a group of no rows. One
+  product, two lowerings, chosen from what the code can observe:
+  ``ops.expert_product``'s kernel, which reads only the rows that have a
+  group and streams only the matrices that have rows, for bf16 operands of
+  whole lanes on ONE device (GSPMD does not partition a Mosaic call; ``mesh``
+  is the caller's), and ``lax.ragged_dot``, which costs each touched group
+  about three times its matrix's bytes' time whatever its rows, for everything
+  else (a float32 stack: a test, an init). ``tally`` (a dict,
+  ``models.transformer.expert_product_tally``) is told of each product and of
+  those that took the kernel, while the call traces. ``split`` turns an
+  activation into the list of
   arrays of the weights' dtype that SUM to it (default: one cast); with n
   terms each assignment's row goes in n times, term after term, into a
   group n times as long, and the n results are added: float32 activations
@@ -292,18 +302,28 @@ def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
     parts = split(lhs) if split is not None else [lhs.astype(rhs.dtype)]
     n = len(parts)
     lhs = jnp.stack(parts, axis=1).reshape(-1, lhs.shape[-1])
-    out = lax.ragged_dot(
-        lhs, rhs, sizes * n, preferred_element_type=jnp.float32,
-        # float32 operands (a test, an init): not one rounded bf16 pass
-        precision=lax.Precision.HIGHEST if lhs.dtype == jnp.float32
-        else None)
+    kernel = ((mesh is None or mesh.size == 1)
+              and ops.expert_product_supports(lhs.shape, lhs.dtype,
+                                              rhs.shape, rhs.dtype))
+    if tally is not None:
+      tally["products"] += 1
+      tally["kernel"] += kernel
+    if kernel:
+      out = ops.expert_product(lhs, rhs, sizes * n,
+                               interpret=ops.pallas_interpret())
+    else:
+      out = lax.ragged_dot(
+          lhs, rhs, sizes * n, preferred_element_type=jnp.float32,
+          # float32 operands (a test, an init): not one rounded bf16 pass
+          precision=lax.Precision.HIGHEST if lhs.dtype == jnp.float32
+          else None)
     return out.reshape(-1, n, out.shape[-1]).sum(axis=1)
 
   hidden = jax.nn.silu(grouped(rows, gate)) * grouped(rows, up)
   out = grouped(hidden, down)                               # [T * k, D]
   w = jnp.where(held, weights, 0.0).reshape(-1)[order]
-  # rows past the last group belong to no expert here: whatever the grouped
-  # product left there is not a number to scale
+  # rows past the last group belong to no expert here: the kernel leaves
+  # zeros there, ragged_dot whatever it left, which is not a number to scale
   out = jnp.where((w > 0)[:, None], out * w[:, None], 0.0)
   # back to assignment order: a gather by the inverse permutation
   y = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1).sum(axis=1)

@@ -390,6 +390,11 @@ class ServingEngine(object):
                   # those of them over a RING leaf (a window layer's): what
                   # splits a trace's %decode_attention calls by leaf kind
                   "decode_attn_reads_ring": 0,
+                  # grouped products of held experts the prefill and
+                  # step_many dispatches made (three a layer application),
+                  # and those of them by ops.expert_product's kernel and not
+                  # lax.ragged_dot (SlotDecoder.expert_products)
+                  "expert_products": 0, "expert_products_kernel": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -1570,6 +1575,9 @@ class ServingEngine(object):
       self.stats["decode_attn_reads"] += reads
       self.stats["decode_attn_reads_ragged"] += ragged
       self.stats["decode_attn_reads_ring"] += ring
+      products, kernel = self.decoder.expert_products["step", self.horizon]
+      self.stats["expert_products"] += products
+      self.stats["expert_products_kernel"] += kernel
     step_seq = self._slab_seq
     # a budget that ends within the horizon ends inside the scan
     behind = self._admit_behind(remaining, self.horizon)

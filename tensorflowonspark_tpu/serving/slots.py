@@ -399,6 +399,11 @@ class SlotDecoder(object):
     #: those of them over a RING leaf: a reader of a device trace splits the
     #: kernel's calls by the leaf they read with it)
     self.attn_reads = {}
+    #: program -> (grouped products of held experts one dispatch of it makes:
+    #: three a layer application, those of them by ops.expert_product's
+    #: kernel, which reads only the rows that have a group); a program is
+    #: ("step", horizon) or ("prefill", the chunk's tokens)
+    self.expert_products = {}
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
 
@@ -429,10 +434,15 @@ class SlotDecoder(object):
     obs_device.note_trace("serve.prefill")
     # padded: only the last REAL row goes through the final norm and the
     # head (a [seg, vocab] logits block is never built to pick one row)
-    logits, mutated = self.model.apply(
-        {"params": params, "cache": cache}, tokens, decode=True,
-        mutable=["cache"],
-        logits_at=None if n_valid is None else n_valid - 1, n_valid=n_valid)
+    with tfm.expert_product_tally() as products:
+      logits, mutated = self.model.apply(
+          {"params": params, "cache": cache}, tokens, decode=True,
+          mutable=["cache"],
+          logits_at=None if n_valid is None else n_valid - 1,
+          n_valid=n_valid)
+    # on the host, while tracing: one program a chunk shape
+    self.expert_products["prefill", tokens.shape[1]] = (
+        products["products"], products["kernel"])
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     if n_valid is None:
       return mutated["cache"], nxt
@@ -539,6 +549,12 @@ class SlotDecoder(object):
         cache, nxt = self._prefill_fn(
             params, cache, tokens,
             np.int32(n) if self.padded_prefill else None)
+        products, kernel = self.expert_products["prefill", seg]
+        if acc is not None and products:
+          # a caller's own dict need not carry the keys
+          acc["expert_products"] = acc.get("expert_products", 0) + products
+          acc["expert_products_kernel"] = acc.get(
+              "expert_products_kernel", 0) + kernel
         seq = None if queue is None else queue.dispatched()
       off += n
     return cache, nxt, seq
@@ -829,7 +845,8 @@ class SlotDecoder(object):
         def body(carry, _):
           slabs, tok, active, remaining = carry
           with tfm.cursor_write_tally() as writes, \
-              tfm.decode_attention_tally() as reads:
+              tfm.decode_attention_tally() as reads, \
+              tfm.expert_product_tally() as products:
             slabs, nxt, counts = self._one_step(params, slabs, tok, active,
                                                 count=self.counted)
           # on the host, while tracing: the body is one step of _h
@@ -837,6 +854,8 @@ class SlotDecoder(object):
                                     _h * writes["dma"])
           self.attn_reads[_h] = tuple(
               _h * reads[k] for k in ("reads", "ragged", "ring"))
+          self.expert_products["step", _h] = (_h * products["products"],
+                                              _h * products["kernel"])
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:

@@ -521,6 +521,33 @@ def test_deepseek_prefill_chunk_builds_no_score_tensor(topo, monkeypatch,
   assert res["tpu_custom_calls"] >= 10, res["tpu_custom_calls"]
 
 
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+@pytest.mark.parametrize("config", ["trinity", "mimo", "deepseek",
+                                    "kimi_linear"])
+def test_expert_product_compiles_at_the_cells_shapes(topo, monkeypatch, config,
+                                                     kind):
+  """``ops.expert_product`` as a layer calls it (the rows through the gate
+  stack, the result's shape through the down stack: K and N exchanged) at the
+  four expert cells' widths, for a decode step's rows (96 to 1152, one row
+  tile where there are fewer than 128) and for the largest chunk's (8192 to
+  16384): the grid whose length is a prefetched scalar, the index maps that
+  read the pairs from SMEM and the ``[K, tn]`` blocks (up to 8 MB, double
+  buffered) lower through Mosaic; two kernels and no loop (the pairs are a
+  few fused reductions); the temporaries are the hidden activations (rows x
+  expert width in f32 and again in bf16) and nothing of a stack's shape."""
+  from tools.mosaic_gate import EXPERT_PRODUCTS
+  held, d, f, step, chunk = EXPERT_PRODUCTS[config]
+  rows = step if kind == "decode" else chunk
+  res = _gate_one("expert_product_%s_%s" % (config, kind), monkeypatch)
+  assert res["tpu_custom_calls"] == 2 and res["while_loops"] == 0, res
+  mb = res["memory_bytes"]
+  assert mb["temp"] < rows * f * (4 + 2) + (4 << 20), mb
+  for stack in ("bf16[%d,%d,%d]" % (held, d, f),
+                "bf16[%d,%d,%d]" % (held, f, d)):
+    assert stack not in res["entry_copies"], res["entry_copies"]
+    assert stack not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+
+
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):
   """The whole make_train_loop K-step scan of chip_smoke's train phase
   (abstract state) compiles for one v5e chip, carries the flash and

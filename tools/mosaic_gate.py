@@ -871,6 +871,34 @@ def t_decode_attention_ouro():
   return _decode_attention_target(OURO_SLOTS, OURO_MAX_SEQ, 16, 128)
 
 
+
+#: the grouped expert product of the four expert cells: (held experts, model
+#: width, expert width, assignment rows of a decode step, of the largest
+#: prefill chunk). Kimi's float32 activations go in as three bf16 terms a
+#: row: 3 x (48 slots x 8, 512 tokens x 8)
+EXPERT_PRODUCTS = {
+    "trinity": (32, 3072, 3072, 24 * 4, 2048 * 4),
+    "mimo": (16, 4096, 2048, 48 * 8, 2048 * 8),
+    "deepseek": (16, 7168, 2048, 24 * 8, 2048 * 8),
+    "kimi_linear": (16, 2304, 1024, 3 * 48 * 8, 3 * 512 * 8),
+}
+
+
+def expert_product_target(held: int, d: int, f: int, rows: int):
+  """``ops.expert_product`` as a layer's feed-forward calls it: ``rows``
+  assignment rows through the gate stack (``[held, d, f]``) and its result's
+  shape through the down stack (``[held, f, d]``)."""
+  import jax
+  from tensorflowonspark_tpu import ops
+
+  def layer(x, gate, down, sizes):
+    hidden = ops.expert_product(x, gate, sizes)
+    return ops.expert_product(hidden.astype(x.dtype), down, sizes)
+
+  return jax.jit(layer), (_on_chip0(_sh(rows, d)), _on_chip0(_sh(held, d, f)),
+                          _on_chip0(_sh(held, f, d)), _i32(held))
+
+
 def t_gpt2l_prefill_512():
   """The benchmark's largest prefill program at its real size: a padded
   512-token chunk (PERF.md section 6, PR 27); only the last real row may
@@ -1306,6 +1334,10 @@ TARGETS.update({"kimi_linear_prefill_%d" % b:
                 for b in KIMI_LINEAR_BUCKETS})
 TARGETS["kimi_linear_prefill_512_exact"] = \
     lambda: kimi_linear_prefill(512, padded=False)
+TARGETS.update({"expert_product_%s_%s" % (name, kind):
+                (lambda a=(g, d, f, rows): expert_product_target(*a))
+                for name, (g, d, f, step, chunk) in EXPERT_PRODUCTS.items()
+                for kind, rows in (("decode", step), ("chunk", chunk))})
 
 #: HBM of one v5e chip (Google Cloud "TPU v5e": 16 GB)
 V5E_HBM_BYTES = 16 * 1024 ** 3
