@@ -252,13 +252,14 @@ def _annotation(name: str):
 
 class Region(object):
   """What ``with region(...) as r`` binds: ``t0`` and ``dur`` (the region's
-  one clock reading, ``dur`` set on exit) and the writable ``attrs`` of its
-  recorder span, for values known only once the work is done."""
+  one clock reading, ``dur`` set on exit) and, for what is known only once
+  the work is done, the writable ``attrs`` of its recorder span and
+  ``recorded``, whether the span reaches the recorder at all."""
 
-  __slots__ = ("t0", "dur", "attrs", "_counted", "_empty")
+  __slots__ = ("t0", "dur", "attrs", "recorded", "_counted", "_empty")
 
-  def __init__(self, attrs: dict):
-    self.attrs = attrs
+  def __init__(self, attrs: dict, recorded: bool = True):
+    self.attrs, self.recorded = attrs, recorded
     self.t0 = self.dur = self._counted = self._empty = 0.0
 
 
@@ -354,7 +355,7 @@ def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
     :class:`SpanRecorder` with ``trace``/attrs, like
     ``SpanRecorder.span``; ``record=False`` keeps a region out of it.
   """
-  r = Region(attrs)
+  r = Region(attrs, record)
   parent = getattr(_tls, "top", None)
   with _annotation(name):
     _tls.top = r
@@ -377,7 +378,7 @@ def region(name: str, acc: Optional[dict] = None, key: Optional[str] = None,
       if parent is not None:
         parent._counted += r._counted
         parent._empty += r._empty
-      if record:
+      if r.recorded:
         rec = active()
         if rec is not None:
           rec.record_span(name, r.t0, dur, trace=trace, **r.attrs)

@@ -71,11 +71,16 @@ parity in tests/test_serving.py, composable with the self-healing surface):
   which leans on it) survives the speedup.
 
 A pass of the loop (``_pass``) dispatches in the order the device can run
-and reads in the order the device finishes: the decode step first, then ONE
-admission's chunks and insert queued behind it unread (for a lane that is
-free, or that the step's budget arithmetic certifies free), then the step's
-tokens, then the admission's first token — the host's dispatch calls pass
-while the chip works (docs/PERFORMANCE.md).
+and reads in the order the device finishes, and it keeps ONE decode step in
+flight: the next ``step_many`` is dispatched, its lane state made on the
+device from the outputs of the step before it, while that step is still
+unread; then ONE admission's chunks and insert are queued behind it unread
+(for a lane that is free, or that the budget arithmetic certifies free);
+then the OLDER step's tokens are read and harvested, then the first token
+of the admission whose insert preceded the new step. The host's dispatch
+calls, its harvest and its reads pass while the chip works
+(docs/PERFORMANCE.md). The paged pool and speculation keep one step at a
+time: dispatch, queue one admission, read.
 
 All waits are timeout-bounded (TOS001) and the loop thread is a daemon
 (TOS007). Config knobs ride registered ``TOS_*`` env vars (TOS008):
@@ -188,6 +193,18 @@ def _env_int(name: str, default: int) -> int:
 
 def _env_float(name: str, default: float) -> float:
   return float(os.environ.get(name, str(default)))
+
+
+class _Step(object):
+  """One dispatched ``step_many`` until its tokens are harvested: what it
+  returned (``out``), its number in the loop's device queue (``seq``) and,
+  lane by lane, the request it was GIVEN live as far as the host knew
+  (``reqs``: only these are harvested from it)."""
+
+  __slots__ = ("out", "seq", "reqs")
+
+  def __init__(self, out, seq: int, reqs):
+    self.out, self.seq, self.reqs = out, seq, reqs
 
 
 class _Admission(object):
@@ -314,15 +331,25 @@ class ServingEngine(object):
     self._prefix: Optional[sched.PrefixCache] = None
     self._req_pages = {}                   # rid -> [page ids] (one ref each)
     self._last = np.full((num_slots,), self.pad_id, np.int32)
+    # how many decode steps may be dispatched ahead of the one being read:
+    # one over the contiguous slab; none where the harvest itself changes
+    # what the next step needs (the paged pool releases pages and resets
+    # page tables in it) or where the host cannot count a lane's budget
+    # before the read (speculation emits a data-dependent number a lane)
+    self._run_ahead = not self.decoder.paged and self.spec_depth == 0
+    self._flight: Optional[_Step] = None   # the step dispatched and unread
     self._stop_evt = threading.Event()
     self._thread: Optional[threading.Thread] = None
     self._loop_error: Optional[BaseException] = None
     self._draining = False
-    # the ONE request popped and not yet in a lane (queue depth, drain and
-    # _recover read it), and the request a crash is blamed on: the same
-    # while the admission's own calls run, nobody while the admission waits
-    # unread behind a decode step (_admit_behind)
+    # the requests popped and not yet in a lane (queue depth, drain and
+    # _recover read both): the ONE whose admission's own calls are running,
+    # and the admissions dispatched with their inserts and left unread
+    # behind a decode step (_admit_behind), oldest first: one, and a second
+    # only from a step's dispatch to the seat of the older. The request a
+    # crash is blamed on is the first while its calls run, nobody's else
     self._admitting: Optional[sched.Request] = None
+    self._unread: List[_Admission] = []
     self._blame: Optional[sched.Request] = None
     self._crash_streak = 0
     self._tok_rate = 0.0                   # EMA tokens/s over decode passes
@@ -348,8 +375,13 @@ class ServingEngine(object):
                   # many of those were a padded tail's padding)
                   "decode_dispatches": 0, "prefill_chunks": 0,
                   "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                  # of decode_dispatches, the step_many dispatched while
+                  # the one before it was unread: its lane state came from
+                  # that step's outputs on the device (_carried)
+                  "decode_dispatches_ahead": 0,
                   # of prefill_chunks, those dispatched while a decode step
-                  # of the same pass was unread (_admit_behind), and of
+                  # was unread (_admit_behind; a later admission of a pass
+                  # that leaves its step in flight), and of
                   # prefills, the admissions into a lane that step was
                   # certain to free and had not freed yet
                   "prefill_chunks_behind_decode": 0, "admits_ahead": 0,
@@ -563,13 +595,7 @@ class ServingEngine(object):
     # submit-vs-loop-death race, docs/ROBUSTNESS.md)
     for req in self._queue.close(err):
       req.finish(err)
-    with self._lock:
-      live = [r for r in self._slots if r is not None]
-      self._slots = [None] * self.num_slots
-      adm, self._admitting = self._admitting, None
-    if adm is not None:
-      live.append(adm)
-    for req in live:
+    for req in self._take_in_flight():
       req.finish(err)                      # finish() is idempotent
     self._slabs = None                     # next start() gets a fresh slab
     # page ids described the dropped slab: the allocator/trie die with it
@@ -612,7 +638,7 @@ class ServingEngine(object):
       return False
     with self._lock:
       return not (any(r is not None for r in self._slots)
-                  or self._admitting is not None)
+                  or self._admitting is not None or self._unread)
 
   def __enter__(self):
     return self.start()
@@ -922,17 +948,33 @@ class ServingEngine(object):
     counting it, a replica mid-prefill reads (queue 0, occupancy 0) and
     a load-aware router double-books exactly the replica that is busiest
     admitting (the drain _idle rule, applied to the scoring read)."""
-    adm = self._admitting
-    return len(self._queue) + (1 if adm is not None else 0)
+    return len(self._queue) + len(self._popped())
 
   @property
   def queued_tokens(self) -> int:
     """Queued-or-admitting token mass: sum of prompt+budget over the
     backlog (same mid-admission rule as :attr:`queue_depth`)."""
+    return self._queue.token_mass + sum(r.token_cost
+                                        for r in self._popped())
+
+  def _popped(self) -> List[sched.Request]:
+    """The requests popped and not yet in a lane: unread behind a decode
+    step, or mid-admission (any thread: a snapshot, never locked)."""
     adm = self._admitting
-    extra = (len(adm.prompt) + adm.max_new_tokens) if adm is not None \
-        else 0
-    return self._queue.token_mass + extra
+    return [a.req for a in list(self._unread)] \
+        + ([adm] if adm is not None else [])
+
+  def _take_in_flight(self) -> List[sched.Request]:
+    """Every request in a lane or popped for one, in the order they were
+    running, taken OUT of the engine's books with the step in flight (a
+    crash, a stop or the loop's death owns them from here)."""
+    with self._lock:
+      live = [r for r in self._slots if r is not None]
+      self._slots = [None] * self.num_slots
+      live += self._popped()
+      self._admitting, self._unread = None, []
+    self._flight = None
+    return live
 
   @property
   def tokens_per_sec(self) -> float:
@@ -981,30 +1023,38 @@ class ServingEngine(object):
   def _pass(self) -> None:
     """One pass of the loop: it dispatches in the order the device can run
     and reads in the order the device finishes. With a live lane the decode
-    step goes first and ONE admission is queued behind it
-    (:meth:`_admit_behind`); the step's tokens are read and harvested, then
-    the admission's first token (:meth:`_admit`), and further free lanes
-    are admitted one at a time. With no live lane there is no step to queue
-    behind: the pass only admits, or waits for work."""
+    step goes first, BEFORE the step in flight is read where one is
+    (:meth:`_decode_once`), and ONE admission is queued behind it
+    (:meth:`_admit_behind`); the older step's tokens are read and
+    harvested, then the first token of the admission that was waiting
+    (:meth:`_admit`), and further free lanes are admitted one at a time,
+    behind the running step. With no live lane and nothing in flight there
+    is no step to queue behind: the pass only admits, or waits for work."""
     self._ensure_slabs()                   # rebuilt after a crash
     # reap/admit/idle run every pass of an IDLE engine too: counter
     # and annotation only, never the bounded recorder
     with obs_spans.region("serve.reap", self.stats, "t_reap_s",
                           record=False, queue=self._devq):
       self._reap()
-    behind = None
-    if any(r is not None for r in self._slots):
-      behind = self._decode_once()
+    seat = None
+    if self._decoding():
+      seat = self._decode_once()
       self._crash_streak = 0               # a full decode pass = healthy
     with obs_spans.region("serve.admit", self.stats, "t_admit_s",
                           record=False, queue=self._devq):
-      self._admit(behind)
-    if not any(r is not None for r in self._slots):
+      self._admit(seat)
+    if not self._decoding():
       # idle: bounded block until work arrives (TOS001)
       with obs_spans.region("serve.idle", self.stats, "t_idle_s",
                             record=False, queue=self._devq):
         self._vouch_idle()
         self._queue.wait_nonempty(timeout=self._poll)
+
+  def _decoding(self) -> bool:
+    """A lane is live, a step is unread, or an admission waits behind one:
+    there is decode work for the next pass."""
+    return self._flight is not None or bool(self._unread) \
+        or any(r is not None for r in self._slots)
 
   def _vouch_idle(self) -> None:
     """An idle pass whose newest dispatch nobody read (a freed lane's
@@ -1041,15 +1091,11 @@ class ServingEngine(object):
                    "recovering: %r", streak, self.max_restarts, error)
     self._count("engine_restarts")
     # collect the victims: in-flight slots in slot order, then the
-    # request that was mid-admission (the _admit prefill path) — it is
-    # in neither the queue nor a slot and must not be lost
-    with self._lock:
-      victims = [r for r in self._slots if r is not None]
-      self._slots = [None] * self.num_slots
-      adm, self._admitting = self._admitting, None
+    # requests popped for a lane (unread behind a step, or mid-admission
+    # in the _admit prefill path): they are in neither the queue nor a
+    # slot and must not be lost. The step in flight goes with them
+    victims = self._take_in_flight()
     blame, self._blame = self._blame, None
-    if adm is not None:
-      victims.append(adm)
     self._last[:] = self.pad_id
     self._devq.unknown()                   # whatever was running, or failed
     self._slabs = None                     # fresh slab next iteration
@@ -1140,13 +1186,7 @@ class ServingEngine(object):
     self._loop_error = error
     for req in self._queue.close(error):
       req.finish(error)
-    with self._lock:
-      live = [r for r in self._slots if r is not None]
-      self._slots = [None] * self.num_slots
-      adm, self._admitting = self._admitting, None
-    if adm is not None:
-      live.append(adm)
-    for req in live:
+    for req in self._take_in_flight():
       req.finish(error)
 
   # -- reaping (deadlines & cancellation) ------------------------------------
@@ -1312,10 +1352,12 @@ class ServingEngine(object):
                             prompt_len=len(req.prompt), slot=adm.slot,
                             shared_tokens=adm.shared_tokens)
 
-  def _dispatch_chunks(self, adm: "_Admission") -> None:
+  def _dispatch_chunks(self, adm: "_Admission", behind: bool) -> None:
     """Dispatch the admission's prefill (inside its ``serve.prefill``
-    region) and read nothing."""
+    region) and read nothing. ``behind``: a decode step is dispatched and
+    unread, so the chunks queue behind it (counted)."""
     req, resume = adm.req, None
+    chunks = self.stats["prefill_chunks"]
     if adm.shared_tokens:
       # prefix hit: rebuild the warm row cache from the shared pages
       # and prefill only the tail — the O(prefix) work is skipped
@@ -1327,6 +1369,9 @@ class ServingEngine(object):
     adm.row, adm.head, adm.seq = self.decoder.prefill_chunks(
         self.params, req.prompt, self.buckets, resume=resume,
         trace=self._chunk_trace(req), acc=self.stats, queue=self._devq)
+    if behind:
+      self.stats["prefill_chunks_behind_decode"] += \
+          self.stats["prefill_chunks"] - chunks
 
   def _chunk_trace(self, req: sched.Request) -> Optional[str]:
     return req.trace_id if self._detail else None
@@ -1346,7 +1391,9 @@ class ServingEngine(object):
       else:
         self._on_slab(lambda slabs: self.decoder.insert(
             slabs, adm.row, adm.slot))
-    adm.inserted = True
+    # the insert holds the row until it has run: an admission left unread
+    # keeps its first token and no 0.3-0.5 GB row beside the next one's
+    adm.row, adm.inserted = None, True
 
   def _seat(self, adm: "_Admission", first: int) -> None:
     """The admission's first token is read: emit it and hand the lane to
@@ -1367,7 +1414,7 @@ class ServingEngine(object):
       if adm.pages is not None:  # never inserted: nothing else holds them
         for p in adm.pages:
           self._pool.unref(p)
-      self._admission_over()
+      self._admission_over(adm)
       return                     # the lane stays free for the next request
     if self._slots[slot] is not None:
       raise RuntimeError(
@@ -1388,64 +1435,77 @@ class ServingEngine(object):
       self._req_pages[req.rid] = adm.pages
     with self._lock:
       self._slots[slot] = req
-    self._admission_over()
+    self._admission_over(adm)
     self._last[slot] = first
 
-  def _admit(self, behind: Optional["_Admission"] = None) -> None:
-    """Read the first token of ``behind`` (the admission
-    :meth:`_admit_behind` queued behind this pass's decode step, harvested
-    by now) and seat it; then prefill queued requests into the lanes still
-    free (EOS-freed or virgin), one at a time: dispatch, read, insert."""
-    if behind is not None:
-      self._blame = behind.req   # from here on a fault is the admission's
-      self._seat(behind, self._read_first(behind))
+  def _admit(self, seat: Optional["_Admission"] = None) -> None:
+    """Read the first token of ``seat`` (an admission :meth:`_admit_behind`
+    left unread behind a step that has been harvested by now, so its lane
+    is free: :meth:`_decode_once` says which) and seat it; then prefill queued
+    requests into the lanes still free (EOS-freed or virgin) and not
+    spoken for, one at a time: dispatch, read, insert. With a step in
+    flight their chunks queue behind it, not into a drained device."""
+    # with no step in flight there is nothing to run ahead of: every
+    # admission left unread is seated before the next step, and is in it
+    due = list(self._unread) if self._flight is None \
+        else [seat] if seat else []
+    for adm in due:
+      self._blame = adm.req      # from here on a fault is the admission's
+      self._seat(adm, self._read_first(adm))
     for slot in range(self.num_slots):
-      if self._slots[slot] is not None:
+      if self._slots[slot] is not None or self._spoken_for(slot):
         continue
       adm = self._begin_admission(slot)
       if adm is None:
         return
       with self._prefill_span(adm):
-        self._dispatch_chunks(adm)
+        self._dispatch_chunks(adm, behind=self._flight is not None)
         first = self._read_first(adm)
       self._seat(adm, first)
 
-  def _admit_behind(self, remaining, certain: int) -> Optional["_Admission"]:
+  def _admit_behind(self, remaining, certain: int) -> None:
     """Queue ONE admission behind the decode dispatch that was just made
     and read nothing: the host's chunk and insert calls pass while the
     device runs the step, and on the in-order device they run after it.
 
     The lane is one that is free, or one the dispatch is CERTAIN to leave
-    free: its request's budget (``remaining``, as the dispatch was given
-    it) ends within ``certain`` tokens, the least the dispatch emits for a
-    live lane, whatever EOS does. An inactive lane of the step writes only
-    where the next insert overwrites (``SlotDecoder._one_step``), so the
-    row may be inserted behind the step before the old request has been
-    harvested; ``_slots`` keeps the old request until the harvest frees
-    the lane, and :meth:`_admit` seats the new one after it. The paged
-    pool releases pages and resets page tables in the harvest, so it takes
-    a free lane only and inserts after its read. At most one admission is
-    ever in flight unread (``_admitting``)."""
+    free: its request's budget (``remaining``, as the dispatch was GIVEN
+    it: with a step in flight unread the host counts what that step spends
+    at most, :meth:`_lanes_given`) ends within ``certain`` tokens, the
+    least the dispatch emits for a live lane, whatever EOS does. An
+    inactive lane of the step writes only where the next insert overwrites
+    (``SlotDecoder._one_step``), so the row may be inserted behind the
+    step before the old request has been harvested; ``_slots`` keeps the
+    old request until the harvest frees the lane, and :meth:`_admit` seats
+    the new one after it. The paged pool releases pages and resets page
+    tables in the harvest, so it takes a free lane only and inserts after
+    its read. The admission is left in ``_unread``: it is the only one
+    there once the older one, dispatched a step earlier, has been seated
+    in this same pass."""
     with obs_spans.region("serve.admit", self.stats, "t_admit_s",
                           record=False, queue=self._devq):
-      slot = next((i for i, r in enumerate(self._slots) if r is None), None)
+      slot = next((i for i, r in enumerate(self._slots)
+                   if r is None and not self._spoken_for(i)), None)
       ahead = slot is None and not self.decoder.paged
       if ahead:
         slot = next((i for i in range(self.num_slots)
-                     if remaining[i] <= certain), None)
+                     if remaining[i] <= certain
+                     and not self._spoken_for(i)), None)
       adm = None if slot is None else self._begin_admission(slot)
       if adm is None:
-        return None
-      chunks = self.stats["prefill_chunks"]
+        return
       with self._prefill_span(adm):
-        self._dispatch_chunks(adm)
-      self.stats["prefill_chunks_behind_decode"] += \
-          self.stats["prefill_chunks"] - chunks
+        self._dispatch_chunks(adm, behind=True)
       self.stats["admits_ahead"] += ahead
       if not self.decoder.paged:
         self._insert(adm)
+      self._unread.append(adm)   # unread first, so it is never in neither
+      self._admitting = None
       self._blame = None         # a fault in the step's read is nobody's
-    return adm
+
+  def _spoken_for(self, slot: int) -> bool:
+    """Whether an admission left unread is on its way into lane ``slot``."""
+    return any(a.slot == slot for a in self._unread)
 
   def _phase(self, name: str, key: str):
     """A per-dispatch phase of the loop thread: counter and annotation
@@ -1456,8 +1516,14 @@ class ServingEngine(object):
   def _mark_admitting(self, req: sched.Request) -> None:
     self._admitting = self._blame = req
 
-  def _admission_over(self) -> None:
-    self._admitting = self._blame = None
+  def _admission_over(self, adm: Optional["_Admission"] = None) -> None:
+    """``adm`` is seated or finished (``None``: the request just popped
+    never got as far as an admission)."""
+    if adm is not None and adm in self._unread:
+      self._unread.remove(adm)
+    else:
+      self._admitting = None
+    self._blame = None
 
   def _finished(self, req: sched.Request, token: int) -> bool:
     if self.eos_id is not None and int(token) == self.eos_id:
@@ -1485,9 +1551,23 @@ class ServingEngine(object):
         q["queue_wait_ms"].observe(req.queue_wait * 1e3)
 
   def _decode_once(self) -> Optional[_Admission]:
-    """One fused ``horizon``-step dispatch + host-side harvest, with one
-    admission queued behind the dispatch where a lane allows it: returned
-    unread, for :meth:`_admit` to seat.
+    """One fused ``horizon``-step dispatch and one host-side harvest, with
+    one admission queued behind the dispatch where a lane allows it.
+    Returns the admission that was waiting unread when the pass began, for
+    :meth:`_admit` to read and seat (with no step left in flight it seats
+    every unread one).
+
+    Where a step may run ahead (``_run_ahead``: the plain step over the
+    contiguous slab) the harvest is of the step dispatched a pass EARLIER:
+    the new step is dispatched before that one is read, its lane state made
+    on the device (:meth:`_carried`), so the copy back, the harvest and the
+    next dispatch call all pass while the device runs. The first step of a
+    run is dispatched from the host's arrays and left unread; a pass in
+    which no lane can still be live dispatches nothing and reads the last
+    one. The admission that was waiting is due then: its insert preceded
+    the new step and its lane went live in it. Elsewhere (the paged pool,
+    speculation) the step is read in the pass that dispatched it, nothing
+    stays in flight, and the admission just queued is due.
 
     The device scan carries each lane's EOS/budget done-mask; the host
     replays the identical stop rule over the returned ``[horizon,
@@ -1498,16 +1578,17 @@ class ServingEngine(object):
     with obs_spans.region("serve.decode", record=self._rec is not None,
                           horizon=self.horizon) as dec:
       with self._phase("serve.decode.prep", "t_decode_prep_s"):
-        active = np.asarray([r is not None for r in self._slots], bool)
-        remaining = np.asarray(
-            [0 if r is None else r.max_new_tokens - r.generated
-             for r in self._slots], np.int32)
+        waiting = self._unread[0] if self._unread else None
+        reqs, remaining = self._lanes_given(waiting)
+        active = remaining > 0
         dec.attrs["active"] = int(active.sum())
       if self.spec_depth > 0:
-        steps, lanes, behind = self._decode_spec(active, remaining)
+        steps, lanes = self._decode_spec(active, remaining)
       else:
-        steps, lanes, behind = self._decode_plain(active, remaining)
-    self.stats["decode_dispatches"] += 1
+        steps, lanes = self._decode_plain(reqs, active, remaining, waiting)
+      # the recorder's span is one a DISPATCH: a pass that only read the
+      # last step of a run leaves its phases and its lanes' spans
+      dec.recorded = dec.recorded and bool(active.any())
     t0, dt = dec.t0, dec.dur         # the one clock reading of the pass
     emitted = self.stats["emitted_tokens"] - tokens_before
     if dt > 0 and emitted:
@@ -1536,7 +1617,60 @@ class ServingEngine(object):
       if self._pool is not None:
         m["kv_pages_in_use"].set(self._pool.in_use)
         m["kv_pages_free"].set(self._pool.free_pages)
-    return behind
+    return waiting
+
+  def _lanes_given(self, waiting: Optional[_Admission]):
+    """What the step about to be dispatched is GIVEN, as far as the host
+    knows: ``(reqs, remaining)``, lane by lane the request that is live in
+    it (``None``: the lane is off) and its unspent budget.
+
+    With a step in flight unread, a lane that is live in that step has, at
+    most, what it had less ``min(horizon, remaining)``: exact without an
+    EOS id, an upper bound with one (the device's own carried mask is what
+    the new step runs on; the host's count only certifies a lane free,
+    :meth:`_admit_behind`, and says whether any lane can still be live).
+    ``waiting``, an admission unread behind that step, goes live with its
+    budget less the first token it has not emitted yet."""
+    flight = self._flight
+    reqs: List[Optional[sched.Request]] = [None] * self.num_slots
+    remaining = np.zeros((self.num_slots,), np.int32)
+    for slot, req in enumerate(self._slots):
+      if req is None:
+        continue
+      left = req.max_new_tokens - req.generated
+      if flight is not None and flight.reqs[slot] is req:
+        left -= self.horizon
+      if left > 0:
+        reqs[slot], remaining[slot] = req, left
+    if waiting is not None:
+      left = waiting.req.max_new_tokens - waiting.req.generated - 1
+      if left > 0:
+        reqs[waiting.slot], remaining[waiting.slot] = waiting.req, left
+    return reqs, remaining
+
+  def _carried(self, flight: _Step, reqs, remaining,
+               waiting: Optional[_Admission]):
+    """The lane state of the next step, made on the device from the
+    outputs of ``flight``, the step before it, unread: ``(last tokens,
+    active, remaining)`` as ``SlotDecoder.step_many`` takes them.
+
+    A lane whose request is the one ``flight`` was given stays the
+    device's. What the host knows and the device does not goes in as the
+    lane's override: a lane seated, cancelled, reaped or reset since that
+    dispatch (its request is another, or none). ``waiting``'s lane takes
+    its first token from the prefill's own output, still on the device."""
+    whose = np.asarray([slots_lib.LANE_DEVICE if mine is was
+                        else slots_lib.LANE_HOST
+                        for mine, was in zip(self._slots, flight.reqs)],
+                       np.int32)
+    first = None
+    if waiting is not None and reqs[waiting.slot] is waiting.req:
+      whose[waiting.slot], first = slots_lib.LANE_FIRST, waiting.head
+    lane = self.decoder.merge_lanes(
+        flight.out[1], flight.out[2], flight.out[3],
+        np.stack([whose, self._last, remaining]), first)
+    self._devq.dispatched()
+    return lane
 
   def _harvest(self, req, tok: int, slot: int, freed: List[int]) -> bool:
     """Record one emitted token; on the request's stop, free its slot
@@ -1556,18 +1690,41 @@ class ServingEngine(object):
       freed.append(slot)
     return True
 
-  def _decode_plain(self, active, remaining):
+  def _decode_plain(self, reqs, active, remaining,
+                    waiting: Optional[_Admission]):
     """The non-speculative fused horizon (SlotDecoder.step_many).
-    Returns ``(steps, lanes, behind)`` — ``lanes`` is the slot-attributed
+    Returns ``(steps, lanes)`` — ``lanes`` is the slot-attributed
     ``(slot, trace_id, emitted)`` list for the per-request decode spans,
-    built only while the recorder is live (zero work otherwise);
-    ``behind`` the admission :meth:`_admit_behind` queued behind the step,
-    or ``None``. The three phases of a dispatch are regions: the call
-    returning, the wait for the token matrix, and the host's harvest of
-    it."""
+    built only while the recorder is live (zero work otherwise). The three
+    phases of a step are regions: the dispatch call returning, the wait
+    for the token matrix, and the host's harvest of it
+    (:meth:`_read_step`) — with a step in flight, of the OLDER step."""
+    flight, step = self._flight, None
+    if active.any():
+      step = self._dispatch_plain(flight, reqs, active, remaining, waiting)
+      # a budget that ends within the horizon ends inside the scan
+      self._admit_behind(remaining, self.horizon)
+    elif flight is not None:
+      # no lane can be live after the step in flight: nothing to dispatch,
+      # but a lane that step is certain to leave free can be filled behind it
+      self._admit_behind(remaining, 0)
+    if not self._run_ahead:
+      return self._read_step(step)
+    self._flight = step
+    return (0, []) if flight is None else self._read_step(flight)
+
+  def _dispatch_plain(self, flight: Optional[_Step], reqs, active,
+                      remaining, waiting: Optional[_Admission]) -> _Step:
+    """Dispatch one ``step_many`` (the ``serve.decode.dispatch`` region):
+    from the host's arrays where no step is in flight, as it always was;
+    ahead of the unread ``flight`` from that step's outputs."""
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
+      lane = (self._last, active, remaining) if flight is None \
+          else self._carried(flight, reqs, remaining, waiting)
       out = self._on_slab(lambda slabs: self.decoder.step_many(
-          self.params, slabs, self._last, active, remaining, self.horizon))
+          self.params, slabs, *lane, self.horizon))
+      self.stats["decode_dispatches"] += 1
+      self.stats["decode_dispatches_ahead"] += flight is not None
       writes, dma = self.decoder.cursor_writes[self.horizon]
       self.stats["cursor_leaf_writes"] += writes
       self.stats["cursor_leaf_writes_dma"] += dma
@@ -1578,25 +1735,30 @@ class ServingEngine(object):
       products, kernel = self.decoder.expert_products["step", self.horizon]
       self.stats["expert_products"] += products
       self.stats["expert_products_kernel"] += kernel
-    step_seq = self._slab_seq
-    # a budget that ends within the horizon ends inside the scan
-    behind = self._admit_behind(remaining, self.horizon)
+    return _Step(out, self._slab_seq, reqs)
+
+  def _read_step(self, step: _Step):
+    """Wait for ``step``'s token matrix and harvest it: ``(steps,
+    lanes)``. A lane is harvested for the request the step was GIVEN live,
+    and only while that request still holds the lane: the tokens of one
+    cancelled, reaped or ended at its first token since the dispatch are
+    discarded, and a request seated since was not in the step."""
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
-      toks = np.asarray(out[1])                   # [horizon, num_slots]
+      toks = np.asarray(step.out[1])              # [horizon, num_slots]
       # the step's read returned: the rest of the region is empty time,
-      # unless the admission's programs are queued behind the step
-      self._devq.drained(step_seq)
+      # unless a newer program (an admission's, the next step) is queued
+      # behind it: the STEP's own number says which
+      self._devq.drained(step.seq)
       if self.decoder.counted:       # the step's own sums, beside the tokens
-        for name, value in out[4].items():
+        for name, value in step.out[4].items():
           self.stats[_STEP_COUNTERS[name]] += int(np.asarray(value))
     lanes: List[tuple] = []
     freed: List[int] = []
     # ONE region round the whole harvest: _harvest runs per token
     with self._phase("serve.decode.harvest", "t_decode_harvest_s"):
       self.stats["steps"] += self.horizon
-      for slot in range(self.num_slots):
-        req = self._slots[slot]
-        if req is None:
+      for slot, req in enumerate(step.reqs):
+        if req is None or self._slots[slot] is not req:
           continue
         emitted = 0
         for j in range(self.horizon):
@@ -1608,7 +1770,7 @@ class ServingEngine(object):
         if self._detail:
           lanes.append((slot, req.trace_id, emitted))
       self._reset_freed(freed)
-    return self.horizon, lanes, behind
+    return self.horizon, lanes
 
   def _decode_spec(self, active, remaining):
     """The self-speculative fused dispatch (SlotDecoder.step_spec).
@@ -1618,17 +1780,18 @@ class ServingEngine(object):
     stop rule per token (the step_many contract), so the two views
     cannot diverge. Accepted/rejected draft verdicts feed the
     ``spec_accepted``/``spec_rejected`` counters. Returns ``(steps,
-    lanes, behind)`` like :meth:`_decode_plain`.
+    lanes)`` like :meth:`_decode_plain`.
     """
     k, rounds = self.spec_depth, self._spec_rounds
     with self._phase("serve.decode.dispatch", "t_decode_dispatch_s"):
       _, toks, counts, acc, rej, _, _ = self._on_slab(
           lambda slabs: self.decoder.step_spec(
               self.params, slabs, self._last, active, remaining, rounds))
+      self.stats["decode_dispatches"] += 1
     step_seq = self._slab_seq
     # every round emits at least one token a live lane: a budget of at
     # most ``rounds`` ends inside the dispatch
-    behind = self._admit_behind(remaining, rounds)
+    self._admit_behind(remaining, rounds)
     with self._phase("serve.decode.fetch", "t_decode_fetch_s"):
       toks = np.asarray(toks)          # [rounds, spec_depth, num_slots]
       # the step's read returned: the rest of the region is empty time,
@@ -1665,4 +1828,4 @@ class ServingEngine(object):
         if self._detail:
           lanes.append((slot, req.trace_id, emitted))
       self._reset_freed(freed)
-    return rounds * k, lanes, behind
+    return rounds * k, lanes

@@ -57,6 +57,12 @@ Jitted functions owning the slab:
   ``horizon - 1`` frozen slot-steps per completion (the same
   done-mask mechanics as ``greedy_generate_kv(eos_id=...)``, so the
   emitted stream stays bit-identical).
+* :meth:`SlotDecoder.merge_lanes` — the lane state ``step_many`` takes
+  (last token, active, remaining), made ON THE DEVICE from the outputs of
+  the ``step_many`` before it, with what the host knows and the device does
+  not laid over it lane by lane: one tiny program, so that the next step can
+  be dispatched before the last one's tokens are read and ``step_many``'s
+  own program stays what it was.
 * :meth:`SlotDecoder.step_spec` — SELF-SPECULATIVE decode
   (``spec_depth > 0``): each fused round drafts ``spec_depth`` tokens
   with a shallow-exit prefix of the model's own layers
@@ -128,6 +134,13 @@ ROW_CHUNK_DIVISOR = 8
 #: what every model ran before the padded plan and the equality tests still
 #: steer a decoder to); 1 must be reachable so every length decomposes.
 EXACT_BUCKETS = (512, 128, 32, 16, 8, 4, 2, 1)
+
+
+#: whose word a lane's state is in :meth:`SlotDecoder.merge_lanes`: the
+#: device's own (what the step before carried out), the host's (a lane it
+#: seated, reaped or reset since that step was dispatched), or an admission's
+#: whose first token is the prefill's output, still unread
+LANE_DEVICE, LANE_HOST, LANE_FIRST = 0, 1, 2
 
 
 def row_buckets(max_seq_len: int):
@@ -388,6 +401,7 @@ class SlotDecoder(object):
     self._reset_slots_fn = jax.jit(self._reset_slots_impl,
                                    donate_argnums=0)
     self._step_fn = jax.jit(self._step_impl, donate_argnums=1)
+    self._merge_lanes_fn = jax.jit(self._merge_lanes_impl)
     self._step_many_jits = {}    # horizon -> jitted fused-scan step
     #: horizon -> (per-slot cursor writes of cache leaves a step_many
     #: dispatch makes, those of them by ops.cursor_write's DMA kernel):
@@ -873,6 +887,45 @@ class SlotDecoder(object):
 
       fn = self._step_many_jits[horizon] = jax.jit(impl, donate_argnums=1)
     return fn
+
+  # -- the lane state, carried on the device --------------------------------
+
+  def _merge_lanes_impl(self, toks, active, remaining, host, first):
+    obs_device.note_trace("serve.merge_lanes")
+    whose, host_tok, host_rem = host[0], host[1], host[2]
+    pad = jnp.int32(self.pad_id)
+    mine = whose != LANE_DEVICE
+    admitted = whose == LANE_FIRST
+    # step_many's own carry: the last token of a lane still live, pad else
+    tok = jnp.where(active, toks[-1], pad)
+    tok = jnp.where(admitted, first[0], jnp.where(mine, host_tok, tok))
+    live = host_rem > 0
+    if self.eos_id is not None:
+      # a first token that is EOS ended its request: the host learns it at
+      # the read, the lane never goes live
+      live = jnp.logical_and(live, jnp.logical_not(
+          jnp.logical_and(admitted, tok == self.eos_id)))
+    active = jnp.where(mine, live, active)
+    remaining = jnp.where(mine, host_rem, remaining)
+    return jnp.where(active, tok, pad), active, remaining
+
+  def merge_lanes(self, toks, active, remaining, host, first=None):
+    """The ``(last_tokens, active, remaining)`` the NEXT :meth:`step_many`
+    takes, made on the device from the ``(tokens, active, remaining)`` the
+    one before it returned, nothing read: a lane the device carried keeps
+    ``where(active, tokens[-1], pad)``, its mask and its budget.
+
+    ``host: [3, num_slots] int32`` lays the host's word over it: row 0 says
+    whose each lane is (:data:`LANE_DEVICE`, :data:`LANE_HOST`,
+    :data:`LANE_FIRST`), rows 1 and 2 the last token and the unspent budget
+    of a lane that is not the device's (budget 0: the lane is off). A
+    :data:`LANE_FIRST` lane's token is ``first[0]``, a prefill's ``[1]``
+    output still on the device (:meth:`prefill_chunks`' ``head``), and the
+    lane stays off where that token is EOS. One program whatever the mix."""
+    if first is None:              # no admission to merge: the same program
+      first = np.zeros((1,), np.int32)
+    return self._merge_lanes_fn(toks, active, remaining,
+                                np.asarray(host, np.int32), first)
 
   # -- self-speculative decode ----------------------------------------------
 

@@ -498,8 +498,10 @@ def test_recorder_names_and_gating(toy, monkeypatch, detail):
   assert len(decode) == stats["decode_dispatches"]
   assert all(set(r["attrs"]) == {"horizon", "active"} for r in decode)
   if detail == "1":
-    # a decode's phases lie inside it, on the region's own clock
-    d = decode[0]
+    # a decode's phases lie inside it, on the region's own clock. The
+    # first step of a run is left in flight unread (prep and dispatch
+    # only); the pass that dispatches the second reads and harvests it
+    d = decode[1]
     inside = [r for r in recs if r["name"].startswith("serve.decode.")
               and d["t0"] <= r["t0"] and r["t0"] + r["dur"]
               <= d["t0"] + d["dur"] + 1e-9]
