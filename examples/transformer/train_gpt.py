@@ -41,10 +41,6 @@ if __name__ == "__main__":
                       help="grouped-query attention: 0=MHA, 1=MQA; "
                            "grouped KV rides the ring unexpanded and the "
                            "flash kernels consume it natively")
-  parser.add_argument("--fused", action="store_true",
-                      help="run ln1+QKV, ln2+up and gelu+down each as "
-                           "ONE Pallas kernel (fuse_qkv + ln_matmul + "
-                           "act_matmul)")
   parser.add_argument("--remat_policy", default="none",
                       choices=("none", "dots"),
                       help="'dots' saves MXU outputs at remat blocks and "
@@ -83,8 +79,6 @@ if __name__ == "__main__":
   from tensorflowonspark_tpu.parallel import sharding as SH
   from tensorflowonspark_tpu import optim
 
-  fused = dict(fuse_qkv=True, ln_matmul_impl="fused",
-               act_matmul_impl="fused") if args.fused else {}
   tx = optim.make_optimizer(learning_rate=args.lr, clip_norm=1.0,
                             optimizer=args.optimizer,
                             grad_accum_steps=args.grad_accum)
@@ -163,8 +157,7 @@ if __name__ == "__main__":
         vocab_size=args.vocab, num_layers=args.layers,
         num_heads=args.heads, d_model=args.d_model,
         d_ff=args.d_model * 4, max_seq_len=args.seq_len,
-        num_kv_heads=args.kv_heads, remat_policy=args.remat_policy,
-        **fused)
+        num_kv_heads=args.kv_heads, remat_policy=args.remat_policy)
     state = tfm.create_state(jax.random.PRNGKey(0), cfg,
                              seq_len=args.seq_len, tx=tx)
     pipe = tfm.make_pipeline_train_step(cfg, mesh, args.microbatches)
@@ -186,7 +179,7 @@ if __name__ == "__main__":
       d_model=args.d_model, d_ff=args.d_model * 4,
       max_seq_len=args.seq_len, num_kv_heads=args.kv_heads,
       remat_policy=args.remat_policy,
-      use_ring_attention=mesh.shape[M.AXIS_SEQUENCE] > 1, **fused)
+      use_ring_attention=mesh.shape[M.AXIS_SEQUENCE] > 1)
   state, sharding = tfm.create_sharded_state(jax.random.PRNGKey(0), cfg,
                                              mesh, seq_len=args.seq_len,
                                              tx=tx)

@@ -21,9 +21,7 @@ CHIPS_PER_NODE="${CHIPS_PER_NODE:-1}"
 # framework's pipeline/transform tasks claim disjoint chip groups
 # themselves (pipeline._allocate_transform_chips); cluster.run carves
 # chips via chips_per_node at reservation time.
-ENV_CONF=(
-  --conf "spark.executorEnv.TFOS_TPU_FLASH_BWD=${TFOS_TPU_FLASH_BWD:-fused}"
-)
+ENV_CONF=()
 [ -n "${TOS_TPU_SERVER_HOST:-}" ] && ENV_CONF+=(
   --conf "spark.executorEnv.TOS_TPU_SERVER_HOST=${TOS_TPU_SERVER_HOST}")
 [ -n "${TOS_TPU_SERVER_PORT:-}" ] && ENV_CONF+=(
@@ -36,7 +34,7 @@ exec "${SPARK_HOME}/bin/spark-submit" \
   --executor-cores 1 \
   --conf spark.task.maxFailures=4 \
   --conf spark.dynamicAllocation.enabled=false \
-  "${ENV_CONF[@]}" \
+  ${ENV_CONF[@]+"${ENV_CONF[@]}"} \
   ${EXTRA_SPARK_CONF:-} \
   "${APP}" \
   --cluster_size "${WORKERS}" \

@@ -67,29 +67,10 @@ class TransformerConfig:
   # kernel-loop iterations. Training, prefill and KV-cache decode all
   # honor it.
   attention_window: int = 0
-  # Project Q, K and V with ONE matmul (heads axis = num_heads + 2·kv_heads,
-  # sliced after): one bigger MXU op instead of three smaller ones. Changes
-  # the parameter tree ("qkv" instead of "q"/"k"/"v")
-  fuse_qkv: bool = False
   # "auto": fused Pallas LayerNorm (ops.layer_norm) on TPU, flax elsewhere;
   # "fused" forces the kernel everywhere (interpret mode off-TPU — how CPU
   # CI exercises the production code path); "flax" opts out
   layer_norm_impl: str = "auto"
-  # "fused": the ln2 -> MLP up-projection pair runs as ONE Pallas kernel
-  # (ops.ln_matmul) — the normalized activation never round-trips HBM
-  # (interpret mode off-TPU). Applies everywhere except decode: mesh-free
-  # contexts run the plain kernel, sharded models map it per-shard
-  # through shard_map (ops.ln_matmul_sharded). Param tree is IDENTICAL
-  # either way (ln2/scale, mlp/up/kernel), so checkpoints are
-  # interchangeable across settings. "off" opts out.
-  ln_matmul_impl: str = "off"
-  # "fused": the MLP's gelu -> down-projection pair runs as ONE Pallas
-  # kernel (ops.gelu_matmul) — the [rows, d_ff] activated tensor (the
-  # widest in the block) never round-trips HBM (interpret off-TPU).
-  # Sharded models contract the tensor-sharded d_ff per shard and psum,
-  # the same collective the unfused down-proj needs. Param tree is
-  # IDENTICAL either way (mlp/down/kernel). "off" opts out.
-  act_matmul_impl: str = "off"
   # Mixture-of-experts: when moe_experts > 0, every `moe_every`-th layer
   # (moe_every >= 1) replaces its dense MLP with an expert-routed FFN
   # (parallel.expert_parallel; experts shard over the `expert` mesh axis)
@@ -309,12 +290,6 @@ class TransformerConfig:
     if self.embed_lookup not in ("gather", "one_hot"):
       raise ValueError("embed_lookup must be 'gather' or 'one_hot', got %r"
                        % (self.embed_lookup,))
-    if self.ln_matmul_impl not in ("off", "fused"):
-      raise ValueError("ln_matmul_impl must be 'off' or 'fused', got %r"
-                       % (self.ln_matmul_impl,))
-    if self.act_matmul_impl not in ("off", "fused"):
-      raise ValueError("act_matmul_impl must be 'off' or 'fused', got %r"
-                       % (self.act_matmul_impl,))
     if self.remat_policy not in ("none", "dots"):
       raise ValueError("remat_policy must be 'none' or 'dots', got %r"
                        % (self.remat_policy,))
@@ -387,9 +362,6 @@ class TransformerConfig:
         raise ValueError(ring_refusal(what, feature))
     if self.wide_heads:
       for asked, feature, what in (
-          (self.fuse_qkv, "fuse_qkv", "fuse_qkv"),
-          (self.ln_matmul_impl == "fused", "fuse_qkv",
-           "ln_matmul_impl='fused'"),
           (self.kv_cache_dtype == "int8", "int8", "kv_cache_dtype='int8'"),
           (self.kv_page_size > 0, "pages",
            "the paged KV pool (kv_page_size=%d)" % self.kv_page_size),
@@ -562,9 +534,6 @@ _RING_REFUSALS = {
 #: why each feature cannot take attention heads whose keys and values differ
 #: in width, whose KV head count differs by layer, or that carry a sink
 _HEADS_REFUSALS = {
-    "fuse_qkv": "the fused projection is ONE kernel of (num_heads + 2 x "
-                "kv_heads) heads of one head_dim (a fused projection of two "
-                "widths is not built)",
     "int8": "an int8 cache quantizes K and V per head under one layout of "
             "scales, untried for leaves of two widths",
     "pages": "the pool's pages are [page, kv_heads, head_dim] for K and V "
@@ -757,41 +726,10 @@ def _make_layer_norm(cfg: TransformerConfig, mesh, name: str):
   return nn.LayerNorm(dtype=jnp.float32, use_bias=False, name=name)
 
 
-def _ln_matmul_call(x, ln_scale, w2, mesh=None):
-  """The fused LN+matmul kernel with the shared off-TPU interpret policy
-  (one definition for the attention and MLP call sites). With a mesh the
-  kernel maps per-shard through shard_map (ops.ln_matmul_sharded), so the
-  multi-chip training path gets the fusion too."""
-  from tensorflowonspark_tpu.ops import ln_matmul as _ln_mm
-  from tensorflowonspark_tpu.ops import ln_matmul_sharded as _ln_mm_sh
-  interp = ops.pallas_interpret()
-  if mesh is not None:
-    return _ln_mm_sh(x, ln_scale, w2, mesh, interpret=interp)
-  return _ln_mm(x, ln_scale, w2, interpret=interp)
-
-
 # grouped-KV head broadcast: ONE definition, shared with the ring
 # (parallel.ring_attention.expand_heads) so the grouping convention
 # (blocked: KV head j serves query heads [j*g, (j+1)*g)) cannot drift
 _expand_kv = ra.expand_heads
-
-
-class _QKVKernel(nn.Module):
-  """Declares the fused-QKV kernel at the same param path
-  (``attn/qkv/kernel``) nn.DenseGeneral would, for the fused-LN path."""
-  d_model: int
-  n_heads_total: int
-  head_dim: int
-  heads_logical: Optional[str]
-
-  @nn.compact
-  def __call__(self):
-    return self.param(
-        "kernel",
-        nn.with_logical_partitioning(
-            nn.initializers.lecun_normal(),
-            ("embed", self.heads_logical, "kv")),
-        (self.d_model, self.n_heads_total, self.head_dim), jnp.float32)
 
 
 def _heads_logical(n_heads: int, mesh) -> Optional[str]:
@@ -799,9 +737,9 @@ def _heads_logical(n_heads: int, mesh) -> Optional[str]:
   tensor-parallel mesh axis) when the head count divides the tensor axis,
   else None (replicated). ONE rule shared by the projection kernels and
   the KV-cache constraint — a head count the axis can't divide (grouped
-  KV heads, or the fused h+2·hk projection) must fall back to replication
-  on BOTH sides or params and cache shard inconsistently (GSPMD then
-  gathers the cache every decode step)."""
+  KV heads) must fall back to replication on BOTH sides or params and
+  cache shard inconsistently (GSPMD then gathers the cache every decode
+  step)."""
   t = 1 if mesh is None else mesh.shape.get(mesh_lib.AXIS_TENSOR, 1)
   return "heads" if n_heads % max(1, t) == 0 else None
 
@@ -1234,12 +1172,9 @@ class Attention(nn.Module):
   sink: bool = False
 
   @nn.compact
-  def __call__(self, x, positions, decode: bool = False, ln_scale=None,
-               loop_pass: int = 0):
-    """With ``ln_scale`` (requires ``fuse_qkv``), ``x`` is the RAW
-    residual stream and ln1 + the QKV projection run as one Pallas kernel
-    (ops.ln_matmul); otherwise ``x`` arrives normalized. ``loop_pass`` is
-    which of ``cfg.loop_passes`` this call is: the decode cache it owns."""
+  def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0):
+    """``x`` arrives normalized. ``loop_pass`` is which of
+    ``cfg.loop_passes`` this call is: the decode cache it owns."""
     cfg = self.cfg
     win = cfg.attention_window if self.window is None else self.window
     dense = lambda feats, logical, name: nn.DenseGeneral(  # noqa: E731
@@ -1248,31 +1183,12 @@ class Attention(nn.Module):
             nn.initializers.lecun_normal(), logical))
     heads_axis = lambda n: _heads_logical(n, self.mesh)  # noqa: E731
 
-    if cfg.fuse_qkv:
-      # one MXU matmul for all three projections, sliced on the heads axis
-      h, hk = cfg.num_heads, cfg.kv_heads
-      if ln_scale is not None:
-        kernel = _QKVKernel(cfg.d_model, h + 2 * hk, cfg.head_dim,
-                            heads_axis(h + 2 * hk), name="qkv")()
-        flat = _ln_matmul_call(
-            x, ln_scale, kernel.reshape(cfg.d_model, -1).astype(cfg.dtype),
-            mesh=self.mesh)
-        qkv = flat.reshape(x.shape[:-1] + (h + 2 * hk, cfg.head_dim))
-      else:
-        qkv = dense((h + 2 * hk, cfg.head_dim),
-                    ("embed", heads_axis(h + 2 * hk), "kv"), "qkv")(x)
-      q = qkv[..., :h, :]
-      k = qkv[..., h:h + hk, :]
-      v = qkv[..., h + hk:, :]
-    else:
-      if ln_scale is not None:
-        raise ValueError("ln-fused attention requires fuse_qkv")
-      q = dense((cfg.num_heads, cfg.head_dim),
-                ("embed", heads_axis(cfg.num_heads), "kv"), "q")(x)
-      # GQA: K/V carry only kv_heads heads (= num_heads unless configured)
-      hk = self.kv_heads or cfg.kv_heads
-      k = dense((hk, cfg.head_dim), ("embed", heads_axis(hk), "kv"), "k")(x)
-      v = dense((hk, cfg.v_head_dim), ("embed", heads_axis(hk), "kv"), "v")(x)
+    q = dense((cfg.num_heads, cfg.head_dim),
+              ("embed", heads_axis(cfg.num_heads), "kv"), "q")(x)
+    # GQA: K/V carry only kv_heads heads (= num_heads unless configured)
+    hk = self.kv_heads or cfg.kv_heads
+    k = dense((hk, cfg.head_dim), ("embed", heads_axis(hk), "kv"), "k")(x)
+    v = dense((hk, cfg.v_head_dim), ("embed", heads_axis(hk), "kv"), "v")(x)
     if cfg.attn_value_scale != 1.0:
       v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     sink = self.param("sink", nn.initializers.zeros, (cfg.num_heads,),
@@ -1289,9 +1205,6 @@ class Attention(nn.Module):
           t).astype(t.dtype) for name, t in (("q_norm", q), ("k_norm", k)))
     gate = None
     if cfg.attn_gate:
-      if ln_scale is not None:
-        raise ValueError("attn_gate reads the normed input; the ln-fused "
-                         "attention hands over the raw stream")
       gate = dense((cfg.num_heads, cfg.v_head_dim),
                    ("embed", heads_axis(cfg.num_heads), "kv"), "gate")(x)
 
@@ -1653,49 +1566,6 @@ class Attention(nn.Module):
     return self._out_proj(o.reshape(b, seg, h, d).astype(q.dtype), gate)
 
 
-class _UpKernel(nn.Module):
-  """Declares the MLP up-projection kernel at the same param path
-  (``mlp/up/kernel``) nn.Dense would, for the fused-LN path that feeds it
-  to ops.ln_matmul instead of a Dense call."""
-  d_model: int
-  d_ff: int
-
-  @nn.compact
-  def __call__(self):
-    return self.param(
-        "kernel",
-        nn.with_logical_partitioning(nn.initializers.lecun_normal(),
-                                     ("embed", "mlp")),
-        (self.d_model, self.d_ff), jnp.float32)
-
-
-class _DownKernel(nn.Module):
-  """Declares the MLP down-projection kernel at the same param path
-  (``mlp/down/kernel``) nn.Dense would, for the fused gelu+matmul path
-  that feeds it to ops.gelu_matmul instead of a Dense call."""
-  d_ff: int
-  d_model: int
-
-  @nn.compact
-  def __call__(self):
-    return self.param(
-        "kernel",
-        nn.with_logical_partitioning(nn.initializers.lecun_normal(),
-                                     ("mlp", "embed")),
-        (self.d_ff, self.d_model), jnp.float32)
-
-
-def _gelu_matmul_call(x, w, mesh=None):
-  """The fused GELU+matmul kernel with the shared off-TPU interpret
-  policy; per-shard through shard_map under a mesh (with the tensor-axis
-  psum the unfused down-proj needs anyway)."""
-  from tensorflowonspark_tpu.ops import gelu_matmul, gelu_matmul_sharded
-  interp = ops.pallas_interpret()
-  if mesh is not None:
-    return gelu_matmul_sharded(x, w, mesh, interpret=interp)
-  return gelu_matmul(x, w, interpret=interp)
-
-
 def _swiglu(x, d_ff: int, cfg):
   """``down(silu(gate x) * up x)`` inside the calling module's scope
   (params ``gate``/``up``/``down``): the gated SiLU MLP, dense or as a
@@ -1708,30 +1578,15 @@ def _swiglu(x, d_ff: int, cfg):
 class MLPBlock(nn.Module):
   cfg: TransformerConfig
   mesh: Optional[Any] = None
-  act_fused: bool = False
 
   @nn.compact
-  def __call__(self, x, ln_scale=None):
-    """With ``ln_scale`` (the preceding LayerNorm's weight), the norm and
-    the up-projection run as one Pallas kernel over the RAW ``x``; without
-    it, ``x`` is expected already normalized (the regular path). With
-    ``act_fused``, gelu + the down-projection run as one Pallas kernel
-    over the pre-activation (ops.gelu_matmul) — combined with the LN
-    fusion the whole MLP is two kernels with nothing unfused between."""
+  def __call__(self, x):
     cfg = self.cfg
     if cfg.mlp_act == "swiglu":
       return _swiglu(x, cfg.d_ff, cfg)
-    if ln_scale is not None:
-      kernel = _UpKernel(cfg.d_model, cfg.d_ff, name="up")()
-      h = _ln_matmul_call(x, ln_scale, kernel.astype(cfg.dtype),
-                          mesh=self.mesh)
-    else:
-      h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="up",
-                   kernel_init=nn.with_logical_partitioning(
-                       nn.initializers.lecun_normal(), ("embed", "mlp")))(x)
-    if self.act_fused:
-      down = _DownKernel(cfg.d_ff, cfg.d_model, name="down")()
-      return _gelu_matmul_call(h, down.astype(cfg.dtype), mesh=self.mesh)
+    h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="up",
+                 kernel_init=nn.with_logical_partitioning(
+                     nn.initializers.lecun_normal(), ("embed", "mlp")))(x)
     h = nn.gelu(h)
     return nn.Dense(cfg.d_model, dtype=cfg.dtype, use_bias=False,
                     name="down",
@@ -1809,18 +1664,6 @@ def _constrain(x, spec, mesh):
                                     mesh=mesh)
 
 
-class _LNScale(nn.Module):
-  """Declares a LayerNorm scale at the same param path ("<name>/scale")
-  the norm modules would, for the fused ln+matmul path that consumes the
-  raw activations plus this weight in one kernel."""
-  features: int
-
-  @nn.compact
-  def __call__(self):
-    return self.param("scale", nn.initializers.ones, (self.features,),
-                      jnp.float32)
-
-
 class Block(nn.Module):
   """One pre-norm residual layer: ``x += Mix(norm(x)); x += FFN(norm(x))``.
   ``mixer``/``ffn`` pick the two (``TransformerConfig.layer_types`` /
@@ -1837,7 +1680,7 @@ class Block(nn.Module):
   theta: float = 0.0
   sink: bool = False
 
-  def _attend(self, y, positions, decode, loop_pass: int = 0, **kw):
+  def _attend(self, y, positions, decode, loop_pass: int = 0):
     """This layer's attention over the normed ``y``; a model with per-layer
     windows runs it under ``jax.named_scope`` ``attn_window`` /
     ``attn_full``."""
@@ -1846,7 +1689,7 @@ class Block(nn.Module):
     scope = jax.named_scope("attn_window" if self.window else "attn_full") \
         if self.cfg.layer_windows else contextlib.nullcontext()
     with scope:
-      return attn(y, positions, decode=decode, loop_pass=loop_pass, **kw)
+      return attn(y, positions, decode=decode, loop_pass=loop_pass)
 
   @nn.compact
   def __call__(self, x, positions, decode: bool = False, loop_pass: int = 0,
@@ -1856,28 +1699,13 @@ class Block(nn.Module):
       return self._typed(x, positions, decode, n_valid)
     if cfg.post_norm:
       return self._sandwich(x, positions, decode, loop_pass)
-    fuse_ln = cfg.ln_matmul_impl == "fused" and not decode
-    if fuse_ln and cfg.fuse_qkv:
-      # ln1 + the fused QKV projection as ONE kernel over the raw
-      # residual stream (param paths unchanged: ln1/scale, attn/qkv)
-      scale1 = _LNScale(cfg.d_model, name="ln1")()
-      x = x + self._attend(x, positions, False, ln_scale=scale1)
+    y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
+    x = x + self._attend(y, positions, decode, loop_pass)
+    y = _make_layer_norm(cfg, self.mesh, "ln2")(x)
+    if self.use_moe:
+      x = x + MoEBlock(cfg, self.mesh, name="moe")(y)
     else:
-      y = _make_layer_norm(cfg, self.mesh, "ln1")(x)
-      x = x + self._attend(y, positions, decode, loop_pass)
-    act_fused = cfg.act_matmul_impl == "fused" and not decode
-    if fuse_ln and not self.use_moe:
-      # ln2 + up-projection as ONE kernel over the raw residual stream;
-      # same param paths as the unfused branch (ln2/scale, mlp/up/kernel)
-      scale = _LNScale(cfg.d_model, name="ln2")()
-      x = x + MLPBlock(cfg, self.mesh, act_fused,
-                       name="mlp")(x, ln_scale=scale)
-    else:
-      y = _make_layer_norm(cfg, self.mesh, "ln2")(x)
-      if self.use_moe:
-        x = x + MoEBlock(cfg, self.mesh, name="moe")(y)
-      else:
-        x = x + MLPBlock(cfg, self.mesh, act_fused, name="mlp")(y)
+      x = x + MLPBlock(cfg, self.mesh, name="mlp")(y)
     if decode:
       return x
     return _constrain(x, ("batch", "sequence", "embed"), self.mesh)

@@ -58,12 +58,6 @@ from tensorflowonspark_tpu.ops.flash_attention import (  # noqa: F401,E402
 from tensorflowonspark_tpu.ops.layer_norm import (  # noqa: F401
     layer_norm, layer_norm_sharded,
 )
-from tensorflowonspark_tpu.ops.act_matmul import (  # noqa: F401
-    gelu_matmul, gelu_matmul_sharded,
-)
-from tensorflowonspark_tpu.ops.ln_matmul import (  # noqa: F401
-    ln_matmul, ln_matmul_sharded,
-)
 from tensorflowonspark_tpu.ops.cursor_write import (  # noqa: F401
     cursor_write, supports as cursor_write_supports,
 )

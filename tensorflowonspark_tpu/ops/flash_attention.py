@@ -8,10 +8,11 @@ in float32 while inputs stay bfloat16. The forward also emits the per-row
 logsumexp; the backward (standard Δ correction, dense scores never
 materialized) defaults to ONE single-pass kernel producing dQ/dK/dV per
 k-block with dQ accumulated in a grid-resident VMEM block — 5 MXU matmuls
-per (q, k) block pair; ``TFOS_TPU_FLASH_BWD=split`` (or ``bwd="split"``)
-selects the two-kernel plan (dQ over q-blocks, dK/dV over k-blocks; 7
-matmuls/pair). Backward block sizes resolve separately from the forward's
-(``DEFAULT_BWD_BLOCKS``).
+per (q, k) block pair; ``bwd="split"`` selects the two-kernel plan (dQ
+over q-blocks, dK/dV over k-blocks; 7 matmuls/pair), and the fused plan
+falls back to it where its resident accumulators do not fit VMEM
+(``_gqa_fused_fits``). Backward block sizes resolve separately from the
+forward's (``DEFAULT_BWD_BLOCKS``).
 
 :func:`flash_attention` is full (self-)attention. :func:`flash_attention_block`
 computes a PARTIAL attention of local queries against one remote KV block
@@ -531,17 +532,6 @@ def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
   return _unfold(out, b, h), lse[:, :, 0].reshape(b, h, s_q)
 
 
-def default_bwd_mode() -> str:
-  """Backward kernel selection: ``fused`` (single-pass, default) or
-  ``split`` (separate dQ and dK/dV kernels) via ``TFOS_TPU_FLASH_BWD``."""
-  import os
-  mode = os.environ.get("TFOS_TPU_FLASH_BWD", "fused")
-  if mode not in ("fused", "split"):
-    raise ValueError("TFOS_TPU_FLASH_BWD must be 'fused' or 'split', got %r"
-                     % (mode,))
-  return mode
-
-
 # The backward prefers different tiles than the forward (v5e fetch-timed
 # sweeps at b4 s4096 h8 d128): the fused single-pass kernel wants smaller
 # q-blocks — each (q,k) pair read-modify-writes a blk_q-row slice of the
@@ -558,7 +548,7 @@ DEFAULT_BWD_BLOCKS = {"fused": (128, 512), "split": (256, 512)}
 def _resolve_bwd(bwd):
   """Validate/default the backward mode (block tuning resolves later,
   see DEFAULT_BWD_BLOCKS)."""
-  bwd = bwd or default_bwd_mode()
+  bwd = bwd or "fused"
   if bwd not in DEFAULT_BWD_BLOCKS:
     raise ValueError("bwd must be 'fused' or 'split', got %r" % (bwd,))
   return bwd
@@ -772,9 +762,9 @@ def flash_attention(q, k, v, causal: bool = True, blk_q: int = 256,
   head_dim]; k/v: same, or with heads/g KV heads (grouped-query
   attention — consumed unexpanded, see module docstring); seq must
   divide by the (clamped) block sizes. ``bwd``: 'fused' (single-pass
-  dQ/dK/dV) or 'split' (two kernels); defaults to
-  :func:`default_bwd_mode`. The backward uses its own block sizes
-  (``DEFAULT_BWD_BLOCKS`` per mode unless overridden). ``window``
+  dQ/dK/dV, the default) or 'split' (two kernels). The backward uses its
+  own block sizes (``DEFAULT_BWD_BLOCKS`` per mode unless overridden).
+  ``window``
   (requires causal) restricts each query to its last ``window``
   positions (sliding-window attention); the kernels' block loops bound
   to the window, so attention FLOPs become O(seq·window) instead of

@@ -189,7 +189,7 @@ def ring_attention(q, k, v, mesh, causal: bool = True,
       ``blk_q``/``blk_k`` tile the forward; ``blk_bwd_q``/``blk_bwd_k``
       tile the backward (None = per-mode DEFAULT_BWD_BLOCKS); ``bwd``
       picks the backward implementation per call ("fused"/"split",
-      None = the TFOS_TPU_FLASH_BWD env default) — the same per-call
+      None = "fused") — the same per-call
       override flash_attention itself offers.
 
   Returns attention output with the same sharding as ``q``.

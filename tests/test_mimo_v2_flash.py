@@ -641,8 +641,6 @@ def test_config_checks_the_new_fields():
 
 
 @pytest.mark.parametrize("changes,reason", [
-    (dict(fuse_qkv=True), "a fused projection of two widths is not built"),
-    (dict(ln_matmul_impl="fused", fuse_qkv=True), "a fused projection"),
     (dict(kv_cache_dtype="int8", layer_windows=()),
      "untried for leaves of two widths"),
     (dict(kv_page_size=16, kv_num_pages=8, kv_pages_per_slot=6,
@@ -655,10 +653,10 @@ def test_config_refuses_what_such_heads_cannot_take(toy, changes, reason):
   assert "attn_v_head_dim" in str(err.value)
   # each of the three alone is such a model
   kw = dict(vocab_size=97, num_layers=2, num_heads=4, d_model=32, d_ff=64,
-            max_seq_len=48, fuse_qkv=True)
+            max_seq_len=48, kv_cache_dtype="int8")
   for alone in (dict(attn_v_head_dim=4), dict(layer_kv_heads=(2, 4)),
                 dict(layer_sink=(True, False))):
-    with pytest.raises(ValueError, match="a fused projection"):
+    with pytest.raises(ValueError, match="untried for leaves of two widths"):
       tfm.TransformerConfig(**kw, **alone)
 
 

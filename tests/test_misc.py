@@ -265,3 +265,23 @@ class TestOpsScripts:
       res = subprocess.run(["bash", "-n", s], capture_output=True,
                            text=True)
       assert res.returncode == 0, "%s: %s" % (s, res.stderr)
+
+  def test_submit_train_runs_with_no_executor_env_to_pass(self, tmp_path):
+    """scripts/submit_train.sh under its own ``set -u`` with neither
+    control-plane pin set: the executor-env list is empty, and the recipe
+    still reaches spark-submit with the app and the cluster size."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spark = tmp_path / "bin" / "spark-submit"
+    spark.parent.mkdir()
+    spark.write_text("#!/bin/bash\nprintf '%s\\n' \"$@\"\n")
+    spark.chmod(0o755)
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("TOS_TPU_SERVER_", "EXTRA_SPARK"))}
+    env.update(SPARK_HOME=str(tmp_path), MASTER="spark://here:7077")
+    res = subprocess.run(
+        ["bash", os.path.join(repo, "scripts", "submit_train.sh"), "app.py",
+         "--steps", "3"], capture_output=True, text=True, env=env, timeout=60)
+    assert res.returncode == 0, res.stderr
+    args = res.stdout.split("\n")
+    assert "app.py" in args and "--cluster_size" in args and "3" in args
+    assert not [a for a in args if "executorEnv" in a]

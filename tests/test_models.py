@@ -84,6 +84,25 @@ class TestSegmentation:
     assert last < first
 
 
+@pytest.mark.parametrize("switch,kernels", [
+    ("fuse_qkv", ()),
+    ("ln_matmul_impl", ("ln_matmul", "ln_matmul_sharded")),
+    ("act_matmul_impl", ("gelu_matmul", "gelu_matmul_sharded")),
+])
+def test_a_retired_fusion_switch_is_gone(switch, kernels):
+  """PR 43 judged the three fusion switches on the chip and none beat the
+  unfused block (PERF.md section 6): each went whole, option and kernel,
+  and none comes back as an alias or a field nobody reads."""
+  import dataclasses
+  from tensorflowonspark_tpu import ops
+  from tensorflowonspark_tpu.models import transformer as tfm
+  assert switch not in {f.name for f in dataclasses.fields(
+      tfm.TransformerConfig)}
+  with pytest.raises(TypeError, match=switch):
+    tfm.TransformerConfig(**{switch: "fused"})
+  assert not [k for k in kernels if hasattr(ops, k)]
+
+
 class TestTransformer:
   def test_remat_policy_numerics_invariant(self):
     """remat is a memory/compute trade, never a numerics one: loss and
@@ -664,39 +683,6 @@ class TestTransformer:
     kv = tfm.greedy_generate_kv(state.params, cfg, prompt, num_steps=8)
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(full))
 
-  def test_fused_qkv_trains_and_decodes(self):
-    """fuse_qkv=True (one projection matmul, sliced) must train to the
-    cycle task and keep the KV-cache decode agreeing with recompute,
-    composed with GQA."""
-    from tensorflowonspark_tpu.models import transformer as tfm
-    cfg = tfm.TransformerConfig(vocab_size=16, num_layers=2, num_heads=4,
-                                num_kv_heads=2, d_model=64, d_ff=128,
-                                max_seq_len=32, remat=False, fuse_qkv=True)
-    state = tfm.create_state(jax.random.PRNGKey(0), cfg,
-                             learning_rate=3e-3, seq_len=24)
-    assert any("qkv" in "/".join(map(str, p))
-               for p, _ in jax.tree_util.tree_flatten_with_path(
-                   state.params)[0])
-    cycle = np.tile(np.arange(8), 10)
-    tokens = jnp.asarray(np.stack([cycle[i:i + 24] for i in range(8)]),
-                         jnp.int32)
-
-    @jax.jit
-    def step(state, tokens):
-      def loss_fn(p):
-        return tfm.causal_lm_loss(
-            state.apply_fn({"params": p}, tokens), tokens)
-      loss, grads = jax.value_and_grad(loss_fn)(state.params)
-      return state.apply_gradients(grads=grads), loss
-
-    for _ in range(150):
-      state, loss = step(state, tokens)
-    assert float(loss) < 0.1, float(loss)
-    prompt = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-    full = tfm.greedy_generate(state.params, cfg, prompt, num_steps=8)
-    kv = tfm.greedy_generate_kv(state.params, cfg, prompt, num_steps=8)
-    np.testing.assert_array_equal(np.asarray(kv), np.asarray(full))
-
   def test_blocked_loss_matches_full(self):
     """causal_lm_loss_blocked (fused projection+xent, [B,chunk,V] peak
     memory) matches causal_lm_loss exactly in f32, including value AND
@@ -775,12 +761,11 @@ class TestTransformer:
     assert float(loss) < 0.1, float(loss)
 
 
-class TestTransformerPipelineFused:
-  def test_pipeline_step_with_fusions_and_gqa(self):
-    """The 1F1B full-model step composes with the round-4 config surface
-    (GQA + fuse_qkv + ln/act fusions run mesh-free inside the stage
-    bodies): loss/grads stay finite and match the same config's dense
-    sequential AD."""
+class TestTransformerPipelineGQA:
+  def test_pipeline_step_with_gqa(self):
+    """The 1F1B full-model step composes with grouped-query attention
+    (K/V of fewer heads, mesh-free inside the stage bodies): loss/grads
+    stay finite and match the same config's dense sequential AD."""
     from tensorflowonspark_tpu.models import transformer as tfm
     from tensorflowonspark_tpu.parallel import mesh as M
 
@@ -789,8 +774,7 @@ class TestTransformerPipelineFused:
     cfg = tfm.TransformerConfig(
         vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
         d_model=32, d_ff=64, max_seq_len=8, dtype=jnp.float32,
-        remat=False, fuse_qkv=True, ln_matmul_impl="fused",
-        act_matmul_impl="fused")
+        remat=False)
     state = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=8)
     tokens = jnp.asarray(
         np.random.RandomState(0).randint(0, 64, (8, 8)), jnp.int32)

@@ -96,10 +96,10 @@ def test_int8_cache_never_materializes_f32(topo, monkeypatch):
 
 
 def test_gate_full_train_step_compiles(topo, monkeypatch):
-  """The dryrun-config 8-chip fused training step (ring + GQA flash +
-  ln_matmul_sharded + act fusion + remat) Mosaic-compiles on a v5e:2x4
-  topology with abstract state — the multi-chip production path is
-  compile-checked without any device."""
+  """The dryrun-config 8-chip training step (ring + GQA flash + fused
+  LayerNorm + remat) Mosaic-compiles on a v5e:2x4 topology with abstract
+  state — the multi-chip production path is compile-checked without any
+  device."""
   monkeypatch.setenv("TOS_PALLAS_INTERPRET", "0")
   from tools.mosaic_gate import run_gate
   results = run_gate(["train_step"])

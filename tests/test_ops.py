@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 import jax
-import jax.flatten_util
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.ops import flash_attention, layer_norm
@@ -141,7 +140,7 @@ class TestFlashAttention:
   @pytest.mark.parametrize("mode", ["fused", "split"])
   def test_backward_modes_match_dense(self, mode):
     """Both backward plans — fused single-pass (default) and split
-    two-kernel (TFOS_TPU_FLASH_BWD=split fallback) — produce dense-XLA
+    two-kernel (``bwd="split"``, the fused plan's fallback) — produce dense-XLA
     gradients for q, k and v."""
     rng = np.random.RandomState(3)
     B, S, H, D = 2, 128, 4, 32
@@ -237,6 +236,17 @@ class TestFlashAttentionGQA:
     with pytest.raises(ValueError, match="divide"):
       flash_attention(q, k, v, interpret=True)
 
+  def test_backward_plan_defaults_to_fused_and_refuses_others(self):
+    """No ``bwd=`` means the fused plan (``_gqa_fused_fits`` alone sends it
+    to split), and a plan that does not exist is refused by name."""
+    from tensorflowonspark_tpu.ops.flash_attention import _resolve_bwd
+    assert [_resolve_bwd(b) for b in (None, "fused", "split")] \
+        == ["fused", "fused", "split"]
+    q, k, v, _ = self._data()
+    with pytest.raises(ValueError, match="bwd must be 'fused' or 'split'"):
+      jax.grad(lambda q: jnp.sum(flash_attention(
+          q, k, v, interpret=True, bwd="both")))(q)
+
   def test_fused_vmem_guard(self):
     """The grouped fused backward falls back to the split plan when its
     resident dK/dV + dQ blocks exceed the VMEM budget."""
@@ -245,136 +255,10 @@ class TestFlashAttentionGQA:
     assert not _gqa_fused_fits(8192, 8192, 128, 2)  # long-context: split
 
 
-class TestGeluMatmul:
-  """Fused GELU + matmul (ops.gelu_matmul): gelu(x) @ W in one kernel —
-  the MLP down-projection fusion (the [rows, d_ff] activated tensor, the
-  block's widest, never round-trips HBM)."""
-
-  def _ref(self, x, W):
-    a = jax.nn.gelu(x.astype(jnp.float32), approximate=True)
-    return (a.astype(x.dtype) @ W).astype(x.dtype)
-
-  def test_forward_matches_reference(self):
-    from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(4, 32, 256), jnp.float32)
-    W = jnp.asarray(rng.randn(256, 64) * 0.1, jnp.float32)
-    out = gelu_matmul(x, W, blk_rows=32, blk_cols=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(self._ref(x, W)),
-                               atol=1e-4, rtol=1e-4)
-
-  def test_gradients_match_reference(self):
-    from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(48, 96), jnp.float32)
-    W = jnp.asarray(rng.randn(96, 80) * 0.1, jnp.float32)
-    gk = jax.grad(lambda *a: jnp.sum(
-        gelu_matmul(*a, interpret=True) ** 2), argnums=(0, 1))(x, W)
-    gr = jax.grad(lambda *a: jnp.sum(
-        self._ref(*a) ** 2), argnums=(0, 1))(x, W)
-    for a, b in zip(gk, gr):
-      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                 atol=2e-3, rtol=2e-3)
-
-  def test_bfloat16(self):
-    from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(2, 16, 128), jnp.bfloat16)
-    W = jnp.asarray(rng.randn(128, 64) * 0.1, jnp.bfloat16)
-    out = gelu_matmul(x, W, interpret=True)
-    assert out.dtype == jnp.bfloat16 and out.shape == (2, 16, 64)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(self._ref(x, W),
-                                                np.float32), atol=0.1)
-
-  def test_sharded_matches_dense(self):
-    """Per-shard kernel with the CONTRACTED dim (d_ff) tensor-sharded:
-    each device contracts its local F/t slice and the partials psum over
-    the tensor axis — the Megatron down-proj layout."""
-    from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul_sharded
-    from tensorflowonspark_tpu.parallel import mesh as M
-
-    if len(jax.devices()) < 8:
-      pytest.skip("needs 8 virtual devices")
-    mesh = M.build_mesh(M.MeshSpec(data=2, sequence=2, tensor=2),
-                        devices=jax.devices()[:8])
-    rng = np.random.RandomState(11)
-    x = jnp.asarray(rng.randn(4, 16, 64), jnp.float32)
-    W = jnp.asarray(rng.randn(64, 48) * 0.1, jnp.float32)
-    out = jax.jit(lambda x, W: gelu_matmul_sharded(
-        x, W, mesh, interpret=True))(x, W)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(self._ref(x, W)),
-                               atol=1e-4, rtol=1e-4)
-
-  def test_sharded_gradients_match_dense(self):
-    from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul_sharded
-    from tensorflowonspark_tpu.parallel import mesh as M
-
-    if len(jax.devices()) < 4:
-      pytest.skip("needs 4 virtual devices")
-    mesh = M.build_mesh(M.MeshSpec(data=2, tensor=2),
-                        devices=jax.devices()[:4])
-    rng = np.random.RandomState(12)
-    x = jnp.asarray(rng.randn(2, 8, 32), jnp.float32)
-    W = jnp.asarray(rng.randn(32, 24) * 0.1, jnp.float32)
-    gs = jax.jit(jax.grad(lambda *a: jnp.sum(gelu_matmul_sharded(
-        *a, mesh, interpret=True) ** 2), argnums=(0, 1)))(x, W)
-    gr = jax.grad(lambda *a: jnp.sum(
-        self._ref(*a) ** 2), argnums=(0, 1))(x, W)
-    for a, b in zip(gs, gr):
-      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                 atol=2e-3, rtol=2e-3)
-
-  def test_model_fused_matches_unfused(self):
-    """act_matmul_impl='fused' changes neither the param tree nor the
-    loss/grads; with ln_matmul also fused the whole MLP is two kernels."""
-    import dataclasses
-    from tensorflowonspark_tpu.models import transformer as tfm
-    cfg = tfm.TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
-                                d_model=64, d_ff=128, max_seq_len=16,
-                                dtype=jnp.float32, remat=False)
-    cfg_f = dataclasses.replace(cfg, act_matmul_impl="fused",
-                                ln_matmul_impl="fused")
-    state = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16)
-    state_f = tfm.create_state(jax.random.PRNGKey(0), cfg_f, seq_len=16)
-    assert (jax.tree.structure(state.params)
-            == jax.tree.structure(state_f.params))
-
-    rng = np.random.RandomState(0)
-    tokens = jnp.asarray(rng.randint(0, 64, (4, 16)), jnp.int32)
-
-    def loss(c, p):
-      return tfm.causal_lm_loss(
-          tfm.Transformer(c, None).apply({"params": p}, tokens), tokens)
-
-    l0, g0 = jax.value_and_grad(lambda p: loss(cfg, p))(state.params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(cfg_f, p))(state.params)
-    np.testing.assert_allclose(float(l0), float(l1), atol=1e-5, rtol=1e-5)
-    f0, _ = jax.flatten_util.ravel_pytree(g0)
-    f1, _ = jax.flatten_util.ravel_pytree(g1)
-    np.testing.assert_allclose(np.asarray(f0), np.asarray(f1),
-                               atol=2e-4, rtol=2e-4)
-
-
 class TestBlockPickers:
-  """Mosaic accepts a last-dim block only when it is a multiple of 128
-  (lanes) — or the whole dim — and a second-minor block only when a
-  multiple of 8 (sublanes) or the whole dim. The pickers must never snap
-  to a bare divisor violating that: caught by the deviceless gate on the
-  GQA fused-QKV sweep config (N = 20 heads · 64 = 1280 snapped to 320 and
-  failed real TPU lowering)."""
-
-  def test_col_picker_lane_aligned(self):
-    from tensorflowonspark_tpu.ops.ln_matmul import _pick_col_block
-    assert _pick_col_block(1280, 512) == 256    # not 320
-    assert _pick_col_block(768, 192) == 128     # 192 divides, but %128!=0
-    assert _pick_col_block(3072, 512) == 512
-    assert _pick_col_block(96, 512) == 96       # < 128: full dim only
-    assert _pick_col_block(1152, 512) == 384
-    # request below the lane floor snaps UP to the smallest aligned
-    # divisor, never to the whole dimension
-    assert _pick_col_block(3072, 64) == 128
+  """Mosaic accepts a second-minor block only when it is a multiple of 8
+  (sublanes) or the whole dim. The picker must never snap to a bare
+  divisor violating that."""
 
   def test_row_picker_sublane_aligned(self):
     from tensorflowonspark_tpu.ops.layer_norm import _pick_block
@@ -384,182 +268,6 @@ class TestBlockPickers:
     assert _pick_block(100, 64, 768) == 100
     # sub-floor request snaps UP to 8, not to the whole dimension
     assert _pick_block(16384, 4, 768) == 8
-
-
-class TestLNMatmul:
-  """Fused LayerNorm + matmul (ops.ln_matmul): LN(x) @ W in one kernel."""
-
-  def _ref(self, x, w, W, eps=1e-6):
-    xf = x.astype(jnp.float32)
-    mu = xf.mean(-1, keepdims=True)
-    var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-    y = ((xf - mu) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32))
-    return (y.astype(x.dtype) @ W).astype(x.dtype)
-
-  def test_forward_matches_reference(self):
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(4, 32, 128), jnp.float32)
-    w = jnp.asarray(rng.rand(128) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(128, 256) * 0.1, jnp.float32)
-    out = ln_matmul(x, w, W, blk_rows=32, blk_cols=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(self._ref(x, w, W)),
-                               atol=1e-4, rtol=1e-4)
-
-  def test_gradients_match_reference(self):
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(48, 96), jnp.float32)
-    w = jnp.asarray(rng.rand(96) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(96, 80) * 0.1, jnp.float32)
-    gk = jax.grad(lambda *a: jnp.sum(
-        ln_matmul(*a, interpret=True) ** 2), argnums=(0, 1, 2))(x, w, W)
-    gr = jax.grad(lambda *a: jnp.sum(
-        self._ref(*a) ** 2), argnums=(0, 1, 2))(x, w, W)
-    for a, b in zip(gk, gr):
-      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                 atol=2e-3, rtol=2e-3)
-
-  def test_bfloat16(self):
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(2, 16, 128), jnp.bfloat16)
-    w = jnp.asarray(rng.rand(128) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(128, 256) * 0.1, jnp.bfloat16)
-    out = ln_matmul(x, w, W, interpret=True)
-    assert out.dtype == jnp.bfloat16 and out.shape == (2, 16, 256)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(self._ref(x, w, W),
-                                                np.float32), atol=0.1)
-
-  def test_sharded_matches_dense(self):
-    """Per-shard kernel over a data×sequence×tensor mesh == unsharded:
-    rows split over data/sequence, W's columns over tensor (the MLP-up /
-    QKV layouts), H contracted fully on-device — no collectives."""
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul_sharded
-    from tensorflowonspark_tpu.parallel import mesh as M
-
-    if len(jax.devices()) < 8:
-      pytest.skip("needs 8 virtual devices")
-    mesh = M.build_mesh(M.MeshSpec(data=2, sequence=2, tensor=2),
-                        devices=jax.devices()[:8])
-    rng = np.random.RandomState(11)
-    x = jnp.asarray(rng.randn(4, 16, 64), jnp.float32)
-    w = jnp.asarray(rng.rand(64) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(64, 96) * 0.1, jnp.float32)
-    out = jax.jit(lambda x, w, W: ln_matmul_sharded(
-        x, w, W, mesh, interpret=True))(x, w, W)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(self._ref(x, w, W)),
-                               atol=1e-4, rtol=1e-4)
-
-  def test_sharded_gradients_match_dense(self):
-    """dW / dw_ln must sum over the row shards (shard_map transpose
-    psums over data/sequence), matching plain AD of the dense pair."""
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul_sharded
-    from tensorflowonspark_tpu.parallel import mesh as M
-
-    if len(jax.devices()) < 8:
-      pytest.skip("needs 8 virtual devices")
-    mesh = M.build_mesh(M.MeshSpec(data=2, sequence=2, tensor=2),
-                        devices=jax.devices()[:8])
-    rng = np.random.RandomState(12)
-    x = jnp.asarray(rng.randn(2, 8, 32), jnp.float32)
-    w = jnp.asarray(rng.rand(32) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(32, 48) * 0.1, jnp.float32)
-    gs = jax.jit(jax.grad(lambda *a: jnp.sum(ln_matmul_sharded(
-        *a, mesh, interpret=True) ** 2), argnums=(0, 1, 2)))(x, w, W)
-    gr = jax.grad(lambda *a: jnp.sum(
-        self._ref(*a) ** 2), argnums=(0, 1, 2))(x, w, W)
-    for a, b in zip(gs, gr):
-      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                 atol=2e-3, rtol=2e-3)
-
-  def test_sharded_indivisible_columns_replicate(self):
-    """A column count the tensor axis cannot divide keeps W replicated
-    instead of failing the shard_map split."""
-    from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul_sharded
-    from tensorflowonspark_tpu.parallel import mesh as M
-
-    if len(jax.devices()) < 4:
-      pytest.skip("needs 4 virtual devices")
-    mesh = M.build_mesh(M.MeshSpec(data=2, tensor=2),
-                        devices=jax.devices()[:4])
-    rng = np.random.RandomState(13)
-    x = jnp.asarray(rng.randn(4, 8, 32), jnp.float32)
-    w = jnp.asarray(rng.rand(32) + 0.5, jnp.float32)
-    W = jnp.asarray(rng.randn(32, 33) * 0.1, jnp.float32)   # 33 % 2 != 0
-    out = jax.jit(lambda x, w, W: ln_matmul_sharded(
-        x, w, W, mesh, interpret=True))(x, w, W)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(self._ref(x, w, W)),
-                               atol=1e-4, rtol=1e-4)
-
-  def test_model_fused_matches_unfused(self):
-    """ln_matmul_impl='fused' changes neither the param tree nor the
-    math of the Transformer (ln2+up as one kernel)."""
-    import dataclasses
-    from tensorflowonspark_tpu.models import transformer as tfm
-    cfg = tfm.TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
-                                d_model=64, d_ff=128, max_seq_len=16,
-                                dtype=jnp.float32, remat=False)
-    cfg_f = dataclasses.replace(cfg, ln_matmul_impl="fused")
-    state = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16)
-    state_f = tfm.create_state(jax.random.PRNGKey(0), cfg_f, seq_len=16)
-    assert (jax.tree.structure(state.params)
-            == jax.tree.structure(state_f.params))
-
-    rng = np.random.RandomState(0)
-    tokens = jnp.asarray(rng.randint(0, 64, (4, 16)), jnp.int32)
-
-    def loss(c, p):
-      return tfm.causal_lm_loss(
-          tfm.Transformer(c, None).apply({"params": p}, tokens), tokens)
-
-    l0, g0 = jax.value_and_grad(lambda p: loss(cfg, p))(state.params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(cfg_f, p))(state.params)
-    np.testing.assert_allclose(float(l0), float(l1), atol=1e-5, rtol=1e-5)
-    f0, _ = jax.flatten_util.ravel_pytree(g0)
-    f1, _ = jax.flatten_util.ravel_pytree(g1)
-    np.testing.assert_allclose(np.asarray(f0), np.asarray(f1),
-                               atol=2e-4, rtol=2e-4)
-
-  def test_model_fused_qkv_ln_matches_unfused(self):
-    """ln_matmul_impl='fused' + fuse_qkv: ln1+QKV and ln2+up both run
-    fused; params, loss and grads match the unfused graph, and the decode
-    path (which takes the unfused branch) serves fused-trained params."""
-    import dataclasses
-    from tensorflowonspark_tpu.models import transformer as tfm
-    cfg = tfm.TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
-                                d_model=64, d_ff=128, max_seq_len=16,
-                                dtype=jnp.float32, remat=False,
-                                fuse_qkv=True)
-    cfg_f = dataclasses.replace(cfg, ln_matmul_impl="fused")
-    state = tfm.create_state(jax.random.PRNGKey(0), cfg, seq_len=16)
-    state_f = tfm.create_state(jax.random.PRNGKey(0), cfg_f, seq_len=16)
-    assert (jax.tree.structure(state.params)
-            == jax.tree.structure(state_f.params))
-
-    rng = np.random.RandomState(0)
-    tokens = jnp.asarray(rng.randint(0, 64, (4, 16)), jnp.int32)
-
-    def loss(c, p):
-      return tfm.causal_lm_loss(
-          tfm.Transformer(c, None).apply({"params": p}, tokens), tokens)
-
-    l0, g0 = jax.value_and_grad(lambda p: loss(cfg, p))(state.params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(cfg_f, p))(state.params)
-    np.testing.assert_allclose(float(l0), float(l1), atol=1e-5, rtol=1e-5)
-    f0, _ = jax.flatten_util.ravel_pytree(g0)
-    f1, _ = jax.flatten_util.ravel_pytree(g1)
-    np.testing.assert_allclose(np.asarray(f0), np.asarray(f1),
-                               atol=2e-4, rtol=2e-4)
-
-    # a fused-config model must still generate (decode branch is unfused)
-    prompt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
-    out = tfm.greedy_generate(state_f.params, cfg_f, prompt, num_steps=4)
-    assert out.shape == (1, 8)
 
 
 class TestSlidingWindow:

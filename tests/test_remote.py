@@ -384,6 +384,14 @@ class TestPlaneWire:
     assert remote_mod.decode_error(None) is None
 
 
+class _NeverSilent(remote_mod._HostRecord):
+  """A host's record whose last sync is always now: the plane cannot read
+  this host as silent, however late its syncs arrive."""
+  __slots__ = ()
+  last_sync = property(lambda self: time.monotonic(),
+                       lambda self, t: None)
+
+
 class TestHostChaos:
   """TOS_CHAOS_HOST-driven proofs (make fleet-chaos): host death and
   wire partitions injected deterministically at sync granularity.
@@ -408,6 +416,9 @@ class TestHostChaos:
     monkeypatch.setenv(chaos.ENV_HOST, "decode@0#3:partition:60")
     with _hosts_up(tiny_state, tmp_path, n=2,
                    plane_kw={"timeout": 0.5}) as (addr, plane, versions):
+      # only the partitioned host 0 can time out: on a loaded machine a
+      # healthy host's sync may come later than 0.5 s too
+      plane._hosts[1].__class__ = _NeverSilent
       fl = ServingFleet(
           remote_mod.remote_engine_factory(plane, version=versions[0]),
           num_replicas=2, poll_interval=0.02,

@@ -4,13 +4,16 @@ the recorder spans under ``TOS_OBS=1`` and the ``jax.profiler`` trace
 annotations — CPU, toy engine.
 """
 
+import contextlib
 import dataclasses
 import glob
+import itertools
 import os
 import subprocess
 import sys
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -237,18 +240,24 @@ def test_phase_keys_exist_at_construction(toy):
   assert all(delta[k] == 0.0 for k in EMPTY_KEYS)
 
 
-def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
-  """Over a warm run of a few dozen requests the phase counters sum to the
-  loop thread's lifetime within 2%: nothing of an iteration is outside a
-  region, and no second is counted twice."""
+def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(
+    toy, monkeypatch):
+  """Over a warm run of a few dozen requests, on the real clock: no counter
+  decreases and no second is counted twice (the counters sum to no more than
+  the loop thread's lifetime). Then the same run on a clock the test owns,
+  one tick a reading, so nothing depends on how the host shares its cores:
+  the counters sum EXACTLY to the ticks inside the loop's outermost counted
+  regions, and every reading taken outside those is a region's own edge:
+  nothing the loop times (a phase, an edge of the device queue) is outside
+  a counter."""
   cfg, state = toy
   eng = ServingEngine(state.params, cfg, num_slots=4, eos_id=None)
   lives, inner = [], eng._loop
 
   def timed_loop():
-    t0 = time.monotonic()
+    t0 = spans_mod.time.monotonic()
     inner()
-    lives.append(time.monotonic() - t0)
+    lives.append((t0, spans_mod.time.monotonic(), threading.get_ident()))
 
   eng._loop = timed_loop
   prompts = _prompts(48)
@@ -274,10 +283,45 @@ def test_phase_counters_never_decrease_and_close_on_the_loops_wall_time(toy):
   delta = {k: eng.stats[k] - base[k] for k in PHASE_KEYS}
   assert all(v >= 0 for v in delta.values())
   assert delta["t_decode_dispatch_s"] > 0 and delta["t_prefill_s"] > 0
-  assert sum(delta.values()) == pytest.approx(lives[1], rel=0.02)
-  assert sum(delta.values()) <= lives[1]
+  assert sum(delta.values()) <= lives[1][1] - lives[1][0]
   # under the pass's order: admissions went behind a running decode step
   assert eng.stats["admits_ahead"] - base["admits_ahead"] > 0
+
+  # the test's own clock: whole numbers, so every sum below is exact
+  ticks = itertools.count()
+  monkeypatch.setattr(spans_mod, "time", types.SimpleNamespace(
+      monotonic=lambda: float(next(ticks))))
+  # counted regions open, on the ONE thread that runs regions (asserted)
+  regions, depth, real_region = [], [0], spans_mod.region
+
+  @contextlib.contextmanager
+  def logged_region(name, acc=None, key=None, **kw):
+    counted = acc is not None
+    outermost = counted and not depth[0]
+    depth[0] += counted
+    try:
+      with real_region(name, acc, key, **kw) as r:
+        yield r
+    finally:
+      depth[0] -= counted
+      regions.append((threading.get_ident(), r.t0, r.t0 + r.dur, outermost))
+
+  monkeypatch.setattr(spans_mod, "region", logged_region)
+  base = dict(eng.stats)
+  with eng:
+    _serve(eng, prompts, 12)
+  monkeypatch.undo()
+  t_in, t_out, loop_thread = lives[2]
+  assert all(who == loop_thread for who, _, _, _ in regions)
+  spans = [(a, b) for _, a, b, outermost in regions if outermost]
+  total = sum(eng.stats[k] - base[k] for k in PHASE_KEYS)
+  assert total == sum(b - a for a, b in spans) and total > 0
+  accounted = {t_in, t_out}
+  for _, a, b, outermost in regions:
+    accounted.update((a, b))
+    if outermost:
+      accounted.update(range(int(a), int(b)))
+  assert [t for t in range(int(t_in), int(t_out)) if t not in accounted] == []
 
 
 @pytest.mark.parametrize("stack", ["plain", "spec", "paged"])

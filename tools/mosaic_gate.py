@@ -214,84 +214,10 @@ def t_layer_norm():
   return fn, (_sh(1024, 1024), _sh(1024, dtype=jnp.float32))
 
 
-def t_ln_matmul():
-  import jax
-  import jax.numpy as jnp
-  from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul
-  mesh = _mesh1()
-
-  def loss(x, s, w):
-    return ln_matmul(x, s, w).astype(jnp.float32).sum()
-
-  fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)),
-               in_shardings=(_repl(mesh),) * 3)
-  return fn, (_sh(2, 512, 1024), _sh(1024, dtype=jnp.float32),
-              _sh(1024, 3072))
-
-
-def t_ln_matmul_sharded():
-  """data×tensor mesh: rows over data, W columns over tensor (the QKV /
-  MLP-up layouts); gradient psums cross shards."""
-  import jax
-  import jax.numpy as jnp
-  from tensorflowonspark_tpu.ops.ln_matmul import ln_matmul_sharded
-  from tensorflowonspark_tpu.parallel import mesh as mesh_lib
-  from jax.sharding import NamedSharding, PartitionSpec as P
-  mesh = mesh_lib.build_mesh(
-      mesh_lib.MeshSpec(data=2, tensor=2),
-      devices=list(_topology("v5e:2x2").devices))
-
-  def loss(x, s, w):
-    return ln_matmul_sharded(x, s, w, mesh).astype(jnp.float32).sum()
-
-  fn = jax.jit(
-      jax.grad(loss, argnums=(0, 1, 2)),
-      in_shardings=(NamedSharding(mesh, P(mesh_lib.AXIS_DATA, None, None)),
-                    _repl(mesh),
-                    NamedSharding(mesh, P(None, mesh_lib.AXIS_TENSOR))))
-  return fn, (_sh(4, 512, 1024), _sh(1024, dtype=jnp.float32),
-              _sh(1024, 3072))
-
-
-def t_gelu_matmul():
-  import jax
-  import jax.numpy as jnp
-  from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul
-  mesh = _mesh1()
-
-  def loss(x, w):
-    return gelu_matmul(x, w).astype(jnp.float32).sum()
-
-  fn = jax.jit(jax.grad(loss, argnums=(0, 1)),
-               in_shardings=(_repl(mesh),) * 2)
-  return fn, (_sh(2, 512, 4096), _sh(4096, 1024))
-
-
-def t_gelu_matmul_sharded():
-  import jax
-  import jax.numpy as jnp
-  from tensorflowonspark_tpu.ops.act_matmul import gelu_matmul_sharded
-  from tensorflowonspark_tpu.parallel import mesh as mesh_lib
-  from jax.sharding import NamedSharding, PartitionSpec as P
-  mesh = mesh_lib.build_mesh(
-      mesh_lib.MeshSpec(data=2, tensor=2),
-      devices=list(_topology("v5e:2x2").devices))
-
-  def loss(x, w):
-    return gelu_matmul_sharded(x, w, mesh).astype(jnp.float32).sum()
-
-  fn = jax.jit(
-      jax.grad(loss, argnums=(0, 1)),
-      in_shardings=(NamedSharding(mesh, P(mesh_lib.AXIS_DATA, None,
-                                          mesh_lib.AXIS_TENSOR)),
-                    NamedSharding(mesh, P(mesh_lib.AXIS_TENSOR, None))))
-  return fn, (_sh(4, 512, 4096), _sh(4096, 1024))
-
-
 def t_train_step():
   """The FULL fused multi-chip training step — the exact dryrun_multichip(8)
-  configuration (ring + GQA-native flash + ln_matmul_sharded + fused
-  act-matmul + remat + optimizer + collectives) on an 8-chip v5e:2x4
+  configuration (ring + GQA-native flash + fused LayerNorm + remat +
+  optimizer + collectives) on an 8-chip v5e:2x4
   topology, with the kernels in REAL (non-interpret) mode. The state is
   abstract (eval_shape): nothing ever materializes on a device."""
   import jax
@@ -308,9 +234,7 @@ def t_train_step():
   cfg = tfm.TransformerConfig(
       vocab_size=512, num_layers=2, num_heads=4, d_model=128, d_ff=256,
       max_seq_len=seq_len, remat=True, use_ring_attention=True,
-      layer_norm_impl="fused", attention_impl="flash",
-      num_kv_heads=2, fuse_qkv=True, ln_matmul_impl="fused",
-      act_matmul_impl="fused")
+      layer_norm_impl="fused", attention_impl="flash", num_kv_heads=2)
 
   params_init, make_state = tfm._init_fns(
       jax.random.PRNGKey(0), cfg, mesh, 3e-4, seq_len,
@@ -450,9 +374,7 @@ def t_train_step_pod():
   cfg = tfm.TransformerConfig(
       vocab_size=1024, num_layers=2, num_heads=8, d_model=256, d_ff=512,
       max_seq_len=seq_len, remat=True, use_ring_attention=True,
-      layer_norm_impl="fused", attention_impl="flash",
-      num_kv_heads=2, fuse_qkv=True, ln_matmul_impl="fused",
-      act_matmul_impl="fused")
+      layer_norm_impl="fused", attention_impl="flash", num_kv_heads=2)
 
   params_init, make_state = tfm._init_fns(
       jax.random.PRNGKey(0), cfg, mesh, 3e-4, seq_len,
@@ -1282,10 +1204,6 @@ TARGETS = {
     "ring_attention_window": t_ring_attention_window,
     "ring_attention_gqa": t_ring_attention_gqa,
     "layer_norm": t_layer_norm,
-    "ln_matmul": t_ln_matmul,
-    "ln_matmul_sharded": t_ln_matmul_sharded,
-    "gelu_matmul": t_gelu_matmul,
-    "gelu_matmul_sharded": t_gelu_matmul_sharded,
     "train_step": t_train_step,
     "serving_decode": t_serving_decode,
     "pipeline_1f1b": t_pipeline_1f1b,
@@ -1438,47 +1356,25 @@ def _abs_bench_step(batch, seq, cfg_kwargs, vocab, layers, heads, d_model,
   return fn, (abs_state, tokens)
 
 
-# The fusion-switch candidates (ROADMAP S5 / D3: fused QKV, ln/act matmul
-# fusions, fused-vs-flax LayerNorm, s=2048, selective remat, GQA) on the
-# 12-layer transformer train step at its full width. This table is their
-# compile evidence (SWEEP_COMPILE.json); their chip numbers come from the
-# train cell's family, which takes the same overrides
-# (benchmarks/families/gpt2.py `program_config`).
+# The train step's candidates (fused-vs-flax LayerNorm, s=2048, selective
+# remat, GQA) on the 12-layer transformer train step at its full width.
+# This table is their compile evidence (SWEEP_COMPILE.json); their chip
+# numbers come from the train cell's family, which takes the same
+# overrides (benchmarks/families/gpt2.py `program_config`).
 TFM_LAYERS, TFM_DMODEL, TFM_HEADS, TFM_DFF = 12, 768, 12, 3072
 TFM_VOCAB, TFM_SEQ, TFM_BATCH = 32000, 1024, 16
 SWEEP_CONFIGS = [
     ("b16_s1024_base", {}),
-    ("b16_s1024_fuseqkv", {"fuse_qkv": True}),
     ("b16_s1024_flaxln", {"layer_norm_impl": "flax"}),
-    ("b16_s1024_lnmm", {"ln_matmul_impl": "fused"}),
-    ("b16_s1024_lnmm_fuseqkv", {"ln_matmul_impl": "fused",
-                                "fuse_qkv": True}),
-    ("b16_s1024_actmm", {"act_matmul_impl": "fused"}),
-    # everything fused: ln1+QKV, ln2+up, gelu+down each one kernel
-    ("b16_s1024_allfused", {"ln_matmul_impl": "fused", "fuse_qkv": True,
-                            "act_matmul_impl": "fused"}),
     ("b8_s2048", {"batch": 8, "seq": 2048}),
-    ("b8_s2048_fuseqkv", {"batch": 8, "seq": 2048, "fuse_qkv": True}),
-    ("b8_s2048_allfused", {"batch": 8, "seq": 2048,
-                           "ln_matmul_impl": "fused", "fuse_qkv": True,
-                           "act_matmul_impl": "fused"}),
     # selective remat: save MXU outputs, recompute elementwise only, to
     # reach the batches that do not fit without remat
     ("b24_s1024_rematdots", {"batch": 24, "remat": True,
                              "remat_policy": "dots"}),
     ("b32_s1024_rematdots", {"batch": 32, "remat": True,
                              "remat_policy": "dots"}),
-    ("b32_s1024_rematdots_allfused", {"batch": 32, "remat": True,
-                                      "remat_policy": "dots",
-                                      "ln_matmul_impl": "fused",
-                                      "fuse_qkv": True,
-                                      "act_matmul_impl": "fused"}),
-    # GQA at this shape: 12 query heads on 4 KV heads; with allfused on top
+    # GQA at this shape: 12 query heads on 4 KV heads
     ("b16_s1024_gqa4", {"num_kv_heads": 4}),
-    ("b16_s1024_gqa4_allfused", {"num_kv_heads": 4,
-                                 "ln_matmul_impl": "fused",
-                                 "fuse_qkv": True,
-                                 "act_matmul_impl": "fused"}),
 ]
 
 
@@ -1539,18 +1435,10 @@ def run_tile_sweep_gate(json_path):
   pass never wastes chip time on Mosaic-invalid tiles."""
   import jax
   import jax.numpy as jnp
-  # importlib: ops/__init__ re-exports `ln_matmul`/`gelu_matmul` as
-  # FUNCTIONS, shadowing the submodule attribute even for
-  # `import ...ops.ln_matmul as m` (same pattern as tpu_validate.py)
-  import importlib
-  am_mod = importlib.import_module("tensorflowonspark_tpu.ops.act_matmul")
-  lnmm_mod = importlib.import_module("tensorflowonspark_tpu.ops.ln_matmul")
   from tensorflowonspark_tpu.ops.flash_attention import flash_attention
   # ONE source of truth for shapes/grids: whatever the on-chip sweep will
   # time is exactly what this gate compile-validates
-  from tools.tpu_validate import (SWEEP_ATTN_SHAPE, SWEEP_FLASH_GRID,
-                                  SWEEP_MM_DTYPE, SWEEP_MM_GRIDS,
-                                  SWEEP_MM_SHAPE)
+  from tools.tpu_validate import SWEEP_ATTN_SHAPE, SWEEP_FLASH_GRID
   mesh = _mesh1()
   repl = _repl(mesh)
   results = []
@@ -1582,34 +1470,6 @@ def run_tile_sweep_gate(json_path):
                                        blk_bwd_q=bq, blk_bwd_k=bk)
                        .astype(jnp.float32)), argnums=(0, 1, 2)),
                    in_shardings=(repl,) * 3), (q, q, q))
-
-  # ln_matmul / gelu_matmul grids at the sweep's bench shapes, deduped by
-  # the kernels' own effective-block snap (tpu_validate.py does the same)
-  rows, dd, n = SWEEP_MM_SHAPE
-  mm_dt = jnp.dtype(SWEEP_MM_DTYPE)
-  x = _sh(rows, dd, dtype=mm_dt)
-  gamma, W = _sh(dd, dtype=jnp.float32), _sh(dd, n, dtype=mm_dt)
-  xg, Wd = _sh(rows, n, dtype=mm_dt), _sh(n, dd, dtype=mm_dt)
-  seen = set()
-  for blk_r, blk_c in SWEEP_MM_GRIDS["ln_matmul"]:
-    eff = lnmm_mod.effective_blocks(rows, dd, n, blk_r, blk_c)
-    if ("ln", eff) in seen:
-      continue
-    seen.add(("ln", eff))
-    _compile("ln_matmul[%dx%d]" % eff,
-             jax.jit(lambda x, g, w, br=blk_r, bc=blk_c: lnmm_mod.ln_matmul(
-                 x, g, w, blk_rows=br, blk_cols=bc),
-                 in_shardings=(repl,) * 3), (x, gamma, W))
-  for blk_r, blk_c in SWEEP_MM_GRIDS["gelu_matmul"]:
-    eff = am_mod.effective_blocks(rows, n, dd, blk_r, blk_c,
-                                  mm_dt.itemsize)
-    if ("gelu", eff) in seen:
-      continue
-    seen.add(("gelu", eff))
-    _compile("gelu_matmul[%dx%d]" % eff,
-             jax.jit(lambda x, w, br=blk_r, bc=blk_c: am_mod.gelu_matmul(
-                 x, w, blk_rows=br, blk_cols=bc),
-                 in_shardings=(repl,) * 2), (xg, Wd))
 
   n_fail = sum(1 for r in results if not r["ok"])
   with open(json_path, "w") as f:

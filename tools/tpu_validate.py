@@ -91,7 +91,7 @@ def check_flash(results, shapes, dtype_name):
       continue
 
     # backward — both kernel plans (fused single-pass is the default;
-    # split two-kernel is the fallback behind TFOS_TPU_FLASH_BWD)
+    # split two-kernel is what it falls back to where it does not fit)
     base = name.replace("fwd", "bwd")
     # the dense reference gradient is mode-independent: compute/time once
     try:
@@ -320,120 +320,12 @@ def check_layer_norm(results, shapes):
       results.append(dict(kernel=name, ok=False, error=repr(e)[:400]))
 
 
-def check_ln_matmul(results, shapes):
-  import jax
-  import jax.numpy as jnp
-  import importlib
-  lnmm = importlib.import_module('tensorflowonspark_tpu.ops.ln_matmul')
-
-  for (rows, d, n), dtype_name in [(s, dt) for s in shapes
-                                   for dt in ("bf16", "f32")]:
-    dtype = dict(bf16=jnp.bfloat16, f32=jnp.float32)[dtype_name]
-    key = jax.random.PRNGKey(2)
-    x = jax.random.normal(key, (rows, d), dtype)
-    gamma = (jnp.ones((d,), jnp.float32) * 1.1)
-    W = (jax.random.normal(jax.random.PRNGKey(3), (d, n), dtype) * 0.05
-         ).astype(dtype)
-    tol = 1e-1 if dtype_name == "bf16" else 1e-3
-
-    fused = jax.jit(lambda x, g, w: lnmm.ln_matmul(x, g, w))
-    ref = jax.jit(lambda x, g, w: (
-        ((x.astype(jnp.float32) -
-          jnp.mean(x.astype(jnp.float32), -1, keepdims=True)) *
-         jax.lax.rsqrt(jnp.var(x.astype(jnp.float32), -1, keepdims=True)
-                       + 1e-6) * g).astype(x.dtype) @ w))
-    name = "ln_matmul[%s %dx%dx%d]" % (dtype_name, rows, d, n)
-    try:
-      err = float(jnp.max(jnp.abs(fused(x, gamma, W).astype(jnp.float32) -
-                                  ref(x, gamma, W).astype(jnp.float32))))
-      t_f = _timeit(fused, x, gamma, W)
-      t_r = _timeit(ref, x, gamma, W)
-      results.append(dict(kernel=name, ok=err < tol, max_err=err,
-                          fused_ms=round(t_f * 1e3, 3),
-                          xla_ms=round(t_r * 1e3, 3),
-                          speedup=round(t_r / t_f, 2)))
-    except Exception as e:  # noqa: BLE001
-      results.append(dict(kernel=name, ok=False, error=repr(e)[:400]))
-
-    name = "ln_matmul_grad[%s %dx%dx%d]" % (dtype_name, rows, d, n)
-    try:
-      gf = jax.jit(jax.grad(
-          lambda x, g, w: jnp.sum(lnmm.ln_matmul(x, g, w)
-                                  .astype(jnp.float32)),
-          argnums=(0, 1, 2)))
-      gr = jax.jit(jax.grad(
-          lambda x, g, w: jnp.sum(ref.__wrapped__(x, g, w)
-                                  .astype(jnp.float32)),
-          argnums=(0, 1, 2)))
-      err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
-                                      b_.astype(jnp.float32))))
-                for a, b_ in zip(gf(x, gamma, W), gr(x, gamma, W)))
-      results.append(dict(kernel=name, ok=err < max(tol, 2e-1), max_err=err))
-    except Exception as e:  # noqa: BLE001
-      results.append(dict(kernel=name, ok=False, error=repr(e)[:400]))
-
-
-def check_gelu_matmul(results, shapes):
-  import jax
-  import jax.numpy as jnp
-  import importlib
-  am = importlib.import_module('tensorflowonspark_tpu.ops.act_matmul')
-
-  for (rows, f, n), dtype_name in [(s, dt) for s in shapes
-                                   for dt in ("bf16", "f32")]:
-    dtype = dict(bf16=jnp.bfloat16, f32=jnp.float32)[dtype_name]
-    x = jax.random.normal(jax.random.PRNGKey(5), (rows, f), dtype)
-    W = (jax.random.normal(jax.random.PRNGKey(6), (f, n), dtype) * 0.05
-         ).astype(dtype)
-    tol = 1e-1 if dtype_name == "bf16" else 1e-3
-
-    fused = jax.jit(lambda x, w: am.gelu_matmul(x, w))
-    ref = jax.jit(lambda x, w: (
-        jax.nn.gelu(x.astype(jnp.float32), approximate=True)
-        .astype(x.dtype) @ w))
-    name = "gelu_matmul[%s %dx%dx%d]" % (dtype_name, rows, f, n)
-    try:
-      err = float(jnp.max(jnp.abs(fused(x, W).astype(jnp.float32) -
-                                  ref(x, W).astype(jnp.float32))))
-      t_f = _timeit(fused, x, W)
-      t_r = _timeit(ref, x, W)
-      results.append(dict(kernel=name, ok=err < tol, max_err=err,
-                          fused_ms=round(t_f * 1e3, 3),
-                          xla_ms=round(t_r * 1e3, 3),
-                          speedup=round(t_r / t_f, 2)))
-    except Exception as e:  # noqa: BLE001 - record, keep going
-      results.append(dict(kernel=name, ok=False, error=repr(e)[:400]))
-
-    name = "gelu_matmul_grad[%s %dx%dx%d]" % (dtype_name, rows, f, n)
-    try:
-      gf = jax.jit(jax.grad(
-          lambda x, w: jnp.sum(am.gelu_matmul(x, w).astype(jnp.float32)),
-          argnums=(0, 1)))
-      gr = jax.jit(jax.grad(
-          lambda x, w: jnp.sum(ref.__wrapped__(x, w).astype(jnp.float32)),
-          argnums=(0, 1)))
-      err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
-                                      b_.astype(jnp.float32))))
-                for a, b_ in zip(gf(x, W), gr(x, W)))
-      results.append(dict(kernel=name, ok=err < max(tol, 2e-1), max_err=err))
-    except Exception as e:  # noqa: BLE001
-      results.append(dict(kernel=name, ok=False, error=repr(e)[:400]))
-
-
 # The sweep's shapes and tile grids — module-level so the deviceless gate
 # (tools/mosaic_gate.py --tile-sweep) compile-validates EXACTLY the tiles
 # this sweep will time on-chip; retune them here and the gate follows.
 SWEEP_ATTN_SHAPE = (2, 1024, 8, 64)          # bench-class b, s, h, d
 SWEEP_FLASH_GRID = [(128, 256), (128, 512), (256, 256), (256, 512),
                     (256, 1024), (512, 512)]
-SWEEP_MM_SHAPE = (16384, 768, 3072)          # bench rows, d_model, N
-SWEEP_MM_DTYPE = "bfloat16"                  # drives the gelu W-tile cap too
-SWEEP_MM_GRIDS = {
-    "ln_matmul": [(128, 256), (128, 512), (256, 512), (256, 1024),
-                  (512, 512), (512, 1536)],
-    "gelu_matmul": [(16, 128), (32, 128), (32, 192), (32, 384),
-                    (64, 128), (64, 192), (64, 256), (64, 384)],
-}
 
 
 def sweep_blocks(results):
@@ -442,8 +334,7 @@ def sweep_blocks(results):
   Round 2 found DEFAULT_BWD_BLOCKS by manual probing during the one
   window the chip answered; this automates it so a single chip session
   yields the full tuning surface: flash forward and both backward plans
-  over a (blk_q, blk_k) grid, and ln_matmul / gelu_matmul over a
-  (blk_rows, blk_cols) grid. Emits one row per timed point plus a
+  over a (blk_q, blk_k) grid. Emits one row per timed point plus a
   ``*_best`` row per kernel — apply the winners to the kernel defaults
   only when they beat the current ones.
   """
@@ -451,8 +342,6 @@ def sweep_blocks(results):
   import jax
   import jax.numpy as jnp
   fa = importlib.import_module('tensorflowonspark_tpu.ops.flash_attention')
-  lnmm = importlib.import_module('tensorflowonspark_tpu.ops.ln_matmul')
-  am = importlib.import_module('tensorflowonspark_tpu.ops.act_matmul')
 
   b, s, h, d = SWEEP_ATTN_SHAPE
   key = jax.random.PRNGKey(7)
@@ -496,54 +385,6 @@ def sweep_blocks(results):
         results.append(dict(kernel=name, ok=False, sweep=True,
                             error=repr(e)[:200]))
 
-  rows, dd, n = SWEEP_MM_SHAPE
-  mm_dt = jnp.dtype(SWEEP_MM_DTYPE)
-  x = jax.random.normal(jax.random.PRNGKey(8), (rows, dd), mm_dt)
-  gamma = jnp.ones((dd,), jnp.float32)
-  W = (jax.random.normal(jax.random.PRNGKey(9), (dd, n), mm_dt)
-       * 0.05).astype(mm_dt)
-  xg = jax.random.normal(jax.random.PRNGKey(10), (rows, n), mm_dt)
-  Wd = (jax.random.normal(jax.random.PRNGKey(11), (n, dd), mm_dt)
-        * 0.05).astype(mm_dt)
-  # the kernels' OWN effective-block functions drive dedup and labels,
-  # so the sweep can never name a configuration the kernel would
-  # silently snap away from, and cap retunes propagate automatically.
-  # Per-kernel grids: gelu's byte caps bound its space far below
-  # ln_matmul's (row cap ~85 at f=3072 f32-acc; col cap 682 → divisors
-  # of 768), so its grid probes BELOW the caps instead of above them.
-  def _effective(label, blk_r, blk_c):
-    if label == "ln_matmul":
-      return lnmm.effective_blocks(rows, dd, n, blk_r, blk_c)
-    return am.effective_blocks(rows, n, dd, blk_r, blk_c,
-                               Wd.dtype.itemsize)
-
-  mm_grids = SWEEP_MM_GRIDS
-  seen = set()
-  for label, fn_maker_t in (
-      ("ln_matmul", lambda br, bc: jax.jit(
-          lambda x, g, w: lnmm.ln_matmul(x, g, w, blk_rows=br,
-                                         blk_cols=bc))),
-      ("gelu_matmul", lambda br, bc: jax.jit(
-          lambda x, w: am.gelu_matmul(x, w, blk_rows=br, blk_cols=bc))),
-  ):
-    for blk_r, blk_c in mm_grids[label]:
-      eff = _effective(label, blk_r, blk_c)
-      if (label, eff) in seen:
-        continue   # snaps to an already-timed effective config
-      seen.add((label, eff))
-      name = "%s_blocks[%dx%d]" % ((label,) + eff)
-      try:
-        fn = fn_maker_t(blk_r, blk_c)
-        args_ = (x, gamma, W) if label == "ln_matmul" else (xg, Wd)
-        t = _timeit(fn, *args_)
-        results.append(dict(kernel=name, ok=True, sweep=True,
-                            ms=round(t * 1e3, 3)))
-        if t < best.get(label, (1e9,))[0]:
-          best[label] = (t, eff)
-      except Exception as e:  # noqa: BLE001
-        results.append(dict(kernel=name, ok=False, sweep=True,
-                            error=repr(e)[:200]))
-
   for kernel, (t, blocks) in sorted(best.items()):
     results.append(dict(kernel="%s_best" % kernel, ok=True, sweep=True,
                         ms=round(t * 1e3, 3), blocks=list(blocks)))
@@ -571,14 +412,14 @@ def main(argv=None):
   ap.add_argument("--json", default=None, help="write results to this file")
   ap.add_argument("--sweep-blocks", action="store_true",
                   help="also auto-tune kernel tile sizes at the bench "
-                       "shapes (flash fwd/bwd, ln_matmul, gelu_matmul)")
+                       "shapes (flash fwd/bwd)")
   ap.add_argument("--sweep-only", action="store_true",
                   help="run ONLY the block sweep (skip the validation "
                        "matrix — e.g. when a capture just ran it)")
   ap.add_argument("--select", default=None,
                   help="comma list of family[:shape_idx] items to run "
                        "instead of the full matrix. Families: "
-                       "flash_bf16, flash_f32, gqa, block, ln, lnmm, gelu")
+                       "flash_bf16, flash_f32, gqa, block, ln")
   ap.add_argument("--append-jsonl", default=None,
                   help="append each result row to this file the moment it "
                        "is produced (survives a mid-run chip drop)")
@@ -600,8 +441,6 @@ def main(argv=None):
     flash_shapes = [(1, 512, 4, 64, True)]
     gqa_shapes = [(2, 1024, 8, 2, 64, True)]
     ln_shapes = [(4096, 1024)]
-    lnmm_shapes = [(4096, 768, 3072)]
-    actmm_shapes = [(4096, 3072, 768)]
   else:
     flash_shapes = [
         (1, 512, 4, 64, True),
@@ -618,13 +457,6 @@ def main(argv=None):
         (1, 4096, 8, 2, 128, True),
     ]
     ln_shapes = [(4096, 1024), (8192, 768), (16384, 4096)]
-    # the bench shape (b16 s1024 GPT-2-small: 16384 rows, 768 -> 3072)
-    # plus a bigger-model shape
-    lnmm_shapes = [(4096, 768, 3072), (16384, 768, 3072),
-                   (8192, 2048, 8192)]
-    # gelu->down-proj: the transposed pair of the lnmm up-proj shapes
-    actmm_shapes = [(4096, 3072, 768), (16384, 3072, 768),
-                    (8192, 8192, 2048)]
 
   families = {
       "flash_bf16": (flash_shapes, lambda sh: check_flash(results, sh,
@@ -634,8 +466,6 @@ def main(argv=None):
       "gqa": (gqa_shapes, lambda sh: check_flash_gqa(results, sh)),
       "block": (None, lambda sh: check_flash_block(results)),
       "ln": (ln_shapes, lambda sh: check_layer_norm(results, sh)),
-      "lnmm": (lnmm_shapes, lambda sh: check_ln_matmul(results, sh)),
-      "gelu": (actmm_shapes, lambda sh: check_gelu_matmul(results, sh)),
   }
   if args.select:
     for spec in args.select.split(","):
@@ -663,8 +493,6 @@ def main(argv=None):
     check_flash_gqa(results, gqa_shapes)
     check_flash_block(results)
     check_layer_norm(results, ln_shapes)
-    check_ln_matmul(results, lnmm_shapes)
-    check_gelu_matmul(results, actmm_shapes)
   if args.sweep_blocks or (args.sweep_only and not args.select):
     sweep_blocks(results)
 
