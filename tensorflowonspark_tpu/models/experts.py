@@ -1,6 +1,7 @@
 """Sparse experts as ONE CHIP'S SHARE of an expert-parallel layer, as one
 feed-forward of ``models.transformer.Block`` (``ffn_types[i] ==
-"experts"``): sigmoid router over the published ``experts_total``, top
+"experts"``): sigmoid router over the published ``experts_total`` (or,
+``experts_score="softmax"``, a softmax one with no selection bias), top
 ``experts_top_k`` a token (inside its ``experts_groups_kept`` best of
 ``experts_groups`` groups, where the router has that limit) with renormalised
 weights times
@@ -47,8 +48,10 @@ class HeldExperts(nn.Module):
     d, f, held = cfg.d_model, cfg.experts_d_ff, cfg.experts_held
     lecun = nn.initializers.lecun_normal()
     router = self.param("router", lecun, (d, cfg.experts_total), jnp.float32)
-    bias = self.param("router_bias", nn.initializers.zeros,
-                      (cfg.experts_total,), jnp.float32)
+    soft = cfg.experts_score == "softmax"      # no selection bias exists
+    bias = None if soft else self.param(
+        "router_bias", nn.initializers.zeros, (cfg.experts_total,),
+        jnp.float32)
     # fan-in is the middle axis of a [held, in, out] stack
     stack = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
                                              in_axis=1, out_axis=2,
@@ -58,9 +61,11 @@ class HeldExperts(nn.Module):
         for name, shape in (("gate", (held, d, f)), ("up", (held, d, f)),
                             ("down", (held, f, d))))
     flat = x.reshape(-1, d)       # the router sees it unrounded
-    experts, weights, *kept = ep.route_sigmoid_topk(
-        flat, router, bias, cfg.experts_top_k, cfg.experts_scale,
-        cfg.experts_groups, cfg.experts_groups_kept)
+    experts, weights, *kept = ep.route_softmax_topk(
+        flat, router, cfg.experts_top_k, cfg.experts_scale) if soft \
+        else ep.route_sigmoid_topk(
+            flat, router, bias, cfg.experts_top_k, cfg.experts_scale,
+            cfg.experts_groups, cfg.experts_groups_kept)
     split = None
     if cfg.act_f32 and gate.dtype == jnp.bfloat16:
       split = tfm._bf16_terms

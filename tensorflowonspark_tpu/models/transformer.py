@@ -268,6 +268,31 @@ class TransformerConfig:
   # sum of its two largest biased scores. 0 = no limit.
   experts_groups: int = 0
   experts_groups_kept: int = 0
+  # The router's SCORE (held experts): "sigmoid" = ``s = sigmoid(x W)``, the
+  # selection over ``s + bias`` (param ``moe/router_bias``); "softmax" = ``p =
+  # softmax(x W)`` over the router's whole width, the ``experts_top_k``
+  # largest, no bias (the param does not exist). Either way the weights are
+  # the chosen scores over their sum, times ``experts_scale``.
+  experts_score: str = "sigmoid"
+  # A learned SELECTION of the cached tokens a query attends (attention
+  # layers): with ``sparse_topk`` > 0 every attention layer carries an INDEXER
+  # (params ``attn/index_q`` ``[d_model, index_heads, index_head_dim]``,
+  # ``attn/index_k`` ``[d_model, index_head_dim]`` with a LayerNorm
+  # ``attn/index_k_norm`` (scale and bias), ``attn/index_w`` ``[d_model,
+  # index_heads]``), all from the layer's normed input: query ``t`` scores
+  # position ``s <= t`` ``I(t, s) = sum_h w_t,h relu(rot(qI_t,h) . rot(kI_s))``
+  # with ``w_t = x_t W_w index_heads^-0.5 index_head_dim^-0.5`` (float32
+  # products of the stored numbers; queries and the one key a token rotate
+  # over all ``index_head_dim`` dims at ``rope_theta``), and attends the
+  # ``min(t + 1, sparse_topk)`` positions with the largest score alone, the
+  # earlier position first among equal scores (:func:`select_topk`: exact).
+  # The decode cache holds the rotated index key as a THIRD leaf a layer
+  # (``cached_ik`` ``[batch, max_seq_len, 128]``, ``index_head_dim`` lanes
+  # used). 0 = every position is attended, no indexer exists: the programs
+  # traced before the field existed.
+  sparse_topk: int = 0
+  index_heads: int = 0
+  index_head_dim: int = 0
 
   def __post_init__(self):
     if self.moe_experts > 0 and self.moe_every < 1:
@@ -410,6 +435,48 @@ class TransformerConfig:
             "largest), with 0 < experts_groups_kept=%d <= that and room for "
             "experts_top_k=%d inside the groups kept" % (
                 g, self.experts_total, kept, self.experts_top_k))
+    if self.experts_score not in ("sigmoid", "softmax"):
+      raise ValueError("experts_score must be 'sigmoid' or 'softmax', got %r"
+                       % (self.experts_score,))
+    if self.experts_score == "softmax" and self.experts_groups:
+      raise ValueError(
+          "experts_score='softmax' routes over the router's whole width; a "
+          "group limit (experts_groups=%d) ranks groups by the sum of their "
+          "two largest BIASED SIGMOID scores, and a group limit over softmax "
+          "scores is not built" % self.experts_groups)
+    if self.sparse_topk < 0 or (not self.sparse_topk and (
+        self.index_heads or self.index_head_dim)):
+      raise ValueError(
+          "sparse_topk must be >= 0 and index_heads / index_head_dim belong "
+          "to a selection (sparse_topk > 0), got %d, %d and %d"
+          % (self.sparse_topk, self.index_heads, self.index_head_dim))
+    if self.sparse_topk:
+      if self.index_heads < 1 or not 0 < self.index_head_dim <= INDEX_LANES \
+          or self.index_head_dim % 2:
+        raise ValueError(
+            "sparse_topk=%d needs index_heads >= 1 and an even index_head_dim "
+            "of at most %d (the index leaf's lanes), got %d and %d" % (
+                self.sparse_topk, INDEX_LANES, self.index_heads,
+                self.index_head_dim))
+      for asked, feature, what in (
+          (bool(self.non_kv_layers), "layers",
+           "layer_types %r" % (self.layer_types,)),
+          (bool(self.attention_window or any(self.layer_windows)
+                or self.kv_ring), "window",
+           "a sliding window or a ring (attention_window=%d, layer_windows "
+           "%r)" % (self.attention_window, self.layer_windows)),
+          (self.wide_heads, "heads",
+           "attn_v_head_dim=%d, layer_kv_heads %r, layer_sink %r" % (
+               self.attn_v_head_dim, self.layer_kv_heads, self.layer_sink)),
+          (self.loop_passes > 1, "loop", "loop_passes=%d" % self.loop_passes),
+          (self.kv_cache_dtype == "int8", "int8", "kv_cache_dtype='int8'"),
+          (self.kv_page_size > 0, "pages",
+           "the paged KV pool (kv_page_size=%d)" % self.kv_page_size),
+          (self.use_ring_attention, "mesh", "use_ring_attention"),
+          (self.moe_experts > 0, "aux", "moe_experts=%d (the trained MoE "
+           "block)" % self.moe_experts)):
+        if asked:
+          raise ValueError(sparse_refusal(self, what, feature))
     if self.kv_page_size > 0:
       if self.loop_passes > 1:
         raise ValueError(loop_refusal(
@@ -490,6 +557,52 @@ class TransformerConfig:
     """The layers (by index) whose window a serving slab holds as a ring."""
     return tuple(i for i, w in enumerate(self.layer_windows)
                  if self.ring_rows(w))
+
+
+#: lanes of the decode cache's index-key leaf (``cached_ik``): whole vregs,
+#: ``index_head_dim`` of them used
+INDEX_LANES = 128
+
+#: why each feature cannot take attention layers that SELECT the cached tokens
+#: a query attends (``TransformerConfig.sparse_topk``; ``sparse_refusal``)
+_SPARSE_REFUSALS = {
+    "layers": "the indexer is built into attention layers (keys and values "
+              "per head); a latent or a recurrent layer that selects is not "
+              "built",
+    "window": "a selection among the positions of a window, and a ring whose "
+              "rows are reordered under the index keys, are not built",
+    "heads": "the selection's mask is built for heads of one width under one "
+             "KV head count and a plain softmax (a sink's share of a selected "
+             "softmax, leaves of two widths or head counts are untried)",
+    "loop": "an index-key leaf a pass is not built",
+    "int8": "an int8 cache beside a bf16 index-key leaf (whose rounding moves "
+            "the selection) is not built",
+    "pages": "the pool holds K and V pages; a third pool of index keys, and "
+             "a selection gathered through a page table, do not exist yet",
+    "prefix": "a prefix's pages hold keys and values, not index keys, and "
+              "the pool they live in cannot take such a model",
+    "draft": "a verify window scores several tokens a lane against per-slot "
+             "cursors, and a rejected draft's index keys sit past the cursor: "
+             "a selection over a verify window is not built",
+    "mesh": "the index scores, the selection and the kernels' keep operand "
+            "are laid out for ONE device (a selection over a sharded sequence "
+            "or sharded heads is not built)",
+    "train": "the flash backward takes no keep operand and the indexer has "
+             "no loss of its own (it is trained to match the attention's "
+             "distribution, which nothing here computes): a model that "
+             "selects is served, not trained",
+    "aux": "the trained MoE block is part of the training path",
+}
+
+
+def sparse_refusal(cfg, what: str, feature: str) -> str:
+  """The message with which ``what`` (``feature`` its key in
+  ``_SPARSE_REFUSALS``) refuses a model whose attention layers select
+  ``cfg.sparse_topk`` cached tokens a query."""
+  return "%s cannot take a model whose attention attends the %d cached " \
+      "tokens a learned indexer chooses (sparse_topk, %d index heads of " \
+      "%d): %s" % (what, cfg.sparse_topk, cfg.index_heads,
+                   cfg.index_head_dim, _SPARSE_REFUSALS[feature])
 
 
 #: why each serving feature cannot take a looped model yet (``loop_refusal``)
@@ -784,7 +897,10 @@ def decode_attention_tally():
   yields ``{"reads": n, "ragged": m, "ring": r}``, ``m`` of the ``n`` having
   taken ``ops.decode_attention``'s kernel, which stops at each slot's
   cursor, and ``r`` of the ``n`` reading a RING leaf
-  (``TransformerConfig.kv_ring``)."""
+  (``TransformerConfig.kv_ring``); where a read lay under a selection's keep
+  mask (``TransformerConfig.sparse_topk``) a fourth key ``"sparse"`` counts
+  those (each also read its layer's index-key leaf whole: every slot's
+  ``max_seq_len`` rows)."""
   return _tally(_attn_reads, "reads", "ragged", "ring")
 
 
@@ -993,9 +1109,115 @@ def _flash_attention_sink(q, k, v, window, sink, interpret):
       window=window or None), sink).astype(q.dtype)
 
 
+#: the float32 products ``[seg, heads, n]`` of the indexer's queries and a
+#: row's keys are made whole up to this many bytes, a ``_ROW_BLOCK`` of keys at
+#: a time above it (a 4096-token chunk over 32768 keys would be 8.6 GB)
+_INDEX_SCORE_BYTES = 1 << 28
+
+
+def select_topk(scores, valid, k: int):
+  """The EXACT top-``k`` of each row as a mask: ``scores [..., n]`` float32,
+  ``valid [..., n]`` bool (a row's candidates); returns ``[..., n]`` bool, true
+  at the ``min(k, candidates)`` valid positions with the largest score, the
+  EARLIER position first among equal scores (``lax.top_k``'s order). A row
+  with at most ``k`` candidates keeps them all.
+
+  No sort and no gather: a float's bits, read as an unsigned integer with the
+  sign folded, order as the floats do, and the ``k``-th largest of a row is
+  the largest threshold ``T`` with ``count(key >= T) >= k``: found bit by bit
+  from the top, 32 passes of compare-and-count, each one pass over the row in
+  whatever memory it lies. The passes read HALF a key each: the upper 16 bits
+  of ``T`` come from the keys' upper halves alone, the lower 16 from the
+  lower halves of the keys whose upper half equals ``T``'s (the others count
+  as 0), so a pass moves two bytes an entry. Keys above ``T`` stay; of the
+  keys EQUAL to it the first ``k - count(key > T)`` by position (a running
+  count, taken only where some row has more equals than it needs: rare with
+  real scores). The mask comes out where a kernel's operand wants it, with no
+  scatter of indices."""
+  scores = scores.astype(jnp.float32)
+  bits = lax.bitcast_convert_type(       # -0.0 is the score 0.0
+      jnp.where(scores == 0, 0.0, scores), jnp.uint32)
+  neg = bits >> 31 == 1
+  key = jnp.where(neg, ~bits, bits | jnp.uint32(1 << 31))
+  # no candidate's key is 0 (that is a NaN with every bit set)
+  key = jnp.where(valid, key, jnp.uint32(0))
+  kk = jnp.minimum(jnp.sum(valid, axis=-1, keepdims=True, dtype=jnp.int32), k)
+
+  def kth(half, want):
+    """The largest 16-bit ``T`` with ``count(half >= T) >= want`` a row."""
+    def one_bit(i, t):
+      cand = t | (jnp.uint16(1) << (15 - i).astype(jnp.uint16))
+      n = jnp.sum(half >= cand, axis=-1, keepdims=True, dtype=jnp.int32)
+      return jnp.where(n >= want, cand, t)
+    return lax.fori_loop(0, 16, one_bit, jnp.zeros(want.shape, jnp.uint16))
+
+  upper = (key >> 16).astype(jnp.uint16)
+  t_hi = kth(upper, kk)
+  tied_hi = upper == t_hi
+  left = kk - jnp.sum(upper > t_hi, axis=-1, keepdims=True, dtype=jnp.int32)
+  t_lo = kth(jnp.where(tied_hi, key.astype(jnp.uint16), jnp.uint16(0)), left)
+  t = t_hi.astype(jnp.uint32) << 16 | t_lo.astype(jnp.uint32)
+  t = jnp.maximum(t, jnp.uint32(1))       # a row with no candidate keeps none
+  above, equal = key > t, key == t
+  need = kk - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+  tied = jnp.any(jnp.sum(equal, axis=-1, keepdims=True, dtype=jnp.int32)
+                 > need)
+  first = lax.cond(
+      tied,
+      lambda: jnp.cumsum(equal, axis=-1, dtype=jnp.int32) <= need,
+      lambda: jnp.ones(equal.shape, jnp.bool_))
+  return jnp.logical_or(above, jnp.logical_and(equal, first))
+
+
+def index_scores(iq, iw, keys, live=None):
+  """The indexer's scores ``I [b, seg, n]`` (float32) of ``seg`` queries over
+  ``n`` index keys: ``iq [b, seg, heads, di]`` (rotated), ``iw [b, seg,
+  heads]`` float32 (scaled), ``keys [b, n, lanes]`` (rotated; the cache leaf
+  as stored, ``di`` of its lanes used, the rest zeros): ``sum_h iw_h relu(iq_h
+  . key)``. bf16 operands meet exactly in float32 (one MXU pass); float32
+  ones (a test) at full precision. The ``[b, seg, heads, n]`` products of a
+  long row are made a block of ``_ROW_BLOCK`` keys at a time, up to the block
+  that holds position ``live - 1`` (a traced scalar; None = all): the columns
+  past it read 0 and are nobody's candidates."""
+  b, seg, hi, di = iq.shape
+  n, lanes = keys.shape[1:]
+  iq = jnp.pad(iq, ((0, 0),) * 3 + ((0, lanes - di),)).astype(keys.dtype)
+  exact = lax.Precision.HIGHEST if keys.dtype == jnp.float32 else None
+
+  def block(kb):
+    s = jnp.einsum("bqhc,bkc->bqhk", iq, kb, precision=exact,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * iw[..., None], axis=2)
+
+  if n <= _ROW_BLOCK or n % _ROW_BLOCK \
+      or seg * hi * n * 4 <= _INDEX_SCORE_BYTES:
+    return block(keys)
+
+  def one(j, acc):
+    kb = lax.dynamic_slice_in_dim(keys, j * _ROW_BLOCK, _ROW_BLOCK, axis=1)
+    return lax.dynamic_update_slice_in_dim(acc, block(kb), j * _ROW_BLOCK,
+                                           axis=2)
+
+  blocks = n // _ROW_BLOCK if live is None \
+      else jnp.minimum((live - 1) // _ROW_BLOCK + 1, n // _ROW_BLOCK)
+  return lax.fori_loop(0, blocks, one, jnp.zeros((b, seg, n), jnp.float32))
+
+
+def _full_attention_keep(q, k, v, keep):
+  """The dense reference attention (K and V at the full head count) under a
+  mask by QUERY: ``keep [b, s, s]`` bool, shared by the heads."""
+  d = q.shape[3]
+  scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                      k.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST) / (d ** 0.5)
+  probs = jax.nn.softmax(jnp.where(keep[:, None], scores, ra.NEG_INF), axis=-1)
+  return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32),
+                    precision=lax.Precision.HIGHEST).astype(q.dtype)
+
+
 def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
                       k_scale=None, v_scale=None, lengths=None, mesh=None,
-                      ring: bool = False, sink=None):
+                      ring: bool = False, sink=None, keep=None):
   """Masked softmax attention of a query block over a KV cache AND the
   block's own keys/values, which the cache does not hold yet.
 
@@ -1056,6 +1278,13 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
   wide. ``sink [h]`` (``TransformerConfig.layer_sink``) joins each head's
   softmax DENOMINATOR beside the cache's and the block's own entries, in
   both lowerings.
+
+  ``keep`` (``TransformerConfig.sparse_topk``): a mask BY QUERY beside the
+  positional one, shared by the heads: ``(keep_cache [b, seg, max],
+  keep_own [b, seg, seg])`` bool over the cache's rows and the block's own
+  entries; a query attends an entry only where both masks allow it (its own
+  entry too: a selection may drop it). The kernel takes the cache's part as
+  one more operand a slot and the own part as a scalar a slot.
   """
   b, seg, h, d = q.shape
   dv = v.shape[-1]
@@ -1065,19 +1294,22 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
               and (mesh is None or mesh.size == 1)
               and ops.decode_attention_supports(
                   (b, h, d), q.dtype, cached_k.shape, cached_k.dtype,
-                  cached_v.shape)
+                  cached_v.shape, keep=keep is not None)
               and ops.pallas_kernels_enabled())
     tally = getattr(_attn_reads, "open", None)
     if tally is not None:
       tally["reads"] += 1
       tally["ragged"] += ragged
       tally["ring"] += bool(ring)
+      if keep is not None:
+        tally["sparse"] = tally.get("sparse", 0) + 1
     if ragged:
       return ops.decode_attention(
           q[:, 0], k[:, 0], v[:, 0], cached_k, cached_v,
           jnp.minimum(lengths, mx) if ring else lengths,
           skip=_ring_skip(lengths, mx, window) if ring else None,
           sink=sink,
+          keep=None if keep is None else (keep[0][:, 0], keep[1][:, 0, 0]),
           interpret=ops.pallas_interpret())[:, None].astype(q.dtype)
   hk = cached_k.shape[2] // d
   g = h // hk
@@ -1121,9 +1353,14 @@ def _cached_attention(q, k, v, cached_k, cached_v, q_pos, window: int = 0,
                                  k_pos > q_pos[..., None] - window)
     causal = jnp.logical_and(causal,
                              own_pos[None, :] > own_pos[:, None] - window)
+  if keep is not None:
+    keep_cache = jnp.logical_and(keep_cache, keep[0])
+    causal = jnp.logical_and(causal[None], keep[1])        # [b, seg, seg]
   s_cache = jnp.where(keep_cache[:, :, None, :], s_cache, -1e30)
-  s_own = jnp.where(causal[None, :, None, :], s_own, -1e30)
-  # ONE softmax over both parts (a query always keeps its own entry)
+  s_own = jnp.where(causal[None, :, None, :] if keep is None
+                    else causal[:, :, None, :], s_own, -1e30)
+  # ONE softmax over both parts (a query always keeps its own entry, or,
+  # under a selection, at least one entry)
   top = jnp.maximum(s_cache.max(axis=-1), s_own.max(axis=-1))[..., None]
   if sink is not None:
     sink = sink.astype(jnp.float32)[None, None, :, None]  # [1, 1, h, 1]
@@ -1208,15 +1445,39 @@ class Attention(nn.Module):
       gate = dense((cfg.num_heads, cfg.v_head_dim),
                    ("embed", heads_axis(cfg.num_heads), "kv"), "gate")(x)
 
+    index = None
+    if cfg.sparse_topk:
+      if self.mesh is not None and self.mesh.size > 1:
+        raise ValueError(sparse_refusal(
+            cfg, "a mesh of %d devices" % self.mesh.size, "mesh"))
+      index = self._index(x)
+
     if decode:
-      return self._decode_attend(q, k, v, loop_pass, win, gate, theta, sink)
+      return self._decode_attend(q, k, v, loop_pass, win, gate, theta, sink,
+                                 index)
 
     if self.rope:
       q = _rotary(q, positions, theta, cfg.rope_dim)
       k = _rotary(k, positions, theta, cfg.rope_dim)
 
     interp = ops.pallas_interpret()           # forced-flash CI runs
-    if cfg.use_ring_attention and self.mesh is not None:
+    if index is not None:
+      # the whole sequence at once (no cache): every query's candidates are
+      # the positions up to its own
+      with jax.named_scope("indexer"):
+        iq, ik, iw = self._index_rotate(index, positions, theta)
+        at = jnp.arange(q.shape[1])
+        keep = select_topk(
+            index_scores(iq, iw, ik.astype(cfg.dtype)),
+            jnp.broadcast_to(at[None, :] <= at[:, None],
+                             (q.shape[0],) + 2 * at.shape), cfg.sparse_topk)
+      if _flash_eligible(cfg, q.shape[1]):
+        out = ops.flash_attention(q, k, v, causal=True, interpret=interp,
+                                  keep=keep)
+      else:
+        out = _full_attention_keep(q, _expand_kv(k, cfg.num_heads),
+                                   _expand_kv(v, cfg.num_heads), keep)
+    elif cfg.use_ring_attention and self.mesh is not None:
       # the ring takes GROUPED K/V as-is: unexpanded blocks rotate on the
       # ICI (num_heads/kv_heads less traffic); the flash kernels consume
       # them unexpanded and the dense block math fuses the expand
@@ -1257,6 +1518,32 @@ class Attention(nn.Module):
 
     return self._out_proj(out, gate)
 
+  def _index(self, x):
+    """The indexer's projections of the layer's normed input ``x``
+    (``TransformerConfig.sparse_topk``): ``(queries [b, s, index_heads,
+    index_head_dim], the one key a token [b, s, index_head_dim] after its
+    LayerNorm (float32), the heads' weights [b, s, index_heads] float32,
+    scaled)``, none rotated yet."""
+    cfg = self.cfg
+    proj = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+        feats, axis=-1, dtype=cfg.dtype, use_bias=False, name=name,
+        kernel_init=nn.initializers.lecun_normal())(x)
+    iq = proj((cfg.index_heads, cfg.index_head_dim), "index_q")
+    ik = nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                      name="index_k_norm")(proj(cfg.index_head_dim, "index_k"))
+    iw = proj(cfg.index_heads, "index_w").astype(jnp.float32) * (
+        cfg.index_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    return iq, ik, iw
+
+  @staticmethod
+  def _index_rotate(index, positions, theta):
+    """The indexer's queries and key rotated at ``positions`` over ALL their
+    dims (half-split, ``theta``'s frequencies of an ``index_head_dim``-wide
+    head); the key stays float32 until it is stored."""
+    iq, ik, iw = index
+    return (_rotary(iq, positions, theta),
+            _rotary(ik[:, :, None, :], positions, theta)[:, :, 0], iw)
+
   def _out_proj(self, out, gate=None):
     cfg = self.cfg
     if gate is not None:
@@ -1268,7 +1555,8 @@ class Attention(nn.Module):
             nn.initializers.lecun_normal(), ("heads", "kv", "embed")))(out)
 
   def _decode_attend(self, q, k, v, loop_pass: int = 0, win: int = 0,
-                     gate=None, theta: float = 10000.0, sink=None):
+                     gate=None, theta: float = 10000.0, sink=None,
+                     index=None):
     """Incremental attention against a KV cache (serving path); ``win`` the
     layer's window, ``gate`` its output gate's pre-activation, ``theta`` its
     rotary base, ``sink`` its softmax's sink (``[num_heads]`` or None). The K
@@ -1310,7 +1598,22 @@ class Attention(nn.Module):
     rows, from the window's lower edge to its own last row: the dense
     branch's float32 scores of ``seg x heads x max_seq_len`` are 1.6 GB at
     512 x 48 x 16384.
-    """
+
+    ``index`` (``TransformerConfig.sparse_topk``; :meth:`_index`'s triple): the
+    layer SELECTS. Its rotated index key is written at the cursor into a
+    third leaf, ``cached_ik`` ``[batch, max_seq_len, INDEX_LANES]``; every
+    query's index scores over its candidates (the cache's rows below the
+    cursor and the block's own up to itself) give the mask by query
+    (:func:`select_topk`) that each lowering of the attention takes beside
+    its positional one: the dense branch, ``ops.decode_attention`` (a row a
+    slot), the flash forward of a fresh chunk and the blocked merge of a later
+    one (an operand tiled as they walk). A decode step reads the index leaf
+    AS IT WAS plus the step's own key (the read does not wait on the write); a
+    chunk under a shared cursor reads its row as written, in blocks up to its
+    own last position. A chunk that ends at or below ``sparse_topk`` positions
+    selects nothing and runs the program it ran without an indexer (decided
+    on the traced cursor). Counter ``counters/sparse_kept`` ``[batch]``: the
+    entries a decode step's query kept."""
     cfg = self.cfg
     if cfg.kv_page_size > 0:
       return self._decode_attend_paged(q, k, v, win, gate)
@@ -1391,6 +1694,69 @@ class Attention(nn.Module):
                      positions, self.mesh), kv_spec, self.mesh)
     if loop_pass == cfg.loop_passes - 1:
       cursor.value = idx + seg
+    if index is None:
+      return self._out_proj(self._attend_cached(
+          q, k, v, k_own, v_own, was, cached_k, cached_v, idx, positions,
+          win, ring, sink, quant), gate)
+
+    if vec and seg > 1:
+      raise ValueError(sparse_refusal(
+          cfg, "a block of %d tokens a lane under per-slot cursors (a "
+          "speculative verify window)" % seg, "draft"))
+    iq, ik, iw = self._index_rotate(index, positions, theta)
+    ik = jnp.pad(ik.astype(cfg.dtype),
+                 ((0, 0), (0, 0), (0, INDEX_LANES - ik.shape[-1])))
+    cached_ik = self.variable("cache", "cached_ik", jnp.zeros,
+                              (b, rows, INDEX_LANES), cfg.dtype)
+    ik_was = cached_ik.value
+    cached_ik.value = _cache_write(ik_was, ik, at, positions, self.mesh)
+    col = jnp.arange(rows)
+    attend = lambda keep: self._attend_cached(  # noqa: E731
+        q, k, v, k_own, v_own, was, cached_k, cached_v, idx, positions, win,
+        ring, sink, quant, keep)
+
+    def _keep_step():
+      # per-slot cursors, one token: the leaf as it was and the own key
+      with jax.named_scope("indexer"):
+        scores = jnp.where(col == idx[:, None],
+                           index_scores(iq, iw, ik)[:, 0],      # [b, 1]
+                           index_scores(iq, iw, ik_was)[:, 0])  # [b, max]
+        keep = select_topk(scores, col <= idx[:, None], cfg.sparse_topk)
+        own = jnp.take_along_axis(
+            keep, jnp.minimum(idx, rows - 1)[:, None], axis=1)
+      self.sow("counters", "sparse_kept",
+               jnp.sum(keep, axis=-1, dtype=jnp.int32))
+      return keep[:, None, :], own[:, :, None]
+
+    def _keep_row():
+      # a shared cursor: the row as written holds the block's own keys too
+      with jax.named_scope("indexer"):
+        keep = select_topk(
+            index_scores(iq, iw, cached_ik.value, live=idx + seg),
+            col <= positions[:, :, None], cfg.sparse_topk)  # [b, seg, max]
+      return keep, lax.dynamic_slice_in_dim(keep, idx, seg, axis=2)
+
+    if vec:
+      out = attend(_keep_step())
+    elif seg > cfg.sparse_topk:
+      out = attend(_keep_row())
+    else:
+      out = lax.cond(idx + seg > cfg.sparse_topk,
+                     lambda: attend(_keep_row()), lambda: attend(None))
+    return self._out_proj(out, gate)
+
+  def _attend_cached(self, q, k, v, k_own, v_own, was, cached_k, cached_v,
+                     idx, positions, win, ring, sink, quant, keep=None):
+    """The attention of :meth:`_decode_attend` once the block is written:
+    ``q``/``k``/``v`` rotated, ``k_own``/``v_own`` the block as the cache
+    stores it, ``was`` the leaves before the write, ``cached_k``/``cached_v``
+    the variables after it; ``keep`` a selection's ``(mask over the row's
+    positions [b, seg, max], mask over the block's own [b, seg, seg])`` or
+    None."""
+    cfg = self.cfg
+    b, seg, h, d = q.shape
+    hk, dv = v.shape[2:]
+    vec = idx.ndim == 1
 
     def _dense_attend(_):
       # the cache as it was plus the block itself (what the write above
@@ -1399,7 +1765,7 @@ class Attention(nn.Module):
       return _cached_attention(
           q, k_own, v_own, q_pos=positions if vec else positions[:1],
           window=win, lengths=idx if vec else None, mesh=self.mesh,
-          ring=bool(ring), sink=sink, **was)
+          ring=bool(ring), sink=sink, keep=keep, **was)
 
     # PREFILL fast path: a fresh-cache multi-token segment attends only
     # within itself (causal), so the flash kernel runs it O(seg²)-tiled
@@ -1436,8 +1802,9 @@ class Attention(nn.Module):
         if sink is not None:
           return _flash_attention_sink(q, k, v, win, sink, interp)
         if single:
-          return flash_attention(q, k, v, causal=True, interpret=interp,
-                                 window=win or None).astype(q.dtype)
+          return flash_attention(
+              q, k, v, causal=True, interpret=interp, window=win or None,
+              keep=None if keep is None else keep[1]).astype(q.dtype)
         # heads_consistent (above) is what flash_attention_sharded's
         # own both-divide rule needs to shard heads here
         return ops.flash_attention_sharded(
@@ -1457,9 +1824,11 @@ class Attention(nn.Module):
           kj, vj = (lax.dynamic_slice_in_dim(
               c.value, base, _ROW_BLOCK, axis=1).reshape(b, _ROW_BLOCK, hk, w)
                     for c, w in ((cached_k, d), (cached_v, dv)))
+          kept = None if keep is None else lax.dynamic_slice_in_dim(
+              keep[0], base, _ROW_BLOCK, axis=2)
           return merge_partials(*partial, *flash_attention_block(
               q, kj, vj, idx, base, causal=True, interpret=interp,
-              window=win or None))
+              window=win or None, keep=kept))
 
         out, lse = lax.fori_loop(
             first, (idx + seg - 1) // _ROW_BLOCK + 1, one_block,
@@ -1471,11 +1840,9 @@ class Attention(nn.Module):
 
       long_row = (single and not quant and cfg.max_seq_len > _ROW_BLOCK
                   and cfg.max_seq_len % _ROW_BLOCK == 0)
-      out = lax.cond(idx == 0, _flash_prefill,
-                     _blocked_attend if long_row else _dense_attend, None)
-    else:
-      out = _dense_attend(None)
-    return self._out_proj(out, gate)
+      return lax.cond(idx == 0, _flash_prefill,
+                      _blocked_attend if long_row else _dense_attend, None)
+    return _dense_attend(None)
 
   def _decode_attend_paged(self, q, k, v, win: int = 0, gate=None):
     """Incremental attention against a PAGED KV cache (serving slabs).
@@ -1683,11 +2050,14 @@ class Block(nn.Module):
   def _attend(self, y, positions, decode, loop_pass: int = 0):
     """This layer's attention over the normed ``y``; a model with per-layer
     windows runs it under ``jax.named_scope`` ``attn_window`` /
-    ``attn_full``."""
+    ``attn_full``, one that selects (``sparse_topk``) under ``attn_sparse``
+    with its scores and selection under ``indexer`` inside."""
     attn = Attention(self.cfg, self.mesh, self.window, self.rope,
                      self.kv_heads, self.theta, self.sink, name="attn")
     scope = jax.named_scope("attn_window" if self.window else "attn_full") \
         if self.cfg.layer_windows else contextlib.nullcontext()
+    if self.cfg.sparse_topk:       # the indexer's own scope lies inside
+      scope = jax.named_scope("attn_sparse")
     with scope:
       return attn(y, positions, decode=decode, loop_pass=loop_pass)
 
@@ -2561,6 +2931,10 @@ def _init_fns(rng, cfg: TransformerConfig, mesh, learning_rate, seq_len,
   import optax
   from flax.training import train_state
 
+  if cfg.sparse_topk:
+    raise ValueError(sparse_refusal(
+        cfg, "a training state (create_state / create_sharded_state)",
+        "train"))
   model = Transformer(cfg, mesh)
   tokens = jnp.zeros((init_batch, seq_len), jnp.int32)
 
@@ -2657,6 +3031,8 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh,
   from tensorflowonspark_tpu.parallel import pipeline_parallel as PP
 
   assert cfg.moe_experts == 0, "pipeline stages must be homogeneous"
+  if cfg.sparse_topk:
+    raise ValueError(sparse_refusal(cfg, "the pipeline train step", "train"))
   n_stages = mesh.shape[mesh_lib.AXIS_PIPELINE]
   # honor cfg.remat like the dense path does: the per-microbatch stage vjp
   # otherwise stores every intra-block intermediate for all

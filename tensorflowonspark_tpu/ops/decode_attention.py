@@ -49,6 +49,16 @@ the shared key of every head and, in its first lanes, their shared value):
 the caller hands it as both and keeps the output's lanes that are values.
 ``scale`` replaces ``d^-0.5`` where the scores' scale is not the contracted
 width's (a latent's 640 lanes stand for keys of 192).
+
+A SELECTION (``TransformerConfig.sparse_topk``: a learned indexer chose the
+cached tokens each slot's query attends) comes as ``keep``: one row a slot
+over the leaf's positions, held whole in VMEM as ``[b, max / block, block]``
+int32 (a slot's row of 32768 positions is 128 KB), and one scalar a slot for
+the step's own token, which the selection may drop like any other. A block's
+row of the mask joins the cursor's mask on the scores; the own token then
+opens the softmax with weight 0 (its score still anchors the running max).
+The kernel brings the same blocks as without a selection: rows chosen by a
+seeded indexer lie in every block, so there is none to skip.
 """
 
 import functools
@@ -76,24 +86,28 @@ def _padded_heads(h: int) -> int:
   return -(-h // 16) * 16
 
 
-def _vmem_bytes(b: int, h: int, dv: int, c: int, cv: int) -> int:
+def _vmem_bytes(b: int, h: int, dv: int, c: int, cv: int,
+                keep_rows: int = 0) -> int:
   """K and V blocks double-buffered, every slot's expanded query, own key
   and value and folded output, the f32 output rows and the three-term
-  product of one slot (``c`` the K leaf's lanes, ``cv`` the V leaf's)."""
+  product of one slot (``c`` the K leaf's lanes, ``cv`` the V leaf's), and a
+  selection's keep rows (``keep_rows`` positions a slot, int32)."""
   hp = _padded_heads(h)
   return (2 * BLOCK * (c + cv) * 2 + b * ((hp + 16) * c + 16 * cv) * 2
-          + b * hp * max(dv, LANES) * 4 + (1 + 3) * hp * cv * 4)
+          + b * hp * max(dv, LANES) * 4 + (1 + 3) * hp * cv * 4
+          + b * keep_rows * 4)
 
 
 def supports(q_shape, q_dtype, cache_shape, cache_dtype,
-             v_shape=None) -> bool:
+             v_shape=None, keep: bool = False) -> bool:
   """Whether :func:`decode_attention` can take queries ``[b, h, d]`` over
   cache leaves ``[b, max, kv_heads * d]`` (K) and ``v_shape`` ``[b, max,
   kv_heads * dv]`` (V; None = the K leaf's): both bf16, each minor axis whole
   lanes, the position axis whole blocks, a VALUE head's ``dv`` lanes a
   divisor or a multiple of a vreg's 128 (the output leaves the kernel folded
   onto ``max(dv, 128)`` lanes; the keys' ``d`` is only contracted over), whole
-  query groups, and the blocks in VMEM."""
+  query groups, and the blocks (with ``keep``, a selection's mask rows too) in
+  VMEM."""
   v_shape = cache_shape if v_shape is None else v_shape
   if len(q_shape) != 3 or len(cache_shape) != 3 or len(v_shape) != 3:
     return False
@@ -109,15 +123,19 @@ def supports(q_shape, q_dtype, cache_shape, cache_dtype,
           and cache_shape[0] == b and c % LANES == 0 and cv % LANES == 0
           and h % hk == 0 and (dv % LANES == 0 or LANES % dv == 0)
           and mx % BLOCK == 0
-          and _vmem_bytes(b, h, dv, c, cv) <= VMEM_BUDGET)
+          and _vmem_bytes(b, h, dv, c, cv, mx if keep else 0) <= VMEM_BUDGET)
 
 
-def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
+def _kernel(len_ref, *refs, g, d, scale, ring, sunk, kept=False):
   skip_ref = refs[0] if ring else None      # [2 * slots]: first rows, counts
   refs = refs[1:] if ring else refs
+  own_ref = refs[0] if kept else None       # [slots]: the own token stays
+  refs = refs[1:] if kept else refs
   sink_ref = refs[0] if sunk else None      # [hp, LANES] f32, lanes alike
+  refs = refs[1:] if sunk else refs
+  keep_ref = refs[0] if kept else None      # [slots, max / block, block] i32
   (q_ref, k_own_ref, v_own_ref, k_hbm, v_hbm, o_ref,
-   k_buf, v_buf, acc, sem) = refs[1:] if sunk else refs
+   k_buf, v_buf, acc, sem) = refs[1:] if kept else refs
   slots, mx = k_hbm.shape[:2]
   hp, c = acc.shape          # the V leaf's lanes; d a VALUE head's
   w = o_ref.shape[2]
@@ -178,6 +196,12 @@ def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
       s_own = jnp.sum(
           q.astype(jnp.float32) * k_own_ref[i].astype(jnp.float32),
           axis=-1, keepdims=True) * scale                # [hp, 1]
+      if kept:
+        # a selection may have dropped the own token: weight 0, its score
+        # still the running max's start (scores are a few units apart)
+        stays = (own_ref[i] > 0).astype(jnp.float32)
+        acc[...] = v_own * stays
+        return q, s_own, jnp.zeros_like(s_own) + stays
       if not sunk:
         acc[...] = v_own
         return q, s_own, jnp.ones_like(s_own)
@@ -223,6 +247,8 @@ def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
           behind = col + (j * block - skip_ref[i])
           behind = jnp.where(behind < 0, behind + mx, behind)
           keep = jnp.logical_and(keep, behind >= skip_ref[slots + i])
+        if kept:
+          keep = jnp.logical_and(keep, keep_ref[i, pl.ds(j, 1), :] > 0)
         s = jnp.where(keep, s, -1e30)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha, p = jnp.exp(m - m_new), jnp.exp(s - m_new)
@@ -257,7 +283,7 @@ def _kernel(len_ref, *refs, g, d, scale, ring, sunk):
 # ops/layer_norm.py's launchers state): the innermost jit names the kernel
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
-                     sink=None, interpret=False, scale=None):
+                     sink=None, interpret=False, scale=None, keep=None):
   """Softmax attention of one query token a slot over that slot's cache
   rows below ``lengths[i]`` AND the token's own key and value: ``q [b, h,
   d]`` (rotated), ``k`` / ``v`` ``[b, kv_heads, d]`` as the cache will hold
@@ -268,10 +294,17 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
   of rows that is not attended. ``v`` / ``cached_v`` may be ``dv`` wide a
   head where the keys are ``d``; ``sink [h]`` float32 joins each head's
   softmax denominator. ``scale`` (static) is the scores' scale, None =
-  ``d^-0.5``. Returns ``[b, h, dv]`` float32. The shapes must pass
+  ``d^-0.5``. ``keep`` is a selection's ``(rows [b, max] bool, own [b]
+  bool)``: slot ``i`` attends cache row ``r`` only where ``rows[i, r]``, and
+  its own token only where ``own[i]`` (a selection is not met with a sink or a
+  ring). Returns ``[b, h, dv]`` float32. The shapes must pass
   :func:`supports`."""
+  if keep is not None and (skip is not None or sink is not None):
+    raise ValueError("decode_attention takes a selection's keep mask over a "
+                     "whole-context leaf under a plain softmax: not beside a "
+                     "ring's skip or a sink")
   if not supports(q.shape, q.dtype, cached_k.shape, cached_k.dtype,
-                  cached_v.shape):
+                  cached_v.shape, keep is not None):
     raise ValueError(
         "decode_attention takes bf16 queries [b, h, d] over bf16 leaves "
         "[b, max, kv_heads * d] of whole lanes and whole blocks of %d rows, "
@@ -289,21 +322,24 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
                  ((0, 0), (0, hp - h), (0, 0)))
   hbm = pl.BlockSpec(memory_space=pltpu.HBM)
   vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-  ring, sunk = skip is not None, sink is not None
+  ring, sunk, kept = skip is not None, sink is not None, keep is not None
   scalars = (lengths.astype(jnp.int32),) + (
-      (skip.astype(jnp.int32).reshape(2 * b),) if ring else ())
+      (skip.astype(jnp.int32).reshape(2 * b),) if ring else ()) + (
+          (keep[1].astype(jnp.int32).reshape(b),) if kept else ())
+  masks = (keep[0].astype(jnp.int32).reshape(b, -1, BLOCK),) if kept else ()
   sinks = (jnp.broadcast_to(jnp.pad(
       sink.astype(jnp.float32), (0, hp - h))[:, None], (hp, LANES)),) \
       if sunk else ()
   o = pl.pallas_call(
       functools.partial(
-          _kernel, g=g, d=d, ring=ring, sunk=sunk,
+          _kernel, g=g, d=d, ring=ring, sunk=sunk, kept=kept,
           scale=1.0 / (dk ** 0.5) if scale is None else scale),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           # ONE grid step, the slots a loop inside it (a grid over slots
           # with the chain's state in SMEM took the same time on the chip)
           num_scalar_prefetch=len(scalars), grid=(1,),
-          in_specs=[vmem] * len(sinks) + [vmem, vmem, vmem, hbm, hbm],
+          in_specs=[vmem] * (len(sinks) + len(masks))
+          + [vmem, vmem, vmem, hbm, hbm],
           out_specs=vmem,
           scratch_shapes=[pltpu.VMEM((2, BLOCK, c), cached_k.dtype),
                           pltpu.VMEM((2, BLOCK, cv), cached_v.dtype),
@@ -314,7 +350,7 @@ def decode_attention(q, k, v, cached_k, cached_v, lengths, skip=None,
           vmem_limit_bytes=VMEM_BUDGET + (8 << 20)),
       interpret=interpret,
       name="decode_attention",
-  )(*scalars, *sinks, q_bd, k.reshape(b, 1, c).astype(q.dtype),
+  )(*scalars, *sinks, *masks, q_bd, k.reshape(b, 1, c).astype(q.dtype),
     v.reshape(b, 1, cv).astype(q.dtype), cached_k, cached_v)
   o = o[:, :h]
   if w == d:

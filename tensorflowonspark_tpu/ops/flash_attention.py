@@ -58,7 +58,7 @@ LANES = 8
 
 
 def _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base=0, k_base=0,
-                   window=None):
+                   window=None, keep=None):
   """Scaled scores for one (q-block, k-block) pair with causal masking.
 
   ``q_base``/``k_base`` are absolute position offsets (traced scalars are
@@ -68,9 +68,13 @@ def _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base=0, k_base=0,
   most recent positions including itself) additionally masks
   ``k_pos <= q_pos - window``; the loop-bound helpers below skip blocks
   the mask would zero entirely, so FLOPs scale with the window, not the
-  sequence.
+  sequence. ``keep`` (``[blk_q, blk_k]``, nonzero = attend; the forward's
+  optional operand, a mask BY QUERY shared by the heads) is AND-ed with the
+  positional mask.
   """
   s = q @ k.astype(jnp.float32).T
+  if keep is not None:
+    s = jnp.where(keep.astype(jnp.int32) != 0, s, NEG_INF)
   if causal:
     q_pos = q_base + qi * blk_q + lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 0)
@@ -127,9 +131,13 @@ def _pair_p_ds(s, lse, delta, do, v):
 # --- kernels ---------------------------------------------------------------
 
 
-def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, *rest,
                      blk_q: int, blk_k: int, kv_len: int, causal: bool,
                      scale: float, window=None):
+  # an optional KEEP operand ([1, blk_q, kv_len] int8, this q-block's rows of
+  # a mask by query) sits between the inputs and the two outputs
+  keep_ref = rest[0] if len(rest) == 3 else None
+  o_ref, lse_ref = rest[-2:]
   qi = pl.program_id(1)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
@@ -142,8 +150,10 @@ def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # value has no Mosaic lowering
     k = k_ref[0, pl.ds(ki * blk_k, blk_k), :]
     v = v_ref[0, pl.ds(ki * blk_k, blk_k), :]
+    keep = None if keep_ref is None else keep_ref[
+        0, :, pl.ds(pl.multiple_of(ki * blk_k, blk_k), blk_k)]
     s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
+                       window, keep)
     m_blk = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m, m_blk)
     m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
@@ -494,7 +504,10 @@ def _check_window(window, causal):
 @functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k",
                                              "interpret", "window", "scale"))
 def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
-              window=None, scale=None):
+              window=None, scale=None, keep=None):
+  """``keep`` (``[b, s_q, s_kv]`` int8, nonzero = attend; None = none): a
+  mask BY QUERY beside the positional one, shared by the heads through its
+  index map (head ``i``'s block is batch row ``i // h``'s); FORWARD only."""
   b, s_q, h, d = q.shape
   s_kv, dv = k.shape[1], v.shape[3]     # values may be another width (forward)
   hk, g = _group(q, k)
@@ -516,7 +529,8 @@ def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
               pl.BlockSpec((1, blk_q, d), lambda i, j, *_: (i, j, 0)),
               pl.BlockSpec((1, s_kv, d), _kv_row_map(h, hk, g)),
               pl.BlockSpec((1, s_kv, dv), _kv_row_map(h, hk, g)),
-          ],
+          ] + ([] if keep is None else [
+              pl.BlockSpec((1, blk_q, s_kv), lambda i, j, *_: (i // h, j, 0))]),
           out_specs=[
               pl.BlockSpec((1, blk_q, dv), lambda i, j, *_: (i, j, 0)),
               pl.BlockSpec((1, blk_q, LANES), lambda i, j, *_: (i, j, 0)),
@@ -527,7 +541,7 @@ def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
           jax.ShapeDtypeStruct((b * h, s_q, LANES), jnp.float32),
       ],
       interpret=interpret,
-  )(qb, kb, qf, kf, vf)
+  )(qb, kb, qf, kf, vf, *(() if keep is None else (keep.astype(jnp.int8),)))
 
   return _unfold(out, b, h), lse[:, :, 0].reshape(b, h, s_q)
 
@@ -757,7 +771,7 @@ def _bwd_impl(q, k, v, out, lse, g, g_lse, q_base, kv_base, causal, blk_q,
 def flash_attention(q, k, v, causal: bool = True, blk_q: int = 256,
                     blk_k: int = 512, interpret: bool = False,
                     bwd: str = None, blk_bwd_q: int = None,
-                    blk_bwd_k: int = None, window: int = None):
+                    blk_bwd_k: int = None, window: int = None, keep=None):
   """Fused (self-)attention with fused backward. q: [batch, seq, heads,
   head_dim]; k/v: same, or with heads/g KV heads (grouped-query
   attention — consumed unexpanded, see module docstring); seq must
@@ -768,9 +782,45 @@ def flash_attention(q, k, v, causal: bool = True, blk_q: int = 256,
   (requires causal) restricts each query to its last ``window``
   positions (sliding-window attention); the kernels' block loops bound
   to the window, so attention FLOPs become O(seq·window) instead of
-  O(seq²)."""
+  O(seq²). ``keep`` (``[batch, seq, seq]``, nonzero = attend) is a mask BY
+  QUERY beside the causal one, shared by the heads: the FORWARD takes it, a
+  gradient through it is refused by name (:func:`_keep_refusal`)."""
+  if keep is not None:
+    return _flash_keep(q, k, v, keep, 0, 0, causal, blk_q, blk_k, interpret,
+                       _check_window(window, causal))[0]
   return _flash_vjp(q, k, v, causal, blk_q, blk_k, interpret,
                     _resolve_bwd(bwd), blk_bwd_q, blk_bwd_k, window)
+
+
+def _keep_refusal():
+  return ValueError(
+      "the flash backward takes no keep operand: attention under a mask by "
+      "query (a learned selection of the cached tokens, "
+      "TransformerConfig.sparse_topk) has the FORWARD kernel only, and a "
+      "gradient through the selection is not built")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash_keep(q, k, v, keep, q_base, kv_base, causal, blk_q, blk_k,
+                interpret, window):
+  """The forward (a whole attention, or a block partial at traced bases)
+  under a keep operand: ``(out, lse)``. Its VJP raises."""
+  return _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
+                   window, None, keep)
+
+
+def _flash_keep_fwd(q, k, v, keep, q_base, kv_base, causal, blk_q, blk_k,
+                    interpret, window):
+  return _flash_keep(q, k, v, keep, q_base, kv_base, causal, blk_q, blk_k,
+                     interpret, window), None
+
+
+def _flash_keep_bwd(causal, blk_q, blk_k, interpret, window, residuals,
+                    cotangents):
+  raise _keep_refusal()
+
+
+_flash_keep.defvjp(_flash_keep_fwd, _flash_keep_bwd)
 
 
 def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
@@ -838,7 +888,8 @@ def flash_attention_block(q, k, v, q_base, kv_base, causal: bool = True,
                           blk_q: int = 256, blk_k: int = 512,
                           interpret: bool = False, bwd: str = None,
                           blk_bwd_q: int = None, blk_bwd_k: int = None,
-                          window: int = None, scale: float = None):
+                          window: int = None, scale: float = None,
+                          keep=None):
   """Partial attention of local queries against ONE KV block.
 
   q: [B, Sq, H, D] at absolute positions ``q_base + arange(Sq)``;
@@ -852,8 +903,15 @@ def flash_attention_block(q, k, v, q_base, kv_base, causal: bool = True,
   computed from the traced bases), so out-of-window ring steps cost only
   the kernel launch and the merge. ``scale`` is the softmax scale where it
   is not ``head_dim^-0.5`` (a latent layer under YaRN): FORWARD only, the
-  backward kernels keep the default scale and are not reached.
+  backward kernels keep the default scale and are not reached. ``keep``
+  (``[B, Sq, Sk]``, nonzero = attend: this block's columns of a mask by
+  query, shared by the heads) likewise: forward only, its VJP raises.
   """
+  if keep is not None:
+    if scale is not None:
+      raise ValueError("a keep operand beside a softmax scale is not built")
+    return _flash_keep(q, k, v, keep, q_base, kv_base, causal, blk_q, blk_k,
+                       interpret, _check_window(window, causal))
   if scale is not None:
     return _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k,
                      interpret, window, scale)

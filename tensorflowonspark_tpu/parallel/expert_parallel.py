@@ -261,6 +261,21 @@ def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
   return out + (kept,) if groups else out
 
 
+def route_softmax_topk(x, router, top_k: int, scale: float = 1.0):
+  """Softmax top-k routing over the router's WHOLE width, in float32 under
+  ``Precision.HIGHEST`` (as :func:`route_sigmoid_topk`: a rounded score moves
+  a near-tie at the k-th place): ``p = softmax(x W)``, the ``top_k`` largest,
+  weights ``p_e / sum of the chosen p`` times ``scale``. No bias, no groups.
+  ``x [T, D]``, ``router [D, E]``; returns ``(experts [T, k] int32, weights
+  [T, k] f32)``."""
+  p = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST), axis=-1)
+  picked, experts = lax.top_k(p, top_k)
+  return (experts.astype(jnp.int32),
+          picked / jnp.sum(picked, axis=-1, keepdims=True) * scale)
+
+
 def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
                      split=None, mesh=None, tally=None):
   """What the experts held here add to the layer: ``sum over a token's
@@ -268,7 +283,7 @@ def held_experts_ffn(x, experts, weights, gate, up, down, first: int = 0,
   x) * up_e x)``; assignments to experts held elsewhere add nothing.
 
   ``x [T, D]``; ``experts``/``weights [T, k]`` from
-  :func:`route_sigmoid_topk`; ``gate``/``up [held, D, F]``, ``down
+  :func:`route_sigmoid_topk` or :func:`route_softmax_topk`; ``gate``/``up [held, D, F]``, ``down
   [held, F, D]`` in the compute dtype. The ``T * k`` assignments are sorted
   by expert (those held elsewhere last, outside every group) and multiplied
   as ONE grouped product a matrix (rows of a group meet that group's expert

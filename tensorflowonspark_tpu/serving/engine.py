@@ -176,7 +176,10 @@ _STEP_COUNTERS = {"held": "moe_assignments_held",
                   "group": "moe_group_hits",
                   "context": "live_context_tokens",
                   "exit_pass": "loop_exit_pass_sum",
-                  "window_context": "window_context_tokens"}
+                  "window_context": "window_context_tokens",
+                  "sparse_kept": "sparse_rows_kept",
+                  "sparse_candidates": "sparse_rows_candidate",
+                  "sparse_limited": "sparse_queries_limited"}
 #: restart backoff never exceeds this many seconds
 _BACKOFF_CAP = 2.0
 #: retry-after hint while the tokens/s EMA is still cold (no decode has
@@ -295,6 +298,10 @@ class ServingEngine(object):
       raise ValueError(slots_lib.tfm.ring_refusal(
           "the shared-prefix cache (prefix_pages=%d)" % self.prefix_pages,
           "prefix"))
+    if self.prefix_pages > 0 and cfg.sparse_topk:
+      raise ValueError(slots_lib.tfm.sparse_refusal(
+          cfg, "the shared-prefix cache (prefix_pages=%d)" % self.prefix_pages,
+          "prefix"))
     if self.prefix_pages > 0 and cfg.non_kv_layers:
       raise ValueError(
           "the shared-prefix cache (prefix_pages=%d) reuses a prompt "
@@ -404,6 +411,19 @@ class ServingEngine(object):
                   # each live lane's step, min(cursor, window), summed
                   # (live_context_tokens is a full layer's)
                   "window_context_tokens": 0,
+                  # a model whose attention SELECTS (sparse_topk; likewise
+                  # counted): entries the live lanes' decode queries kept
+                  # and chose among (a layer-step each), live decode queries
+                  # with more candidates than the selection keeps (over
+                  # live_slot_steps: the share it bites), the same two for
+                  # real prompt tokens (counted on the host, a token and not
+                  # a layer), decode reads under a keep mask and the rows of
+                  # the index-key leaf those read (whole: slots x max_seq_len
+                  # a read)
+                  "sparse_rows_kept": 0, "sparse_rows_candidate": 0,
+                  "sparse_queries_limited": 0,
+                  "sparse_prefill_queries": 0, "sparse_prefill_limited": 0,
+                  "decode_attn_reads_sparse": 0, "index_rows_read": 0,
                   # calls of a slab-returning program, and those after
                   # which the slab that went in is deleted: its donation
                   # was USED, the program ran in place (_on_slab)
@@ -1732,6 +1752,10 @@ class ServingEngine(object):
       self.stats["decode_attn_reads"] += reads
       self.stats["decode_attn_reads_ragged"] += ragged
       self.stats["decode_attn_reads_ring"] += ring
+      sparse = self.decoder.sparse_reads[self.horizon]
+      self.stats["decode_attn_reads_sparse"] += sparse
+      self.stats["index_rows_read"] += sparse * self.decoder.num_slots \
+          * self.cfg.max_seq_len
       products, kernel = self.decoder.expert_products["step", self.horizon]
       self.stats["expert_products"] += products
       self.stats["expert_products_kernel"] += kernel

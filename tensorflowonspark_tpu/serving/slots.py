@@ -320,7 +320,17 @@ class SlotDecoder(object):
     #: ``counters``; its window layers hold rings): step_many then returns a
     #: fifth member
     self.counted = ("experts" in cfg.ffn_types or cfg.loop_passes > 1
-                    or bool(self.ring_windows))
+                    or bool(self.ring_windows) or cfg.sparse_topk > 0)
+    # what a layer that SELECTS its cached tokens cannot take is refused by
+    # name: the paged pool and an int8 cache by TransformerConfig itself
+    if cfg.sparse_topk and int(spec_depth) > 0:
+      raise ValueError(tfm.sparse_refusal(
+          cfg, "speculative decoding (spec_depth=%d)" % int(spec_depth),
+          "draft"))
+    if cfg.sparse_topk and mesh is not None and mesh.size > 1:
+      raise ValueError(tfm.sparse_refusal(
+          cfg, "a serving slab over a mesh of %d devices" % mesh.size,
+          "mesh"))
     # what a ring cannot take is refused by name: the paged pool and an int8
     # cache by TransformerConfig itself (the slab's config, below)
     if self.ring_windows and int(spec_depth) > 0:
@@ -413,6 +423,10 @@ class SlotDecoder(object):
     #: those of them over a RING leaf: a reader of a device trace splits the
     #: kernel's calls by the leaf they read with it)
     self.attn_reads = {}
+    #: horizon -> those of the same reads that lay under a selection's keep
+    #: mask (``TransformerConfig.sparse_topk``): each also read its layer's
+    #: index-key leaf WHOLE, ``num_slots x max_seq_len`` rows
+    self.sparse_reads = {}
     #: program -> (grouped products of held experts one dispatch of it makes:
     #: three a layer application, those of them by ops.expert_product's
     #: kernel, which reads only the rows that have a group); a program is
@@ -518,7 +532,9 @@ class SlotDecoder(object):
     ``t_prefill_s`` / d ``prefill_chunks``). ``acc`` (the engine's
     ``stats``) counts the dispatches in ``prefill_chunks``, the tokens they
     computed in ``prefill_tokens`` and the padding among them in
-    ``prefill_padded_tokens``. ``queue`` (the calling thread's
+    ``prefill_padded_tokens`` (and, for a model that selects,
+    ``sparse_prefill_queries`` / ``sparse_prefill_limited``). ``queue`` (the
+    calling thread's
     ``obs.spans.DeviceQueue``) is told of each chunk's dispatch.
     """
     plen = len(prompt)
@@ -551,6 +567,14 @@ class SlotDecoder(object):
       acc["prefill_chunks"] += len(plan)
       acc["prefill_tokens"] += sum(seg for seg, _ in plan)
       acc["prefill_padded_tokens"] += sum(seg - n for seg, n in plan)
+      if self.cfg.sparse_topk:
+        # real prompt tokens as queries of a layer that selects, and those
+        # of them at a position with more candidates than it keeps
+        acc["sparse_prefill_queries"] = acc.get(
+            "sparse_prefill_queries", 0) + plen - off
+        acc["sparse_prefill_limited"] = acc.get(
+            "sparse_prefill_limited", 0) + max(
+                0, plen - max(off, self.cfg.sparse_topk))
     nxt = seq = None
     for seg, n in plan:
       with obs_spans.region("serve.prefill.chunk", trace=trace,
@@ -747,7 +771,12 @@ class SlotDecoder(object):
     ``exit_pass``, the pass at which its gates let each live lane's token
     exit; of a model whose window layers hold rings ``window_context``, the
     rows ONE window layer has to read for the step, ``min(cursor, window)``
-    (the mean over the window layers, should their windows differ).
+    (the mean over the window layers, should their windows differ); of a
+    model whose attention SELECTS (``TransformerConfig.sparse_topk``)
+    ``sparse_kept`` entries the live lanes' queries kept and
+    ``sparse_candidates`` they chose among (both summed over the layers) and
+    ``sparse_limited`` live queries with more candidates than the selection
+    keeps (a token, not a layer).
 
     An inactive lane (free, or finished inside this horizon) runs its pad
     token at cursor 0 of its own slot: the row it writes lands where the
@@ -787,6 +816,15 @@ class SlotDecoder(object):
       if self.cfg.loop_passes > 1:
         (exits,) = _sown(sown, "exit_pass")            # [slots, 1]
         counts["exit_pass"] = jnp.sum(jnp.where(active, exits[:, 0], 0))
+      if self.cfg.sparse_topk:
+        kept = _sown(sown, "sparse_kept")              # [slots] a layer
+        counts.update(
+            sparse_kept=sum(jnp.sum(jnp.where(active, x, 0)) for x in kept),
+            # a query's candidates: the cache's rows and its own token
+            sparse_candidates=len(kept) * jnp.sum(
+                jnp.where(active, cursor + 1, 0)),
+            sparse_limited=jnp.sum(jnp.logical_and(
+                active, cursor + 1 > self.cfg.sparse_topk), dtype=jnp.int32))
 
     def freeze(path, new, old):
       # inactive slots must not advance: undo their cursor bump so the
@@ -868,6 +906,7 @@ class SlotDecoder(object):
                                     _h * writes["dma"])
           self.attn_reads[_h] = tuple(
               _h * reads[k] for k in ("reads", "ragged", "ring"))
+          self.sparse_reads[_h] = _h * reads.get("sparse", 0)
           self.expert_products["step", _h] = (_h * products["products"],
                                               _h * products["kernel"])
           remaining = jnp.where(active, remaining - 1, remaining)

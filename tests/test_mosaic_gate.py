@@ -521,14 +521,76 @@ def test_deepseek_prefill_chunk_builds_no_score_tensor(topo, monkeypatch,
   assert res["tpu_custom_calls"] >= 10, res["tpu_custom_calls"]
 
 
+def test_keye_step_many_selects_and_reads_three_leaves_in_place(
+    topo, monkeypatch):
+  """The cell keye-vl2-serve-backlog's decode step at its real size (6 layers
+  at published widths, 32 / 4 heads of 128, an indexer of 16 heads of 64, 16
+  held softmax experts a layer, 16 slots x 32768, horizon 4): ONE slab of
+  three leaves a layer (K and V of 16 x 32768 x 512, the index key of 16 x
+  32768 x 128: 7.25 GB) is aliased whole; with 1.32 GB of weights beside it
+  the program holds 8.7 GB; no leaf is copied at the program's edge or comes
+  back from fast memory; all 6 attention reads a step took the kernel that
+  stops at the cursor UNDER THE KEEP ROWS (a read fallen to the dense masked
+  contraction over all 32768 rows fails HERE and not in a chip run) and all
+  18 leaf writes the DMA kernel; the while loops are the horizon's scan and
+  each layer's two searches of 16 passes of the exact selection."""
+  import tools.mosaic_gate as gate
+  from tools.mosaic_gate import V5E_HBM_BYTES
+  made, build = [], gate.keye_decoder
+  monkeypatch.setattr(gate, "keye_decoder",
+                      lambda *a: made.append(build(*a)) or made[-1])
+  res = _gate_one("serving_decode_keye", monkeypatch)
+  mb = res["memory_bytes"]
+  slab_bytes = 16 * 6 * 2304 * 32768
+  assert slab_bytes <= mb["alias"] < 1.001 * slab_bytes, mb
+  assert mb["temp"] < 0.3e9, mb
+  assert res["device_bytes"] < 9.0e9 < 13.7e9 < V5E_HBM_BYTES, res
+  for leaf in ("bf16[16,32768,512]", "bf16[16,32768,128]"):
+    assert leaf not in res["entry_copies"], res["entry_copies"]
+    assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+  # three writes, one read and three expert products a layer
+  assert res["tpu_custom_calls"] == 6 * 7, res["tpu_custom_calls"]
+  assert res["while_loops"] == 1 + 2 * 6, res
+  (dec, _, _, _), = made
+  assert dec.attn_reads[4] == (6 * 4, 6 * 4, 0)
+  assert dec.sparse_reads[4] == 6 * 4
+  assert dec.cursor_writes[4] == (18 * 4, 18 * 4)
+  assert dec.expert_products["step", 4] == (18 * 4, 18 * 4)
+
+
+@pytest.mark.parametrize("bucket,temp_max", [
+    (4096, 2.8e9),
+    # the small chunk runs the same code in less memory: outside tier-1
+    pytest.param(256, 0.2e9, marks=pytest.mark.slow)])
+def test_keye_prefill_chunk_builds_no_score_tensor_of_heads(
+    topo, monkeypatch, bucket, temp_max):
+  """The same cell's largest prefill chunk (4096 tokens) and its 256-token
+  one into a positional row of 32768 (0.453 GB: three leaves a layer; one
+  program for a cursor at 0 and above it, selecting or not): the index scores
+  are ``[chunk, 32768]`` float32, ONE head's worth after the 16 are summed a
+  block of 2048 keys at a time (0.54 GB at 4096 tokens; the 16 heads' would be
+  8.6 GB), the selection's keep operand int8, and the attention goes through
+  the flash forward and the block call under it, so no ``[chunk, 32, 32768]``
+  float32 score tensor (17 GB) exists: temporaries stay under 2.8 GB (2.53 /
+  0.05 when written), and the program fits beside the resident slab of 16
+  slots (7.25 GB) under the 13.7 GB ISSUE 44's step zero allows."""
+  res = _gate_one("keye_prefill_%d" % bucket, monkeypatch)
+  mb = res["memory_bytes"]
+  assert mb["temp"] < temp_max, mb
+  assert res["device_bytes"] + 16 * 6 * 2304 * 32768 < 13.7e9, res
+  # the flash kernel a layer (first chunk) and again a layer (later chunks),
+  # the expert products, in the branch that selects at least
+  assert res["tpu_custom_calls"] >= 6 * 5, res["tpu_custom_calls"]
+
+
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
 @pytest.mark.parametrize("config", ["trinity", "mimo", "deepseek",
-                                    "kimi_linear"])
+                                    "kimi_linear", "keye"])
 def test_expert_product_compiles_at_the_cells_shapes(topo, monkeypatch, config,
                                                      kind):
   """``ops.expert_product`` as a layer calls it (the rows through the gate
   stack, the result's shape through the down stack: K and N exchanged) at the
-  four expert cells' widths, for a decode step's rows (96 to 1152, one row
+  five expert cells' widths, for a decode step's rows (96 to 1152, one row
   tile where there are fewer than 128) and for the largest chunk's (8192 to
   16384): the grid whose length is a prefetched scalar, the index maps that
   read the pairs from SMEM and the ``[K, tn]`` blocks (up to 8 MB, double

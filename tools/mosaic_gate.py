@@ -802,6 +802,7 @@ EXPERT_PRODUCTS = {
     "trinity": (32, 3072, 3072, 24 * 4, 2048 * 4),
     "mimo": (16, 4096, 2048, 48 * 8, 2048 * 8),
     "deepseek": (16, 7168, 2048, 24 * 8, 2048 * 8),
+    "keye": (16, 2048, 768, 16 * 8, 4096 * 8),
     "kimi_linear": (16, 2304, 1024, 3 * 48 * 8, 3 * 512 * 8),
 }
 
@@ -1177,6 +1178,64 @@ def deepseek_prefill(bucket: int):
   return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
 
 
+#: the benchmark cell keye-vl2-serve-backlog: slots x max_seq
+KEYE_SLOTS, KEYE_MAX_SEQ = 16, 32768
+
+
+def keye_cfg(max_seq: int = KEYE_MAX_SEQ):
+  """Keye-VL-2.0-30B-A3B's language model as ``benchmarks/configs/
+  keye-vl-2.0-30b-a3b.json`` cuts it to one chip's share (published widths,
+  published layers 0-5; 16 of 128 experts held, 1/8 of the vocabulary),
+  spelled out so that the gate needs nothing of ``benchmarks/``;
+  ``benchmarks/tests/test_keye_vl2.py`` keeps the two equal."""
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu.models import transformer as tfm
+  return tfm.TransformerConfig(
+      vocab_size=19072, num_layers=6, num_heads=32, num_kv_heads=4,
+      attn_head_dim=128, d_model=2048, d_ff=768, max_seq_len=max_seq,
+      remat=False, dtype=jnp.bfloat16, ffn_types=("experts",) * 6,
+      qk_norm=True, rope_theta=1e7, norm="rms", norm_eps=1e-6,
+      mlp_act="swiglu", tie_embeddings=False,
+      experts_total=128, experts_held=16, experts_first=0, experts_top_k=8,
+      experts_d_ff=768, experts_shared=0, experts_score="softmax",
+      sparse_topk=2048, index_heads=16, index_head_dim=64)
+
+
+def keye_decoder(slots: int = KEYE_SLOTS, max_seq: int = KEYE_MAX_SEQ):
+  """:func:`_sparse_decoder` at the Keye cell's sizes."""
+  return _sparse_decoder(keye_cfg(max_seq), slots)
+
+
+def t_serving_decode_keye():
+  """The cell keye-vl2-serve-backlog's decode step at its real size: six
+  layers of THREE leaves (K and V of 16 x 32768 x 512, the index key of 16 x
+  32768 x 128: 7.25 GB), a step's 6 index-score reads of the index leaf, 6
+  exact selections of 2048 among up to 32768 a lane, 6 attention reads by the
+  kernel that stops at the cursor under the keep rows, 18 leaf writes, top 8
+  of 16 held softmax experts a layer, horizon 4."""
+  dec, params, _, slabs = keye_decoder()
+  return _step_many_target(dec, params, slabs)
+
+
+#: the prefill shapes step zero compiles: the ladder's largest and 256
+KEYE_BUCKETS = (4096, 256)
+
+
+def keye_prefill(bucket: int):
+  """One of the same cell's prefill programs: a padded chunk of ``bucket``
+  tokens into a positional row of 32768 (0.453 GB: three leaves a layer). A
+  chunk that ends above 2048 positions scores its queries against the row's
+  index keys in blocks of 2048 (``[bucket, 32768]`` float32 index scores, one
+  head's worth; no ``[bucket, heads, 32768]`` tensor), selects a query, and
+  attends under the keep operand: the first chunk itself through the flash
+  forward, a later one its row in blocks of 2048 through the block call; one
+  that ends at or below 2048 runs the branch without an indexer."""
+  dec, params, row, _ = keye_decoder()
+  assert set(KEYE_BUCKETS) <= set(dec.buckets) \
+      and dec.buckets[0] == KEYE_BUCKETS[0], dec.buckets
+  return dec._prefill_fn, (params, row, _i32(1, bucket), _i32())
+
+
 def t_smoke_step_many():
   return _smoke_step_many(paged=False)
 
@@ -1238,6 +1297,7 @@ TARGETS = {
     "trinity_insert": t_trinity_insert,
     "serving_decode_mimo": t_serving_decode_mimo,
     "serving_decode_deepseek": t_serving_decode_deepseek,
+    "serving_decode_keye": t_serving_decode_keye,
 }
 TARGETS.update({"smoke_prefill_%d" % b: (lambda b=b: smoke_prefill(b))
                 for b in SMOKE_BUCKETS})
@@ -1247,6 +1307,8 @@ TARGETS.update({"mimo_prefill_%d" % b: (lambda b=b: mimo_prefill(b))
                 for b in MIMO_BUCKETS})
 TARGETS.update({"deepseek_prefill_%d" % b: (lambda b=b: deepseek_prefill(b))
                 for b in DEEPSEEK_BUCKETS})
+TARGETS.update({"keye_prefill_%d" % b: (lambda b=b: keye_prefill(b))
+                for b in KEYE_BUCKETS})
 TARGETS.update({"kimi_linear_prefill_%d" % b:
                 (lambda b=b: kimi_linear_prefill(b))
                 for b in KIMI_LINEAR_BUCKETS})
