@@ -868,6 +868,7 @@ _MXU_COLS = 128
 _cursor_writes = threading.local()
 _attn_reads = threading.local()
 _expert_products = threading.local()
+_index_selects = threading.local()
 
 
 @contextlib.contextmanager
@@ -912,6 +913,15 @@ def expert_product_tally():
   rows that have a group, and not ``lax.ragged_dot``. ``SlotDecoder`` opens
   one round a ``step_many`` program's trace and one a prefill shape's."""
   return _tally(_expert_products, "products", "kernel")
+
+
+def index_select_tally():
+  """The same for the indexer's exact selections (:func:`select_topk`, one a
+  layer application of a model whose attention selects): yields
+  ``{"selections": n, "kernel": m}``, ``m`` of the ``n`` searches having run
+  in ``ops.select_topk``'s kernel and not as XLA operations. ``SlotDecoder``
+  opens one round a ``step_many`` program's trace and one a prefill shape's."""
+  return _tally(_index_selects, "selections", "kernel")
 
 
 def _cache_write(buf, val, idx, positions, mesh):
@@ -1115,25 +1125,42 @@ def _flash_attention_sink(q, k, v, window, sink, interpret):
 _INDEX_SCORE_BYTES = 1 << 28
 
 
-def select_topk(scores, valid, k: int):
+def select_topk(scores, last, k: int, mesh=None):
   """The EXACT top-``k`` of each row as a mask: ``scores [..., n]`` float32,
-  ``valid [..., n]`` bool (a row's candidates); returns ``[..., n]`` bool, true
-  at the ``min(k, candidates)`` valid positions with the largest score, the
-  EARLIER position first among equal scores (``lax.top_k``'s order). A row
-  with at most ``k`` candidates keeps them all.
+  ``last [...]`` integers, each row's last candidate (its candidates are the
+  columns ``0..last``); returns ``[..., n]`` bool, true at the ``min(k,
+  candidates)`` candidates with the largest score, the EARLIER position first
+  among equal scores (``lax.top_k``'s order). A row with at most ``k``
+  candidates keeps them all. A caller that has nothing else may hand the
+  candidates as ``[..., n]`` bool in place of ``last``.
 
   No sort and no gather: a float's bits, read as an unsigned integer with the
   sign folded, order as the floats do, and the ``k``-th largest of a row is
   the largest threshold ``T`` with ``count(key >= T) >= k``: found bit by bit
-  from the top, 32 passes of compare-and-count, each one pass over the row in
-  whatever memory it lies. The passes read HALF a key each: the upper 16 bits
-  of ``T`` come from the keys' upper halves alone, the lower 16 from the
-  lower halves of the keys whose upper half equals ``T``'s (the others count
-  as 0), so a pass moves two bytes an entry. Keys above ``T`` stay; of the
-  keys EQUAL to it the first ``k - count(key > T)`` by position (a running
-  count, taken only where some row has more equals than it needs: rare with
-  real scores). The mask comes out where a kernel's operand wants it, with no
-  scatter of indices."""
+  from the top, 32 passes of compare-and-count. One search, two homes for its
+  passes, chosen from what the code can observe: float32 scores whose ``n`` is
+  whole lanes, with each row's ``last``, on one device (``mesh``) go to
+  ``ops.select_topk``'s kernel, which holds a tile of rows in VMEM for all 32
+  (``ops.select_topk_supports``); everything else keeps them as XLA
+  operations, below, each one pass over the row in whatever memory it lies.
+  Those read HALF a key each: the upper 16 bits of ``T`` come from the keys'
+  upper halves alone, the lower 16 from the lower halves of the keys whose
+  upper half equals ``T``'s (the others count as 0), so a pass moves two bytes
+  an entry. Keys above ``T`` stay; of the keys EQUAL to it the first ``k -
+  count(key > T)`` by position (a running count, taken only where some row has
+  more equals than it needs: rare with real scores). The mask comes out where
+  a kernel's operand wants it, with no scatter of indices."""
+  by_mask = last.dtype == jnp.bool_
+  kernel = not by_mask and ops.select_topk_supports(
+      scores.shape, scores.dtype, mesh)
+  tally = getattr(_index_selects, "open", None)
+  if tally is not None:
+    tally["selections"] += 1
+    tally["kernel"] += kernel
+  if kernel:
+    return ops.select_topk(scores, last, k, interpret=ops.pallas_interpret())
+  valid = last if by_mask \
+      else jnp.arange(scores.shape[-1]) <= last[..., None]
   scores = scores.astype(jnp.float32)
   bits = lax.bitcast_convert_type(       # -0.0 is the score 0.0
       jnp.where(scores == 0, 0.0, scores), jnp.uint32)
@@ -1466,11 +1493,10 @@ class Attention(nn.Module):
       # the positions up to its own
       with jax.named_scope("indexer"):
         iq, ik, iw = self._index_rotate(index, positions, theta)
-        at = jnp.arange(q.shape[1])
         keep = select_topk(
             index_scores(iq, iw, ik.astype(cfg.dtype)),
-            jnp.broadcast_to(at[None, :] <= at[:, None],
-                             (q.shape[0],) + 2 * at.shape), cfg.sparse_topk)
+            jnp.broadcast_to(jnp.arange(q.shape[1]), q.shape[:2]),
+            cfg.sparse_topk)
       if _flash_eligible(cfg, q.shape[1]):
         out = ops.flash_attention(q, k, v, causal=True, interpret=interp,
                                   keep=keep)
@@ -1721,7 +1747,7 @@ class Attention(nn.Module):
         scores = jnp.where(col == idx[:, None],
                            index_scores(iq, iw, ik)[:, 0],      # [b, 1]
                            index_scores(iq, iw, ik_was)[:, 0])  # [b, max]
-        keep = select_topk(scores, col <= idx[:, None], cfg.sparse_topk)
+        keep = select_topk(scores, idx, cfg.sparse_topk)
         own = jnp.take_along_axis(
             keep, jnp.minimum(idx, rows - 1)[:, None], axis=1)
       self.sow("counters", "sparse_kept",
@@ -1733,7 +1759,7 @@ class Attention(nn.Module):
       with jax.named_scope("indexer"):
         keep = select_topk(
             index_scores(iq, iw, cached_ik.value, live=idx + seg),
-            col <= positions[:, :, None], cfg.sparse_topk)  # [b, seg, max]
+            positions, cfg.sparse_topk)                     # [b, seg, max]
       return keep, lax.dynamic_slice_in_dim(keep, idx, seg, axis=2)
 
     if vec:

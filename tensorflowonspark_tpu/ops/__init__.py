@@ -67,3 +67,6 @@ from tensorflowonspark_tpu.ops.decode_attention import (  # noqa: F401
 from tensorflowonspark_tpu.ops.expert_product import (  # noqa: F401
     expert_product, supports as expert_product_supports,
 )
+from tensorflowonspark_tpu.ops.select_topk import (  # noqa: F401
+    select_topk, supports as select_topk_supports,
+)

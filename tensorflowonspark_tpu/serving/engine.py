@@ -447,6 +447,11 @@ class ServingEngine(object):
                   # and those of them by ops.expert_product's kernel and not
                   # lax.ragged_dot (SlotDecoder.expert_products)
                   "expert_products": 0, "expert_products_kernel": 0,
+                  # exact selections of an indexer the same dispatches made
+                  # (one a layer application under sparse_topk), and those
+                  # of them searched in ops.select_topk's kernel and not by
+                  # XLA's passes (SlotDecoder.index_selections)
+                  "index_selections": 0, "index_selections_kernel": 0,
                   # the loop thread's SELF seconds by phase, written by
                   # the regions below (obs.spans.region): the keys
                   # partition the loop thread's wall time
@@ -1759,6 +1764,10 @@ class ServingEngine(object):
       products, kernel = self.decoder.expert_products["step", self.horizon]
       self.stats["expert_products"] += products
       self.stats["expert_products_kernel"] += kernel
+      selections, kernel = self.decoder.index_selections[
+          "step", self.horizon]
+      self.stats["index_selections"] += selections
+      self.stats["index_selections_kernel"] += kernel
     return _Step(out, self._slab_seq, reqs)
 
   def _read_step(self, step: _Step):

@@ -432,6 +432,11 @@ class SlotDecoder(object):
     #: kernel, which reads only the rows that have a group); a program is
     #: ("step", horizon) or ("prefill", the chunk's tokens)
     self.expert_products = {}
+    #: program -> (exact selections of an indexer one dispatch of it makes:
+    #: one a layer application of a model with ``sparse_topk``, those of them
+    #: whose threshold search ran in ops.select_topk's kernel); programs as
+    #: in ``expert_products``
+    self.index_selections = {}
     self._step_spec_jits = {}    # rounds -> jitted fused spec-round scan
     self._zero_row = None        # memoized fresh [1, ...] cache (immutable)
 
@@ -462,7 +467,8 @@ class SlotDecoder(object):
     obs_device.note_trace("serve.prefill")
     # padded: only the last REAL row goes through the final norm and the
     # head (a [seg, vocab] logits block is never built to pick one row)
-    with tfm.expert_product_tally() as products:
+    with tfm.expert_product_tally() as products, \
+        tfm.index_select_tally() as selections:
       logits, mutated = self.model.apply(
           {"params": params, "cache": cache}, tokens, decode=True,
           mutable=["cache"],
@@ -471,6 +477,8 @@ class SlotDecoder(object):
     # on the host, while tracing: one program a chunk shape
     self.expert_products["prefill", tokens.shape[1]] = (
         products["products"], products["kernel"])
+    self.index_selections["prefill", tokens.shape[1]] = (
+        selections["selections"], selections["kernel"])
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     if n_valid is None:
       return mutated["cache"], nxt
@@ -593,6 +601,12 @@ class SlotDecoder(object):
           acc["expert_products"] = acc.get("expert_products", 0) + products
           acc["expert_products_kernel"] = acc.get(
               "expert_products_kernel", 0) + kernel
+        selections, kernel = self.index_selections["prefill", seg]
+        if acc is not None and selections:
+          acc["index_selections"] = acc.get(
+              "index_selections", 0) + selections
+          acc["index_selections_kernel"] = acc.get(
+              "index_selections_kernel", 0) + kernel
         seq = None if queue is None else queue.dispatched()
       off += n
     return cache, nxt, seq
@@ -898,7 +912,8 @@ class SlotDecoder(object):
           slabs, tok, active, remaining = carry
           with tfm.cursor_write_tally() as writes, \
               tfm.decode_attention_tally() as reads, \
-              tfm.expert_product_tally() as products:
+              tfm.expert_product_tally() as products, \
+              tfm.index_select_tally() as selections:
             slabs, nxt, counts = self._one_step(params, slabs, tok, active,
                                                 count=self.counted)
           # on the host, while tracing: the body is one step of _h
@@ -909,6 +924,8 @@ class SlotDecoder(object):
           self.sparse_reads[_h] = _h * reads.get("sparse", 0)
           self.expert_products["step", _h] = (_h * products["products"],
                                               _h * products["kernel"])
+          self.index_selections["step", _h] = (
+              _h * selections["selections"], _h * selections["kernel"])
           remaining = jnp.where(active, remaining - 1, remaining)
           done_now = remaining <= 0
           if self.eos_id is not None:

@@ -532,8 +532,10 @@ def test_keye_step_many_selects_and_reads_three_leaves_in_place(
   back from fast memory; all 6 attention reads a step took the kernel that
   stops at the cursor UNDER THE KEEP ROWS (a read fallen to the dense masked
   contraction over all 32768 rows fails HERE and not in a chip run) and all
-  18 leaf writes the DMA kernel; the while loops are the horizon's scan and
-  each layer's two searches of 16 passes of the exact selection."""
+  18 leaf writes the DMA kernel; all 6 exact selections a step ran their
+  threshold search in ``ops.select_topk``'s kernel, so the one while loop
+  left is the horizon's scan (each layer's two searches of 16 passes were two
+  more, before PR 45)."""
   import tools.mosaic_gate as gate
   from tools.mosaic_gate import V5E_HBM_BYTES
   made, build = [], gate.keye_decoder
@@ -548,18 +550,19 @@ def test_keye_step_many_selects_and_reads_three_leaves_in_place(
   for leaf in ("bf16[16,32768,512]", "bf16[16,32768,128]"):
     assert leaf not in res["entry_copies"], res["entry_copies"]
     assert leaf not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
-  # three writes, one read and three expert products a layer
-  assert res["tpu_custom_calls"] == 6 * 7, res["tpu_custom_calls"]
-  assert res["while_loops"] == 1 + 2 * 6, res
+  # three writes, one selection, one read and three expert products a layer
+  assert res["tpu_custom_calls"] == 6 * 8, res["tpu_custom_calls"]
+  assert res["while_loops"] == 1, res
   (dec, _, _, _), = made
   assert dec.attn_reads[4] == (6 * 4, 6 * 4, 0)
   assert dec.sparse_reads[4] == 6 * 4
   assert dec.cursor_writes[4] == (18 * 4, 18 * 4)
   assert dec.expert_products["step", 4] == (18 * 4, 18 * 4)
+  assert dec.index_selections["step", 4] == (6 * 4, 6 * 4)
 
 
 @pytest.mark.parametrize("bucket,temp_max", [
-    (4096, 2.8e9),
+    (4096, 1.2e9),
     # the small chunk runs the same code in less memory: outside tier-1
     pytest.param(256, 0.2e9, marks=pytest.mark.slow)])
 def test_keye_prefill_chunk_builds_no_score_tensor_of_heads(
@@ -571,16 +574,18 @@ def test_keye_prefill_chunk_builds_no_score_tensor_of_heads(
   block of 2048 keys at a time (0.54 GB at 4096 tokens; the 16 heads' would be
   8.6 GB), the selection's keep operand int8, and the attention goes through
   the flash forward and the block call under it, so no ``[chunk, 32, 32768]``
-  float32 score tensor (17 GB) exists: temporaries stay under 2.8 GB (2.53 /
-  0.05 when written), and the program fits beside the resident slab of 16
+  float32 score tensor (17 GB) exists: temporaries stay under 1.2 GB (0.98 /
+  0.06 since the selection's search runs in ``ops.select_topk``'s kernel: the
+  XLA search's keys and halves made them 2.53 GB, so a selection fallen back
+  to it fails HERE), and the program fits beside the resident slab of 16
   slots (7.25 GB) under the 13.7 GB ISSUE 44's step zero allows."""
   res = _gate_one("keye_prefill_%d" % bucket, monkeypatch)
   mb = res["memory_bytes"]
   assert mb["temp"] < temp_max, mb
   assert res["device_bytes"] + 16 * 6 * 2304 * 32768 < 13.7e9, res
   # the flash kernel a layer (first chunk) and again a layer (later chunks),
-  # the expert products, in the branch that selects at least
-  assert res["tpu_custom_calls"] >= 6 * 5, res["tpu_custom_calls"]
+  # the selection and the expert products, in the branch that selects at least
+  assert res["tpu_custom_calls"] >= 6 * 6, res["tpu_custom_calls"]
 
 
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
@@ -608,6 +613,23 @@ def test_expert_product_compiles_at_the_cells_shapes(topo, monkeypatch, config,
                 "bf16[%d,%d,%d]" % (held, f, d)):
     assert stack not in res["entry_copies"], res["entry_copies"]
     assert stack not in res["copies_back_to_hbm"], res["copies_back_to_hbm"]
+
+
+@pytest.mark.parametrize("kind", ["step", "chunk"])
+def test_select_topk_compiles_at_the_cells_shapes(topo, monkeypatch, kind):
+  """``ops.select_topk`` at the Keye cell's two shapes, 2048 of a row of 32768
+  for a decode step's 16 queries (one tile of 16 rows) and a chunk's 4096
+  (tiles of 64): the manual DMAs of a tile's live blocks, the 32 passes over
+  the keys in VMEM (29 MB a tile of 64 with its landing buffers and mask) and
+  the int8 mask lower through Mosaic; ONE kernel and no loop outside it; beside
+  the scores and the mask nothing of the scores' size is left in HBM (the XLA
+  search's keys and halves were 2.5 GB of a chunk program's temporaries)."""
+  from tools.mosaic_gate import SELECT_TOPK
+  rows = SELECT_TOPK[kind]
+  res = _gate_one("select_topk_keye_%s" % kind, monkeypatch)
+  assert res["tpu_custom_calls"] == 1 and res["while_loops"] == 0, res
+  # the int8 mask at most
+  assert res["memory_bytes"]["temp"] <= rows * 32768, res
 
 
 def test_smoke_train_loop_compiles_and_fits(topo, monkeypatch):

@@ -822,6 +822,22 @@ def expert_product_target(held: int, d: int, f: int, rows: int):
                           _on_chip0(_sh(held, f, d)), _i32(held))
 
 
+#: ``ops.select_topk`` at the Keye cell's two shapes: a decode step's 16 slots
+#: and a 4096-token chunk, over a row of 32768 positions, 2048 kept
+SELECT_TOPK = {"step": 16, "chunk": 4096}
+
+
+def select_topk_target(rows: int, n: int = 32768, k: int = 2048):
+  """``ops.select_topk`` alone: ``rows`` queries' float32 index scores over
+  ``n`` positions and each query's last candidate."""
+  import jax
+  import jax.numpy as jnp
+  from tensorflowonspark_tpu import ops
+
+  return (jax.jit(lambda scores, last: ops.select_topk(scores, last, k)),
+          (_on_chip0(_sh(rows, n, dtype=jnp.float32)), _i32(rows)))
+
+
 def t_gpt2l_prefill_512():
   """The benchmark's largest prefill program at its real size: a padded
   512-token chunk (PERF.md section 6, PR 27); only the last real row may
@@ -1318,6 +1334,9 @@ TARGETS.update({"expert_product_%s_%s" % (name, kind):
                 (lambda a=(g, d, f, rows): expert_product_target(*a))
                 for name, (g, d, f, step, chunk) in EXPERT_PRODUCTS.items()
                 for kind, rows in (("decode", step), ("chunk", chunk))})
+TARGETS.update({"select_topk_keye_%s" % kind:
+                (lambda rows=rows: select_topk_target(rows))
+                for kind, rows in SELECT_TOPK.items()})
 
 #: HBM of one v5e chip (Google Cloud "TPU v5e": 16 GB)
 V5E_HBM_BYTES = 16 * 1024 ** 3
