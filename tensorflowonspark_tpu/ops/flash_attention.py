@@ -4,12 +4,42 @@ ring-composable block partials.
 Flash-attention-style: the forward streams over K/V blocks with an online
 softmax carried in VMEM scratch, so the [Sq, Sk] score matrix never hits
 HBM — scores are produced on the MXU, normalized on the VPU, accumulated
-in float32 while inputs stay bfloat16. The forward also emits the per-row
-logsumexp; the backward (standard Δ correction, dense scores never
-materialized) defaults to ONE single-pass kernel producing dQ/dK/dV per
-k-block with dQ accumulated in a grid-resident VMEM block — 5 MXU matmuls
-per (q, k) block pair; ``bwd="split"`` selects the two-kernel plan (dQ
-over q-blocks, dK/dV over k-blocks; 7 matmuls/pair), and the fused plan
+in float32 while inputs stay bfloat16.
+
+Every MXU product takes its operands AT THE WIDTH THE CALLER'S ARRAYS HAVE,
+with a float32 accumulator (``_dot``). The two products of two array blocks,
+``S = Q·Kᵀ`` and ``dP = dO·Vᵀ``, go in as they are loaded (bf16 x bf16 is
+exact in float32; only the order of the sum differs from a float32 product),
+and the softmax scale is applied to the float32 SCORES, not to ``q`` (exact at
+every head width; ``q * scale`` in bf16 is only where the scale is a power of
+two). The products with a float32 INTERMEDIATE on one side (``P·V``; ``Pᵀ·dO``,
+``dSᵀ·Q``, ``dS·K``) round that intermediate to the array operand's dtype just
+before the product, as a bf16 model's stated precision does; since ``q`` is
+not pre-scaled, ``dK = scale · Σ dSᵀ·Q`` and ``dQ = scale · Σ dS·K`` take the
+scale on their float32 sums. The online-softmax state (``m``, ``l``, ``acc``),
+the logsumexp, Δ and the dQ/dK/dV accumulators stay float32. The operands'
+dtype alone decides: float32 callers keep float32 products (and, where the
+scale is a power of two, the bits they had). What this buys on the chip is
+the scale's exactness and no upcast a load, NOT passes of the MXU: a float32
+product inside a Mosaic kernel at the default precision already rounds its
+operands to bf16 and makes one pass (v5e, PR 46: the kernels' results and
+times were the same to the last digit either way). The kernels are bound by
+the LATENCY of a block pair's dependent chain (scores, max, exp, P·V: about
+0.4 us a pair whatever its size), so fewer and larger pairs win
+(``_default_bwd_blocks``).
+
+The positional mask is one compare a pair: a row-less-column iota, formed
+once a kernel (``_rel``), against the pair's scalar offset. A masked entry's
+probability needs no second ``where``: ``exp(NEG_INF - m)`` is 0 by itself,
+and a fully masked row (``m`` or the logsumexp read as 0) holds only masked
+entries.
+
+The forward also emits the per-row logsumexp; the backward (standard Δ
+correction, dense scores never materialized) defaults to ONE single-pass
+kernel producing dQ/dK/dV per k-block with dQ accumulated in a
+grid-resident VMEM block — 5 MXU matmuls per (q, k) block pair;
+``bwd="split"`` selects the two-kernel plan (dQ over q-blocks, dK/dV over
+k-blocks; 7 matmuls/pair), and the fused plan
 falls back to it where its resident accumulators do not fit VMEM
 (``_gqa_fused_fits``). Backward block sizes resolve separately from the
 forward's (``DEFAULT_BWD_BLOCKS``).
@@ -57,9 +87,39 @@ NEG_INF = -1e30
 LANES = 8
 
 
-def _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base=0, k_base=0,
-                   window=None, keep=None):
-  """Scaled scores for one (q-block, k-block) pair with causal masking.
+# dot_general contractions of two 2-D blocks: a·b, a·bᵀ, aᵀ·b
+_NN = ((1,), (0,))
+_NT = ((1,), (1,))
+_TN = ((0,), (0,))
+
+
+def _dot(a, b, contract=_NN):
+  """One MXU product at ITS OPERANDS' width with a float32 accumulator: bf16
+  blocks go in as bf16 (a bf16 x bf16 product is exact in float32), float32
+  blocks as float32 (on the TPU one bf16 pass too, at the default precision:
+  module docstring). Two array blocks of different widths meet at the wider;
+  a float32 intermediate (P, dS) is rounded by its CALLER to the array
+  operand's dtype first."""
+  dtype = jnp.promote_types(a.dtype, b.dtype)
+  return lax.dot_general(a.astype(dtype), b.astype(dtype), (contract, ((), ())),
+                         preferred_element_type=jnp.float32)
+
+
+def _rel(blk_q, blk_k):
+  """Row index less column index over one (q-block, k-block) pair: the part
+  of the positional mask that no pair changes, formed ONCE a kernel, outside
+  its block loop."""
+  return (lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+          - lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1))
+
+
+def _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal, q_base=0,
+                   k_base=0, window=None, keep=None):
+  """Scaled scores ``(q·kᵀ) * scale`` (the scale on the float32 scores) for
+  one (q-block, k-block) pair with causal masking. ``rel`` is :func:`_rel`
+  of the pair's shape (None without a causal mask): ``k_pos <= q_pos`` is
+  ``rel >= first k position - first q position``, one compare against a
+  scalar a pair.
 
   ``q_base``/``k_base`` are absolute position offsets (traced scalars are
   fine) so the same kernel works for ring-attention blocks where the KV
@@ -72,17 +132,14 @@ def _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base=0, k_base=0,
   optional operand, a mask BY QUERY shared by the heads) is AND-ed with the
   positional mask.
   """
-  s = q @ k.astype(jnp.float32).T
+  s = _dot(q, k, _NT) * scale
   if keep is not None:
     s = jnp.where(keep.astype(jnp.int32) != 0, s, NEG_INF)
   if causal:
-    q_pos = q_base + qi * blk_q + lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 0)
-    k_pos = k_base + ki * blk_k + lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 1)
-    keep = k_pos <= q_pos
+    ahead = (k_base + ki * blk_k) - (q_base + qi * blk_q)
+    keep = rel >= ahead
     if window is not None:
-      keep = jnp.logical_and(keep, k_pos > q_pos - window)
+      keep = jnp.logical_and(keep, rel < ahead + window)
     s = jnp.where(keep, s, NEG_INF)
   return s
 
@@ -118,13 +175,14 @@ def _window_q_hi(ki, q_base, k_base, blk_q, blk_k, window, n_qblocks):
 
 def _pair_p_ds(s, lse, delta, do, v):
   """Shared backward math for one (q, k) block pair — P recomputed from
-  the forward's logsumexp (fully-masked rows/entries forced to 0), then
-  dP = dO·Vᵀ and dS = P ⊙ (dP − Δ). Used by all three backward kernels
-  (dQ, dK/dV, fused) so a masking/Δ fix lands everywhere at once."""
+  the forward's logsumexp (a masked entry is ``exp(NEG_INF - lse) == 0`` by
+  itself, and in a fully-masked row, where ``lse`` reads as 0, every entry is
+  masked), then dP = dO·Vᵀ and dS = P ⊙ (dP − Δ), both float32. Used by all the backward
+  kernels (dQ, dK/dV, fused, grouped) so a masking/Δ fix lands everywhere at
+  once."""
   lse_safe = jnp.where(lse <= NEG_INF, 0.0, lse)
   p = jnp.exp(s - lse_safe)
-  p = jnp.where(jnp.logical_or(s <= NEG_INF, lse <= NEG_INF), 0.0, p)
-  ds = p * (do @ v.T - delta)
+  ds = p * (_dot(do, v, _NT) - delta)
   return p, ds
 
 
@@ -141,8 +199,9 @@ def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, *rest,
   qi = pl.program_id(1)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  q = q_ref[0].astype(jnp.float32) * scale          # [blk_q, D]
+  q = q_ref[0]                                      # [blk_q, D]
   n_kblocks = kv_len // blk_k
+  rel = _rel(blk_q, blk_k) if causal else None
 
   def body(ki, carry):
     m, l, acc = carry                               # [blk_q,1] ×2, [blk_q,D]
@@ -152,16 +211,15 @@ def _attn_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, *rest,
     v = v_ref[0, pl.ds(ki * blk_k, blk_k), :]
     keep = None if keep_ref is None else keep_ref[
         0, :, pl.ds(pl.multiple_of(ki * blk_k, blk_k), blk_k)]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window, keep)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window, keep)
     m_blk = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m, m_blk)
     m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-    p = jnp.exp(s - m_safe)
-    p = jnp.where(s <= NEG_INF, 0.0, p)
+    p = jnp.exp(s - m_safe)         # a masked entry: exp(NEG_INF - m) == 0
     corr = jnp.where(m <= NEG_INF, 0.0, jnp.exp(m - m_safe))
     l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = acc * corr + p @ v.astype(jnp.float32)
+    acc_new = acc * corr + _dot(p.astype(v.dtype), v)
     return m_new, l_new, acc_new
 
   m0 = jnp.full((blk_q, 1), NEG_INF, jnp.float32)
@@ -187,19 +245,20 @@ def _attn_bwd_dq_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
   qi = pl.program_id(1)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  q = q_ref[0].astype(jnp.float32) * scale
-  do = do_ref[0].astype(jnp.float32)                # [blk_q, D]
+  q = q_ref[0]
+  do = do_ref[0]                                    # [blk_q, D]
   lse = lse_ref[0][:, 0:1]                          # [blk_q, 1]
   delta = delta_ref[0][:, 0:1]                      # [blk_q, 1]
   n_kblocks = kv_len // blk_k
+  rel = _rel(blk_q, blk_k) if causal else None
 
   def body(ki, dq):
     k = k_ref[0, pl.ds(ki * blk_k, blk_k), :]
     v = v_ref[0, pl.ds(ki * blk_k, blk_k), :]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
-    _, ds = _pair_p_ds(s, lse, delta, do, v.astype(jnp.float32))
-    return dq + ds @ k.astype(jnp.float32)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window)
+    _, ds = _pair_p_ds(s, lse, delta, do, v)
+    return dq + _dot(ds.astype(k.dtype), k)
 
   dq0 = jnp.zeros((blk_q, q.shape[-1]), jnp.float32)
   hi = _causal_k_hi(qi, q_base, k_base, blk_q, blk_k, n_kblocks) \
@@ -218,21 +277,22 @@ def _attn_bwd_dkv_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
   ki = pl.program_id(1)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  k = k_ref[0].astype(jnp.float32)                  # [blk_k, D]
-  v = v_ref[0].astype(jnp.float32)
+  k = k_ref[0]                                      # [blk_k, D]
+  v = v_ref[0]
   n_qblocks = q_len // blk_q
+  rel = _rel(blk_q, blk_k) if causal else None
 
   def body(qi, carry):
     dk, dv = carry
-    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32) * scale
-    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
+    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :]
+    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :]
     lse = lse_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
     delta = delta_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window)
     p, ds = _pair_p_ds(s, lse, delta, do, v)
-    dv_new = dv + p.T @ do
-    dk_new = dk + ds.T @ q
+    dv_new = dv + _dot(p.astype(do.dtype), do, _TN)
+    dk_new = dk + _dot(ds.astype(q.dtype), q, _TN)
     return dk_new, dv_new
 
   dk0 = jnp.zeros((blk_k, k.shape[-1]), jnp.float32)
@@ -241,7 +301,7 @@ def _attn_bwd_dkv_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
   hi = _window_q_hi(ki, q_base, k_base, blk_q, blk_k, window, n_qblocks) \
       if window is not None else n_qblocks
   dk, dv = lax.fori_loop(lo, hi, body, (dk0, dv0))
-  dk_ref[0] = dk.astype(dk_ref.dtype)   # q was pre-scaled; dk absorbs it
+  dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
   dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -261,27 +321,30 @@ def _attn_bwd_fused_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   ki = pl.program_id(1)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  k = k_ref[0].astype(jnp.float32)                  # [blk_k, D]
-  v = v_ref[0].astype(jnp.float32)
+  k = k_ref[0]                                      # [blk_k, D]
+  v = v_ref[0]
   n_qblocks = q_len // blk_q
 
   @pl.when(ki == 0)
   def _zero_dq():  # noqa: ANN202 - pallas region
     dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
+  rel = _rel(blk_q, blk_k) if causal else None
+
   def body(qi, carry):
     dk, dv = carry
-    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32) * scale
-    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
+    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :]
+    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :]
     lse = lse_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
     delta = delta_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window)
     p, ds = _pair_p_ds(s, lse, delta, do, v)
-    dv_new = dv + p.T @ do
-    dk_new = dk + ds.T @ q                          # q pre-scaled: absorbs it
+    ds = ds.astype(k.dtype)                 # one rounding serves dK and dQ
+    dv_new = dv + _dot(p.astype(do.dtype), do, _TN)
+    dk_new = dk + _dot(ds, q, _TN)
     prev = dq_ref[0, pl.ds(qi * blk_q, blk_q), :]
-    dq_ref[0, pl.ds(qi * blk_q, blk_q), :] = prev + (ds @ k) * scale
+    dq_ref[0, pl.ds(qi * blk_q, blk_q), :] = prev + _dot(ds, k) * scale
     return dk_new, dv_new
 
   dk0 = jnp.zeros((blk_k, k.shape[-1]), jnp.float32)
@@ -290,7 +353,7 @@ def _attn_bwd_fused_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   hi = _window_q_hi(ki, q_base, k_base, blk_q, blk_k, window, n_qblocks) \
       if window is not None else n_qblocks
   dk, dv = lax.fori_loop(lo, hi, body, (dk0, dv0))
-  dk_ref[0] = dk.astype(dk_ref.dtype)
+  dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
   dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -312,20 +375,22 @@ def _attn_bwd_dkv_gqa_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   qh = pl.program_id(2)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  k = k_ref[0].astype(jnp.float32)                  # [blk_k, D]
-  v = v_ref[0].astype(jnp.float32)
+  k = k_ref[0]                                      # [blk_k, D]
+  v = v_ref[0]
   n_qblocks = q_len // blk_q
+  rel = _rel(blk_q, blk_k) if causal else None
 
   def body(qi, carry):
     dk, dv = carry
-    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32) * scale
-    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
+    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :]
+    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :]
     lse = lse_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
     delta = delta_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window)
     p, ds = _pair_p_ds(s, lse, delta, do, v)
-    return dk + ds.T @ q, dv + p.T @ do
+    return (dk + _dot(ds.astype(q.dtype), q, _TN),
+            dv + _dot(p.astype(do.dtype), do, _TN))
 
   dk0 = jnp.zeros((blk_k, k.shape[-1]), jnp.float32)
   dv0 = jnp.zeros((blk_k, v.shape[-1]), jnp.float32)
@@ -333,6 +398,7 @@ def _attn_bwd_dkv_gqa_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   hi = _window_q_hi(ki, q_base, k_base, blk_q, blk_k, window, n_qblocks) \
       if window is not None else n_qblocks
   dk, dv = lax.fori_loop(lo, hi, body, (dk0, dv0))
+  dk = dk * scale
 
   @pl.when(qh == 0)
   def _assign():  # noqa: ANN202 - pallas region
@@ -363,27 +429,30 @@ def _attn_bwd_fused_gqa_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   ki = pl.program_id(2)
   q_base = qb_ref[0]
   k_base = kb_ref[0]
-  k = k_ref[0].astype(jnp.float32)                  # [blk_k, D]
-  v = v_ref[0].astype(jnp.float32)
+  k = k_ref[0]                                      # [blk_k, D]
+  v = v_ref[0]
   n_qblocks = q_len // blk_q
 
   @pl.when(ki == 0)
   def _zero_dq():  # noqa: ANN202 - pallas region
     dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
+  rel = _rel(blk_q, blk_k) if causal else None
+
   def body(qi, carry):
     dk, dv = carry
-    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32) * scale
-    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :].astype(jnp.float32)
+    q = q_ref[0, pl.ds(qi * blk_q, blk_q), :]
+    do = do_ref[0, pl.ds(qi * blk_q, blk_q), :]
     lse = lse_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
     delta = delta_ref[0, pl.ds(qi * blk_q, blk_q), 0:1]
-    s = _masked_scores(q, k, qi, ki, blk_q, blk_k, causal, q_base, k_base,
-                       window)
+    s = _masked_scores(q, k, scale, rel, qi, ki, blk_q, blk_k, causal,
+                       q_base, k_base, window)
     p, ds = _pair_p_ds(s, lse, delta, do, v)
-    dv_new = dv + p.T @ do
-    dk_new = dk + ds.T @ q                          # q pre-scaled: absorbs it
+    ds = ds.astype(k.dtype)                 # one rounding serves dK and dQ
+    dv_new = dv + _dot(p.astype(do.dtype), do, _TN)
+    dk_new = dk + _dot(ds, q, _TN)
     prev = dq_ref[0, pl.ds(qi * blk_q, blk_q), :]
-    dq_ref[0, pl.ds(qi * blk_q, blk_q), :] = prev + (ds @ k) * scale
+    dq_ref[0, pl.ds(qi * blk_q, blk_q), :] = prev + _dot(ds, k) * scale
     return dk_new, dv_new
 
   dk0 = jnp.zeros((blk_k, k.shape[-1]), jnp.float32)
@@ -392,6 +461,7 @@ def _attn_bwd_fused_gqa_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, do_ref,
   hi = _window_q_hi(ki, q_base, k_base, blk_q, blk_k, window, n_qblocks) \
       if window is not None else n_qblocks
   dk, dv = lax.fori_loop(lo, hi, body, (dk0, dv0))
+  dk = dk * scale
 
   sl = pl.ds(ki * blk_k, blk_k)
 
@@ -559,6 +629,20 @@ def _fwd_impl(q, k, v, q_base, kv_base, causal, blk_q, blk_k, interpret,
 DEFAULT_BWD_BLOCKS = {"fused": (128, 512), "split": (256, 512)}
 
 
+def _default_bwd_blocks(bwd, d):
+  """``DEFAULT_BWD_BLOCKS[bwd]`` at head_dim ``d``. The fused plan's 128
+  rows were swept at head_dim 128, where the slice of the resident float32
+  dQ that a pair read-modify-writes is 64 KB; a narrower head takes the rows
+  that make the same 64 KB, up to 256 (head_dim 64: v5e, 16 x 1024 x 12
+  heads bf16, forward + backward 3.91 -> 3.59 ms a call, where 128 x 256,
+  256 x 256 and 64 x 512 read 4.63, 3.98 and 5.68: a pair costs about 0.4 us
+  whatever its size, so fewer and larger pairs win)."""
+  blk_q, blk_k = DEFAULT_BWD_BLOCKS[bwd]
+  if bwd == "fused":
+    blk_q = min(256, max(blk_q, blk_q * 128 // d))
+  return blk_q, blk_k
+
+
 def _resolve_bwd(bwd):
   """Validate/default the backward mode (block tuning resolves later,
   see DEFAULT_BWD_BLOCKS)."""
@@ -606,7 +690,7 @@ def _bwd_impl(q, k, v, out, lse, g, g_lse, q_base, kv_base, causal, blk_q,
     bwd = "split"   # resident dK/dV would not fit VMEM; split plan wins
   # block defaults resolve AFTER the fallback so a fused→split switch
   # gets split tuning; explicit caller overrides (non-None) are untouched
-  dq_def, dk_def = DEFAULT_BWD_BLOCKS[bwd]
+  dq_def, dk_def = _default_bwd_blocks(bwd, d)
   blk_q = dq_def if blk_q is None else blk_q
   blk_k = dk_def if blk_k is None else blk_k
   blk_q, blk_k = _blocks(s_q, s_kv, blk_q, blk_k)
@@ -777,7 +861,8 @@ def flash_attention(q, k, v, causal: bool = True, blk_q: int = 256,
   attention — consumed unexpanded, see module docstring); seq must
   divide by the (clamped) block sizes. ``bwd``: 'fused' (single-pass
   dQ/dK/dV, the default) or 'split' (two kernels). The backward uses its
-  own block sizes (``DEFAULT_BWD_BLOCKS`` per mode unless overridden).
+  own block sizes (``DEFAULT_BWD_BLOCKS`` per mode, the fused plan's q-block
+  by head_dim: ``_default_bwd_blocks``; unless overridden).
   ``window``
   (requires causal) restricts each query to its last ``window``
   positions (sliding-window attention); the kernels' block loops bound

@@ -376,3 +376,213 @@ class TestSlidingWindow:
       # every visited block must contain at least one unmasked pair
       assert lo * blk_k <= qi * blk_q                      # not past diag
       assert (hi * blk_k) > qi * blk_q - window            # window reaches
+
+
+# --- the kernels multiply at their operands' width (PR 46) -------------------
+#
+# One case a call path. ``_width_case(name, dtype)`` gives ``(fn, args, ref)``:
+# ``fn(*args)`` runs the kernels in interpret mode and returns a tuple of
+# arrays; ``ref(lo)`` is the same result from a dense float32 attention that
+# rounds the probabilities (and dS) to ``lo`` before their products, as the
+# kernels do to a float32 intermediate whose other operand is ``lo`` wide.
+
+_WIDTH_CASES = ("forward", "fused_backward", "split_backward", "gqa_fused",
+                "gqa_split", "keep_operand", "window", "keys_192_values_128",
+                "block_merge")
+
+
+def _dense_rounded(q, k, v, t, lo, causal=True, window=None, keep=None,
+                   scale=None, q_base=0):
+  """``(out, dq, dk, dv)`` of ``sum(t * attention(q, k, v))`` in float32 with
+  the flash kernels' formulas: out = round(e)·v / Σe; P = e / Σe, dP = dO·vᵀ,
+  Δ = Σ dO ⊙ out, dS = P ⊙ (dP − Δ); dV = round(P)ᵀ·dO, dK = scale ·
+  round(dS)ᵀ·q, dQ = scale · round(dS)·k. Grouped K/V are expanded and their
+  gradients summed over each group."""
+  h, hk = q.shape[2], k.shape[2]
+  f32 = lambda x: x.astype(jnp.float32)      # noqa: E731
+  rnd = lambda x: f32(x.astype(lo))          # noqa: E731
+  ke, ve = f32(ra.expand_heads(k, h)), f32(ra.expand_heads(v, h))
+  if scale is None:
+    scale = q.shape[-1] ** -0.5
+  s = jnp.einsum("bqhd,bkhd->bhqk", f32(q), ke) * scale
+  q_pos = q_base + jnp.arange(q.shape[1])[:, None]
+  k_pos = jnp.arange(k.shape[1])[None, :]
+  mask = jnp.ones(s.shape[-2:], bool)
+  if causal:
+    mask = k_pos <= q_pos
+    if window is not None:
+      mask = jnp.logical_and(mask, k_pos > q_pos - window)
+  mask = mask[None, None]
+  if keep is not None:
+    mask = jnp.logical_and(mask, (keep != 0)[:, None])
+  s = jnp.where(mask, s, -1e30)
+  e = jnp.where(mask, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+  total = e.sum(-1, keepdims=True)
+  out = (jnp.einsum("bhqk,bkhd->bqhd", rnd(e), ve)
+         / total[:, :, :, 0].transpose(0, 2, 1)[..., None]).astype(q.dtype)
+  if t is None:
+    return (out,)
+  p = e / total
+  do = f32(t)
+  delta = jnp.sum(do * f32(out), -1).transpose(0, 2, 1)[..., None]
+  ds = p * (jnp.einsum("bqhd,bkhd->bhqk", do, ve) - delta)
+  dv = jnp.einsum("bhqk,bqhd->bkhd", rnd(p), do)
+  dk = jnp.einsum("bhqk,bqhd->bkhd", rnd(ds), f32(q)) * scale
+  dq = jnp.einsum("bhqk,bkhd->bqhd", rnd(ds), ke) * scale
+  b, sk = k.shape[:2]
+  dk, dv = (x.reshape(b, sk, hk, h // hk, -1).sum(3) for x in (dk, dv))
+  return out, dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _width_case(name, dtype):
+  from tensorflowonspark_tpu.ops import flash_attention_block, merge_partials
+  rng = np.random.RandomState(_WIDTH_CASES.index(name))
+  B, S, H, D = 1, 64, 4, 16          # head_dim 16: the scale is a power of two
+  hk = 2 if name.startswith("gqa") or name == "keys_192_values_128" else H
+  d, dv = (192, 128) if name == "keys_192_values_128" else (D, D)
+  q = jnp.asarray(rng.randn(B, S, H, d), dtype)
+  k = jnp.asarray(rng.randn(B, S, hk, d), dtype)
+  v = jnp.asarray(rng.randn(B, S, hk, dv), dtype)
+  t = jnp.asarray(rng.randn(B, S, H, dv), dtype)
+  blocks = dict(blk_q=32, blk_k=32, blk_bwd_q=32, blk_bwd_k=32,
+                interpret=True)
+  # the call, what the dense reference is told of it, and whether the case
+  # takes gradients (a keep operand and heads of two widths are forward only)
+  call, told, grads = flash_attention, {}, True
+  if name == "forward":
+    grads = False
+  elif name in ("fused_backward", "split_backward", "gqa_fused", "gqa_split"):
+    blocks["bwd"] = "fused" if "fused" in name else "split"
+  elif name == "keep_operand":
+    told["keep"] = jnp.asarray(np.logical_or(
+        rng.rand(B, S, S) < 0.5, np.eye(S, dtype=bool)[None]), jnp.int8)
+    grads = False
+  elif name == "window":
+    told["window"] = 24
+  elif name == "keys_192_values_128":
+    # 192 ** -0.5 is no power of two; a latent layer hands the kernel its own
+    # scale, and so does this case
+    told["scale"] = 0.0625
+    call, grads = (lambda q, k, v, **kw: flash_attention_block(
+        q, k, v, 0, 0, **kw)[0]), False
+  else:
+    assert name == "block_merge"
+    half = S // 2
+
+    def call(q, k, v, **kw):
+      parts = [flash_attention_block(q, k[:, lo:lo + half], v[:, lo:lo + half],
+                                     0, lo, **kw) for lo in (0, half)]
+      return merge_partials(*parts[0], *parts[1])[0]
+
+  def fn(q, k, v):
+    if not grads:
+      return (call(q, k, v, **told, **blocks),)
+    out, vjp = jax.vjp(lambda *a: call(*a, **told, **blocks), q, k, v)
+    return (out,) + vjp(t)
+
+  return fn, (q, k, v), lambda lo: _dense_rounded(
+      q, k, v, t if grads else None, lo, **told)
+
+
+def _kernel_dots(fn, args):
+  """Every ``dot_general`` inside a Pallas call of ``fn``'s jaxpr, as its two
+  operands' dtypes."""
+  found = []
+
+  def walk(jaxpr, inside):
+    for eqn in jaxpr.eqns:
+      if inside and eqn.primitive.name == "dot_general":
+        found.append(tuple(x.aval.dtype for x in eqn.invars))
+      here = inside or eqn.primitive.name == "pallas_call"
+      for sub in jax.core.jaxprs_in_params(eqn.params):
+        walk(sub, here)
+  walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+  return found
+
+
+# float32 callers: bits of three entries of every output (flat indices 0,
+# size // 2, -1) and the float64 sum of all of them, as the tree before PR 46
+# gave them in interpret mode
+_WIDTH_PINS = {
+    "forward": [
+        [3204933429, 3204558732, 3160235439, "-0x1.58a8c9c42f980p+6"],
+    ],
+    "fused_backward": [
+        [3206967567, 1035695896, 3178991340, "0x1.54a4a4f1a2600p+6"],
+        [0, 1050001634, 3203485861, "-0x1.379b136141aa0p+1"],
+        [3164599072, 3163528063, 978851348, "0x1.4e52c80000000p-19"],
+        [1053600794, 1035683874, 3130384547, "0x1.3b2fdc8977c00p+4"],
+    ],
+    "split_backward": [
+        [1049189874, 3173625635, 3193237168, "0x1.445589c86d400p+6"],
+        [863965384, 1056596368, 3191434026, "-0x1.1ce52d00f4752p+4"],
+        [1045733472, 1039512236, 973113556, "-0x1.63b5980000000p-19"],
+        [3218444989, 3183933439, 1014629103, "0x1.a1477cad16280p+6"],
+    ],
+    "gqa_fused": [
+        [3197324623, 3182164194, 1047346715, "-0x1.2d070b7181b38p+7"],
+        [863235108, 3192774219, 3178784112, "0x1.416e19aa4f6bap+4"],
+        [1061355868, 1042394318, 3160956620, "-0x1.61c4000000000p-20"],
+        [1066640432, 3166633864, 3163459313, "-0x1.4dc76ea798000p+4"],
+    ],
+    "gqa_split": [
+        [1060658957, 3194364756, 1048785243, "-0x1.2c1fda0973000p+0"],
+        [0, 3173867046, 3192014024, "0x1.c7b8b96ba0000p+3"],
+        [3199318954, 3183321830, 3140257348, "0x1.db1a000000000p-19"],
+        [3229183754, 1054926062, 3133189216, "-0x1.25c440d5e8000p+5"],
+    ],
+    "keep_operand": [
+        [1053495039, 3200813825, 3193748231, "-0x1.18fb7f4280c00p+5"],
+    ],
+    "window": [
+        [3190642396, 3172296145, 3205673114, "-0x1.ed99b55670000p+5"],
+        [0, 3197515316, 1048130542, "0x1.235e82a40add0p+4"],
+        [1058148867, 1032901822, 3173915727, "0x1.a034000000000p-22"],
+        [1070596335, 1047750874, 1034070716, "-0x1.5773041186700p+6"],
+    ],
+    "keys_192_values_128": [
+        [3208043063, 3191495982, 3176944183, "-0x1.a3ca12aa64e60p+5"],
+    ],
+    "block_merge": [
+        [3216741609, 3199479229, 1043899728, "0x1.cab666fa45800p+2"],
+        [0, 3181727969, 1047462980, "-0x1.42bad611c0713p+4"],
+        [1051186834, 3200063286, 3176819537, "-0x1.4c37e00000000p-20"],
+        [3213217936, 3176148752, 984121120, "-0x1.4e9985fdcb400p+5"],
+    ],
+}
+
+
+def _pins(outs):
+  pins = []
+  for x in outs:
+    flat = np.asarray(x, np.float32).ravel()
+    pins.append([int(b) for b in flat[[0, flat.size // 2, -1]].view(np.uint32)]
+                + [float(flat.astype(np.float64).sum()).hex()])
+  return pins
+
+
+@pytest.mark.parametrize("name", _WIDTH_CASES)
+def test_flash_kernels_multiply_at_their_operands_width(name):
+  """bf16 operands go to the MXU as bf16 (no product inside a kernel has a
+  float32 operand) and the result is a dense attention's that rounds P and dS
+  to bf16; float32 callers keep float32 products and the results they had."""
+  fn, args, ref = _width_case(name, jnp.bfloat16)
+  dots = _kernel_dots(fn, args)
+  assert dots and all(dt == jnp.bfloat16 for pair in dots for dt in pair), dots
+  # against the float32 dense attention these gaps need 6e-3; the merge
+  # rounds each partial's output to bf16 once more
+  tol = 7e-3 if name == "block_merge" else 4e-3
+  for got, want in zip(fn(*args), ref(jnp.bfloat16)):
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+  fn, args, ref = _width_case(name, jnp.float32)
+  dots = _kernel_dots(fn, args)
+  assert dots and all(dt == jnp.float32 for pair in dots for dt in pair), dots
+  outs = fn(*args)
+  for got, want in zip(outs, ref(jnp.float32)):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+  assert _pins(outs) == _WIDTH_PINS[name]
